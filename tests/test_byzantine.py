@@ -109,6 +109,9 @@ class TestWireStrictness:
 
 
 def _feed_reader(chunks: bytes):
+    """Call from INSIDE the coroutine that _run drives: StreamReader() binds
+    the running loop, and with none it asks the policy for one — which on
+    Python 3.12 raises once an earlier test on this worker ran asyncio.run."""
     reader = asyncio.StreamReader()
     reader.feed_data(chunks)
     reader.feed_eof()
@@ -130,11 +133,11 @@ class TestReadMessage:
         ))
         bad[258] ^= 1  # body bit flip: header stays valid
         rejects = []
-        reader = _feed_reader(bytes(bad) + good)
 
         async def go():
             return await read_message(
-                reader, 1 << 20, on_reject=rejects.append
+                _feed_reader(bytes(bad) + good), 1 << 20,
+                on_reject=rejects.append,
             )
 
         msg = self._run(go())
@@ -153,11 +156,11 @@ class TestReadMessage:
         h["checksum_hi"] = c >> 64
         good = wire.encode(wire.new_header(wire.Command.ping, cluster=1))
         rejects = []
-        reader = _feed_reader(h.tobytes() + good)
 
         async def go():
             return await read_message(
-                reader, 1 << 20, on_reject=rejects.append
+                _feed_reader(h.tobytes() + good), 1 << 20,
+                on_reject=rejects.append,
             )
 
         msg = self._run(go())

@@ -456,3 +456,11 @@ class TestVoprSharded:
 
         result = run_seed(42, workdir=str(tmp_path), ticks=3_000)
         assert result.exit_code == EXIT_PASSED
+
+
+def test_more_shards_than_devices_is_an_error():
+    """Asked-for shards that cannot be had must not silently serve
+    single-device (`start --shards N` exits non-zero on the same check)."""
+    shards = 1 << len(jax.devices()).bit_length()  # > device count
+    with pytest.raises(RuntimeError, match=r"device\(s\) visible"):
+        TpuStateMachine(small_cfg(), batch_lanes=LANES, shards=shards)

@@ -1,0 +1,139 @@
+"""Compile the commit path's programs for a DESCRIBED TPU v5e (no chip).
+
+The chip's compiler is installed here and compiles for a topology that is
+described, not attached: what it refuses here costs no chip time.  Nothing
+runs, so this says nothing about results or speed.  This is the only test
+file that describes the chip; the topology, shardings and shapes are built
+in fixtures/tests (never at import — one worker may load libtpu), and the
+persistent compile cache is off around the compiles (an entry compiled for a
+described chip cannot be read back without one).
+
+8192 lanes as served; small tables (compile time and legality do not depend
+on the table size — the real-size memory analysis is a by-hand rehearsal,
+CHANGES.md PR 22)."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from tigerbeetle_tpu import machine, types
+from tigerbeetle_tpu.ops import state_machine as sm
+from tigerbeetle_tpu.ops import transfer_full as tf
+from tigerbeetle_tpu.parallel import sharded
+
+LANES = 8192
+ACC, TR, POSTED, HIST = 1 << 16, 1 << 18, 1 << 12, 1 << 12
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _soa(dtype, sharding, lead=()):
+    cols = types.to_soa(np.zeros(1, dtype=dtype))
+    return {
+        k: jax.ShapeDtypeStruct(lead + (LANES,), v.dtype, sharding=sharding)
+        for k, v in cols.items()
+    }
+
+
+def _on(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _one_chip_lowerings(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    led = _on(jax.eval_shape(lambda: sm.make_ledger(ACC, TR, POSTED, HIST)),
+              one)
+    u64 = jax.ShapeDtypeStruct((), jnp.uint64, sharding=one)
+    batch = _soa(types.TRANSFER_DTYPE, one)
+    k = machine.TpuStateMachine.GROUP_K
+    kvec = jax.ShapeDtypeStruct((k,), jnp.uint64, sharding=one)
+
+    def full(has_postvoid):
+        # static_trip=True is the lax.scan form of the Jacobi loop, which
+        # the kernel picks by itself only on a TPU backend.
+        return lambda: tf.create_transfers_full.lower(
+            led, batch, u64, u64, None, None, max_passes=8,
+            has_postvoid=has_postvoid, has_history=False, static_trip=True,
+            use_waves=True,
+        )
+
+    return {
+        "fast": lambda: sm.create_transfers_fast.jitted.lower(
+            led, batch, u64, u64),
+        "grouped": lambda: machine._group_fast_dispatch.lower(
+            led, _soa(types.TRANSFER_DTYPE, one, lead=(k,)), kvec, kvec),
+        "full_scan_plain": full(False),
+        "full_scan_postvoid": full(True),
+    }
+
+
+def _sharded_fast_lowering(topo):
+    mesh = Mesh(np.array(topo.devices[:4]), (sharded.AXIS,))
+    shard = NamedSharding(mesh, P(sharded.AXIS))
+    repl = NamedSharding(mesh, P())
+    led = jax.eval_shape(lambda: sm.make_ledger(ACC, TR, POSTED, HIST))
+
+    def table(t):
+        # make_sharded_ledger's layout: rows sharded, per-shard counters.
+        return _on(dataclasses.replace(
+            t, count=jax.ShapeDtypeStruct((4,), np.uint64),
+            probe_overflow=jax.ShapeDtypeStruct((4,), np.bool_),
+        ), shard)
+
+    led = sm.Ledger(
+        accounts=table(led.accounts), transfers=table(led.transfers),
+        posted=table(led.posted), history=_on(led.history, repl),
+    )
+    u64 = jax.ShapeDtypeStruct((), jnp.uint64, sharding=repl)
+    step = sharded.sharded_create_transfers(mesh, probed=True)
+    return step.lower(led, _soa(types.TRANSFER_DTYPE, repl), u64, u64)
+
+
+@pytest.mark.parametrize("program", [
+    "fast", "grouped", "full_scan_plain", "full_scan_postvoid",
+    "sharded_fast_4",
+])
+def test_compiles_for_v5e(topo, no_persistent_cache, program):
+    if program == "sharded_fast_4":
+        lowered = _sharded_fast_lowering(topo)
+    else:
+        lowered = _one_chip_lowerings(topo)[program]()
+    compiled = lowered.compile()  # raises what the chip's compiler raises
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 0
+    if program == "sharded_fast_4":
+        # The cross-shard context exchange is a psum: the compiler must
+        # have put an all-reduce in.
+        assert "all-reduce" in compiled.as_text()

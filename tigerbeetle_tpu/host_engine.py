@@ -1,8 +1,8 @@
 """Host data-plane bridge: numpy-backed ledger + native engine dispatch.
 
-The solo-server OLTP hot path runs here when the deployment's accelerator is
-remote (per-batch round trips through the tunnel are latency-prohibitive) or
-absent (XLA-CPU's gather/scatter throughput is ~30x off native).  The native
+The solo-server OLTP hot path runs here when the per-batch device round trip
+is latency-prohibitive or the accelerator is absent (XLA-CPU's gather/scatter
+throughput is ~30x off native).  The native
 engine (native/engine.cpp) is a sequential, exact port of the scalar oracle
 (testing/model.py — the same semantics the device kernels are differentially
 tested against).

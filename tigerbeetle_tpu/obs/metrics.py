@@ -8,7 +8,7 @@ layer (vsr, net, ops, sim) records into ONE process-global table of named
 series, and three sinks read it:
 
 - a JSON snapshot (``TB_METRICS_PATH`` env / ``--metrics-json`` flags) for
-  bench artifacts and tools/devhub.py;
+  bench artifacts and chip_smoke.py's server report;
 - the StatsD bridge (``flush_statsd``), so the existing UDP path keeps
   carrying the new series;
 - direct inspection from tests (deterministic bucket layout).

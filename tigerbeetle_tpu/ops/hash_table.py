@@ -244,7 +244,14 @@ def claim_slots(
     # the wide phase exited on overflow, in which case the narrow cond is
     # already false and the truncation is inert).  Fill lanes carry index
     # n: inactive in the narrow body, dropped by its scatters.
-    idx = jnp.nonzero(unplaced, size=window, fill_value=n)[0]
+    # (jnp.nonzero(size=window, fill_value=n) by hand, in int32: under x64
+    # jnp.nonzero cumsums in int64, which a TPU emulates as a u32-pair
+    # reduce-window whose scoped-vmem stack the v5e compiler cannot place
+    # inside the grouped dispatch's scan — RESOURCE_EXHAUSTED at compile.)
+    pos_u = jnp.cumsum(unplaced.astype(jnp.int32)) - 1
+    idx = jnp.full((window,), n, jnp.int32).at[
+        jnp.where(unplaced & (pos_u < window), pos_u, window)
+    ].set(jnp.arange(n, dtype=jnp.int32), mode="drop")
     active = idx < n
     idx_safe = jnp.where(active, idx, 0)
     home_w = home[idx_safe]

@@ -47,7 +47,14 @@ def _sentinel_level(capacity: int) -> Dict[str, jax.Array]:
 
 
 def _sort_level(lvl: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-    order = jnp.lexsort((lvl["ts"], lvl["acct_lo"], lvl["acct_hi"]))
+    """Order by (acct_hi, acct_lo, ts) — jnp.lexsort's order, as three
+    stable single-key passes: one three-key u64 sort (six emulated u32
+    compares per comparison on a TPU) takes the v5e compiler 43 s at 16 K
+    rows and grows with the level, which a client pays inside its request
+    the first time a level fills; the single-key form compiles in seconds."""
+    order = jnp.argsort(lvl["ts"], stable=True)
+    order = order[jnp.argsort(lvl["acct_lo"][order], stable=True)]
+    order = order[jnp.argsort(lvl["acct_hi"][order], stable=True)]
     return {name: lvl[name][order] for name in COLS}
 
 

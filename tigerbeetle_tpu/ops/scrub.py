@@ -65,7 +65,7 @@ class SimulatedDeviceFault(RuntimeError):
 
     Raised from the dispatch funnels when machine.inject_device_faults
     armed one — stands in for the XlaRuntimeError family a real failed
-    dispatch, lost device, or dead tunnel raises."""
+    dispatch or lost device raises."""
 
 
 class DeviceStateUnrecoverable(RuntimeError):

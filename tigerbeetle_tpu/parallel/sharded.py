@@ -139,6 +139,19 @@ def _replicated_like(tree):
     return jax.tree_util.tree_map(lambda _: P(), tree)
 
 
+def _psum_one_owner(x):
+    """psum of a value that at most ONE shard holds non-zero (every other
+    shard contributes 0).  The TPU lowers only plain 32-bit Sum all-reduces
+    — a u64 add is an emulated u32 pair with a carry, UNIMPLEMENTED at
+    compile — and with a single owner the two u32 halves sum without
+    carries, so splitting is exact."""
+    if x.dtype != jnp.uint64:
+        return jax.lax.psum(x, AXIS)
+    lo = jax.lax.psum(x.astype(jnp.uint32), AXIS)
+    hi = jax.lax.psum((x >> jnp.uint64(32)).astype(jnp.uint32), AXIS)
+    return lo.astype(jnp.uint64) | (hi.astype(jnp.uint64) << jnp.uint64(32))
+
+
 class _ShardGather:
     """Per-shard masked probe + psum combine for one key set."""
 
@@ -155,13 +168,13 @@ class _ShardGather:
         self.found = (
             jax.lax.psum(self.found_l.astype(jnp.uint32), AXIS) > 0
         )
-        self.gslot = jax.lax.psum(
-            jnp.where(self.found_l, gslot, jnp.uint64(0)), AXIS
+        self.gslot = _psum_one_owner(
+            jnp.where(self.found_l, gslot, jnp.uint64(0))
         )
 
     def rows(self, table: ht.Table) -> Dict[str, jax.Array]:
         local = ht.gather_cols(table, self.slot_l, self.found_l)
-        return {k: jax.lax.psum(v, AXIS) for k, v in local.items()}
+        return {k: _psum_one_owner(v) for k, v in local.items()}
 
 
 def sharded_create_transfers(mesh: Mesh, probed: bool = False):

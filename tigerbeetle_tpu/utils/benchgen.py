@@ -1,7 +1,7 @@
 """Shared in-jit batch generators for the measurement tools.
 
-tools/kernel_bisect.py (device cost forensics) and tools/copyhound.py
-(compiled-HLO copy audit) must lower THE SAME program: a batch derived
+tools/copyhound.py (compiled-HLO copy audit) and any device cost forensics
+must lower THE SAME program: a batch derived
 inside jit from the batch index, in the flagship bench's workload shape.
 Two hand-rolled copies drifted within a day of each other (different
 amount formulas, post lanes keeping ledger/code); one definition cannot.

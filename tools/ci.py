@@ -499,7 +499,7 @@ def main() -> None:
         "total_seconds": round(sum(r["seconds"] for r in results), 1),
         "green": failed == 0,
         # A --tier/--fast run only proves its own tiers; consumers
-        # (tools/devhub.py) must not read a partial green as full-matrix.
+        # of CI_LAST.json must not read a partial green as full-matrix.
         "partial": tiers != ORDER,
         "iso": time.strftime("%Y-%m-%dT%H:%M:%S"),
     }

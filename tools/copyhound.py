@@ -76,7 +76,7 @@ def main() -> None:
     from tigerbeetle_tpu import jaxenv
 
     jaxenv.enable_compile_cache()
-    platform = jaxenv.ensure_backend(retry_tpu=False)
+    platform = jaxenv.backend_info()[0]
     print(f"# platform={platform}", file=sys.stderr)
 
     import jax

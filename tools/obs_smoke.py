@@ -13,8 +13,8 @@ Three probes, each asserting the ARTIFACT (not just the exit code):
    histogram) and a parseable merged host+device Chrome trace containing
    the bench spans.
 
-Artifacts land at the repo root: METRICS.json (the serving snapshot, which
-tools/devhub.py renders) and OBS_SMOKE.json (the summary; the obs tier in
+Artifacts land at the repo root: METRICS.json (the serving snapshot) and
+OBS_SMOKE.json (the summary; the obs tier in
 tools/ci.py records pass/fail in CI_LAST.json).
 
 Usage: python tools/obs_smoke.py

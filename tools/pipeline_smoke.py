@@ -14,7 +14,7 @@ ARTIFACTS, not just the exit code:
    and the ``pipeline.inflight`` histogram), so BENCH_r06+ can read the
    overlap forensics the same way docs/commit_pipeline.md describes.
 3. the primary JSON line carries the sweep (``reps.pipeline_sweep``) and
-   the ``pipeline`` block with both real and rtt-emulated speedups.
+   the ``pipeline`` block with the depth sweep.
 
 Artifacts land at the repo root: METRICS.json (shared with the obs tier's
 snapshot path — this run overwrites it with fresh series) and
@@ -67,23 +67,15 @@ def main() -> int:
     assert d1["digest"] == d2["digest"], (
         "ledger digests diverge between depth 1 and depth 2"
     )
-    rtt1 = d1.get("rtt_emulated") or {}
-    rtt2 = d2.get("rtt_emulated") or {}
-    assert rtt1.get("replies_sha") == rtt2.get("replies_sha"), (
-        "rtt-emulated reply bodies diverge"
-    )
     summary["identity"] = {
         "replies_sha": d1["replies_sha"], "digest": d1["digest"],
         "depth1_tx_s": d1["tx_s"], "depth2_tx_s": d2["tx_s"],
-        "rtt15_depth1_tx_s": rtt1.get("tx_s"),
-        "rtt15_depth2_tx_s": rtt2.get("tx_s"),
     }
 
     # 2. the pipeline block rides the primary line.
     pipe = payload.get("pipeline") or {}
     assert "depth" in pipe and "sweep" in pipe, pipe
     summary["speedup_vs_depth1"] = pipe.get("speedup_vs_depth1")
-    summary["rtt15_speedup_vs_depth1"] = pipe.get("rtt15_speedup_vs_depth1")
 
     # 3. occupancy/stall counters in METRICS.json.
     with open(metrics_path) as f:

@@ -32,8 +32,6 @@ def main() -> None:
     jaxenv.enable_compile_cache()
     if args.force_cpu:
         jaxenv.force_cpu()
-    else:
-        jaxenv.ensure_backend(retry_tpu=False)
     import jax
 
     from tigerbeetle_tpu.sim import vopr_tpu
