@@ -799,7 +799,7 @@ def run_pipeline_bench(depth, n_groups=32, n_clients=4, count=2048,
         ClusterConfig, LedgerConfig, ProcessConfig,
     )
     from tigerbeetle_tpu.obs.metrics import registry as _obs
-    from tigerbeetle_tpu.obs.txtrace import txtrace
+    from tigerbeetle_tpu.obs.txtrace import NESTED_STAGES, txtrace
     from tigerbeetle_tpu.vsr import wire
     from tigerbeetle_tpu.vsr.replica import Replica
 
@@ -1052,12 +1052,16 @@ def run_pipeline_bench(depth, n_groups=32, n_clients=4, count=2048,
         if attribution:
             # Per-commit-batch stage ledger (docs/tracing.md): one batch =
             # one request's dispatch; the timed loop ran n_groups groups of
-            # n_clients requests.  `coverage` is sum(stage us) / wall —
-            # ~1.0 at depth 1 (serial path), legitimately ABOVE 1.0 at
-            # depth > 1 where overlapped stages (fsync under compute,
-            # lane-idle dispatch_wait) each bill their own wall clock.
+            # n_clients requests.  `coverage` is sum(stage us) / wall over
+            # the stages that are not nested in another's block — ~1.0 at
+            # depth 1 (serial path), legitimately ABOVE 1.0 at depth > 1
+            # where overlapped stages (fsync under compute, lane-idle
+            # dispatch_wait) each bill their own wall clock.
             batches = n_groups * n_clients
-            attributed_us = sum(v["us"] for v in stage_totals.values())
+            attributed_us = sum(
+                v["us"] for k, v in stage_totals.items()
+                if k not in NESTED_STAGES
+            )
             measured_us = elapsed * 1e6
             out["attribution"] = {
                 "stages_us_per_batch": {

@@ -1,0 +1,111 @@
+"""Stable device names: the `tb/<phase>` scopes of the programs the accepted
+cell's profiler window shows (the fast kernel, the grouped scan, the index).
+
+An operation's `op_name` in a device trace carries the `jax.named_scope` it
+was traced under, so the scopes must be in each program's lowered text; and
+they are metadata only, so the programs still answer as `testing/model.py`
+does.  The general kernel and the lookups carry none yet: their scopes come
+with the cell whose window reaches them."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from test_pipeline import LANES, batch, make_machine, make_model
+from tigerbeetle_tpu import machine, types
+from tigerbeetle_tpu.ops import index
+from tigerbeetle_tpu.ops import state_machine as sm
+from tigerbeetle_tpu.testing import model as M
+
+
+def _shapes(tree):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+
+def _soa(lead=()):
+    cols = types.to_soa(np.zeros(1, dtype=types.TRANSFER_DTYPE))
+    return {k: jax.ShapeDtypeStruct(lead + (LANES,), v.dtype)
+            for k, v in cols.items()}
+
+
+def _lowered(program):
+    led = jax.eval_shape(lambda: sm.make_ledger(1 << 10, 1 << 12, 1 << 10,
+                                                1 << 10))
+    u64 = jax.ShapeDtypeStruct((), jnp.uint64)
+    ids = jax.ShapeDtypeStruct((LANES,), jnp.uint64)
+    ok = jax.ShapeDtypeStruct((LANES,), jnp.bool_)
+    k = machine.TpuStateMachine.GROUP_K
+    kvec = jax.ShapeDtypeStruct((k,), jnp.uint64)
+    if program == "fast":
+        return sm.create_transfers_fast_probed.jitted.lower(
+            led, _soa(), u64, u64)
+    if program == "grouped":
+        return machine._group_fast_dispatch.lower(
+            led, _soa(lead=(k,)), kvec, kvec)
+    if program == "index_build":
+        return index.build_runs.lower(led, ids, ids, ok)
+    assert program == "index_merge"
+    level = _shapes(index._sentinel_level(LANES))
+    return index._merge_jit.lower([level, level])
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("fast", ("tb/probe", "tb/validate", "tb/balance", "tb/insert")),
+    ("grouped", ("tb/group_step", "tb/probe", "tb/validate", "tb/balance",
+                 "tb/insert")),
+    ("index_build", ("tb/index_probe", "tb/index_sort")),
+    ("index_merge", ("tb/index_merge",)),
+])
+def test_scopes_are_in_the_lowered_text(program, scopes):
+    text = _lowered(program).as_text(debug_info=True)
+    for scope in scopes:
+        assert scope in text, f"{program}: no {scope} in the lowered text"
+
+
+def test_scoped_programs_answer_as_the_model_does():
+    """Fast, grouped and general commits, both lookups and the index query
+    on one small ledger, beside the scalar oracle."""
+    m = make_machine()
+    m.group_device_commit = True
+    ref = make_model()
+
+    def both(b):
+        got = m.create_transfers(b, wall_clock_ns=0)
+        want = ref.create_transfers([M.transfer_from_row(r) for r in b])
+        assert got == want
+        return got
+
+    both(batch(1000, 20))                       # the fast kernel
+    run = [batch(2000, 9), batch(3000, 12), batch(1000, 20)]
+    timestamps = [m.prepare("create_transfers", len(b), 0) for b in run]
+    got = m.commit_group_fast(run, timestamps)  # the grouped scan
+    assert got is not None
+    for b, res in zip(run, got):
+        assert res == ref.create_transfers(
+            [M.transfer_from_row(r) for r in b])
+    pending = batch(4000, 10, flags=int(types.TransferFlags.PENDING))
+    both(pending)                               # the general kernel
+    post = types.transfers_array([
+        types.transfer(id=5000 + i, pending_id=4000 + i, ledger=1, code=10,
+                       flags=int(types.TransferFlags.POST_PENDING_TRANSFER))
+        for i in range(6)
+    ])
+    both(post)
+    assert m.balances_snapshot() == ref.balances_snapshot()
+
+    ids = [1000, 2003, 4001, 5002, 77]
+    rows = m.lookup_transfers(ids)
+    want = ref.lookup_transfers(ids)
+    assert [(int(r["id_lo"]), int(r["amount_lo"]), int(r["timestamp"]))
+            for r in rows] == [(t.id, t.amount, t.timestamp) for t in want]
+    accounts = m.lookup_accounts([1, 2, 99])
+    assert [(int(a["id_lo"]), int(a["debits_posted_lo"]),
+             int(a["credits_pending_lo"])) for a in accounts] == [
+        (a.id, a.debits_posted, a.credits_pending)
+        for a in ref.lookup_accounts([1, 2, 99])]
+    f = np.zeros(1, dtype=types.ACCOUNT_FILTER_DTYPE)[0]
+    f["account_id_lo"], f["limit"], f["flags"] = 1, 100, 3
+    assert [int(r["id_lo"]) for r in m.get_account_transfers(f)] == [
+        t.id for t in ref.get_account_transfers(1, 0, 0, 100, 3)]

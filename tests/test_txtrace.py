@@ -17,6 +17,7 @@ from tigerbeetle_tpu import types
 from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
 from tigerbeetle_tpu.obs.txtrace import (
+    NESTED_STAGES,
     REPLICA_PID_BASE,
     STAGES,
     Blackbox,
@@ -138,13 +139,60 @@ def test_stage_ledger_reconciles_against_wall():
     assert set(totals) <= set(STAGES)
 
 
-def test_stage_free_when_inactive():
+@pytest.mark.parametrize("site", [
+    dict(name="device_execute"),
+    dict(name="prepare", n=3),
+    dict(name="readback", seq=9),
+    dict(name="reply_release", seq=4, n=8),
+    dict(name=None),
+])
+def test_stage_free_when_inactive(monkeypatch, site):
+    """Inactive, every site gets the SAME no-op, whatever it passes: no
+    clock read, nothing allocated, no annotation opened."""
+    from tigerbeetle_tpu.obs import txtrace as txtrace_mod
+
     assert not txtrace.active
-    with txtrace.stage("device_execute"):
-        pass
+    reads = []
+
+    class Clock:
+        def __getattr__(self, name):
+            reads.append(name)
+            return getattr(time, name)
+
+    monkeypatch.setattr(txtrace_mod, "time", Clock())
+    monkeypatch.setattr(
+        txtrace_mod, "_StageSpan",
+        lambda *a: pytest.fail("an inactive stage built a span"))
+    off = txtrace.stage(**site)
+    assert off is txtrace.stage("grow") is txtrace_mod._STAGE_OFF
+    with off:
+        with txtrace.stage(**site):  # re-entrant
+            pass
+    assert reads == []
+    monkeypatch.undo()
     txtrace.stage_observe("readback", 123.0)  # guard is the CALLER's job
     with txtrace.attribution_scope() as t:  # reset=True clears any residue
         assert t.stage_totals() == {}
+        # stage(None): a site with no stage to bill stays free when active.
+        assert txtrace.stage(None) is off
+
+
+def test_stage_nests_and_bills_each_name_once():
+    with txtrace.attribution_scope():
+        with txtrace.stage("device_execute", seq=3):
+            with txtrace.stage("grow", seq=3):
+                time.sleep(0.002)
+            with txtrace.stage("dispatch", seq=3, n=2):
+                time.sleep(0.002)
+        totals = txtrace.stage_totals()
+    assert {k: v["count"] for k, v in totals.items()} == {
+        "device_execute": 1, "grow": 1, "dispatch": 1}
+    assert totals["device_execute"]["us"] >= (
+        totals["grow"]["us"] + totals["dispatch"]["us"])
+    assert set(totals) <= set(STAGES)
+    # A sum that wants wall time skips the nested names.
+    assert sum(v["us"] for k, v in totals.items()
+               if k not in NESTED_STAGES) == totals["device_execute"]["us"]
 
 
 def test_machine_commit_bills_device_execute():

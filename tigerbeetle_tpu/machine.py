@@ -94,7 +94,8 @@ def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
 
     def step(led, xs):
         soa, cnt, ts = xs
-        led, codes = sm.create_transfers_impl(led, soa, cnt, ts)
+        with jax.named_scope("tb/group_step"):
+            led, codes = sm.create_transfers_impl(led, soa, cnt, ts)
         return led, codes
 
     ledger, codes = jax.lax.scan(step, ledger, (stacked, counts, timestamps))
@@ -160,7 +161,7 @@ class DeviceCommitHandle:
 
     __slots__ = ("_machine", "_result", "_stacked", "_counts",
                  "_timestamps", "_stage", "_resolved", "join_wait_s",
-                 "_batches", "_recovered", "_deferred")
+                 "_batches", "_recovered", "_deferred", "_seq")
 
     def __init__(self, machine, result, counts, timestamps,
                  stacked: bool, stage=None, batches=None,
@@ -173,6 +174,9 @@ class DeviceCommitHandle:
         self._stage = stage          # staging buffer set to release on resolve
         self._resolved = False
         self._deferred = deferred    # counted in the machine's in-flight depth
+        # The bus's group this run belongs to: resolve() runs inside a
+        # LATER group's call, and its spans name this one.
+        self._seq = txtrace.group_seq
         self.join_wait_s = 0.0
         # Host-side copies of the dispatched batches: the device fault
         # domain re-dispatches a quarantined run from these after a failed
@@ -220,24 +224,14 @@ class DeviceCommitHandle:
         try:
             if hasattr(self._result, "result"):
                 t0 = _time.perf_counter()
-                self._result = self._result.result()
+                # The join of the lane closure: the serving thread waits
+                # for the lane thread (its queue and its closure's run).
+                with txtrace.stage("dispatch_wait", seq=self._seq):
+                    self._result = self._result.result()
                 self.join_wait_s = _time.perf_counter() - t0
-                if txtrace.active:
-                    # FIFO lane queue time — pipeline idle, not commit work.
-                    txtrace.stage_observe(
-                        "dispatch_wait", self.join_wait_s * 1e6
-                    )
-                if _obs.enabled:
-                    _obs.histogram(
-                        "pipeline.resolve_wait_us", "us"
-                    ).observe(self.join_wait_s * 1e6)
-                    if m.shards:
-                        _obs.histogram(
-                            "pipeline.shard.resolve_wait_us", "us"
-                        ).observe(self.join_wait_s * 1e6)
             codes_dev, overflow_dev = self._result
             codes, overflow = m._d2h_codes(codes_dev, overflow_dev,
-                                           stage="readback")
+                                           stage="readback", seq=self._seq)
         except DEVICE_FAULT_TYPES as err:
             # Dispatch-lane funnel: the dispatch (or its readback) failed —
             # quarantine the in-flight pipeline and re-dispatch every
@@ -585,10 +579,16 @@ class TpuStateMachine:
             self._bloom_np = np.zeros(((1 << self._bloom_log2) // 32,), np.uint32)
             self._bloom_dev = make_bloom(self._bloom_log2)
 
-    def _d2h_codes(self, codes, overflow=None, stage=None):
+    def _d2h_codes(self, codes, overflow=None, stage=None, seq=0):
         """The blocking device->host read of a commit's result codes: the
         ONE point every device dispatch funnels through.  Timed so the e2e
         bench can decompose wall time into device-wait vs host work.
+
+        ``stage`` names the txtrace stage the read bills to (``seq``: its
+        group): only EXPLICITLY staged readbacks bill — the deferred
+        resolve passes "readback"; the default funnel already sits inside
+        a ``device_execute`` block (commit_batch / the lane closures), and
+        billing its wait again would double-count the barrier.
 
         ``overflow`` (the table's probe_overflow flag) rides the SAME
         device_get, so the per-batch/per-group overflow check costs zero
@@ -600,20 +600,14 @@ class TpuStateMachine:
         bench's dispatch accounting reads exactly this method)."""
         self._injected_fault_check()
         t0 = _time.perf_counter()
-        if overflow is None:
-            out = jax.device_get(codes)
-        else:
-            out, overflow = jax.device_get((codes, overflow))
+        with txtrace.stage(stage, seq=seq):
+            if overflow is None:
+                out = jax.device_get(codes)
+            else:
+                out, overflow = jax.device_get((codes, overflow))
         wait = _time.perf_counter() - t0
         self.disp_wait_s += wait
         self.disp_count += 1
-        if stage is not None and txtrace.active:
-            # Attribution ledger: only EXPLICITLY staged readbacks bill
-            # (the deferred resolve passes stage="readback").  The default
-            # funnel is already inside a device_execute stage block
-            # (commit_batch / the lane closures) — billing its wait again
-            # would double-count the barrier.
-            txtrace.stage_observe(stage, wait * 1e6)
         if _obs.enabled:
             _obs.counter("ops.dispatch").inc()
             _obs.histogram("ops.dispatch_wait_us", "us").observe(wait * 1e6)
@@ -2924,10 +2918,12 @@ class TpuStateMachine:
         handle alone overlaps nothing — the lane restores the async-
         dispatch property: device execute happens GIL-free on this thread
         while the serving thread journals, stages the next upload, and
-        builds replies.  On async backends (TPU) the submit returns as
-        soon as the dispatch is enqueued, so the lane adds one cheap hop.
-        ONE worker == dispatch order == op order; growth rides each
-        closure so the ledger chain never interleaves."""
+        builds replies.  On a TPU the jitted commit call returns once it is
+        enqueued (2-3 ms), but the closure then sits in its index appends
+        until the device has run the commit (_lane_dispatch): the lane is
+        not "one cheap hop", it is where the device wait shows.  ONE worker
+        == dispatch order == op order; growth rides each closure so the
+        ledger chain never interleaves."""
         if self._lane is None:
             import concurrent.futures
 
@@ -2936,13 +2932,20 @@ class TpuStateMachine:
             )
         return self._lane
 
-    def _lane_dispatch(self, dispatch, deferred):
+    def _lane_dispatch(self, dispatch, deferred, seq=0):
         """Run (deferred=False) or submit (deferred=True) a commit closure,
-        timed as the ``device_execute`` attribution stage: on XLA-CPU the
-        jitted calls compute synchronously inside the closure, on an async
-        backend the closure is the enqueue and the deferred resolve's
-        ``readback`` stage carries the completion wait.  The lane thread's
-        stage observations land in the same process-global ledger."""
+        timed as the ``device_execute`` stage on the thread that runs it,
+        with ``grow`` / ``dispatch`` / ``index_append`` as its children.
+        On XLA-CPU the jitted calls may compute synchronously inside the
+        closure.  On a TPU only the ``dispatch`` child is an enqueue
+        (1.9 ms mean); ``index_append`` then holds the thread until the
+        device has run the commit and the appends queued behind it (all
+        but 2 ms of a grouped closure's 1.1 s, and so the same in the
+        serving thread's join, ``dispatch_wait``; ``readback`` is left
+        with under 1 ms after a grouped closure — one TPU v5 lite, PR 26,
+        PERF.md section 5).  The lane thread's observations land in the
+        same process-global ledger.  ``seq``: the submitting group's (the
+        lane runs later)."""
         if not txtrace.active:
             return (
                 self._dispatch_lane().submit(dispatch) if deferred
@@ -2950,7 +2953,7 @@ class TpuStateMachine:
             )
 
         def staged():
-            with txtrace.stage("device_execute"):
+            with txtrace.stage("device_execute", seq=seq):
                 return dispatch()
 
         return (
@@ -3070,14 +3073,20 @@ class TpuStateMachine:
                 batches, timestamps, counts, deferred
             )
         k = len(batches)
-        stacked, stage = self._stage_group(batches)
-        cnt = jnp.asarray(
-            counts + [0] * (self.GROUP_K - k), dtype=jnp.uint64
-        )
-        tss = jnp.asarray(
-            timestamps + [timestamps[-1]] * (self.GROUP_K - k),
-            dtype=jnp.uint64,
-        )
+        if _obs.enabled:
+            # Useful steps over steps run: the scan always runs GROUP_K.
+            _obs.counter("ops.group.batches").inc(k)
+            _obs.counter("ops.group.steps").inc(self.GROUP_K)
+        seq = txtrace.group_seq  # for the closure's spans on the lane
+        with txtrace.stage("stage_h2d", n=k):
+            stacked, stage = self._stage_group(batches)
+            cnt = jnp.asarray(
+                counts + [0] * (self.GROUP_K - k), dtype=jnp.uint64
+            )
+            tss = jnp.asarray(
+                timestamps + [timestamps[-1]] * (self.GROUP_K - k),
+                dtype=jnp.uint64,
+            )
         # Host row bounds advance at SUBMIT (not readback): the next
         # group's growth decision must see this group's inserts coming,
         # and the closure's growth target is snapshotted HERE so it never
@@ -3095,18 +3104,21 @@ class TpuStateMachine:
             # Growth + dispatch + index maintenance stay ONE unit so the
             # FIFO lane preserves the ledger chain (the appends need THIS
             # ledger live).
-            self._grow_if_needed(transfers_need=need)
+            with txtrace.stage("grow", seq=seq):
+                self._grow_if_needed(transfers_need=need)
             # The ONE-worker FIFO lane orders every ledger write, and the
             # serving thread reads self.ledger only after resolve()'s join
             # (or lane.shutdown(wait=True) in reset paths).
-            (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
-             id_lo, id_hi) = _group_fast_dispatch(
-                self.ledger, stacked, cnt, tss
-            )
-            for j in range(k):
-                self._index_append_device(
-                    id_lo[j], id_hi[j], codes[j], counts[j],
+            with txtrace.stage("dispatch", seq=seq, n=k):
+                (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
+                 id_lo, id_hi) = _group_fast_dispatch(
+                    self.ledger, stacked, cnt, tss
                 )
+            with txtrace.stage("index_append", seq=seq, n=k):
+                for j in range(k):
+                    self._index_append_device(
+                        id_lo[j], id_hi[j], codes[j], counts[j],
+                    )
             if merkle_closure:
                 # Commitment updates ride the ledger chain on the lane,
                 # PER BATCH: one key-size class per workload shape, so
@@ -3119,7 +3131,7 @@ class TpuStateMachine:
 
         armed_mirror = self._scrub_mirror is not None
         armed = armed_mirror or self._merkle_forest is not None
-        result = self._lane_dispatch(dispatch, deferred)
+        result = self._lane_dispatch(dispatch, deferred, seq)
         handle = DeviceCommitHandle(
             self, result, counts, timestamps, stacked=True, stage=stage,
             # Batch retention feeds mirror recovery re-dispatch; the
@@ -3163,9 +3175,16 @@ class TpuStateMachine:
             if owners is not None:
                 owner_sum += owners
             total += c
-        soas = [self._pad_soa(b) for b in batches]  # serving-thread staging
-        cnts = [jnp.uint64(c) for c in counts]
-        tss = [jnp.uint64(t) for t in timestamps]
+        if _obs.enabled:
+            # K per-batch dispatches, no padded steps on this route.
+            _obs.counter("ops.group.batches").inc(k)
+            _obs.counter("ops.group.steps").inc(k)
+        seq = txtrace.group_seq  # for the closure's spans on the lane
+        with txtrace.stage("stage_h2d", n=k):
+            # Serving-thread staging.
+            soas = [self._pad_soa(b) for b in batches]
+            cnts = [jnp.uint64(c) for c in counts]
+            tss = [jnp.uint64(t) for t in timestamps]
         # Submit-time growth snapshot (see commit_group_fast / the
         # shard_bounds note in _grow_if_needed).
         need = self._transfers_bound + total
@@ -3177,17 +3196,20 @@ class TpuStateMachine:
         merkle_closure = self._merkle_forest is not None and not self.merkle_async
 
         def dispatch():
-            self._grow_if_needed(transfers_need=need, shard_bounds=snap)
+            with txtrace.stage("grow", seq=seq):
+                self._grow_if_needed(transfers_need=need, shard_bounds=snap)
             codes_out, ovf_out = [], []
             for j in range(k):
                 # Same handoff as the single-device closure above: ONE
                 # FIFO lane worker, serving-thread reads behind the join.
-                self.ledger, codes, overflow = step(  # tblint: ignore[lane-race] FIFO+join
-                    self.ledger, soas[j], cnts[j], tss[j]
-                )
-                self._index_append_device(
-                    soas[j]["id_lo"], soas[j]["id_hi"], codes, counts[j]
-                )
+                with txtrace.stage("dispatch", seq=seq):
+                    self.ledger, codes, overflow = step(  # tblint: ignore[lane-race] FIFO+join
+                        self.ledger, soas[j], cnts[j], tss[j]
+                    )
+                with txtrace.stage("index_append", seq=seq):
+                    self._index_append_device(
+                        soas[j]["id_lo"], soas[j]["id_hi"], codes, counts[j]
+                    )
                 if merkle_closure:
                     self._merkle_update_transfers_batches([batches[j]])
                 codes_out.append(codes)
@@ -3198,7 +3220,7 @@ class TpuStateMachine:
 
         armed_mirror = self._scrub_mirror is not None
         armed = armed_mirror or self._merkle_forest is not None
-        result = self._lane_dispatch(dispatch, deferred)
+        result = self._lane_dispatch(dispatch, deferred, seq)
         handle = DeviceCommitHandle(
             self, result, list(counts), list(timestamps), stacked=True,
             batches=list(batches) if armed_mirror else None,
@@ -3283,8 +3305,10 @@ class TpuStateMachine:
         if self._ledger_is_sharded:
             self._note_cross_shard(batch, count)
             owners = self._note_shard_inserts("transfers", batch, count)
-        soa = self._pad_soa(batch)  # staged on the serving thread
-        cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
+        seq = txtrace.group_seq  # for the closure's spans on the lane
+        with txtrace.stage("stage_h2d"):
+            soa = self._pad_soa(batch)  # staged on the serving thread
+            cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
         # Snapshot the growth target pre-submit (see _grow_if_needed).
         need = self._transfers_bound + count
         self._transfers_bound += count
@@ -3299,11 +3323,18 @@ class TpuStateMachine:
                 # The sharded probed step donates only the ledger (the
                 # replicated batch may alias pooled host buffers); the
                 # overflow lanes ride a fresh output.
-                self._grow_if_needed(transfers_need=need, shard_bounds=snap)
-                self.ledger, codes, overflow = step(self.ledger, soa, cnt, ts)
-                self._index_append_device(
-                    soa["id_lo"], soa["id_hi"], codes, count
-                )
+                with txtrace.stage("grow", seq=seq):
+                    self._grow_if_needed(
+                        transfers_need=need, shard_bounds=snap
+                    )
+                with txtrace.stage("dispatch", seq=seq):
+                    self.ledger, codes, overflow = step(
+                        self.ledger, soa, cnt, ts
+                    )
+                with txtrace.stage("index_append", seq=seq):
+                    self._index_append_device(
+                        soa["id_lo"], soa["id_hi"], codes, count
+                    )
                 if merkle_closure:
                     self._merkle_update_transfers_batches([batch])
                 if _obs.enabled:
@@ -3311,17 +3342,20 @@ class TpuStateMachine:
                 return codes, overflow
         else:
             def dispatch():
-                self._grow_if_needed(transfers_need=need)
+                with txtrace.stage("grow", seq=seq):
+                    self._grow_if_needed(transfers_need=need)
                 # The probed kernel donates BOTH the ledger and the staged
                 # SoA (the pad columns become scratch instead of pinned
                 # inputs); index maintenance uses the passed-through id
                 # columns — the donated ``soa`` dict must not be touched
                 # after this call.
-                (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
-                 id_lo, id_hi) = (
-                    sm.create_transfers_fast_probed(self.ledger, soa, cnt, ts)
-                )
-                self._index_append_device(id_lo, id_hi, codes, count)
+                with txtrace.stage("dispatch", seq=seq):
+                    (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
+                     id_lo, id_hi) = sm.create_transfers_fast_probed(
+                        self.ledger, soa, cnt, ts
+                    )
+                with txtrace.stage("index_append", seq=seq):
+                    self._index_append_device(id_lo, id_hi, codes, count)
                 if merkle_closure:
                     # Commitment update rides the ledger chain; keys come
                     # from the retained HOST batch (the staged SoA was
@@ -3331,7 +3365,7 @@ class TpuStateMachine:
 
         armed_mirror = self._scrub_mirror is not None
         armed = armed_mirror or self._merkle_forest is not None
-        fut = self._lane_dispatch(dispatch, True)
+        fut = self._lane_dispatch(dispatch, True, seq)
         handle = DeviceCommitHandle(
             self, fut, [count], [timestamp], stacked=False,
             batches=[batch] if armed_mirror else None, deferred=True,

@@ -18,13 +18,16 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import logging
+import os
+import signal
+import sys
 import time
 from typing import Optional
 
 import numpy as np
 
 from ..obs.metrics import registry as _obs
-from ..obs.txtrace import txtrace
+from ..obs.txtrace import now_us, txtrace
 from ..vsr import overload, wire
 from ..vsr.replica import Replica
 
@@ -90,11 +93,31 @@ async def read_message(
             # Empty bodies verify too: a header-only frame with a stale
             # checksum_body is forged/corrupt even though its header
             # checksum (which covers the stale field) passes.
-            wire.verify_body(h, body)
+            with txtrace.stage("ingress_verify"):
+                wire.verify_body(h, body)
         except ValueError as err:
             _count_reject(getattr(err, "reason", "body"), on_reject)
             continue  # framing intact: skip the frame, keep the connection
         return h, command, body
+
+
+class _HeaderFirst:
+    """A stream whose first ``readexactly`` hands back header bytes already
+    read: lets ``_handle_connection`` stamp a request's header arrival
+    itself and still have ``read_message`` frame, check and verify it."""
+
+    __slots__ = ("_head", "_reader")
+
+    def __init__(self, head: bytes, reader: asyncio.StreamReader) -> None:
+        self._head = head
+        self._reader = reader
+
+    async def readexactly(self, n: int) -> bytes:
+        head = self._head
+        if head is not None:
+            self._head = None
+            return head
+        return await self._reader.readexactly(n)
 
 
 class ReplicaServer:
@@ -151,6 +174,14 @@ class ReplicaServer:
         self._requests: Optional[asyncio.Queue] = None
         self._processor: Optional[asyncio.Task] = None
         self._flushes: set = set()
+        # Commit groups picked up while txtrace was active: a group's
+        # sequence number, the ``seq`` of its spans and of its timeline
+        # (obs/txtrace.py).
+        self._group_seq = 0
+        # Connections whose request header has been read and whose body
+        # has not been enqueued yet (raised and lowered only while txtrace
+        # is active): what a pickup observes as ``net.pickup.arriving``.
+        self._arriving = 0
         # Overload control (vsr/overload.py): with the knob ON, a full
         # request queue SIGNALS busy (retryable, with a retry hint) instead
         # of silently backpressuring the connection reader until the client
@@ -183,9 +214,18 @@ class ReplicaServer:
         return self.port
 
     async def serve_forever(self) -> None:
+        """Serve (the server accepts since ``start``) until cancelled, then
+        ``close``.  NOT ``async with self._server`` nor
+        ``Server.serve_forever()``: on the way out both await
+        ``wait_closed()``, which since Python 3.12 waits for every accepted
+        transport to detach, and one whose ``connection_lost`` never comes
+        (a callback lost to an exception thrown into the loop) holds the
+        shutdown for ever."""
         assert self._server is not None
-        async with self._server:
-            await self._server.serve_forever()
+        try:
+            await asyncio.get_running_loop().create_future()
+        finally:
+            await self.close()
 
     async def close(self) -> None:
         if self._server is not None:
@@ -234,8 +274,11 @@ class ReplicaServer:
                 # call below: a flush error fails that group's reply
                 # promise (its flush task drops the connections), and the
                 # processor must keep serving everyone else.
+                if _obs.enabled:
+                    _obs.counter("pipeline.flush.idle").inc()
                 try:
-                    self.replica.pipeline_flush()
+                    with txtrace.stage("pipeline_flush"):
+                        self.replica.pipeline_flush()
                 except Exception:
                     log.exception("pipeline flush failed")
             group = [await self._requests.get()]
@@ -245,19 +288,23 @@ class ReplicaServer:
                 except asyncio.QueueEmpty:
                     break
             observing = self.statsd is not None or _obs.enabled
+            timeline = None
             if txtrace.active:
-                now = time.monotonic()
-                for _h, _b, _w, t_enq in group:
-                    if t_enq:
-                        txtrace.stage_observe(
-                            "admission_wait", (now - t_enq) * 1e6
-                        )
+                self._group_seq += 1
+                timeline = txtrace.group_begin(self._group_seq)
+                if _obs.enabled:
+                    # Requests that miss this group by the length of their
+                    # own body read.
+                    _obs.histogram(
+                        "net.pickup.arriving", "requests"
+                    ).observe(self._arriving)
             t0 = time.monotonic() if observing else 0.0
             try:
-                replies, fsync = self.replica.on_request_group_pipelined(
-                    [(h, body) for h, body, _w, _t in group],
-                    deferred_replies=True,
-                )
+                with txtrace.stage("commit_group", n=len(group)):
+                    replies, fsync = self.replica.on_request_group_pipelined(
+                        [(h, body) for h, body, _w, _t in group],
+                        deferred_replies=True,
+                    )
             except Exception:
                 # A group execution failure is a server-side fault (storage
                 # error mid-commit); surviving connections would otherwise
@@ -268,10 +315,12 @@ class ReplicaServer:
                 for _h, _b, w, _t in group:
                     w.close()
                 continue
+            if timeline is not None:
+                timeline.returned(replies, fsync)
             if observing:
                 self._emit_stats(group, time.monotonic() - t0)
             if fsync is None:
-                await self._flush_group(group, replies, fsync)
+                await self._flush_group(group, replies, fsync, timeline)
             else:
                 # Reply release rides the durability barrier; the processor
                 # moves on.  (Tracked so close() can cancel stragglers.)
@@ -285,12 +334,13 @@ class ReplicaServer:
                         return_when=asyncio.FIRST_COMPLETED,
                     )
                 task = asyncio.get_running_loop().create_task(
-                    self._flush_group(group, replies, fsync)
+                    self._flush_group(group, replies, fsync, timeline)
                 )
                 self._flushes.add(task)
                 task.add_done_callback(self._flushes.discard)
 
-    async def _flush_group(self, group, replies, fsync) -> None:
+    async def _flush_group(self, group, replies, fsync,
+                           timeline=None) -> None:
         if fsync is not None:
             try:
                 await asyncio.wrap_future(fsync)
@@ -314,22 +364,29 @@ class ReplicaServer:
                 for _h, _b, w, _t in group:
                     w.close()
                 return
-        t_rel = time.monotonic() if txtrace.active else 0.0
-        for (h, _b, writer, _t), outs in zip(group, replies):
-            if writer.is_closing():
-                continue
-            for out in outs:
-                writer.write(out)
-            if outs:
-                # The request header's trace rides the reply we just
-                # released (replica._commit_prepare copied it) — close the
-                # server half of the causal chain here.
-                txtrace.hop(int(h["trace"]), "bus.release",
-                            replica=self.replica.replica)
-        if t_rel:
-            txtrace.stage_observe(
-                "reply_release", (time.monotonic() - t_rel) * 1e6
-            )
+        # Stamps of the requests whose replies are written below (only
+        # with a timeline, i.e. picked up while txtrace was active).
+        released = None if timeline is None else []
+        with txtrace.stage("reply_release",
+                           seq=0 if timeline is None else timeline.seq,
+                           n=len(group)):
+            for (h, _b, writer, stamps), outs in zip(group, replies):
+                if writer.is_closing():
+                    continue
+                for out in outs:
+                    writer.write(out)
+                if outs:
+                    # The request header's trace rides the reply we just
+                    # released (replica._commit_prepare copied it) — close
+                    # the server half of the causal chain here.
+                    txtrace.hop(int(h["trace"]), "bus.release",
+                                replica=self.replica.replica)
+                    if released is not None and stamps is not None:
+                        released.append(stamps)
+        if released:
+            timeline.t_released = now_us()
+            for t_header, t_enqueued in released:
+                txtrace.request_observe(timeline, t_header, t_enqueued)
         # Parallel bounded drains: one slow client must not serialize the
         # group, and a client that stops reading is evicted after
         # drain_timeout_ms (the bounded-send-queue discipline; a stuck
@@ -428,12 +485,33 @@ class ReplicaServer:
                     peer, reason,
                 )
 
+        # The request timeline's first stamp (obs/txtrace.py): when the
+        # header of the frame being read came in; 0 = none (txtrace off, or
+        # between frames).  Non-zero, the connection counts as arriving.
+        t_header = 0
         try:
             while True:
+                source = reader
+                if txtrace.active:
+                    # Header and body reads split HERE and nowhere else:
+                    # read_message finds the header's bytes in ``source``.
+                    try:
+                        head = await reader.readexactly(wire.HEADER_SIZE)
+                    except (asyncio.IncompleteReadError,
+                            ConnectionResetError):
+                        break
+                    t_header = now_us()
+                    self._arriving += 1
+                    source = _HeaderFirst(head, reader)
                 msg = await read_message(
-                    reader, self.replica.config.message_size_max,
+                    source, self.replica.config.message_size_max,
                     on_reject=on_reject,
                 )
+                stamps = None
+                if t_header:
+                    stamps = (t_header, now_us())
+                    t_header = 0
+                    self._arriving -= 1
                 if msg is None:
                     break
                 h, command, body = msg
@@ -459,12 +537,9 @@ class ReplicaServer:
                     txtrace.hop(int(h["trace"]), "bus.ingress",
                                 replica=self.replica.replica,
                                 request=int(h["request"]))
-                    # Enqueue stamp for the admission_wait stage; 0.0 when
-                    # attribution is off (no clock read on the hot path).
-                    t_enq = (
-                        time.monotonic() if txtrace.active else 0.0
-                    )
-                    await self._requests.put((h, body, writer, t_enq))
+                    # ``stamps``: (header read, enqueued) for the request
+                    # timeline; None when txtrace is off (no clock read).
+                    await self._requests.put((h, body, writer, stamps))
                     continue
                 for out in self._dispatch(h, command, body):
                     writer.write(out)
@@ -479,6 +554,8 @@ class ReplicaServer:
         except (ConnectionResetError, BrokenPipeError):
             pass
         finally:
+            if t_header:  # the connection ended inside a frame
+                self._arriving -= 1
             self._accepted.discard(writer)
             writer.close()
             try:
@@ -502,17 +579,55 @@ class ReplicaServer:
         return []
 
 
+def _exit_after_dumps(code: int) -> None:
+    """End the process now with ``code``, the exit-time dumps (metrics
+    snapshot, trace, flight recorder: all atexit callbacks) written first.
+    Not ``raise SystemExit``: the interpreter's own finalization then tears
+    the device runtime down, which on a TPU host has taken longer than a
+    supervisor waits (PERF.md section 6, PR 26: a stop that was still in
+    there after 8 s, its signal handlers already reset, died of the next
+    signal it was sent)."""
+    import atexit
+
+    atexit._run_exitfuncs()
+    for stream in (sys.stdout, sys.stderr):
+        try:
+            stream.flush()
+        except (OSError, ValueError):
+            pass  # a closed pipe must not keep the process
+    os._exit(code)
+
+
 def run_server(replica: Replica, host: str = "127.0.0.1", port: int = 0,
                ready_callback=None, statsd=None) -> None:
-    """Blocking entry point: serve until cancelled."""
+    """Blocking entry point: serve until cancelled; on SIGTERM (main thread
+    only) stop serving, write the exit-time dumps and exit with code 143."""
     # Overlap checkpoints with request processing (replica.zig:3153-3169):
     # safe in solo mode — no view changes, so no concurrent superblock
     # writer; the sim keeps checkpoints synchronous for determinism.
     replica.async_checkpoint = True
+    terminated = []
 
     async def main():
         server = ReplicaServer(replica, host, port, statsd=statsd)
         actual_port = await server.start()
+        serving = asyncio.current_task()
+
+        def on_sigterm() -> None:
+            terminated.append(True)
+            serving.cancel()
+
+        # SIGTERM as a callback of the loop, not as an exception thrown into
+        # whatever the main thread is executing: cli's handler raises
+        # SystemExit wherever it lands, and inside the loop's own
+        # bookkeeping that lost a task's wakeup, so the shutdown waited for
+        # a second signal (a third of the chip runs, PERF.md section 6).
+        try:
+            asyncio.get_running_loop().add_signal_handler(
+                signal.SIGTERM, on_sigterm
+            )
+        except (ValueError, RuntimeError, NotImplementedError):
+            pass  # not the main thread (tests): the process's handler stays
         if ready_callback is not None:
             ready_callback(actual_port)
         await server.serve_forever()
@@ -521,3 +636,8 @@ def run_server(replica: Replica, host: str = "127.0.0.1", port: int = 0,
         asyncio.run(main())
     except KeyboardInterrupt:
         pass
+    except asyncio.CancelledError:
+        if not terminated:
+            raise
+    if terminated:
+        _exit_after_dumps(143)  # the code cli's own handler exits with

@@ -13,14 +13,17 @@ cross-process *flow event* into the host tracer's Chrome buffer.  One
 request, one causal chain, across all replicas of a SimCluster or a
 real cluster_bus deployment, readable in Perfetto as connected arrows.
 
-**ATTRIBUTE** — a per-commit-batch stage ledger.  Each commit stage
-(admission_wait, wal_fsync, dispatch_wait, device_execute,
-merkle_refresh, readback, reply_release) reports its duration here;
-durations land in ``txtrace.stage.*`` registry histograms (when the
-registry is on) and accumulate into an in-process total table that
-``bench.py`` surfaces as ``payload.attribution`` — the instrument that
-names the dominant per_batch_us term (ROADMAP item 2's deferred
-commitment lane is tuned against exactly this).
+**ATTRIBUTE** — two kinds of record (docs/tracing.md).  *Thread spans*:
+``txtrace.stage(name)`` is the one way a commit-path site times a block;
+the duration lands in a ``txtrace.stage.<name>`` registry histogram (when
+the registry is on), in the in-process total table that ``bench.py``
+surfaces as ``payload.attribution``, and — while a ``jax.profiler``
+session is on — as a ``tb.<name>`` event on that thread's line of the
+profile's host plane, on the device trace's clock.  Spans nest and
+overlap; they do not sum.  *Request intervals*: the bus stamps every
+commit group (``GroupTimeline``) and at reply release observes, once per
+request, six consecutive ``txtrace.request.*`` intervals that sum to the
+request's time in the server exactly.
 
 **BLACKBOX** — a bounded per-replica ring of protocol events (command,
 view, op, checksums, queue depths, tick) at one-append cost when
@@ -39,7 +42,7 @@ import contextlib
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from ..utils.tracer import tracer
 from .metrics import registry as _obs
@@ -51,17 +54,130 @@ from .metrics import registry as _obs
 # typical range is irrelevant — rows are keyed by exact pid value.
 REPLICA_PID_BASE = 1 << 18
 
-# The commit pipeline's stage vocabulary, in pipeline order.  Attribution
-# blocks and docs/tracing.md list stages in exactly this order.
+# The thread-span vocabulary, in pipeline order (docs/tracing.md has the
+# site, the thread and the bounds of each).  ``tb.<name>`` in a profile.
 STAGES = (
-    "admission_wait",   # request queued at the bus -> group pickup
-    "wal_fsync",        # journal append + fsync barrier
-    "dispatch_wait",    # FIFO dispatch-lane queue time
-    "device_execute",   # kernel dispatch -> completion
-    "merkle_refresh",   # touched-path leaf->root update kernels
-    "readback",         # deferred D2H resolve (codes readback)
-    "reply_release",    # reply encode + release to the wire
+    "ingress_verify",   # bus: body checksum of one ingress frame
+    "commit_group",     # bus: the synchronous replica call for one group
+    "prepare",          # replica: header assign + hash chain per request
+    "stage_h2d",        # machine: staging fill + device_put of a run
+    "device_execute",   # machine: the commit closure (lane thread if deferred)
+    "grow",             # ... its table growth check
+    "dispatch",         # ... its jitted commit call(s)
+    "index_append",     # ... its secondary-index maintenance
+    "merkle_refresh",   # ... touched-path leaf->root update kernels
+    "wal_write",        # replica: journal appends of the group
+    "wal_fsync",        # replica: the fsync barrier (io pool thread)
+    "pipeline_flush",   # bus: flush because the request queue idled
+    "dispatch_wait",    # machine: join of the lane closure
+    "readback",         # machine: deferred D2H resolve (codes readback)
+    "phase_b",          # replica: bookkeeping + reply build per op
+    "reply_release",    # bus: reply writes of one group
 )
+
+# Stages that only ever run INSIDE another stage's block on the same
+# thread: ``device_execute``'s children.  A sum over stages that wants
+# wall time (bench.py's coverage) leaves them out.  The bus's sections
+# below hold the replica's and the machine's serving-thread spans the same
+# way: a sum that takes the sections takes nothing else of that thread.
+NESTED_STAGES = ("grow", "dispatch", "index_append", "merkle_refresh")
+
+# The serving thread's top-level synchronous sections (bus sites, never
+# nested in one another): their durations add up to ``serve.busy_us``,
+# which over a window is the one serving thread's occupancy.
+SERVING_SECTIONS = frozenset(
+    ("ingress_verify", "commit_group", "pipeline_flush", "reply_release")
+)
+
+# Consecutive intervals of one request inside the server; they sum to
+# ``total`` exactly (integer microseconds of one clock).
+REQUEST_INTERVALS = (
+    "ingress",          # header read -> body read, verified, enqueued
+    "admission_wait",   # enqueued -> its group is picked up
+    "commit_host",      # pickup -> the synchronous replica call returned
+    "results_wait",     # returned -> the replies promise resolved
+    "barrier_wait",     # results -> max(results, fsync done)
+    "reply_release",    # -> the group's replies are written
+)
+_REQUEST_SERIES = tuple(
+    f"txtrace.request.{name}" for name in REQUEST_INTERVALS + ("total",)
+)
+
+
+def now_us() -> int:
+    """The request timeline's clock: integer microseconds, monotonic."""
+    return time.monotonic_ns() // 1000
+
+
+class GroupTimeline:
+    """Stamps (``now_us``) of one commit group from pickup to release,
+    made by the bus only while ``txtrace.active``.  ``t_results`` and
+    ``t_durable`` are taken in the futures' done callbacks — on whichever
+    thread completes them — not when the awaiting task resumes: the
+    resumption waits for the serving thread, which is what is measured."""
+
+    __slots__ = ("seq", "t_pickup", "t_returned", "t_results", "t_durable",
+                 "t_released")
+
+    def __init__(self, seq: int) -> None:
+        self.seq = seq
+        self.t_pickup = now_us()
+        self.t_returned = self.t_results = self.t_durable = 0
+        self.t_released = 0
+
+    def returned(self, replies, fsync) -> None:
+        """The synchronous call returned ``(replies, fsync)``: either may
+        be a concurrent future still to complete."""
+        self.t_returned = now_us()
+        if hasattr(replies, "add_done_callback"):
+            replies.add_done_callback(self._results_done)
+        else:
+            self.t_results = self.t_returned
+        if fsync is not None:
+            fsync.add_done_callback(self._durable_done)
+
+    def _results_done(self, _future) -> None:
+        self.t_results = now_us()
+
+    def _durable_done(self, _future) -> None:
+        self.t_durable = now_us()
+
+    def intervals(self, t_header: int, t_enqueued: int) -> Tuple[int, ...]:
+        """The six REQUEST_INTERVALS of one request of this group, then
+        their total; consecutive stamps, so the six sum to the total."""
+        results = max(self.t_results, self.t_returned)
+        barrier = max(results, self.t_durable)
+        stamps = (t_header, t_enqueued, self.t_pickup, self.t_returned,
+                  results, barrier, self.t_released)
+        return tuple(
+            b - a for a, b in zip(stamps, stamps[1:])
+        ) + (self.t_released - t_header,)
+
+
+class _StageSpan:
+    """One open ``txtrace.stage`` block: a TraceMe annotation held open
+    (the profiler's clock) around a perf_counter_ns duration."""
+
+    __slots__ = ("_tx", "_name", "_annotation", "_t0")
+
+    def __init__(self, tx, name: str, annotation) -> None:
+        self._tx = tx
+        self._name = name
+        self._annotation = annotation
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc) -> bool:
+        us = (time.perf_counter_ns() - self._t0) / 1e3
+        self._annotation.__exit__(*exc)
+        self._tx.stage_observe(self._name, us)
+        return False
+
+
+# What an inactive stage site gets: shared, re-entrant, does nothing.
+_STAGE_OFF = contextlib.nullcontext()
 
 
 def _mix64(x: int) -> int:
@@ -100,6 +216,11 @@ class TxTracer:
         # Attribution accumulation is independent of sampling: bench arms
         # it for every batch (no sampling) while flow tracing stays off.
         self.attribution = False
+        # Sequence number of the commit group the serving thread is in
+        # (set by the bus at pickup while active): the default ``seq``
+        # argument of a stage's profile event.
+        self.group_seq = 0
+        self._trace_annotation = None  # jax.profiler's, on first use
         self._seq = 0
         self._lock = threading.Lock()
         # name -> [count, total_us]; plain dict + lock (stage sites are
@@ -207,10 +328,13 @@ class TxTracer:
     # -- stage ledger (attribution) ------------------------------------------
 
     def stage_observe(self, name: str, us: float) -> None:
-        """Record one commit stage duration.  Callers guard on
-        ``txtrace.active`` BEFORE reading any clock (cost discipline)."""
+        """Record one thread-span duration (``stage`` calls this; a site
+        that has a duration already guards on ``txtrace.active`` BEFORE
+        reading any clock)."""
         if _obs.enabled:
             _obs.histogram(f"txtrace.stage.{name}", "us").observe(us)
+            if name in SERVING_SECTIONS:
+                _obs.counter("serve.busy_us").inc(int(us))
         if self.attribution:
             with self._lock:
                 slot = self._stages.get(name)
@@ -219,17 +343,43 @@ class TxTracer:
                 slot[0] += 1
                 slot[1] += us
 
-    @contextlib.contextmanager
-    def stage(self, name: str):
-        """Timed stage block; free (no clock read) when inactive."""
-        if not self.active:
-            yield
-            return
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            self.stage_observe(name, (time.perf_counter_ns() - t0) / 1e3)
+    def stage(self, name: Optional[str], seq: int = 0, n: int = 0):
+        """Timed thread span, the only way a commit-path site times a
+        block.  Inactive (or ``name`` None) it hands back one shared no-op:
+        no clock read, nothing allocated.  Active it observes
+        ``txtrace.stage.<name>`` and holds a ``tb.<name>`` TraceMe
+        annotation open for the block, with the group's sequence number
+        (``seq``, default: the group the serving thread is in; sites that
+        run later or on another thread pass the one they captured at
+        submit) and, where the site knows it, a count ``n``."""
+        if name is None or not self.active:
+            return _STAGE_OFF
+        annotation = self._trace_annotation
+        if annotation is None:
+            # Here, not at import: the client, the simulator and tbmc
+            # import txtrace and must not pull the profiler in (importing
+            # it starts no backend either).
+            from jax.profiler import TraceAnnotation
+
+            annotation = self._trace_annotation = TraceAnnotation
+        return _StageSpan(self, name, annotation(
+            "tb." + name, seq=seq or self.group_seq, n=n
+        ))
+
+    def group_begin(self, seq: int) -> GroupTimeline:
+        """The bus picked a group up (callers guard on ``active``)."""
+        self.group_seq = seq
+        return GroupTimeline(seq)
+
+    def request_observe(self, timeline: GroupTimeline, t_header: int,
+                        t_enqueued: int) -> None:
+        """One released request: its six consecutive intervals and their
+        total, into seven series of equal count (so their means add)."""
+        if not _obs.enabled:
+            return  # registry series only: the stage table is per batch
+        values = timeline.intervals(t_header, t_enqueued)
+        for series, us in zip(_REQUEST_SERIES, values):
+            _obs.histogram(series, "us").observe(us)
 
     def stage_totals(self) -> Dict[str, dict]:
         """Accumulated {stage: {count, us}} since the last reset."""

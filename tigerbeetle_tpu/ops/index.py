@@ -64,9 +64,10 @@ def build_runs(
 ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
     """Sorted level-0 runs (debit side, credit side) for a just-committed
     batch: gather the stored rows by id and key them by each side's account."""
-    look = ht.lookup(ledger.transfers, id_lo, id_hi, sm.MAX_PROBE)
-    use = ok & look.found
-    rows = ht.gather_cols(ledger.transfers, look.slot, use)
+    with jax.named_scope("tb/index_probe"):
+        look = ht.lookup(ledger.transfers, id_lo, id_hi, sm.MAX_PROBE)
+        use = ok & look.found
+        rows = ht.gather_cols(ledger.transfers, look.slot, use)
 
     def side(acct_field):
         lvl = {
@@ -76,16 +77,19 @@ def build_runs(
             "tid_lo": jnp.where(use, id_lo, jnp.uint64(U64M)),
             "tid_hi": jnp.where(use, id_hi, jnp.uint64(U64M)),
         }
-        return _sort_level(lvl)
+        with jax.named_scope("tb/index_sort"):
+            return _sort_level(lvl)
 
     return side("debit_account_id"), side("credit_account_id")
 
 
 def _merge(levels: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
-    cat = {
-        name: jnp.concatenate([lvl[name] for lvl in levels]) for name in COLS
-    }
-    return _sort_level(cat)
+    with jax.named_scope("tb/index_merge"):
+        cat = {
+            name: jnp.concatenate([lvl[name] for lvl in levels])
+            for name in COLS
+        }
+        return _sort_level(cat)
 
 
 _merge_jit = jax.jit(_merge)

@@ -677,33 +677,42 @@ def create_transfers_impl(
     lane = jnp.arange(n, dtype=jnp.int32)
     valid = lane < count.astype(jnp.int32)
 
-    dr_look = ht.lookup(ledger.accounts, dr_id.lo, dr_id.hi, MAX_PROBE)
-    cr_look = ht.lookup(ledger.accounts, cr_id.lo, cr_id.hi, MAX_PROBE)
-    ex_look = ht.lookup(ledger.transfers, tid.lo, tid.hi, MAX_PROBE)
-    dr_found = dr_look.found & valid
-    cr_found = cr_look.found & valid
-    ex_found = ex_look.found & valid
-    ctx = TransferCtx(
-        dr_found=dr_found,
-        cr_found=cr_found,
-        dr_slot=dr_look.slot,
-        cr_slot=cr_look.slot,
-        dr=ht.gather_cols(ledger.accounts, dr_look.slot, dr_found),
-        cr=ht.gather_cols(ledger.accounts, cr_look.slot, cr_found),
-        ex_found=ex_found,
-        e=ht.gather_cols(ledger.transfers, ex_look.slot, ex_found),
-    )
+    # The tb/<phase> scopes name the phases in a device trace (an
+    # operation's op_name); metadata only.
+    with jax.named_scope("tb/probe"):
+        dr_look = ht.lookup(ledger.accounts, dr_id.lo, dr_id.hi, MAX_PROBE)
+        cr_look = ht.lookup(ledger.accounts, cr_id.lo, cr_id.hi, MAX_PROBE)
+        ex_look = ht.lookup(ledger.transfers, tid.lo, tid.hi, MAX_PROBE)
+        dr_found = dr_look.found & valid
+        cr_found = cr_look.found & valid
+        ex_found = ex_look.found & valid
+        ctx = TransferCtx(
+            dr_found=dr_found,
+            cr_found=cr_found,
+            dr_slot=dr_look.slot,
+            cr_slot=cr_look.slot,
+            dr=ht.gather_cols(ledger.accounts, dr_look.slot, dr_found),
+            cr=ht.gather_cols(ledger.accounts, cr_look.slot, cr_found),
+            ex_found=ex_found,
+            e=ht.gather_cols(ledger.transfers, ex_look.slot, ex_found),
+        )
 
-    codes, ok, ts, pending = transfer_codes(batch, ctx, count, timestamp)
+    with jax.named_scope("tb/validate"):
+        codes, ok, ts, pending = transfer_codes(batch, ctx, count, timestamp)
 
-    plan = balance_plan(
-        ctx.dr_slot, ctx.cr_slot, ok, amt.lo, pending, ledger.accounts.capacity
-    )
-    accounts = apply_balance_plan(ledger.accounts, plan)
+    with jax.named_scope("tb/balance"):
+        plan = balance_plan(
+            ctx.dr_slot, ctx.cr_slot, ok, amt.lo, pending,
+            ledger.accounts.capacity,
+        )
+        accounts = apply_balance_plan(ledger.accounts, plan)
 
     # --- transfer inserts (timestamps recomputed in transfer_rows CSE under jit) ---
-    rows = transfer_rows(batch, count, timestamp)
-    transfers, _ = ht.insert(ledger.transfers, tid.lo, tid.hi, ok, rows, MAX_PROBE)
+    with jax.named_scope("tb/insert"):
+        rows = transfer_rows(batch, count, timestamp)
+        transfers, _ = ht.insert(
+            ledger.transfers, tid.lo, tid.hi, ok, rows, MAX_PROBE
+        )
 
     return ledger.replace(accounts=accounts, transfers=transfers), codes
 

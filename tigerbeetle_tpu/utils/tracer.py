@@ -28,23 +28,6 @@ import threading
 import time
 from typing import Dict, List, Tuple
 
-# Typed event names mirroring tracer.zig:48-78.
-EVENTS = (
-    "commit",
-    "checkpoint",
-    "state_machine_prefetch",
-    "state_machine_commit",
-    "state_machine_compact",
-    "journal_write",
-    "grid_read",
-    "grid_write",
-    "io_flush",
-    "replica_tick",
-    "view_change",
-    "repair",
-    "sync",
-)
-
 
 class Tracer:
     # Bounded buffer (tracer.zig's fixed slot count): recording stops at the

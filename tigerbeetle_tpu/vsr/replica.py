@@ -703,12 +703,17 @@ class Replica:
         effects)."""
         self._pipeline_settle()  # a depth change mid-run must not reorder
         prepared = []
-        for i, operation, header, body in admitted:
-            prepare_h, prepare_body = self._prepare(
-                header, body, operation, sync=False
-            )
-            prepared.append((i, prepare_h, prepare_body))
-        fsync = self._io_pool_submit(self._journal_sync_staged)
+        # Here ``prepare`` holds the journal appends too (this engine
+        # writes each prepare as it is assigned).
+        with txtrace.stage("prepare", n=len(admitted)):
+            for i, operation, header, body in admitted:
+                prepare_h, prepare_body = self._prepare(
+                    header, body, operation, sync=False
+                )
+                prepared.append((i, prepare_h, prepare_body))
+        fsync = self._io_pool_submit(
+            self._journal_sync_staged, txtrace.group_seq
+        )
         self._last_group_fsync = fsync
         runs = self._group_device_runs(prepared)
         precomputed: Dict[int, bytes] = {}
@@ -814,13 +819,14 @@ class Replica:
         # never disagree, or the next group's hash chain points at ops
         # recovery cannot find.
         try:
-            for i, operation, header, body in admitted:
-                prepare_h, prepare_body = self._prepare(
-                    header, body, operation, sync=False,
-                    defer_write=messages
-                )
-                prepared.append((i, prepare_h, prepare_body))
-            runs = self._group_device_runs(prepared, single_ok=True)
+            with txtrace.stage("prepare", n=len(admitted)):
+                for i, operation, header, body in admitted:
+                    prepare_h, prepare_body = self._prepare(
+                        header, body, operation, sync=False,
+                        defer_write=messages
+                    )
+                    prepared.append((i, prepare_h, prepare_body))
+                runs = self._group_device_runs(prepared, single_ok=True)
             if _obs.enabled:
                 _obs.gauge("pipeline.depth").set(self.pipeline_depth)
                 _obs.counter("pipeline.groups").inc()
@@ -844,9 +850,12 @@ class Replica:
                     break  # refused (whole run or a fused tail): those
                     # ops execute inline in phase A
         finally:
-            for message in messages:
-                self.journal.write_prepare(message, sync=False)
-        fsync = self._io_pool_submit(self._journal_sync_staged)
+            with txtrace.stage("wal_write", n=len(messages)):
+                for message in messages:
+                    self.journal.write_prepare(message, sync=False)
+        fsync = self._io_pool_submit(
+            self._journal_sync_staged, txtrace.group_seq
+        )
         self._last_group_fsync = fsync
 
         def drain(reason: str) -> None:
@@ -920,11 +929,15 @@ class Replica:
                 "result_bodies": result_bodies,
                 "promise": promise,
                 "last_op": int(prepared[-1][1]["op"]),
+                # For the spans of its phase B, which runs inside a later
+                # group's call.
+                "seq": txtrace.group_seq,
             }
             return promise, fsync
 
         drain("flush")
-        self._pipeline_phase_b(prepared, result_bodies, out)
+        self._pipeline_phase_b(prepared, result_bodies, out,
+                               txtrace.group_seq)
         if self._checkpoint_due():
             self.checkpoint()
         return out, fsync
@@ -995,8 +1008,9 @@ class Replica:
             results = handle.resolve()
         if _obs.enabled:
             # Queue wait (the join) is pipeline idle time, NOT commit
-            # work: it rides pipeline.resolve_wait_us; commit_us must stay
-            # comparable with the blocking path's execution-only series.
+            # work: it rides txtrace.stage.dispatch_wait; commit_us must
+            # stay comparable with the blocking path's execution-only
+            # series.
             _obs.histogram("replica.commit_us", "us").observe(max(
                 (time.perf_counter_ns() - t0) / 1e3  # tblint: ignore[nondet] metrics
                 - handle.join_wait_s * 1e6, 0.0,
@@ -1019,7 +1033,8 @@ class Replica:
         self._pipeline_pending = None
         try:
             self._pipeline_phase_b(
-                pending["prepared"], pending["result_bodies"], pending["out"]
+                pending["prepared"], pending["result_bodies"], pending["out"],
+                pending["seq"],
             )
         except BaseException as err:
             # The promise must ALWAYS resolve (the bus flush task awaits
@@ -1032,17 +1047,20 @@ class Replica:
             raise
         pending["promise"].set_result(pending["out"])
 
-    def _pipeline_phase_b(self, prepared, result_bodies, out) -> None:
+    def _pipeline_phase_b(self, prepared, result_bodies, out,
+                          seq: int = 0) -> None:
         """Phase B: bookkeeping + reply construction, strictly in op
         order.  The reply barrier is unchanged: the caller withholds these
-        until the group fsync resolves."""
-        for j, (i, prepare_h, prepare_body) in enumerate(prepared):
-            reply = self._commit_prepare(
-                prepare_h, prepare_body, replay=False,
-                result_body=result_bodies.get(j),
-            )
-            assert reply is not None
-            out[i] = [reply]
+        until the group fsync resolves.  ``seq``: the group's sequence
+        number at the bus, for the span."""
+        with txtrace.stage("phase_b", seq=seq, n=len(prepared)):
+            for j, (i, prepare_h, prepare_body) in enumerate(prepared):
+                reply = self._commit_prepare(
+                    prepare_h, prepare_body, replay=False,
+                    result_body=result_bodies.get(j),
+                )
+                assert reply is not None
+                out[i] = [reply]
 
     def _pipeline_abort(self, err) -> None:
         """Engine failure: QUIESCE in-flight handles (join their lane
@@ -1289,20 +1307,21 @@ class Replica:
         flush()
         return runs
 
-    def _io_pool_submit(self, fn):
+    def _io_pool_submit(self, fn, *args):
         if getattr(self, "_io_pool", None) is None:
             import concurrent.futures
 
             self._io_pool = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="tb-wal-fsync"
             )
-        return self._io_pool.submit(fn)
+        return self._io_pool.submit(fn, *args)
 
-    def _journal_sync_staged(self):
-        """journal.sync under the ``wal_fsync`` attribution stage — the
-        stage times the durability barrier itself (it runs on the IO pool
-        thread), not the serving thread's wait for it."""
-        with txtrace.stage("wal_fsync"):
+    def _journal_sync_staged(self, seq: int = 0):
+        """journal.sync under the ``wal_fsync`` stage — the stage times the
+        durability barrier itself (it runs on the IO pool thread), not the
+        serving thread's wait for it.  ``seq``: the group that submitted
+        it."""
+        with txtrace.stage("wal_fsync", seq=seq):
             return self.journal.sync()
 
     def _prepare(
