@@ -3,16 +3,17 @@ _group_device_runs): a run of consecutive create_transfers prepares
 executes in ONE device dispatch, amortizing the per-dispatch host<->device
 round trip the per-op path pays for every batch.
 
-Results must be bit-identical to the per-batch path: scan order == op
-order, per-op prepare timestamps ride along.  The auto-gate enables
-grouping only on the TPU backend (an empty scan step costs table-sized
-temporaries on XLA-CPU), so these tests force it on.
+Results must be bit-identical to the per-batch path: loop order == op
+order, per-op prepare timestamps ride along.  The dispatch runs one loop
+step per batch of the run (never the GROUP_K its operands are shaped to),
+in ONE compiled program whatever the run's length.  The auto-gate enables
+grouping only on the TPU backend, so these tests force it on.
 """
 
 import numpy as np
 import pytest
 
-from tigerbeetle_tpu import types
+from tigerbeetle_tpu import machine, types
 from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
 
@@ -45,20 +46,34 @@ def batch(first_id, n, amount=3):
 
 
 class TestMachineGroupParity:
-    def test_grouped_equals_per_batch(self):
-        grouped = make_machine(True)
-        serial = make_machine(False)
-        batches = [batch(1000 * (k + 1), 20 + k) for k in range(5)]
+    @staticmethod
+    def _run_of(k, first_id=10_000):
+        """k batches: a lane that fails in the first, the second a full
+        duplicate of the first, the rest fresh."""
+        batches = [batch(first_id + 100 * j, 9 + j % 7) for j in range(k)]
+        batches[0]["debit_account_id_lo"][3] = 999  # no such account
+        batches[1] = batches[0].copy()
+        return batches
+
+    @staticmethod
+    def _commit_both(grouped, serial, batches):
         # Assign timestamps exactly as the replica's _prepare would.
         tss = [
             grouped.prepare("create_transfers", len(b), 0) for b in batches
         ]
         res_g = grouped.commit_group_fast(batches, tss)
-        assert res_g is not None, "eligible run must group"
         res_s = []
         for b, ts in zip(batches, tss):
             serial.prepare("create_transfers", len(b), 0)
             res_s.append(serial.commit_batch("create_transfers", b, ts))
+        return res_g, res_s
+
+    def test_grouped_equals_per_batch(self):
+        grouped = make_machine(True)
+        serial = make_machine(False)
+        batches = [batch(1000 * (k + 1), 20 + k) for k in range(5)]
+        res_g, res_s = self._commit_both(grouped, serial, batches)
+        assert res_g is not None, "eligible run must group"
         assert res_g == res_s
         assert grouped.digest() == serial.digest()
 
@@ -69,20 +84,72 @@ class TestMachineGroupParity:
         b2 = batch(2000, 12)  # full duplicate of b1: every lane 'exists'
         b3 = batch(3000, 8)
         b3["debit_account_id_lo"][3] = 999  # no such account
-        tss = [
-            grouped.prepare("create_transfers", len(b), 0)
-            for b in (b1, b2, b3)
-        ]
-        res_g = grouped.commit_group_fast([b1, b2, b3], tss)
+        res_g, res_s = self._commit_both(grouped, serial, [b1, b2, b3])
         assert res_g is not None
-        res_s = []
-        for b, ts in zip((b1, b2, b3), tss):
-            serial.prepare("create_transfers", len(b), 0)
-            res_s.append(serial.commit_batch("create_transfers", b, ts))
         assert res_g == res_s
         assert grouped.digest() == serial.digest()
         # The duplicate batch must report per-lane 'exists' codes.
         assert len(res_g[1]) == 12
+
+    @pytest.mark.parametrize("k", [2, 3, 7, 8, TpuStateMachine.GROUP_K])
+    def test_run_of_k_equals_per_batch(self, k):
+        grouped = make_machine(True)
+        serial = make_machine(False)
+        res_g, res_s = self._commit_both(grouped, serial, self._run_of(k))
+        assert res_g is not None and len(res_g) == k
+        assert res_g == res_s
+        assert grouped.digest() == serial.digest()
+        assert res_g[0] and len(res_g[1]) == len(res_s[1]) > 0
+
+    def test_steps_past_the_run_are_not_run_and_their_codes_not_read(
+        self, monkeypatch
+    ):
+        """The loop ends at the first empty row: a live batch planted past
+        it is never applied and its codes row stays zeroed; and resolve()
+        never looks at the rows past the run: poisoned, the results stay
+        equal."""
+        real = machine._group_fast_dispatch
+        seen = []
+
+        def planted_and_poisoned(ledger, stacked, counts, timestamps):
+            k = int(np.count_nonzero(np.asarray(counts)))
+            stacked = {name: col.at[k + 1].set(col[0])
+                       for name, col in stacked.items()}
+            stacked["id_lo"] = stacked["id_lo"].at[k + 1].add(5_000_000)
+            ledger, codes, *rest = real(
+                ledger, stacked, counts.at[k + 1].set(counts[0]), timestamps
+            )
+            seen.append((k, np.asarray(codes)))
+            return (ledger, codes.at[k:].set(0xFFFFFFFF), *rest)
+
+        monkeypatch.setattr(machine, "_group_fast_dispatch",
+                            planted_and_poisoned)
+        grouped = make_machine(True)
+        serial = make_machine(False)
+        for n, k in enumerate((3, 7)):
+            res_g, res_s = self._commit_both(
+                grouped, serial, self._run_of(k, 10_000 * (n + 1))
+            )
+            assert res_g == res_s
+        assert grouped.digest() == serial.digest()
+        assert [k for k, _ in seen] == [3, 7]
+        for k, codes in seen:
+            assert codes.shape[0] == TpuStateMachine.GROUP_K
+            assert codes[:k].any() and not codes[k:].any()
+
+    def test_every_run_length_reuses_one_compiled_program(self):
+        grouped = make_machine(True)
+        serial = make_machine(False)
+        self._commit_both(grouped, serial, self._run_of(2))
+        warmed = machine._group_fast_dispatch._cache_size()
+        assert warmed >= 1
+        for n, k in enumerate((3, 7, 8, TpuStateMachine.GROUP_K, 2)):
+            res_g, res_s = self._commit_both(
+                grouped, serial, self._run_of(k, 10_000 * (n + 2))
+            )
+            assert res_g == res_s
+            assert machine._group_fast_dispatch._cache_size() == warmed
+        assert grouped.digest() == serial.digest()
 
     def test_ineligible_run_refused(self):
         m = make_machine(True)

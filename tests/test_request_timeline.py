@@ -418,7 +418,6 @@ def test_group_scan_counters_count_useful_and_run_steps(tmp_path):
         for c in clients:
             h.register(c)
         h.setup_accounts(clients[0])
-        k_steps = h.r.machine.GROUP_K
         with registry.enabled_scope():
             for n, (request_n, width) in enumerate(((2, 3), (3, 2), (4, 1))):
                 reqs = [h.request(c, request_n,
@@ -428,10 +427,10 @@ def test_group_scan_counters_count_useful_and_run_steps(tmp_path):
                         for k, c in enumerate(clients[:width])]
                 h.serve(reqs)[1].result()
                 counters = registry.snapshot()["counters"]
-                # 3 of 32, then 2 more of 32 more; a lone request rides
-                # the fast kernel and moves neither.
-                want = {0: (3, k_steps), 1: (5, 2 * k_steps),
-                        2: (5, 2 * k_steps)}[n]
+                # 3 steps for 3, then 2 more for 2 more (never GROUP_K a
+                # group); a lone request rides the fast kernel and moves
+                # neither.
+                want = {0: (3, 3), 1: (5, 5), 2: (5, 5)}[n]
                 assert (counters["ops.group.batches"],
                         counters["ops.group.steps"]) == want
     finally:

@@ -71,6 +71,22 @@ def _on(tree, sharding):
     )
 
 
+def _padded_scan_dispatch(ledger, stacked, counts, timestamps):
+    """The grouped dispatch as it was before PR 27: a lax.scan over all
+    GROUP_K rows, zero-count rows included.  Kept here as the yardstick
+    for the loop's temp bytes only."""
+
+    def step(led, xs):
+        soa, cnt, ts = xs
+        return sm.create_transfers_impl(led, soa, cnt, ts)
+
+    ledger, codes = jax.lax.scan(step, ledger, (stacked, counts, timestamps))
+    return (
+        ledger, codes, ledger.transfers.probe_overflow.astype(jnp.uint32),
+        stacked["id_lo"], stacked["id_hi"],
+    )
+
+
 def _one_chip_lowerings(topo):
     one = SingleDeviceSharding(topo.devices[0])
     led = _on(jax.eval_shape(lambda: sm.make_ledger(ACC, TR, POSTED, HIST)),
@@ -94,6 +110,9 @@ def _one_chip_lowerings(topo):
             led, batch, u64, u64),
         "grouped": lambda: machine._group_fast_dispatch.lower(
             led, _soa(types.TRANSFER_DTYPE, one, lead=(k,)), kvec, kvec),
+        "grouped_padded_scan": lambda: jax.jit(
+            _padded_scan_dispatch, donate_argnames=("ledger",)
+        ).lower(led, _soa(types.TRANSFER_DTYPE, one, lead=(k,)), kvec, kvec),
         "full_scan_plain": full(False),
         "full_scan_postvoid": full(True),
     }
@@ -137,3 +156,16 @@ def test_compiles_for_v5e(topo, no_persistent_cache, program):
         # The cross-shard context exchange is a psum: the compiler must
         # have put an all-reduce in.
         assert "all-reduce" in compiled.as_text()
+
+
+def test_grouped_loop_needs_no_more_temp_than_the_padded_scan(
+    topo, no_persistent_cache
+):
+    """The run-time trip count must not cost the program a second copy of
+    anything table-sized (at the served 2^21 / 2^23 by hand: 929,940,992 B
+    against the scan's 929,973,248, PERF.md PR 27)."""
+    lowerings = _one_chip_lowerings(topo)
+    loop = lowerings["grouped"]().compile().memory_analysis()
+    scan = lowerings["grouped_padded_scan"]().compile().memory_analysis()
+    assert 0 < loop.temp_size_in_bytes <= scan.temp_size_in_bytes
+    assert loop.argument_size_in_bytes == scan.argument_size_in_bytes

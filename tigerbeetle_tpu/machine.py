@@ -76,9 +76,19 @@ QUERY_ROWS_MAX = ((1 << 20) - 256) // 128
 
 
 def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
-    """Scan the fast commit kernel over GROUP_K stacked batches: one device
-    dispatch, batch order preserved, ledger threaded through the carry
-    (see TpuStateMachine.commit_group_fast).
+    """Run the fast commit kernel over the leading batches of a GROUP_K
+    stack, one loop step per batch the group HOLDS: one device dispatch,
+    batch order preserved, ledger threaded through the carry (see
+    TpuStateMachine.commit_group_fast).
+
+    The trip count is a run-time value: the loop ends at the first zero
+    of ``counts`` (a group's batches lead the stack and none is empty) or
+    at GROUP_K, so every group length runs the ONE program the
+    (GROUP_K, lanes) shapes compile to.  On the chip a step over an empty
+    batch costs most of what a full one does (its table-sized work does
+    not depend on the count: one TPU v5 lite, PERF.md section 5), so the
+    rows past the group are not run at all: their codes stay the zeros
+    the buffer starts with and are never read.
 
     Besides (ledger, codes) it returns the transfers probe_overflow flag
     widened into a FRESH uint32 buffer (the deferred readback handle must
@@ -91,14 +101,34 @@ def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
     (the _stage_group zero-copy note), and a donated alias would let XLA
     scribble scratch into the pooled staging set behind the dirty-row
     tracking's back."""
+    steps_max = counts.shape[0]
 
-    def step(led, xs):
-        soa, cnt, ts = xs
+    def row(i):
+        soa = {
+            name: jax.lax.dynamic_index_in_dim(col, i, keepdims=False)
+            for name, col in stacked.items()
+        }
+        return soa, counts[i], timestamps[i]
+
+    def holds_a_batch(carry):
+        i = carry[0]
+        # counts[i] clamps at the last row when i == steps_max.
+        return (i < steps_max) & (counts[i] != 0)
+
+    def step(carry):
+        i, led, codes = carry
         with jax.named_scope("tb/group_step"):
-            led, codes = sm.create_transfers_impl(led, soa, cnt, ts)
-        return led, codes
+            led, row_codes = sm.create_transfers_impl(led, *row(i))
+        return i + 1, led, jax.lax.dynamic_update_index_in_dim(
+            codes, row_codes, i, 0
+        )
 
-    ledger, codes = jax.lax.scan(step, ledger, (stacked, counts, timestamps))
+    # Result codes are uint32 lanes (a step of another dtype fails the
+    # trace at the update below).
+    codes = jnp.zeros(stacked["id_lo"].shape, jnp.uint32)
+    _, ledger, codes = jax.lax.while_loop(
+        holds_a_batch, step, (jnp.int32(0), ledger, codes)
+    )
     return (
         ledger, codes, ledger.transfers.probe_overflow.astype(jnp.uint32),
         stacked["id_lo"], stacked["id_hi"],
@@ -380,9 +410,11 @@ class TpuStateMachine:
             if _obs.enabled:
                 _obs.gauge("sharding.shards").set(shards)
         # Grouped device commit (commit_group_fast).  None = auto: enabled
-        # on the TPU backend, where an empty scan step is us-scale; on
-        # XLA-CPU each step pays table-sized temporaries, so per-batch
-        # dispatch is cheaper there.  Tests force True to pin the path.
+        # on the TPU backend only.  The gate dates from the padded scan,
+        # whose empty steps paid table-sized temporaries on XLA-CPU (and
+        # 32 ms each on a v5e, PERF.md PR 27); the loop runs none now, but
+        # nobody has measured it on XLA-CPU.  Tests force True to pin the
+        # path.
         self._group_device_commit: Optional[bool] = None
         # Host data-plane mode (host_engine.py): commits run in the native
         # engine over a numpy mirror; the device ledger is materialized
@@ -1990,8 +2022,10 @@ class TpuStateMachine:
                 )
                 np.asarray(codes_p)
             if self.group_device_commit:
-                # The grouped dispatch is a distinct program (scan over
-                # GROUP_K); a client must never pay its compile mid-group.
+                # The grouped dispatch is a distinct program, ONE for
+                # every group length (its shapes are GROUP_K's; zero counts
+                # run zero steps); a client must never pay its compile
+                # mid-group.
                 stacked = {
                     key: jnp.stack([v] * self.GROUP_K)
                     for key, v in soa_t.items()
@@ -2938,11 +2972,11 @@ class TpuStateMachine:
         with ``grow`` / ``dispatch`` / ``index_append`` as its children.
         On XLA-CPU the jitted calls may compute synchronously inside the
         closure.  On a TPU only the ``dispatch`` child is an enqueue
-        (1.9 ms mean); ``index_append`` then holds the thread until the
+        (2-4 ms); ``index_append`` then holds the thread until the
         device has run the commit and the appends queued behind it (all
-        but 2 ms of a grouped closure's 1.1 s, and so the same in the
-        serving thread's join, ``dispatch_wait``; ``readback`` is left
-        with under 1 ms after a grouped closure — one TPU v5 lite, PR 26,
+        but 4 ms of a grouped closure's 0.54-0.62 s, and so the same in
+        the serving thread's join, ``dispatch_wait``; ``readback`` is left
+        with under 1 ms after a grouped closure — one TPU v5 lite, PR 27,
         PERF.md section 5).  The lane thread's observations land in the
         same process-global ledger.  ``seq``: the submitting group's (the
         lane runs later)."""
@@ -2960,11 +2994,14 @@ class TpuStateMachine:
             self._dispatch_lane().submit(staged) if deferred else staged()
         )
 
-    # Fixed scan length for the grouped dispatch: ONE jit variant (warmed at
-    # startup), groups pad with zero-count batches (the kernel applies
-    # nothing for count=0).  An empty step costs ~the kernel's launch-free
-    # body; amortizing GROUP_K batches over one dispatch + one readback
-    # keeps the device serving path off the per-dispatch host round trip.
+    # The cap on a grouped dispatch's run, and the leading dimension of its
+    # stacked operands: ONE jit variant (warmed at startup) whatever the
+    # group's length.  A group pads the stack with zero-count rows and the
+    # program's loop stops at the first of them, so a group of k costs k
+    # steps (a padded step would cost most of what a full one does: the
+    # kernel's table-sized work does not depend on the count).  Amortizing
+    # a run over one dispatch + one readback keeps the device serving path
+    # off the per-dispatch host round trip.
     GROUP_K = 32
 
     def _stage_acquire(self):
@@ -3024,12 +3061,13 @@ class TpuStateMachine:
         deferred: bool = False,
     ):
         """Commit a RUN of fast-path-eligible create_transfers batches in
-        ONE device dispatch (lax.scan over the stacked batches) with ONE
-        device->host codes transfer.
+        ONE device dispatch (a loop over the stacked batches that runs
+        len(batches) steps, not GROUP_K) with ONE device->host codes
+        transfer.
 
         Returns per-batch results index-aligned with ``batches``, or None
         when the run is not groupable — caller falls back to per-batch
-        commits.  Scan order == batch order, and each batch carries its
+        commits.  Loop order == batch order, and each batch carries its
         own already-assigned prepare timestamp, so results are
         bit-identical to committing the run batch by batch.
 
@@ -3074,9 +3112,9 @@ class TpuStateMachine:
             )
         k = len(batches)
         if _obs.enabled:
-            # Useful steps over steps run: the scan always runs GROUP_K.
+            # Useful steps over steps run: the loop runs one a batch.
             _obs.counter("ops.group.batches").inc(k)
-            _obs.counter("ops.group.steps").inc(self.GROUP_K)
+            _obs.counter("ops.group.steps").inc(k)
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d", n=k):
             stacked, stage = self._stage_group(batches)
@@ -3160,7 +3198,7 @@ class TpuStateMachine:
         batches are staged H2D on the serving thread, then ONE dispatch-
         lane closure drives the cached ``sharded.machine_steps``
         fast_probed program once per batch — per-batch shard_map dispatch
-        (the scan-grouped single-device program would re-trace per mesh
+        (the loop-grouped single-device program would re-trace per mesh
         layout; the per-shard lanes are the parallelism lever here) with
         the ledger chain threaded through, growth snapshotted at submit,
         and ONE deferred D2H readback (codes + per-shard overflow lanes)

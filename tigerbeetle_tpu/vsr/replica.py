@@ -1182,7 +1182,7 @@ class Replica:
         # Grouping off (or one segment): each segment dispatches through
         # the per-batch deferred fast kernel — a fused segment IS one
         # batch there, which is the whole win on hosts without the
-        # grouped scan (fewer padded kernel bodies, fewer readbacks).
+        # grouped dispatch (fewer padded kernel bodies, fewer readbacks).
         pairs = []
         covered = 0
         for subrun, batch, timestamp in plan:
@@ -1254,19 +1254,22 @@ class Replica:
         across the whole commit group.  Returns {first_admitted_index: run} where
         run = [(admitted_index, batch, timestamp), ...]; the commit loop
         dispatches each run when it REACHES it, preserving op order.
-        Results are bit-identical to per-op commits (scan order == op
-        order, per-op prepare timestamps ride along).
+        Results are bit-identical to per-op commits (loop order == op
+        order, per-op prepare timestamps ride along), and a run of k costs
+        k steps of the device loop, whatever GROUP_K is: cutting a group
+        into two runs costs one more dispatch and join, not a second
+        GROUP_K of steps.
 
         ``single_ok`` (the pipelined engine): length-1 runs are emitted
         too — a lone create_transfers op dispatches DEFERRED through the
         per-batch fast kernel (machine.commit_fast_deferred), so the
-        readback overlap works even where grouping is off (XLA-CPU, where
-        an empty scan step pays table-sized temporaries).  When grouping
-        is off entirely, every create_transfers op becomes its own run."""
+        readback overlap works even where grouping is off (XLA-CPU: the
+        auto-gate turns it on only on a TPU backend).  When grouping is
+        off entirely, every create_transfers op becomes its own run."""
         runs: Dict[int, List[Tuple]] = {}
         machine = self.machine
         grouping = bool(getattr(machine, "group_device_commit", False))
-        # TB_FUSE widens run collection even where the grouped scan is
+        # TB_FUSE widens run collection even where the grouped dispatch is
         # unavailable: the fusion planner (_dispatch_run_split) needs to
         # SEE consecutive create_transfers ops to coalesce them, and its
         # fused segments dispatch through the per-batch kernel there.
