@@ -1,11 +1,12 @@
 """Stable device names: the `tb/<phase>` scopes of the programs the accepted
-cell's profiler window shows (the fast kernel, the grouped scan, the index).
+cells' profiler windows show (the fast kernel, the grouped scan, the general
+kernel, the index).
 
 An operation's `op_name` in a device trace carries the `jax.named_scope` it
 was traced under, so the scopes must be in each program's lowered text; and
 they are metadata only, so the programs still answer as `testing/model.py`
-does.  The general kernel and the lookups carry none yet: their scopes come
-with the cell whose window reaches them."""
+does.  The lookups carry none yet: their scopes come with the cell whose
+window reaches them."""
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from test_pipeline import LANES, batch, make_machine, make_model
 from tigerbeetle_tpu import machine, types
 from tigerbeetle_tpu.ops import index
 from tigerbeetle_tpu.ops import state_machine as sm
+from tigerbeetle_tpu.ops import transfer_full as tf
 from tigerbeetle_tpu.testing import model as M
 
 
@@ -44,6 +46,10 @@ def _lowered(program):
     if program == "grouped":
         return machine._group_fast_dispatch.lower(
             led, _soa(lead=(k,)), kvec, kvec)
+    if program == "general":  # the two-phase variant the machine serves
+        return tf.create_transfers_full.lower(
+            led, _soa(), u64, u64, max_passes=8, has_postvoid=True,
+            has_history=False, use_waves=True)
     if program == "index_build":
         return index.build_runs.lower(led, ids, ids, ok)
     assert program == "index_merge"
@@ -55,6 +61,8 @@ def _lowered(program):
     ("fast", ("tb/probe", "tb/validate", "tb/balance", "tb/insert")),
     ("grouped", ("tb/group_step", "tb/probe", "tb/validate", "tb/balance",
                  "tb/insert")),
+    ("general", ("tb/full_gather", "tb/full_waves", "tb/full_pass",
+                 "tb/full_apply", "tb/full_posted")),
     ("index_build", ("tb/index_probe", "tb/index_sort")),
     ("index_merge", ("tb/index_merge",)),
 ])

@@ -79,8 +79,22 @@ def main(argv=None) -> int:
     p_start.add_argument("--addresses", default=default_address,
                          help="host:port to listen on")
     p_start.add_argument("--cache-accounts-log2", type=int, default=None,
-                         help="accounts table capacity (log2 slots)")
-    p_start.add_argument("--cache-transfers-log2", type=int, default=None)
+                         metavar="N",
+                         help="accounts table capacity at start, 2^N slots "
+                              "(default 16; alone it also sets the "
+                              "transfers table to N + 2)")
+    p_start.add_argument("--cache-transfers-log2", type=int, default=None,
+                         metavar="N",
+                         help="transfers table capacity at start, 2^N "
+                              "slots (default 18)")
+    p_start.add_argument("--cache-posted-log2", type=int, default=None,
+                         metavar="N",
+                         help="posted table capacity at start, 2^N slots "
+                              "(default 16): one row per posted or voided "
+                              "pending transfer.  Every table still "
+                              "doubles at load 0.5, and each growth "
+                              "recompiles the commit kernels: size for "
+                              "twice the rows you expect")
     p_start.add_argument("--aof", default=None, metavar="PATH",
                          help="append-only audit log of committed prepares")
     p_start.add_argument("--statsd", default=None, metavar="HOST:PORT",
@@ -772,8 +786,46 @@ def _arm_blackbox(replica) -> None:
     atexit.register(lambda: replica.dump_blackbox("exit"))
 
 
-def _cmd_start(args) -> int:
+# What a table can take: 2^32 slots of the narrowest table (posted, 21 B a
+# slot) are more than the memory of any device this serves from.
+_TABLE_LOG2_MAX = 32
+
+
+def _ledger_config(args):
+    """`start`'s three table options onto the default LedgerConfig, each on
+    its own.  Raises ValueError for a size the tables cannot take: nothing
+    is clamped, because a server with other tables than its operator asked
+    for is another deployment."""
+    import dataclasses
+
     from .config import LedgerConfig
+
+    sizes = {
+        "accounts": args.cache_accounts_log2,
+        "transfers": args.cache_transfers_log2,
+        "posted": args.cache_posted_log2,
+    }
+    if sizes["transfers"] is None and sizes["accounts"] is not None:
+        sizes["transfers"] = sizes["accounts"] + 2
+    shards = args.shards
+    if shards is None:
+        env = os.environ.get("TB_SHARDS", "")
+        shards = int(env) if env.isdigit() else 0
+    log2_min = max(1, shards).bit_length() - 1  # a slot for every shard
+    for table, log2 in sizes.items():
+        if log2 is not None and not log2_min <= log2 <= _TABLE_LOG2_MAX:
+            raise ValueError(
+                f"--cache-{table}-log2 {log2}: the {table} table takes "
+                f"2^{log2_min} to 2^{_TABLE_LOG2_MAX} slots"
+                + (f" under --shards {shards}" if shards >= 2 else "")
+            )
+    return dataclasses.replace(LedgerConfig(), **{
+        f"{table}_capacity_log2": log2
+        for table, log2 in sizes.items() if log2 is not None
+    })
+
+
+def _cmd_start(args) -> int:
     from .net.bus import run_server
     from .vsr.replica import Replica
 
@@ -847,14 +899,11 @@ def _cmd_start(args) -> int:
         **({"tick_ms": args.tick_ms} if args.tick_ms is not None else {}),
     )
 
-    ledger_config = LedgerConfig()
-    if args.cache_accounts_log2 is not None:
-        ledger_config = LedgerConfig(
-            accounts_capacity_log2=args.cache_accounts_log2,
-            transfers_capacity_log2=(
-                args.cache_transfers_log2 or args.cache_accounts_log2 + 2
-            ),
-        )
+    try:
+        ledger_config = _ledger_config(args)
+    except ValueError as err:
+        print(f"error: {err}", file=sys.stderr)
+        return 1
     addresses = _parse_addresses(args.addresses)
     if len(addresses) > 1:
         # Multi-replica cluster: full VSR consensus over the TCP bus.  The

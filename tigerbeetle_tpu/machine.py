@@ -2228,14 +2228,28 @@ class TpuStateMachine:
         if self._fast_path_ok(batch):
             return self._commit_fast(batch, timestamp, count)
 
+        with txtrace.stage("general_commit", n=count):
+            return self._commit_general(batch, timestamp, count)
+
+    def _commit_general(
+        self, batch: np.ndarray, timestamp: int, count: int
+    ) -> List[Tuple[int, int]]:
+        """The general (Jacobi) kernel's route, blocking on the calling
+        thread: one dispatch and one device sync per attempt."""
         from .ops import transfer_full as tf
 
         pv_count, hist_count = self._transfer_growth_counts(batch)
-        self._grow_if_needed(transfers=count, posted=pv_count, history=hist_count)
-        soa = self._pad_soa(batch)
-        cold_checked = (
-            jnp.zeros((self.batch_lanes,), jnp.bool_) if self._tiering else None
-        )
+        with txtrace.stage("grow"):
+            self._grow_if_needed(
+                transfers=count, posted=pv_count, history=hist_count
+            )
+        with txtrace.stage("stage_h2d"):
+            soa = self._pad_soa(batch)
+            cold_checked = (
+                jnp.zeros((self.batch_lanes,), jnp.bool_)
+                if self._tiering else None
+            )
+            count_dev, timestamp_dev = jnp.uint64(count), jnp.uint64(timestamp)
         # STATIC phase hints: a batch with no post/void lanes skips the
         # four pending-side probe loops and the posted write; a ledger that
         # provably holds no HISTORY-flagged account skips the 21-column
@@ -2244,13 +2258,14 @@ class TpuStateMachine:
         has_history = self._history_accounts_possible
         use_waves = self.waves_enabled
         for _attempt in range(8):
-            r = tf.create_transfers_full(
-                self.ledger, soa, jnp.uint64(count), jnp.uint64(timestamp),
-                self._bloom_dev, cold_checked,
-                max_passes=self.config.jacobi_max_passes,
-                has_postvoid=has_postvoid, has_history=has_history,
-                use_waves=use_waves,
-            )
+            with txtrace.stage("dispatch"):
+                r = tf.create_transfers_full(
+                    self.ledger, soa, count_dev, timestamp_dev,
+                    self._bloom_dev, cold_checked,
+                    max_passes=self.config.jacobi_max_passes,
+                    has_postvoid=has_postvoid, has_history=has_history,
+                    use_waves=use_waves,
+                )
             self.ledger, codes, kflags = r[0], r[1], r[2]
             wave_vec = r[3] if use_waves else None
             # The kflags scalar read IS this path's blocking device sync
@@ -2266,6 +2281,8 @@ class TpuStateMachine:
                 # and the batch's hot gathers).
                 self._maybe_evict_between_batches()
                 return results
+            if _obs.enabled:
+                _obs.counter("ops.general.retries").inc()
             ev0 = self._evictions
             if kflags & tf.FLAG_COLD:
                 # Possible cold-tier ids: resolve exactly on the host,
@@ -2298,14 +2315,15 @@ class TpuStateMachine:
         timed so the e2e decomposition sees the device wait."""
         self._injected_fault_check()
         t0 = _time.perf_counter()
-        if wave_vec is not None and _obs.enabled:
-            got = jax.device_get(  # tblint: ignore[host-sync] commit barrier
-                (kflags, wave_vec)
-            )
-            kflags, wave_host = int(got[0]), got[1]
-        else:
-            kflags = int(kflags)
-            wave_host = None
+        with txtrace.stage("full_sync"):
+            if wave_vec is not None and _obs.enabled:
+                got = jax.device_get(  # tblint: ignore[host-sync] commit barrier
+                    (kflags, wave_vec)
+                )
+                kflags, wave_host = int(got[0]), got[1]
+            else:
+                kflags = int(kflags)
+                wave_host = None
         wait = _time.perf_counter() - t0
         self.disp_wait_s += wait
         self.disp_count += 1
@@ -2324,11 +2342,14 @@ class TpuStateMachine:
             self._record_wave_metrics(wave_host)
         if _obs.enabled:
             _obs.counter("ops.route.general").inc()
+            _obs.counter("ops.general.lanes").inc(count)
+            _obs.counter("ops.general.postvoid_lanes").inc(pv_count)
         codes = np.asarray(codes)
         self._transfers_bound += count
         self._posted_bound += pv_count
         self._history_bound += hist_count
-        self._index_append(soa, codes, count)
+        with txtrace.stage("index_append"):
+            self._index_append(soa, codes, count)
         results = self._compress(codes, count)
         self._update_commit_timestamp(codes, count, timestamp)
         return results

@@ -62,8 +62,10 @@ STAGES = (
     "prepare",          # replica: header assign + hash chain per request
     "stage_h2d",        # machine: staging fill + device_put of a run
     "device_execute",   # machine: the commit closure (lane thread if deferred)
+    "general_commit",   # ... the general kernel's blocking route, whole
     "grow",             # ... its table growth check
     "dispatch",         # ... its jitted commit call(s)
+    "full_sync",        # ... the general route's blocking device wait
     "index_append",     # ... its secondary-index maintenance
     "merkle_refresh",   # ... touched-path leaf->root update kernels
     "wal_write",        # replica: journal appends of the group
@@ -77,10 +79,14 @@ STAGES = (
 
 # Stages that only ever run INSIDE another stage's block on the same
 # thread: ``device_execute``'s children.  A sum over stages that wants
-# wall time (bench.py's coverage) leaves them out.  The bus's sections
-# below hold the replica's and the machine's serving-thread spans the same
-# way: a sum that takes the sections takes nothing else of that thread.
-NESTED_STAGES = ("grow", "dispatch", "index_append", "merkle_refresh")
+# wall time (bench.py's coverage) leaves them out; on the general route
+# alone ``stage_h2d`` is nested too (the staging is part of the blocking
+# closure), so such a sum over general requests counts it twice.  The
+# bus's sections below hold the replica's and the machine's
+# serving-thread spans the same way: a sum that takes the sections takes
+# nothing else of that thread.
+NESTED_STAGES = ("general_commit", "grow", "dispatch", "full_sync",
+                 "index_append", "merkle_refresh")
 
 # The serving thread's top-level synchronous sections (bus sites, never
 # nested in one another): their durations add up to ``serve.busy_us``,

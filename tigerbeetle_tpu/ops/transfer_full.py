@@ -59,6 +59,15 @@ Structure (round-3 refactor for the sharded path, parallel/sharded.py):
                     legs — identical replicated math on every shard, no
                     table access;
     apply           claims + scatters, owner-local on a mesh.
+
+Device names (docs/tracing.md; ``jax.named_scope``, metadata only): the
+phases of the single-chip program carry ``tb/full_gather`` (the id index,
+its in-batch pending join, and every table probe and gather of
+build_gather_ctx), ``tb/full_waves`` (_wave_schedule), ``tb/full_pass`` (one
+Jacobi pass: _leg_balances and the ladders), ``tb/full_apply`` (the
+transfers claim, the balance scatter, the row and history writes) and
+``tb/full_posted`` (the posted table's claim and fulfillment write).  The
+routing flags and history values taken from the fixpoint carry none.
 """
 
 from __future__ import annotations
@@ -196,6 +205,7 @@ class IdIndex(NamedTuple):
     any_dup: jax.Array  # bool: some nonzero id occurs twice
 
 
+@jax.named_scope("tb/full_gather")
 def _build_id_index(id_lo, id_hi) -> IdIndex:
     n = id_lo.shape[0]
     lane = jnp.arange(n, dtype=jnp.int32)
@@ -419,6 +429,7 @@ def _leg_balances(
     )
 
 
+@jax.named_scope("tb/full_waves")
 def _wave_schedule(
     hazard: jax.Array,
     unschedulable: jax.Array,
@@ -533,6 +544,7 @@ def _account_view(table, look, found, rows=None) -> AccountView:
     )
 
 
+@jax.named_scope("tb/full_gather")
 def build_gather_ctx(
     ledger: Ledger,
     batch: Dict[str, jax.Array],
@@ -722,13 +734,14 @@ def _kernel_core(
 
     if has_postvoid:
         # In-batch pending-create candidate group for each pv lane.
-        pj = _search128(idx.s_hi, idx.s_lo, pend_id.hi, pend_id.lo)
-        pj_c = jnp.minimum(pj, n - 1)
-        pj_hit = (
-            (idx.s_hi[pj_c] == pend_id.hi)
-            & (idx.s_lo[pj_c] == pend_id.lo) & (pj < n)
-        )
-        pj_group = idx.gid[pj_c]
+        with jax.named_scope("tb/full_gather"):
+            pj = _search128(idx.s_hi, idx.s_lo, pend_id.hi, pend_id.lo)
+            pj_c = jnp.minimum(pj, n - 1)
+            pj_hit = (
+                (idx.s_hi[pj_c] == pend_id.hi)
+                & (idx.s_lo[pj_c] == pend_id.lo) & (pj < n)
+            )
+            pj_group = idx.gid[pj_c]
 
     timeout_ns = batch["timeout"].astype(jnp.uint64) * jnp.uint64(NS_PER_S)
     ov_timeout = (ts + timeout_ns) < ts
@@ -803,6 +816,7 @@ def _kernel_core(
     # One Jacobi pass of the sequential semantics.
     # ------------------------------------------------------------------
 
+    @jax.named_scope("tb/full_pass")
     def one_pass(ok_prev: jax.Array, amt_prev: U128):
         inf = jnp.int32(n)
         winner_g, winner_of_lane = _group_winner(idx, ok_prev)
@@ -1352,14 +1366,16 @@ def create_transfers_full_impl(
 
     # Insert slots are claimed (no writes) BEFORE the flags are finalized so
     # an insert-probe overflow also routes the batch with nothing applied.
-    t_claim, t_ovf = ht.claim_slots(
-        ledger.transfers, tid.lo, tid.hi, plan.ok, MAX_PROBE
-    )
-    if has_postvoid:
-        p_claim, p_ovf = ht.claim_slots(
-            ledger.posted, plan.posted_key, jnp.zeros((n,), jnp.uint64),
-            plan.pv_ok, MAX_PROBE,
+    with jax.named_scope("tb/full_apply"):
+        t_claim, t_ovf = ht.claim_slots(
+            ledger.transfers, tid.lo, tid.hi, plan.ok, MAX_PROBE
         )
+    if has_postvoid:
+        with jax.named_scope("tb/full_posted"):
+            p_claim, p_ovf = ht.claim_slots(
+                ledger.posted, plan.posted_key, jnp.zeros((n,), jnp.uint64),
+                plan.pv_ok, MAX_PROBE,
+            )
     else:
         # Host proved no post/void lanes: plan.pv_ok is all-False, so the
         # probe loop and the fulfillment write below compile away.
@@ -1378,50 +1394,56 @@ def create_transfers_full_impl(
     # last iterate, which equals the final (ok, amount) whenever the batch
     # commits (stability), so the last leg of each slot run carries the
     # slot's exact final field values.
-    scat = plan.scat & commit
-    cap_sentinel = jnp.uint64(ledger.accounts.capacity)
-    accounts = ht.scatter_cols(
-        ledger.accounts, jnp.where(scat, plan.s_slot, cap_sentinel), scat,
-        plan.bal_incl,
-    )
-
-    # ---------------- apply: transfer + posted inserts ---------------------
-    ins_rows = {
-        name: plan.row[name].astype(dt) for name, dt in TRANSFER_COLS.items()
-    }
-    transfers = ht.write_rows(
-        ledger.transfers, tid.lo, tid.hi, t_claim, plan.ok & commit, ins_rows
-    )
-    if has_postvoid:
-        posted = ht.write_rows(
-            ledger.posted,
-            plan.posted_key,
-            jnp.zeros((n,), jnp.uint64),
-            p_claim,
-            plan.pv_ok & commit,
-            {"fulfillment": jnp.where(plan.post, jnp.uint32(1), jnp.uint32(2))},
+    with jax.named_scope("tb/full_apply"):
+        scat = plan.scat & commit
+        cap_sentinel = jnp.uint64(ledger.accounts.capacity)
+        accounts = ht.scatter_cols(
+            ledger.accounts, jnp.where(scat, plan.s_slot, cap_sentinel), scat,
+            plan.bal_incl,
         )
+
+        # ------------- apply: transfer + posted inserts -------------------
+        ins_rows = {
+            name: plan.row[name].astype(dt)
+            for name, dt in TRANSFER_COLS.items()
+        }
+        transfers = ht.write_rows(
+            ledger.transfers, tid.lo, tid.hi, t_claim, plan.ok & commit,
+            ins_rows,
+        )
+    if has_postvoid:
+        with jax.named_scope("tb/full_posted"):
+            posted = ht.write_rows(
+                ledger.posted,
+                plan.posted_key,
+                jnp.zeros((n,), jnp.uint64),
+                p_claim,
+                plan.pv_ok & commit,
+                {"fulfillment": jnp.where(
+                    plan.post, jnp.uint32(1), jnp.uint32(2))},
+            )
     else:
         posted = ledger.posted
 
     # ---------------- apply: history rows ---------------------------------
     if has_history:
-        do_hist_c = plan.do_hist & commit
-        h = ledger.history
-        h_off = (
-            jnp.cumsum(do_hist_c.astype(jnp.uint64))
-            - do_hist_c.astype(jnp.uint64)
-        )
-        h_idx = jnp.where(do_hist_c, h.count + h_off, jnp.uint64(h.capacity))
-        history = h.replace(
-            cols={
-                name: h.cols[name].at[h_idx].set(
-                    plan.hist_row[name], mode="drop"
-                )
-                for name in h.cols
-            },
-            count=h.count + jnp.sum(do_hist_c.astype(jnp.uint64)),
-        )
+        with jax.named_scope("tb/full_apply"):
+            do_hist_c = plan.do_hist & commit
+            h = ledger.history
+            h_off = (
+                jnp.cumsum(do_hist_c.astype(jnp.uint64))
+                - do_hist_c.astype(jnp.uint64)
+            )
+            h_idx = jnp.where(do_hist_c, h.count + h_off, jnp.uint64(h.capacity))
+            history = h.replace(
+                cols={
+                    name: h.cols[name].at[h_idx].set(
+                        plan.hist_row[name], mode="drop"
+                    )
+                    for name in h.cols
+                },
+                count=h.count + jnp.sum(do_hist_c.astype(jnp.uint64)),
+            )
     else:
         # Host proved no account carries the HISTORY flag: the 21-column
         # append scatter compiles away.
