@@ -118,7 +118,7 @@ def _one_chip_lowerings(topo):
     }
 
 
-def _sharded_fast_lowering(topo):
+def _sharded_lowering(topo, make_step):
     mesh = Mesh(np.array(topo.devices[:4]), (sharded.AXIS,))
     shard = NamedSharding(mesh, P(sharded.AXIS))
     repl = NamedSharding(mesh, P())
@@ -136,23 +136,37 @@ def _sharded_fast_lowering(topo):
         posted=table(led.posted), history=_on(led.history, repl),
     )
     u64 = jax.ShapeDtypeStruct((), jnp.uint64, sharding=repl)
-    step = sharded.sharded_create_transfers(mesh, probed=True)
-    return step.lower(led, _soa(types.TRANSFER_DTYPE, repl), u64, u64)
+    return make_step(mesh).lower(
+        led, _soa(types.TRANSFER_DTYPE, repl), u64, u64
+    )
 
 
 @pytest.mark.parametrize("program", [
     "fast", "grouped", "full_scan_plain", "full_scan_postvoid",
-    "sharded_fast_4",
+    "sharded_fast_4", "sharded_full_scan_4",
 ])
-def test_compiles_for_v5e(topo, no_persistent_cache, program):
+def test_compiles_for_v5e(topo, no_persistent_cache, monkeypatch, program):
     if program == "sharded_fast_4":
-        lowered = _sharded_fast_lowering(topo)
+        lowered = _sharded_lowering(
+            topo, lambda mesh: sharded.sharded_create_transfers(
+                mesh, probed=True)
+        )
+    elif program == "sharded_full_scan_4":
+        # The mesh path leaves the loop form to the backend: say "tpu"
+        # while it traces, so the gated scan is what gets lowered.
+        with monkeypatch.context() as m:
+            m.setattr(tf.jax, "default_backend", lambda: "tpu")
+            lowered = _sharded_lowering(
+                topo, lambda mesh: sharded.sharded_create_transfers_full(
+                    mesh, max_passes=8, use_waves=True)
+            )
+        assert "stablehlo.case" in lowered.as_text()  # the gate, per pass
     else:
         lowered = _one_chip_lowerings(topo)[program]()
     compiled = lowered.compile()  # raises what the chip's compiler raises
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
-    if program == "sharded_fast_4":
+    if program.startswith("sharded"):
         # The cross-shard context exchange is a psum: the compiler must
         # have put an all-reduce in.
         assert "all-reduce" in compiled.as_text()

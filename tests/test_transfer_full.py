@@ -337,10 +337,10 @@ class TestStaticTripParity:
     @pytest.mark.slow  # ~27 s; tools/ci.py integration tier runs it
     def test_scan_and_while_paths_identical(self):
         """The TPU path runs the Jacobi fixpoint as a STATIC-trip lax.scan
-        (data-independent trip count; see _kernel_core), other backends as
-        the early-exit while_loop.  The fixpoint is absorbing, so the two
-        must agree bit-for-bit — this pins the scan path on CPU, where the
-        auto-gate would otherwise leave it untested."""
+        whose passes are gated on the loop's exit (see _kernel_core), other
+        backends as the early-exit while_loop.  Both run the same passes,
+        so the two must agree bit-for-bit — this pins the scan path on CPU,
+        where the auto-gate would otherwise leave it untested."""
         import functools
 
         import jax
@@ -428,3 +428,209 @@ class TestStaticTripParity:
         assert kf_w == kf_s
         for k in tabs_w:
             np.testing.assert_array_equal(tabs_w[k], tabs_s[k], err_msg=k)
+
+
+# ---------------------------------------------------------------------------
+# The Jacobi loop's two lowerings (the gated lax.scan a TPU gets, the
+# lax.while_loop elsewhere) run the SAME sequence of passes: codes, flags,
+# tables and the count of passes run are identical, batch by batch.
+# ---------------------------------------------------------------------------
+
+_LOOP_LANES, _LOOP_ACCOUNTS = 32, 12
+_LIMITED = range(6)  # accounts 1..6: debits_must_not_exceed_credits
+_PENDING = int(types.TransferFlags.PENDING)
+_POST = int(types.TransferFlags.POST_PENDING_TRANSFER)
+_VOID = int(types.TransferFlags.VOID_PENDING_TRANSFER)
+
+
+def _t(id, dr=0, cr=0, amount=0, flags=0, pending_id=0):
+    funded = 0 if flags & (_POST | _VOID) else 1
+    return types.transfer(
+        id=id, debit_account_id=dr, credit_account_id=cr, amount=amount,
+        ledger=funded, code=10 * funded, flags=flags, pending_id=pending_id,
+    )
+
+
+# Accounts 7..12 are unrestricted.  A limited account holds nothing, so in
+# `_CHAIN` transfer k is accepted only once transfer k-1 has been: pass k
+# settles lane k, and one more pass observes the fixpoint.
+_FUND = [_t(1, dr=7, cr=1, amount=10)]
+_CHAIN = [_t(10 + k, dr=1 + k, cr=2 + k, amount=10) for k in range(5)]
+_PENDINGS = [
+    _t(100 + k, dr=7 + k % 3, cr=10 + k % 3, amount=5 + k, flags=_PENDING)
+    for k in range(12)
+]
+
+# case -> (use_waves, max_passes, [(batch, passes the loop runs, flags)])
+_LOOP_CASES = {
+    "table_postvoid_waves_bound_1": (True, 8, [
+        (_PENDINGS, 1, 0),
+        ([_t(200 + k, flags=_VOID if k % 4 == 3 else _POST,
+             pending_id=100 + k) for k in range(12)], 1, 0),
+    ]),
+    "plain_uncontended_stable_at_2": (False, 8, [
+        ([_t(300 + k, dr=7 + k % 3, cr=10 + k % 3, amount=1 + k)
+          for k in range(9)], 2, 0),
+    ]),
+    "pending_and_post_in_one_batch_3": (True, 8, [
+        (_PENDINGS[:6] + [_t(400 + k, flags=_POST, pending_id=100 + k)
+                          for k in range(6)], 3, 0),
+    ]),
+    "limit_chain_past_the_old_head_6": (False, 8, [
+        (_FUND, 2, 0), (_CHAIN, 6, 0),
+    ]),
+    "max_passes_too_small_routes_seq": (False, 3, [
+        (_FUND, 2, 0), (_CHAIN, 3, 1),  # FLAG_SEQ: nothing applied
+    ]),
+}
+
+
+def _loop_ledger():
+    import jax.numpy as jnp
+
+    from tigerbeetle_tpu.ops import state_machine as sm
+
+    acc = types.accounts_array([
+        types.account(
+            id=i + 1, ledger=1, code=10,
+            flags=(types.AccountFlags.DEBITS_MUST_NOT_EXCEED_CREDITS
+                   if i in _LIMITED else 0),
+        )
+        for i in range(_LOOP_ACCOUNTS)
+    ])
+    padded = np.zeros(_LOOP_LANES, dtype=types.ACCOUNT_DTYPE)
+    padded[: len(acc)] = acc
+    soa = {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
+    n = jnp.uint64(_LOOP_ACCOUNTS)
+    led, codes = sm.create_accounts(
+        sm.make_ledger(1 << 6, 1 << 8, 1 << 6), soa, n, n
+    )
+    assert not np.asarray(codes)[:_LOOP_ACCOUNTS].any()
+    return led
+
+
+def _run_loop_case(monkeypatch, static_trip, use_waves, max_passes, batches):
+    """The case's batches through ONE jitted create_transfers_full_impl;
+    ``passes`` comes out of the same trace (the impl returns it only with
+    waves on)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tigerbeetle_tpu.ops import transfer_full as tf
+
+    core, seen = tf._kernel_core, {}
+
+    def spy(*args, **kwargs):
+        plan = core(*args, **kwargs)
+        seen["passes"] = plan.passes
+        return plan
+
+    monkeypatch.setattr(tf, "_kernel_core", spy)
+
+    @jax.jit
+    def fn(led, soa, count, ts):
+        out = tf.create_transfers_full_impl(
+            led, soa, count, ts, max_passes=max_passes,
+            static_trip=static_trip, use_waves=use_waves,
+        )
+        return out[0], out[1], out[2], seen["passes"]
+
+    led, ts, got = _loop_ledger(), 1_000, []
+    for rows, _, _ in batches:
+        padded = np.zeros(_LOOP_LANES, dtype=types.TRANSFER_DTYPE)
+        padded[: len(rows)] = types.transfers_array(rows)
+        soa = {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
+        ts += _LOOP_LANES
+        led, codes, kflags, passes = fn(
+            led, soa, jnp.uint64(len(rows)), jnp.uint64(ts)
+        )
+        got.append({
+            "codes": np.asarray(codes), "flags": int(kflags),
+            "passes": int(passes),
+            **{
+                f"{name}.{col}": np.asarray(v)
+                for name, t in (("accounts", led.accounts),
+                                ("transfers", led.transfers),
+                                ("posted", led.posted))
+                for col, v in {"key_lo": t.key_lo, "count": t.count,
+                               **t.cols}.items()
+            },
+        })
+    return got
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for j in v if isinstance(v, (tuple, list)) else (v,):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield from _eqns(j)
+
+
+def _named(eqns, *names):
+    return [e for e in eqns if e.primitive.name in names]
+
+
+class TestJacobiLoopForms:
+    @pytest.mark.parametrize("case", list(_LOOP_CASES))
+    def test_gated_scan_runs_the_while_loops_passes(self, case, monkeypatch):
+        use_waves, max_passes, batches = _LOOP_CASES[case]
+        whiles, scans = (
+            _run_loop_case(monkeypatch, static, use_waves, max_passes, batches)
+            for static in (False, True)
+        )
+        for (rows, passes, flags), w, s in zip(batches, whiles, scans):
+            assert w.keys() == s.keys()
+            for k in w:
+                np.testing.assert_array_equal(w[k], s[k], err_msg=k)
+            assert (s["passes"], s["flags"]) == (passes, flags)
+            if not flags:
+                assert not s["codes"][: len(rows)].any()
+        if scans[-1]["flags"]:
+            # Routed: the batch left every table as it found it.
+            for k in (k for k in scans[-1] if "." in k):
+                np.testing.assert_array_equal(
+                    scans[-2][k], scans[-1][k], err_msg=k
+                )
+
+    @pytest.mark.parametrize("max_passes", [3, 8])
+    def test_static_form_is_one_scan_of_gated_passes(self, max_passes):
+        """The static form's jaxpr has ONE pass loop: a scan of length
+        max_passes whose body is the gate and a cond (skip | pass).  A
+        pass is known by its leg sort; the program holds as many as the
+        while form's (the loop's pass and the aux pass)."""
+        import jax
+        import jax.numpy as jnp
+
+        from tigerbeetle_tpu.ops import transfer_full as tf
+
+        padded = np.zeros(_LOOP_LANES, dtype=types.TRANSFER_DTYPE)
+        soa = {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
+        u64 = jnp.uint64(0)
+
+        def pass_loops(static_trip):
+            eqns = list(_eqns(jax.make_jaxpr(
+                lambda led: tf.create_transfers_full_impl(
+                    led, soa, u64, u64, max_passes=max_passes,
+                    static_trip=static_trip,
+                )
+            )(_loop_ledger()).jaxpr))
+            loops = [
+                e for e in _named(eqns, "scan", "while")
+                if _named(_eqns(e.params.get("jaxpr", e.params.get(
+                    "body_jaxpr")).jaxpr), "sort")
+            ]
+            return loops, len(_named(eqns, "sort"))
+
+        (scan,), sorts = pass_loops(True)
+        assert scan.primitive.name == "scan"
+        assert scan.params["length"] == max_passes
+        body = scan.params["jaxpr"].jaxpr.eqns
+        (cond,) = _named(body, "cond")
+        assert len(cond.params["branches"]) == 2
+        assert not _named(body, "sort", "scan", "while")
+        (loop,), sorts_while = pass_loops(False)
+        assert loop.primitive.name == "while" and sorts == sorts_while

@@ -33,11 +33,14 @@ vector, then re-evaluates every ladder.  References only point to earlier
 lanes and a stable pass (codes AND amounts unchanged) is a fixpoint of the
 exact "evaluate lane i given outcomes of lanes j<i" operator, whose fixpoint
 is unique and equal to the sequential answer (induction over lanes).  The
-pass runs under a lax.while_loop with an early-exit stability check: pass
-k+1 resolves every batch whose outcome-change cascade depth is <= k
-(uncontended batches stabilize in 2 passes; each clamp/rejection cascade
-adds 1), up to _MAX_PASSES; deeper cascades set FLAG_SEQ and run
-sequentially.
+pass runs in a loop that ends at the first stable pass, or at the pass count
+the wave schedule proves (docs/waves.md): pass k+1 resolves every batch
+whose outcome-change cascade depth is <= k (uncontended batches stabilize
+in 2 passes, or run the 1 their wave bound proves; each clamp/rejection
+cascade adds 1), up to _MAX_PASSES; deeper cascades set FLAG_SEQ and run
+sequentially.  On a TPU the loop is a static-trip lax.scan whose every pass
+sits behind a lax.cond on that exit, elsewhere a lax.while_loop: the same
+passes run either way (see _kernel_core).
 
 The remaining FLAG_SEQ routes are genuinely order-chaotic or out-of-scope
 for the u64-limb delta machinery: unconverged fixpoints, u128 amounts,
@@ -1134,35 +1137,38 @@ def _kernel_core(
 
     # Jacobi iteration: a pass whose codes and accepted amounts equal the
     # previous pass's is a fixpoint => THE sequential answer (induction
-    # over lanes).  Two loop forms, identical results:
+    # over lanes).  The loop ends after a stable pass, or once the wave
+    # schedule's certified count has run (the iterate then IS the fixpoint,
+    # docs/waves.md: no verification pass), or when max_passes are spent.
+    # With use_waves off, sched_proved is a False constant and the exit
+    # folds to stability alone.  ONE loop in two lowerings that run the
+    # same sequence of passes, so every output is bit-identical, `passes`
+    # (the passes run) included:
     #
-    # - STATIC trip (lax.scan, length=max_passes) on TPU.  The fixpoint is
-    #   absorbing (a pass from a stable state reproduces it bit-for-bit),
-    #   so running all max_passes passes returns exactly what the early-
-    #   exit loop returns; `converged` tracks whether stability was EVER
-    #   observed (unconverged batches set FLAG_SEQ, as before).  The trip
-    #   count being data-INdependent lets XLA:TPU schedule the passes as
-    #   one straight-line program — the round-4 window-4 phase bisect
-    #   measured the while-based core at +47 ms/batch on v5e-1 with every
-    #   primitive in the body at 1-3 us (the dynamic-condition lowering
-    #   was the overhead, not the pass body).
-    # - EARLY EXIT (lax.while_loop) elsewhere: on XLA-CPU the dynamic
-    #   lowering is cheap and cascade-free batches stop after 2 of the
-    #   max_passes=8 passes — always paying all 8 would be a ~4x
-    #   regression for the CPU engine/fallback paths.
-    # The carry holds ONLY the iterate (k, stable, ok, code, amount) — aux
-    # (legs, composed rows, pending views: ~6 MB at 8k lanes) stays OUT of
-    # the loop state and is recomputed ONCE from the fixpoint afterwards.
-    # At a fixpoint the recompute reproduces the stable pass bit-for-bit
-    # (the absorbing property), so every downstream consumer sees exactly
-    # the converged pass's values; unconverged batches route FLAG_SEQ and
-    # apply nothing, so their aux values are never observable.
+    # - on a TPU a STATIC trip: lax.scan(length=max_passes) whose body is a
+    #   lax.cond on the exit condition, so a pass after the exit is skipped
+    #   on the device, not evaluated;
+    # - elsewhere a lax.while_loop (the CPU engine, fallback and tests).
+    #
+    # Measured on one v5e (PERF.md section 6, PR 29: 7,780 posts/voids of
+    # table pendings, proved bound 1, the served table sizes at 2.0-2.5 M
+    # rows, device ms an execution): this scan 84.9, the while_loop 86.0,
+    # and 120.8 for an ungated scan(4) with 4 more passes behind one gate,
+    # which evaluates 4 passes + the aux pass where the batch needs 1 + 1.
+    #
+    # The carry holds ONLY the iterate (k, stable, ok, code, amount), ~170
+    # KB to cond over — aux (legs, composed rows, pending views: ~6 MB at
+    # 8k lanes) stays OUT of the loop state and is recomputed ONCE from the
+    # final iterate afterwards.  At a fixpoint a pass reproduces its input
+    # bit-for-bit, so every downstream consumer sees exactly the converged
+    # pass's values; unconverged batches route FLAG_SEQ and apply nothing,
+    # so their aux values are never observable.
     ok0 = jnp.zeros((n,), jnp.bool_)
     code_sentinel = jnp.full((n,), 0xFFFFFFFF, jnp.uint32)
     carry0 = (jnp.int32(0), jnp.bool_(False), ok0, code_sentinel, t_amt)
 
     def step_pass(carry):
-        k, ever_stable, ok_p, code_p, amt_p = carry
+        k, _stable, ok_p, code_p, amt_p = carry
         ok_n, code_n, amt_n, _aux = one_pass(ok_p, amt_p)
         # The pass consumed (ok_p, amt_p); equality of codes and of accepted
         # amounts makes the next pass a no-op. Amounts of rejected lanes are
@@ -1171,52 +1177,26 @@ def _kernel_core(
             jnp.any(code_n != code_p)
             | jnp.any(ok_n & ((amt_n.lo != amt_p.lo) | (amt_n.hi != amt_p.hi)))
         )
-        # k counts passes up to and including the stabilizing one (the
-        # bench's jacobi_passes diagnostic).
-        k = k + jnp.where(ever_stable, jnp.int32(0), jnp.int32(1))
-        return (k, ever_stable | stable, ok_n, code_n, amt_n)
+        # k counts the passes run (waves.jacobi_passes, on every backend).
+        return (k + 1, stable, ok_n, code_n, amt_n)
+
+    def done(c):
+        return c[1] | (sched_proved & (c[0] >= passes_needed))
 
     use_scan = (
         static_trip if static_trip is not None
         else jax.default_backend() == "tpu"
     )
     if use_scan:
-        def chunk(c, length):
-            c, _ = jax.lax.scan(
-                lambda c_, _: (step_pass(c_), None), c, None, length=length
-            )
-            return c
+        def gated(c, _):
+            return jax.lax.cond(done(c), lambda c_: c_, step_pass, c), None
 
-        # Two static chunks with a convergence gate between them: chunk 1
-        # covers every measured workload's cascade depth (plain: 2,
-        # two-phase in-batch: 3, balancing chain: 3 — run_kernel_profile's
-        # jacobi_passes), so the lax.cond skips the second chunk's passes
-        # for the common shapes while deep cascades still get max_passes.
-        # The carry is ~170 KB post-aux-removal, so the cond is cheap.
-        head = min(max_passes, 4)
-        c = chunk(carry0, head)
-        if max_passes > head:
-            # The wave bound joins the stability flag in the chunk gate: a
-            # certified batch whose proved pass count fits in the head
-            # chunk skips the tail even when stability was never observed.
-            c = jax.lax.cond(
-                c[1] | (sched_proved & (c[0] >= passes_needed)),
-                lambda c_: c_,
-                lambda c_: chunk(c_, max_passes - head), c,
-            )
-        k_passes, converged, ok_f, code_f, amt_f = c
+        c, _ = jax.lax.scan(gated, carry0, None, length=max_passes)
     else:
-        # Wave-bound early exit: once the certified pass count has run,
-        # the iterate IS the fixpoint (docs/waves.md) — stop without the
-        # verification pass.  With use_waves off, sched_proved is a False
-        # constant and this folds to the pre-waves condition.
-        k_passes, converged, ok_f, code_f, amt_f = jax.lax.while_loop(
-            lambda c: (
-                ~c[1] & (c[0] < max_passes)
-                & ~(sched_proved & (c[0] >= passes_needed))
-            ),
-            step_pass, carry0,
+        c = jax.lax.while_loop(
+            lambda c: ~done(c) & (c[0] < max_passes), step_pass, carry0
         )
+    k_passes, converged, ok_f, code_f, amt_f = c
     proved_done = sched_proved & (k_passes >= passes_needed)
     unconverged = ~converged & ~proved_done
 
