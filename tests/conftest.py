@@ -3,7 +3,8 @@
 The tests run on the CPU backend (``JAX_PLATFORMS=cpu``); jaxenv.force_cpu()
 pins 8 virtual CPU devices so sharding/collective paths are exercised without
 TPU hardware.  The chip itself is only ever reached through ``chip_smoke.py``
-and ``bench.py``; tests/test_tpu_compile.py compiles for a described one.
+and ``benchmarks/run.py``; tests/test_tpu_compile.py compiles for a
+described one.
 """
 
 import os
@@ -15,9 +16,8 @@ from tigerbeetle_tpu import jaxenv  # noqa: E402
 
 # Persistent XLA compile cache (repo-local .jax_cache/, gitignored): the
 # kernel suites are compile-dominated on CPU — a warm cache cuts e.g.
-# test_transfer_full from ~81 s to ~26 s, which is what keeps the full
-# 'not slow' sweep inside the driver's 870 s tier-1 budget.  Must be set
-# before the first backend init, like the device-count flag.
+# test_transfer_full from ~81 s to ~26 s.  Must be set before the first
+# backend init, like the device-count flag.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 jaxenv.enable_compile_cache()
 

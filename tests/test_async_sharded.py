@@ -219,7 +219,6 @@ class TestMachineComposition:
         assert blocking.balances_snapshot() == mixed.balances_snapshot()
 
 
-@pytest.mark.slow
 def test_pipeline_shard_metrics_recorded():
     """The pipeline.shard.* occupancy series land in the registry for
     deferred sharded commits (docs/observability.md rows)."""
@@ -263,7 +262,8 @@ class ReplicaHarness:
     """A solo replica served through on_request_group_pipelined (the TCP
     bus's path), clock pinned so reply bytes compare across engines;
     ``shards`` rides the machine constructor via TB_SHARDS-equivalent
-    plumbing (the env twin is covered by bench/async_smoke)."""
+    plumbing (tests/test_sharded_machine.py::test_env_twin_engages covers the
+    env twin)."""
 
     def __init__(self, tmp, name, depth, shards, merkle):
         import os
@@ -371,7 +371,6 @@ def _mixed_stream(h: ReplicaHarness):
     return bodies, op_batches, kinds
 
 
-@pytest.mark.slow
 class TestReplicaComposition:
     def test_matrix_bitwise_identical_and_match_model(self, tmp_path):
         """The full composition matrix — TB_PIPELINE {1,2,4} x TB_SHARDS

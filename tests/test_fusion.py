@@ -495,7 +495,6 @@ class TestFusionDifferential:
         depth x mix point — single device."""
         self._run_cell(str(tmp_path), depth, 0, mix)
 
-    @pytest.mark.slow  # mesh compiles; listed in the ci integration tier
     @pytest.mark.parametrize("mix", ["disjoint", "two_phase"])
     @pytest.mark.parametrize("depth", [1, 2])
     def test_vs_model_and_off_path_sharded(self, tmp_path, depth, mix):
@@ -524,17 +523,29 @@ class TestFusionDifferential:
         on.close()
         _check_against_model(groups, bodies_off)
 
-    def test_disjoint_mix_actually_fuses(self, tmp_path):
-        """The non-conflicting mix must drive fuse.fused_runs with width
-        > 1 — otherwise the differential above proves nothing."""
+    @pytest.mark.parametrize("knob", ["fuse", "async"])
+    def test_one_knob_alone_engages_and_matches_off(self, tmp_path, knob):
+        """Each knob ON ALONE serves the off path's bytes, and engages:
+        the non-conflicting mix must drive fuse.fused_runs with width > 1
+        (the deferred lane: merkle.lane.deferred_updates) — otherwise
+        the differentials above prove nothing."""
+        tmp, groups = str(tmp_path), _mix_groups("disjoint")
+        off = ReplicaHarness(tmp, "off", 2)
+        want = off.serve_groups(groups), off.r.machine.digest()
+        off.close()
         with registry.enabled_scope():
-            h = ReplicaHarness(str(tmp_path), "fusing", 2, fuse=True)
-            h.serve_groups(_mix_groups("disjoint"))
+            h = ReplicaHarness(tmp, knob, 2, fuse=knob == "fuse",
+                               merkle_async=knob == "async",
+                               merkle=knob == "async")
+            got = h.serve_groups(groups), h.r.machine.digest()
             h.close()
             snap = registry.snapshot()
+        assert got == want
+        if knob == "fuse":
             assert snap["counters"].get("fuse.fused_runs", 0) > 0
-            width = snap["histograms"]["fuse.fused_width"]
-            assert width["max"] > 1
+            assert snap["histograms"]["fuse.fused_width"]["max"] > 1
+        else:
+            assert snap["counters"]["merkle.lane.deferred_updates"] > 0
 
 
 class TestForcedConflictNoFuse:

@@ -351,6 +351,7 @@ def test_replica_commit_series_recorded(tmp_path):
         assert snap["histograms"]["replica.commit_us"]["count"] >= 1
         assert snap["histograms"]["replica.prefetch_us"]["count"] >= 2
         assert snap["histograms"]["replica.batch_events"]["min"] == 4
+        assert snap["histograms"]["ops.batch_fill_pct"]["count"] >= 1
     finally:
         registry.disable()
         registry.reset()

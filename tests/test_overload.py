@@ -249,6 +249,7 @@ def _request_header(client=0xF00, request=1, session=1):
 
 class TestPrimaryShedSignals:
     def test_pipeline_full_sheds_busy_when_on(self, tmp_path):
+        from tigerbeetle_tpu.obs.metrics import registry
         from tigerbeetle_tpu.vsr.consensus import PipelineEntry
 
         cluster, primary = _primary_cluster(tmp_path)
@@ -270,13 +271,15 @@ class TestPrimaryShedSignals:
         # register lands in the (full) pipeline path too: off -> silence.
         assert out == []
         primary.overload_control = True
-        out = primary.on_request_msg(
-            wire.new_header(
-                wire.Command.request, cluster=7, client=0xF00,
-                request=0, session=0,
-                operation=int(wire.Operation.register),
-            ), b"",
-        )
+        with registry.enabled_scope():
+            out = primary.on_request_msg(
+                wire.new_header(
+                    wire.Command.request, cluster=7, client=0xF00,
+                    request=0, session=0,
+                    operation=int(wire.Operation.register),
+                ), b"",
+            )
+            counters = registry.snapshot()["counters"]
         assert len(out) == 1
         (kind, ident), message = out[0]
         assert (kind, ident) == ("client", 0xF00)
@@ -284,6 +287,9 @@ class TestPrimaryShedSignals:
         assert command == wire.Command.busy
         assert int(bh["reason"]) == wire.BUSY_PIPELINE
         assert int(bh["retry_after_ticks"]) > 0
+        # The shed accounting every sink reads, by name.
+        assert counters["overload.shed.pipeline"] == 1
+        assert counters["overload.busy_sent"] == 1
 
     def test_wal_full_sheds_busy_with_wal_reason(self, tmp_path):
         cluster, primary = _primary_cluster(tmp_path)
@@ -890,6 +896,48 @@ class TestSoloBusGate:
         assert ReplicaServer(replica).overload_control is True
         monkeypatch.setenv("TB_OVERLOAD", "0")
         assert ReplicaServer(replica).overload_control is False
+
+
+# ---------------------------------------------------------------------------
+# A polite flood against the real consensus cluster (deterministic sim time)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("multiple", [2, 4])
+def test_polite_flood_is_signalled_and_completes(tmp_path, multiple):
+    """A cohort that honors busy, offering ``multiple``x the pipeline's
+    capacity with overload control on, gets explicit busy replies (not
+    silence), backs off, and still completes EVERY request.  At 2x the
+    admission queues absorb the flood; 4x forces queue-cap evictions, and
+    those shed client-class traffic only."""
+    import dataclasses
+
+    from tigerbeetle_tpu.config import TEST_MIN
+    from tigerbeetle_tpu.sim.cluster import SimCluster
+    from tigerbeetle_tpu.sim.network import PacketSimulator
+
+    pc = TEST_MIN.pipeline_prepare_queue_max
+    flood_n, requests, seed = multiple * pc, 6, 11
+    cluster = SimCluster(
+        str(tmp_path), n_replicas=3, n_clients=1, seed=seed,
+        requests_per_client=2,
+        config=dataclasses.replace(TEST_MIN, clients_max=flood_n + 16),
+        net=PacketSimulator(seed=seed + 1, delay_mean=1, delay_max=6),
+        overload={"queue_cap": 4 * pc, "dispatch_budget": pc,
+                  "priority": True, "signal": True},
+    )
+    ids = cluster.add_flood_clients(
+        flood_n, seed, n_requests=requests, retry_ticks=40, start_tick=50,
+        aggressive=False,
+    )
+    assert cluster.run_until(cluster.clients_done, max_ticks=120_000)
+    assert sum(cluster.clients[c].busy_seen for c in ids) > 0
+    assert sum(
+        cluster.clients[c].requests_done for c in ids
+    ) == flood_n * requests
+    shed = cluster.overload_stats()["shed_by_class"]
+    assert shed["view_change"] == 0 and shed["repair"] == 0
+    assert (shed["client"] > 0) == (multiple == 4), shed
 
 
 # ---------------------------------------------------------------------------

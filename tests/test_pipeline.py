@@ -21,6 +21,7 @@ import pytest
 from tigerbeetle_tpu import types
 from tigerbeetle_tpu.config import TEST_MIN, LedgerConfig
 from tigerbeetle_tpu.machine import DeviceCommitHandle, TpuStateMachine
+from tigerbeetle_tpu.obs.txtrace import txtrace
 from tigerbeetle_tpu.testing import model as M
 
 LANES = 64
@@ -185,6 +186,9 @@ class ReplicaHarness:
             operation=int(op),
         )
         h["size"] = wire.HEADER_SIZE + len(body)
+        # A sampled request carries a trace id, stamped as client.py does
+        # (0 while sampling is off: the legacy bytes).
+        h["trace"] = txtrace.maybe_trace(client)
         return wire.set_checksums(h, body), body
 
     def register(self, client):

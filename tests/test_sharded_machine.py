@@ -95,16 +95,28 @@ def test_env_twin_engages(monkeypatch):
     assert np.asarray(m.ledger.accounts.count).shape == (2,)
 
 
-@pytest.mark.slow
 def test_sharded_machine_parity_mixed():
     """Compact parity pass: plain cross-shard + two-phase + history
     seq-fallback through the live machine at 2 shards, results, digest,
     and balances equal the single-device machine; cross-shard and
-    fallback accounting fires.  @slow (tier-1 budget: ~75 s of 8-device
-    compiles on a cold cache); tools/sharded_smoke.py keeps an equivalent
-    fast-path proof in the ci ``sharded`` tier, and this runs whole in
-    the integration tier."""
+    fallback accounting fires, in the machine's counters and in the
+    ``sharding.*`` series."""
+    from tigerbeetle_tpu.obs.metrics import registry
+
     _need_devices(2)
+    with registry.enabled_scope():
+        sharded = _parity_mixed()
+        snap = registry.snapshot()
+    counters = snap["counters"]
+    assert counters["sharding.batches"] == 4  # the history batch fell back
+    assert counters["sharding.lanes"] == sharded.shard_lanes_total > 0
+    assert counters["sharding.cross_shard_lanes"] == sharded.shard_lanes_cross
+    assert counters["sharding.seq_fallbacks"] == 1
+    assert snap["histograms"]["sharding.cross_shard_pct"]["max"] == 100
+    assert snap["gauges"]["sharding.shards"] == 2
+
+
+def _parity_mixed():
     single, sharded = make_pair(2)
     buckets, accounts = accounts_by_owner(2, 6)
     # One HISTORY account, touched only by the final batch.
@@ -177,6 +189,7 @@ def test_sharded_machine_parity_mixed():
     )
     q1, q2 = single.get_account_transfers(filt), sharded.get_account_transfers(filt)
     assert len(q1) == len(q2) and (q1 == q2).all()
+    return sharded
 
 
 def zipf_mix(rng, accounts, pendings, n=48, two_phase=True):
@@ -214,12 +227,11 @@ def zipf_mix(rng, accounts, pendings, n=48, two_phase=True):
     return types.transfers_array(specs)
 
 
-@pytest.mark.slow
 class TestShardedDifferential:
     """Machine-level differentials vs the scalar oracle across cross-shard
-    fraction x pipeline depth x workload mix (the satellite matrix).
-    @slow: many sharded-kernel variants; rides the ci integration tier."""
+    fraction x pipeline depth x workload mix (the satellite matrix)."""
 
+    @pytest.mark.slow  # rides the ci integration tier
     @pytest.mark.parametrize("cross_pct", [0, 50, 100])
     @pytest.mark.parametrize("depth", [1, 2])
     def test_cross_fraction_vs_model(self, cross_pct, depth):

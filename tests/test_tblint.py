@@ -91,7 +91,7 @@ def test_every_rule_has_a_suppression_case():
 
 
 def test_real_tree_is_clean():
-    """The package, tools, tests, and bench.py must stay lint-clean AND
+    """The package, tools and tests must stay lint-clean AND
     free of stale suppressions — the same gate tools/ci.py's lint tier
     enforces (tests/fixtures holds the deliberate violations and is
     excluded)."""
@@ -100,7 +100,6 @@ def test_real_tree_is_clean():
             os.path.join(REPO, "tigerbeetle_tpu"),
             os.path.join(REPO, "tools"),
             os.path.join(REPO, "tests"),
-            os.path.join(REPO, "bench.py"),
         ],
         exclude=[os.path.join(REPO, "tests", "fixtures")],
     )

@@ -97,12 +97,9 @@ def _one_chip_lowerings(topo):
     kvec = jax.ShapeDtypeStruct((k,), jnp.uint64, sharding=one)
 
     def full(has_postvoid):
-        # static_trip=True is the lax.scan form of the Jacobi loop, which
-        # the kernel picks by itself only on a TPU backend.
         return lambda: tf.create_transfers_full.lower(
             led, batch, u64, u64, None, None, max_passes=8,
-            has_postvoid=has_postvoid, has_history=False, static_trip=True,
-            use_waves=True,
+            has_postvoid=has_postvoid, has_history=False, use_waves=True,
         )
 
     return {
@@ -145,21 +142,17 @@ def _sharded_lowering(topo, make_step):
     "fast", "grouped", "full_scan_plain", "full_scan_postvoid",
     "sharded_fast_4", "sharded_full_scan_4",
 ])
-def test_compiles_for_v5e(topo, no_persistent_cache, monkeypatch, program):
+def test_compiles_for_v5e(topo, no_persistent_cache, program):
     if program == "sharded_fast_4":
         lowered = _sharded_lowering(
             topo, lambda mesh: sharded.sharded_create_transfers(
                 mesh, probed=True)
         )
     elif program == "sharded_full_scan_4":
-        # The mesh path leaves the loop form to the backend: say "tpu"
-        # while it traces, so the gated scan is what gets lowered.
-        with monkeypatch.context() as m:
-            m.setattr(tf.jax, "default_backend", lambda: "tpu")
-            lowered = _sharded_lowering(
-                topo, lambda mesh: sharded.sharded_create_transfers_full(
-                    mesh, max_passes=8, use_waves=True)
-            )
+        lowered = _sharded_lowering(
+            topo, lambda mesh: sharded.sharded_create_transfers_full(
+                mesh, max_passes=8, use_waves=True)
+        )
         assert "stablehlo.case" in lowered.as_text()  # the gate, per pass
     else:
         lowered = _one_chip_lowerings(topo)[program]()

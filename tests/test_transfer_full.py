@@ -333,107 +333,10 @@ class TestGrowth:
         assert not bool(np.asarray(dev.ledger.transfers.probe_overflow))
 
 
-class TestStaticTripParity:
-    @pytest.mark.slow  # ~27 s; tools/ci.py integration tier runs it
-    def test_scan_and_while_paths_identical(self):
-        """The TPU path runs the Jacobi fixpoint as a STATIC-trip lax.scan
-        whose passes are gated on the loop's exit (see _kernel_core), other
-        backends as the early-exit while_loop.  Both run the same passes,
-        so the two must agree bit-for-bit — this pins the scan path on CPU,
-        where the auto-gate would otherwise leave it untested."""
-        import functools
-
-        import jax
-        import jax.numpy as jnp
-
-        from tigerbeetle_tpu.ops import state_machine as sm
-        from tigerbeetle_tpu.ops import transfer_full as tf
-
-        lanes, n_accounts = 64, 8
-        count = 40
-
-        def fresh_ledger():
-            led = sm.make_ledger(1 << 8, 1 << 10, 1 << 8)
-            acc = np.zeros(lanes, dtype=types.ACCOUNT_DTYPE)
-            acc["id_lo"][:n_accounts] = 1 + np.arange(
-                n_accounts, dtype=np.uint64
-            )
-            acc["ledger"][:n_accounts] = 1
-            acc["code"][:n_accounts] = 10
-            soa = {
-                k: jnp.asarray(v) for k, v in types.to_soa(acc).items()
-            }
-            led, codes = sm.create_accounts(
-                led, soa, jnp.uint64(n_accounts), jnp.uint64(n_accounts)
-            )
-            assert int(np.asarray(codes)[:n_accounts].sum()) == 0
-            return led
-
-        # Mixed batch: pendings, same-batch posts of those pendings, a
-        # balancing-style zero-amount lane, and a plain chain — exercises
-        # multi-pass convergence (the two-phase/balancing classes measure
-        # 3 Jacobi passes).
-        b = np.zeros(lanes, dtype=types.TRANSFER_DTYPE)
-        half = count // 2
-        lane = np.arange(lanes, dtype=np.uint64)
-        act = lane < count
-        is_post = (lane >= half) & act
-        b["id_lo"] = np.where(act, 1000 + lane, 0)
-        b["flags"] = np.where(
-            act,
-            np.where(
-                is_post,
-                np.uint16(types.TransferFlags.POST_PENDING_TRANSFER),
-                np.uint16(types.TransferFlags.PENDING),
-            ),
-            0,
-        ).astype(np.uint16)
-        b["pending_id_lo"] = np.where(is_post, 1000 + lane - half, 0)
-        pend = act & ~is_post
-        b["debit_account_id_lo"] = np.where(pend, 1 + lane % n_accounts, 0)
-        b["credit_account_id_lo"] = np.where(
-            pend, 1 + (lane + 1) % n_accounts, 0
-        )
-        b["amount_lo"] = np.where(pend, 7 + lane % 13, 0)
-        b["ledger"] = np.where(pend, 1, 0).astype(np.uint32)
-        b["code"] = np.where(pend, 10, 0).astype(np.uint16)
-        soa = {k: jnp.asarray(v) for k, v in types.to_soa(b).items()}
-
-        outs = {}
-        for static in (False, True):
-            fn = functools.partial(
-                tf.create_transfers_full_impl, static_trip=static
-            )
-            led, codes, kflags = jax.jit(fn)(
-                fresh_ledger(), soa, jnp.uint64(count), jnp.uint64(10_000)
-            )
-            outs[static] = (
-                np.asarray(codes),
-                int(kflags),
-                {
-                    k: np.asarray(v)
-                    for k, v in {
-                        "t_keys": led.transfers.key_lo,
-                        "t_count": led.transfers.count,
-                        "a_dr": led.accounts.cols["debits_posted_lo"],
-                        "a_cr": led.accounts.cols["credits_posted_lo"],
-                        "a_dp": led.accounts.cols["debits_pending_lo"],
-                        "p_keys": led.posted.key_lo,
-                    }.items()
-                },
-            )
-        codes_w, kf_w, tabs_w = outs[False]
-        codes_s, kf_s, tabs_s = outs[True]
-        np.testing.assert_array_equal(codes_w, codes_s)
-        assert kf_w == kf_s
-        for k in tabs_w:
-            np.testing.assert_array_equal(tabs_w[k], tabs_s[k], err_msg=k)
-
-
 # ---------------------------------------------------------------------------
-# The Jacobi loop's two lowerings (the gated lax.scan a TPU gets, the
-# lax.while_loop elsewhere) run the SAME sequence of passes: codes, flags,
-# tables and the count of passes run are identical, batch by batch.
+# The Jacobi loop (one gated lax.scan on every backend) runs the passes its
+# batch needs: the count of passes run, the flags, and tables a routed batch
+# leaves as it found them, batch by batch.
 # ---------------------------------------------------------------------------
 
 _LOOP_LANES, _LOOP_ACCOUNTS = 32, 12
@@ -509,7 +412,7 @@ def _loop_ledger():
     return led
 
 
-def _run_loop_case(monkeypatch, static_trip, use_waves, max_passes, batches):
+def _run_loop_case(monkeypatch, use_waves, max_passes, batches):
     """The case's batches through ONE jitted create_transfers_full_impl;
     ``passes`` comes out of the same trace (the impl returns it only with
     waves on)."""
@@ -530,8 +433,7 @@ def _run_loop_case(monkeypatch, static_trip, use_waves, max_passes, batches):
     @jax.jit
     def fn(led, soa, count, ts):
         out = tf.create_transfers_full_impl(
-            led, soa, count, ts, max_passes=max_passes,
-            static_trip=static_trip, use_waves=use_waves,
+            led, soa, count, ts, max_passes=max_passes, use_waves=use_waves,
         )
         return out[0], out[1], out[2], seen["passes"]
 
@@ -576,32 +478,26 @@ def _named(eqns, *names):
 
 class TestJacobiLoopForms:
     @pytest.mark.parametrize("case", list(_LOOP_CASES))
-    def test_gated_scan_runs_the_while_loops_passes(self, case, monkeypatch):
+    def test_loop_runs_the_passes_the_batch_needs(self, case, monkeypatch):
         use_waves, max_passes, batches = _LOOP_CASES[case]
-        whiles, scans = (
-            _run_loop_case(monkeypatch, static, use_waves, max_passes, batches)
-            for static in (False, True)
-        )
-        for (rows, passes, flags), w, s in zip(batches, whiles, scans):
-            assert w.keys() == s.keys()
-            for k in w:
-                np.testing.assert_array_equal(w[k], s[k], err_msg=k)
-            assert (s["passes"], s["flags"]) == (passes, flags)
+        got = _run_loop_case(monkeypatch, use_waves, max_passes, batches)
+        for (rows, passes, flags), g in zip(batches, got):
+            assert (g["passes"], g["flags"]) == (passes, flags)
             if not flags:
-                assert not s["codes"][: len(rows)].any()
-        if scans[-1]["flags"]:
+                assert not g["codes"][: len(rows)].any()
+        if got[-1]["flags"]:
             # Routed: the batch left every table as it found it.
-            for k in (k for k in scans[-1] if "." in k):
+            for k in (k for k in got[-1] if "." in k):
                 np.testing.assert_array_equal(
-                    scans[-2][k], scans[-1][k], err_msg=k
+                    got[-2][k], got[-1][k], err_msg=k
                 )
 
     @pytest.mark.parametrize("max_passes", [3, 8])
-    def test_static_form_is_one_scan_of_gated_passes(self, max_passes):
-        """The static form's jaxpr has ONE pass loop: a scan of length
-        max_passes whose body is the gate and a cond (skip | pass).  A
-        pass is known by its leg sort; the program holds as many as the
-        while form's (the loop's pass and the aux pass)."""
+    def test_loop_is_one_scan_of_gated_passes(self, max_passes):
+        """The jaxpr has ONE pass loop: a scan of length max_passes whose
+        body is the gate and a cond (skip | pass).  A pass is known by its
+        leg sorts; the program holds each of them twice (the loop's pass
+        and the aux pass)."""
         import jax
         import jax.numpy as jnp
 
@@ -610,27 +506,29 @@ class TestJacobiLoopForms:
         padded = np.zeros(_LOOP_LANES, dtype=types.TRANSFER_DTYPE)
         soa = {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
         u64 = jnp.uint64(0)
-
-        def pass_loops(static_trip):
-            eqns = list(_eqns(jax.make_jaxpr(
-                lambda led: tf.create_transfers_full_impl(
-                    led, soa, u64, u64, max_passes=max_passes,
-                    static_trip=static_trip,
-                )
-            )(_loop_ledger()).jaxpr))
-            loops = [
-                e for e in _named(eqns, "scan", "while")
-                if _named(_eqns(e.params.get("jaxpr", e.params.get(
-                    "body_jaxpr")).jaxpr), "sort")
-            ]
-            return loops, len(_named(eqns, "sort"))
-
-        (scan,), sorts = pass_loops(True)
+        eqns = list(_eqns(jax.make_jaxpr(
+            lambda led: tf.create_transfers_full_impl(
+                led, soa, u64, u64, max_passes=max_passes,
+            )
+        )(_loop_ledger()).jaxpr))
+        (scan,) = [
+            e for e in _named(eqns, "scan", "while")
+            if _named(_eqns(e.params.get("jaxpr", e.params.get(
+                "body_jaxpr")).jaxpr), "sort")
+        ]
         assert scan.primitive.name == "scan"
         assert scan.params["length"] == max_passes
         body = scan.params["jaxpr"].jaxpr.eqns
         (cond,) = _named(body, "cond")
         assert len(cond.params["branches"]) == 2
         assert not _named(body, "sort", "scan", "while")
-        (loop,), sorts_while = pass_loops(False)
-        assert loop.primitive.name == "while" and sorts == sorts_while
+
+        def kinds(es):
+            return sorted(
+                (e.params["num_keys"], tuple(str(v.aval) for v in e.invars))
+                for e in _named(es, "sort")
+            )
+
+        in_loop = kinds(_eqns(scan.params["jaxpr"].jaxpr))
+        assert in_loop
+        assert [k for k in kinds(eqns) if k in in_loop] == sorted(2 * in_loop)

@@ -263,6 +263,75 @@ def test_dump_blackboxes_writes_files(tmp_path):
     assert dump_blackboxes(boxes, str(tmp_path / "missing" / "nested")) == []
 
 
+# -- one request, one flow, across every replica -----------------------------
+
+# Every member must appear in a state-machine request's flow, in causal
+# order (client stamp -> consensus ingress -> kernel execution -> reply
+# release -> client receipt).
+EXPECTED_CHAIN = (
+    "client.request", "consensus.ingress", "replica.prepare",
+    "consensus.commit", "replica.execute", "replica.reply", "client.reply",
+)
+
+
+def test_cluster_request_is_one_flow_across_three_replicas(tmp_path,
+                                                           json_tracer):
+    from tigerbeetle_tpu.sim.cluster import SimCluster
+
+    with txtrace.sampling_scope(every=1):
+        sim = SimCluster(str(tmp_path), n_replicas=3, n_clients=2, seed=7)
+        assert sim.run_until(sim.clients_done, max_ticks=20_000)
+    events = sorted(
+        (e for e in json_tracer.drain()
+         if e.get("cat") in ("txtrace", "txflow")),
+        key=lambda e: e["ts"],
+    )
+    chains = {}
+    for e in events:
+        if e["cat"] == "txtrace":
+            chains.setdefault(int(e["args"]["trace"], 16), []).append(e)
+    # Registers legitimately skip replica.execute: take the requests that
+    # carry the whole chain, and of those the one on the most replicas.
+    full = {
+        t: evs for t, evs in chains.items()
+        if set(EXPECTED_CHAIN) <= {e["name"] for e in evs}
+    }
+    assert full, sorted({e["name"] for evs in chains.values() for e in evs})
+    trace, evs = max(full.items(), key=lambda kv: len(
+        {e["pid"] for e in kv[1] if e["pid"] >= REPLICA_PID_BASE}))
+    assert len({e["pid"] for e in evs if e["pid"] >= REPLICA_PID_BASE}) >= 3
+    names = [e["name"] for e in evs]
+    firsts = [names.index(n) for n in EXPECTED_CHAIN]
+    assert firsts == sorted(firsts), list(zip(EXPECTED_CHAIN, firsts))
+    # One arrow: it starts at the client's stamp and finishes once, at the
+    # client's receipt (backups emit step hops after it).
+    phases = [e["ph"] for e in events
+              if e["cat"] == "txflow" and e["id"] == trace]
+    assert phases[0] == "s" and phases.count("s") == 1, phases
+    assert phases.count("f") == 1, phases
+
+
+def test_sampling_every_request_serves_the_untraced_bytes(tmp_path,
+                                                          json_tracer):
+    """Every request sampled and the tracer recording: reply bodies,
+    digest and balances equal the unsampled run's, and the sampled run
+    did emit flow events."""
+    from test_pipeline import ReplicaHarness, _mixed_stream
+
+    def served(name):
+        h = ReplicaHarness(str(tmp_path), name, 2, False)
+        bodies, _, _ = _mixed_stream(h)
+        out = bodies, h.r.machine.digest(), h.r.machine.balances_snapshot()
+        h.close()
+        return out
+
+    off = served("unsampled")
+    assert not any(e.get("cat") == "txflow" for e in json_tracer.drain())
+    with txtrace.sampling_scope(every=1):
+        assert served("sampled") == off
+    assert any(e.get("cat") == "txflow" for e in json_tracer.drain())
+
+
 # -- VOPR integration --------------------------------------------------------
 
 
@@ -287,14 +356,31 @@ def test_vopr_pinned_seed_green_with_tracing_on(tmp_path, json_tracer):
     assert len(replica_pids) >= 2  # chain crosses replica rows
 
 
-def test_vopr_failing_seed_carries_blackboxes(tmp_path):
-    """A failing seed attaches every seat's flight-recorder dump (and
-    the CLI writes them next to the viz grid).  Forced cheaply: too few
+@pytest.mark.parametrize("through", ["run_seed", "cli"])
+def test_vopr_failing_seed_carries_blackboxes(tmp_path, monkeypatch, through):
+    """A failing seed attaches every seat's flight-recorder dump, and the
+    real CLI writes them next to the viz grid.  Forced cheaply: too few
     ticks to converge -> liveness failure."""
-    from tigerbeetle_tpu.sim.vopr import EXIT_PASSED, run_seed
+    from tigerbeetle_tpu import cli, jaxenv
+    from tigerbeetle_tpu.sim import vopr
 
-    result = run_seed(3, workdir=str(tmp_path), ticks=40, settle_ticks=1)
-    assert result.exit_code != EXIT_PASSED
-    assert result.blackboxes, "failing seed carried no blackbox dumps"
-    for name, text in result.blackboxes.items():
+    run_seed = vopr.run_seed
+    if through == "run_seed":
+        result = run_seed(3, workdir=str(tmp_path), ticks=40, settle_ticks=1)
+        assert result.exit_code != vopr.EXIT_PASSED
+        boxes = result.blackboxes
+    else:
+        monkeypatch.setattr(vopr, "run_seed", lambda seed, **kw: run_seed(
+            seed, **{**kw, "ticks": 40, "settle_ticks": 1}))
+        # conftest pinned this process to the CPU: the CLI's own pin would
+        # reset the backends under every later test of this worker.
+        monkeypatch.setattr(jaxenv, "force_cpu", lambda n=None: None)
+        monkeypatch.chdir(tmp_path)
+        assert cli.main(["vopr", "--seed", "3", "--vopr-viz"]) != 0
+        assert (tmp_path / "vopr_viz_3.txt").exists()
+        boxes = {p.stem[len("blackbox_3_"):]: p.read_text()
+                 for p in tmp_path.glob("blackbox_3_r*.txt")}
+    assert boxes, "failing seed carried no blackbox dumps"
+    for name, text in boxes.items():
         assert text.startswith(f"# blackbox {name}:")
+        assert "events recorded" in text.splitlines()[0]

@@ -241,7 +241,7 @@ class TestWaveBound:
         led, _ = sm.create_accounts(led, soa, jnp.uint64(n), jnp.uint64(n))
         return led, n
 
-    def _plan(self, led, batch, count, ts, static_trip=None):
+    def _plan(self, led, batch, count, ts):
         p = np.zeros(64, dtype=types.TRANSFER_DTYPE)
         p[:count] = batch[:count]
         soa = {k: jnp.asarray(v) for k, v in types.to_soa(p).items()}
@@ -254,7 +254,6 @@ class TestWaveBound:
         ctx = tf.build_gather_ctx(led, soa, valid, pv)
         return tf._kernel_core(
             ctx, soa, jnp.uint64(count), jnp.uint64(ts), use_waves=True,
-            static_trip=static_trip,
         )
 
     @pytest.mark.slow  # ~25s; runs whole in the ci integration tier
@@ -274,12 +273,9 @@ class TestWaveBound:
         assert int(hist[0]) == 8 and int(hist[1:].sum()) == 0
         assert int(plan.route) == 0
 
-    @pytest.mark.parametrize("static_trip", [False, True],
-                             ids=["while", "scan"])
-    def test_limit_chain_bounds_or_falls_back(self, static_trip):
+    def test_limit_chain_bounds_or_falls_back(self):
         """Lanes sharing a limit-flagged account: hazard chain — either a
-        proved bound > 1 or (deep chains) fall back to stability.  Both
-        lowerings of the loop stop at the same pass."""
+        proved bound > 1 or (deep chains) fall back to stability."""
         led, n = self._setup(limits=(0,))
         b = np.zeros(64, dtype=types.TRANSFER_DTYPE)
         b["id_lo"][:4] = 200 + np.arange(4, dtype=np.uint64)
@@ -288,20 +284,18 @@ class TestWaveBound:
         b["amount_lo"][:4] = 5
         b["ledger"][:4] = 1
         b["code"][:4] = 10
-        plan = self._plan(led, b, 4, n + 8, static_trip)
+        plan = self._plan(led, b, 4, n + 8)
         bound = int(plan.wave_bound)
         hist = np.asarray(plan.wave_hist)
         # 4 hazard lanes chained through account 1: depths 1..4.
         assert bound == 5
         assert hist[1:5].tolist() == [1, 1, 1, 1]
         # All 4 reject (unfunded limit account): stability lands first,
-        # at pass 2, and neither form runs a pass after it.
+        # at pass 2, and no pass runs after it.
         assert int(plan.passes) == 2
         assert np.asarray(plan.codes)[:4].tolist() == [54] * 4
 
-    @pytest.mark.parametrize("static_trip", [False, True],
-                             ids=["while", "scan"])
-    def test_linked_batch_is_unscheduled(self, static_trip):
+    def test_linked_batch_is_unscheduled(self):
         led, n = self._setup()
         b = np.zeros(64, dtype=types.TRANSFER_DTYPE)
         b["id_lo"][:2] = 300 + np.arange(2, dtype=np.uint64)
@@ -311,7 +305,7 @@ class TestWaveBound:
         b["ledger"][:2] = 1
         b["code"][:2] = 10
         b["flags"][0] = types.TransferFlags.LINKED
-        plan = self._plan(led, b, 2, n + 8, static_trip)
+        plan = self._plan(led, b, 2, n + 8)
         assert int(plan.wave_bound) == 0  # unschedulable: stability exit
         assert int(plan.passes) == 2 and int(plan.route) == 0
 

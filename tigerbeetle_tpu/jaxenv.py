@@ -51,8 +51,8 @@ def _set_host_device_flag(n: int, env=os.environ) -> None:
     parts.append(f"{_HOST_COUNT_FLAG}={n}")
     env["XLA_FLAGS"] = " ".join(parts)
 
-# Persistent XLA compilation cache, shared by the server, bench.py, the tools
-# and the tests so a second start never re-pays the first compile.  One
+# Persistent XLA compilation cache, shared by the server, the tools and the
+# tests so a second start never re-pays the first compile.  One
 # definition here — two independently-spelled paths would silently diverge.
 COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"

@@ -344,7 +344,7 @@ class TpuStateMachine:
         self._shard_steps = None
         self._canon = None            # cached canonical (single-layout) view
         self._ledger_is_sharded = False
-        self.shard_lanes_total = 0    # plain-int counters (tests/bench)
+        self.shard_lanes_total = 0    # plain-int counters (tests)
         self.shard_lanes_cross = 0
         self.shard_seq_fallbacks = 0
         # Per-shard attempted-insert bounds (accounts/transfers): the
@@ -517,10 +517,6 @@ class TpuStateMachine:
         self._bloom_np = None
         self._bloom_dev = None
         self._evictions = 0
-        # Device-dispatch accounting (bench.py e2e decomposition, VERDICT r5
-        # ask #6): every blocking codes D2H counts one dispatch + its wait.
-        self.disp_count = 0
-        self.disp_wait_s = 0.0
         # Commit pipeline (docs/commit_pipeline.md): bounded deferred-
         # readback depth (TB_PIPELINE; resolved lazily so tests can set the
         # env per-instance), plus the cached host staging buffers for the
@@ -613,8 +609,8 @@ class TpuStateMachine:
 
     def _d2h_codes(self, codes, overflow=None, stage=None, seq=0):
         """The blocking device->host read of a commit's result codes: the
-        ONE point every device dispatch funnels through.  Timed so the e2e
-        bench can decompose wall time into device-wait vs host work.
+        ONE point every device dispatch funnels through.  Timed
+        (``ops.dispatch_wait_us``): device wait against host work.
 
         ``stage`` names the txtrace stage the read bills to (``seq``: its
         group): only EXPLICITLY staged readbacks bill — the deferred
@@ -629,7 +625,7 @@ class TpuStateMachine:
 
         host-sync: commit barrier — this is the deliberate readback point
         of the deferred commit pipeline (docs/commit_pipeline.md; the
-        bench's dispatch accounting reads exactly this method)."""
+        ``ops.dispatch`` series counts exactly this method)."""
         self._injected_fault_check()
         t0 = _time.perf_counter()
         with txtrace.stage(stage, seq=seq):
@@ -638,8 +634,6 @@ class TpuStateMachine:
             else:
                 out, overflow = jax.device_get((codes, overflow))
         wait = _time.perf_counter() - t0
-        self.disp_wait_s += wait
-        self.disp_count += 1
         if _obs.enabled:
             _obs.counter("ops.dispatch").inc()
             _obs.histogram("ops.dispatch_wait_us", "us").observe(wait * 1e6)
@@ -2325,8 +2319,6 @@ class TpuStateMachine:
                 kflags = int(kflags)
                 wave_host = None
         wait = _time.perf_counter() - t0
-        self.disp_wait_s += wait
-        self.disp_count += 1
         if _obs.enabled:
             _obs.counter("ops.dispatch").inc()
             _obs.histogram("ops.dispatch_wait_us", "us").observe(wait * 1e6)
@@ -2845,12 +2837,11 @@ class TpuStateMachine:
             self._balance_bound = _BOUND_CLAMP
 
     def _fast_path_ok(self, batch: np.ndarray) -> bool:
-        """Plain-transfer batches run the round-1 fast kernel.  Measured
-        cost ratio (bench.py run_kernel_profile, XLA-CPU): the general
-        kernel is ~2-3x the fast kernel per batch; on TPU the gap is
-        expected to widen toward the op-count ratio (the general kernel's
-        sorted ladders + Jacobi fixpoint are launch-overhead-bound at 8192
-        lanes — see utils/roofline.py OVERHEAD_US).  The preconditions are
+        """Plain-transfer batches run the fast kernel.  Measured on one v5e
+        at the served table sizes (PERF.md section 5; 8190-event batches):
+        an execution of the general program is 101 ms, a lone fast request
+        53.5 ms and a grouped fast step ~48 ms, so a batch that can take
+        the fast kernel does.  The preconditions are
         ops/state_machine.py's P1-P4, checked host-side in a few vector ops
         over the batch."""
         if (
@@ -2946,7 +2937,7 @@ class TpuStateMachine:
         value = bool(value)
         if not value and self._merkle_async and self._merkle_pending:
             # Turning the lane off must not strand queued records (callers
-            # toggle at quiescent points: setup, tests, bench arms).
+            # toggle at quiescent points: setup, tests).
             self.merkle_settle()
         self._merkle_async = value
 

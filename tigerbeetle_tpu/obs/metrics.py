@@ -8,7 +8,7 @@ layer (vsr, net, ops, sim) records into ONE process-global table of named
 series, and three sinks read it:
 
 - a JSON snapshot (``TB_METRICS_PATH`` env / ``--metrics-json`` flags) for
-  bench artifacts and chip_smoke.py's server report;
+  the benchmark's per-layer metrics and chip_smoke.py's server report;
 - the StatsD bridge (``flush_statsd``), so the existing UDP path keeps
   carrying the new series;
 - direct inspection from tests (deterministic bucket layout).
@@ -267,7 +267,7 @@ class Registry:
 
 # The process-global registry (the reference's comptime-global tracer/statsd
 # pattern).  TB_METRICS_PATH enables it at import and dumps at exit;
-# --metrics-json flags (cli.py, bench.py) enable it programmatically.
+# cli.py's --metrics-json flags enable it programmatically.
 registry = Registry(enabled=bool(os.environ.get("TB_METRICS_PATH")))
 
 if registry.enabled:

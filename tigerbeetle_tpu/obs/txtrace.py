@@ -16,8 +16,8 @@ real cluster_bus deployment, readable in Perfetto as connected arrows.
 **ATTRIBUTE** — two kinds of record (docs/tracing.md).  *Thread spans*:
 ``txtrace.stage(name)`` is the one way a commit-path site times a block;
 the duration lands in a ``txtrace.stage.<name>`` registry histogram (when
-the registry is on), in the in-process total table that ``bench.py``
-surfaces as ``payload.attribution``, and — while a ``jax.profiler``
+the registry is on), in the in-process total table that
+``stage_totals()`` returns, and — while a ``jax.profiler``
 session is on — as a ``tb.<name>`` event on that thread's line of the
 profile's host plane, on the device trace's clock.  Spans nest and
 overlap; they do not sum.  *Request intervals*: the bus stamps every
@@ -79,7 +79,7 @@ STAGES = (
 
 # Stages that only ever run INSIDE another stage's block on the same
 # thread: ``device_execute``'s children.  A sum over stages that wants
-# wall time (bench.py's coverage) leaves them out; on the general route
+# wall time leaves them out; on the general route
 # alone ``stage_h2d`` is nested too (the staging is part of the blocking
 # closure), so such a sum over general requests counts it twice.  The
 # bus's sections below hold the replica's and the machine's
@@ -219,8 +219,8 @@ class TxTracer:
 
     def __init__(self) -> None:
         self.sample_every = parse_sample(os.environ.get("TB_TRACE_SAMPLE", ""))
-        # Attribution accumulation is independent of sampling: bench arms
-        # it for every batch (no sampling) while flow tracing stays off.
+        # Attribution accumulation is independent of sampling: a caller
+        # arms it for every batch (no sampling) while flow tracing stays off.
         self.attribution = False
         # Sequence number of the commit group the serving thread is in
         # (set by the bus at pickup while active): the default ``seq``

@@ -38,9 +38,8 @@ the wave schedule proves (docs/waves.md): pass k+1 resolves every batch
 whose outcome-change cascade depth is <= k (uncontended batches stabilize
 in 2 passes, or run the 1 their wave bound proves; each clamp/rejection
 cascade adds 1), up to _MAX_PASSES; deeper cascades set FLAG_SEQ and run
-sequentially.  On a TPU the loop is a static-trip lax.scan whose every pass
-sits behind a lax.cond on that exit, elsewhere a lax.while_loop: the same
-passes run either way (see _kernel_core).
+sequentially.  The loop is a static-trip lax.scan whose every pass sits
+behind a lax.cond on that exit (see _kernel_core).
 
 The remaining FLAG_SEQ routes are genuinely order-chaotic or out-of-scope
 for the u64-limb delta machinery: unconverged fixpoints, u128 amounts,
@@ -75,7 +74,7 @@ routing flags and history values taken from the fixpoint carry none.
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -689,7 +688,6 @@ def _kernel_core(
     count: jax.Array,
     timestamp: jax.Array,
     max_passes: int = _MAX_PASSES,
-    static_trip: Optional[bool] = None,
     has_postvoid: bool = True,
     use_waves: bool = False,
 ) -> ApplyPlan:
@@ -1141,20 +1139,17 @@ def _kernel_core(
     # schedule's certified count has run (the iterate then IS the fixpoint,
     # docs/waves.md: no verification pass), or when max_passes are spent.
     # With use_waves off, sched_proved is a False constant and the exit
-    # folds to stability alone.  ONE loop in two lowerings that run the
-    # same sequence of passes, so every output is bit-identical, `passes`
-    # (the passes run) included:
+    # folds to stability alone.  The loop is a STATIC trip on every backend:
+    # lax.scan(length=max_passes) whose body is a lax.cond on the exit
+    # condition, so a pass after the exit is skipped on the device, not
+    # evaluated, and `passes` counts the passes run.
     #
-    # - on a TPU a STATIC trip: lax.scan(length=max_passes) whose body is a
-    #   lax.cond on the exit condition, so a pass after the exit is skipped
-    #   on the device, not evaluated;
-    # - elsewhere a lax.while_loop (the CPU engine, fallback and tests).
-    #
-    # Measured on one v5e (PERF.md section 6, PR 29: 7,780 posts/voids of
-    # table pendings, proved bound 1, the served table sizes at 2.0-2.5 M
-    # rows, device ms an execution): this scan 84.9, the while_loop 86.0,
-    # and 120.8 for an ungated scan(4) with 4 more passes behind one gate,
-    # which evaluates 4 passes + the aux pass where the batch needs 1 + 1.
+    # There is no second form because the chip measured none better (one
+    # v5e, PERF.md section 6, PR 29: 7,780 posts/voids of table pendings,
+    # proved bound 1, the served table sizes at 2.0-2.5 M rows, device ms an
+    # execution): this scan 84.9, a plain lax.while_loop 86.0, and 120.8 for
+    # an ungated scan(4) with 4 more passes behind one gate, which evaluates
+    # 4 passes + the aux pass where the batch needs 1 + 1.
     #
     # The carry holds ONLY the iterate (k, stable, ok, code, amount), ~170
     # KB to cond over — aux (legs, composed rows, pending views: ~6 MB at
@@ -1177,25 +1172,16 @@ def _kernel_core(
             jnp.any(code_n != code_p)
             | jnp.any(ok_n & ((amt_n.lo != amt_p.lo) | (amt_n.hi != amt_p.hi)))
         )
-        # k counts the passes run (waves.jacobi_passes, on every backend).
+        # k counts the passes run (waves.jacobi_passes).
         return (k + 1, stable, ok_n, code_n, amt_n)
 
     def done(c):
         return c[1] | (sched_proved & (c[0] >= passes_needed))
 
-    use_scan = (
-        static_trip if static_trip is not None
-        else jax.default_backend() == "tpu"
-    )
-    if use_scan:
-        def gated(c, _):
-            return jax.lax.cond(done(c), lambda c_: c_, step_pass, c), None
+    def gated(c, _):
+        return jax.lax.cond(done(c), lambda c_: c_, step_pass, c), None
 
-        c, _ = jax.lax.scan(gated, carry0, None, length=max_passes)
-    else:
-        c = jax.lax.while_loop(
-            lambda c: ~done(c) & (c[0] < max_passes), step_pass, carry0
-        )
+    c, _ = jax.lax.scan(gated, carry0, None, length=max_passes)
     k_passes, converged, ok_f, code_f, amt_f = c
     proved_done = sched_proved & (k_passes >= passes_needed)
     unconverged = ~converged & ~proved_done
@@ -1311,7 +1297,6 @@ def create_transfers_full_impl(
     max_passes: int = _MAX_PASSES,
     has_postvoid: bool = True,
     has_history: bool = True,
-    static_trip: Optional[bool] = None,
     use_waves: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """Returns (ledger', codes uint32[N], flags uint32 scalar), plus a
@@ -1327,7 +1312,7 @@ def create_transfers_full_impl(
     conflict-index wave scheduler: bit-identical codes/ledger, fewer
     Jacobi passes on batches the conflict index certifies, and a FOURTH
     return — int32[11] = (passes, wave_bound, hist[9 wave-depth buckets])
-    — for the bench/metrics surface.  Off compiles exactly the pre-waves
+    — for the metrics surface.  Off compiles exactly the pre-waves
     program with the three-tuple return.
     """
     n = batch["id_lo"].shape[0]
@@ -1341,7 +1326,7 @@ def create_transfers_full_impl(
         ledger, batch, valid, postvoid, bloom, cold_checked,
         has_postvoid=has_postvoid,
     )
-    plan = _kernel_core(ctx, batch, count, timestamp, max_passes, static_trip,
+    plan = _kernel_core(ctx, batch, count, timestamp, max_passes,
                         has_postvoid=has_postvoid, use_waves=use_waves)
 
     # Insert slots are claimed (no writes) BEFORE the flags are finalized so
@@ -1503,7 +1488,6 @@ def _exists_postvoid(t, e, p, n) -> jax.Array:
 create_transfers_full = jax.jit(
     create_transfers_full_impl, donate_argnames=("ledger",),
     static_argnames=(
-        "max_passes", "has_postvoid", "has_history", "static_trip",
-        "use_waves",
+        "max_passes", "has_postvoid", "has_history", "use_waves",
     ),
 )

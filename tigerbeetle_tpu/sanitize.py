@@ -182,10 +182,9 @@ class compile_tripwire:
     Requires jaxenv.instrument_compiles() (installed on entry).  On a
     nonzero delta: counts ``sanitize.recompiles``, warns loudly, and —
     when ``raise_on_trip`` (default: TB_SANITIZE_STRICT) — raises
-    SanitizeError.  The report object is yielded so callers (bench timed
-    loops) can record the count either way; ``quiet=True`` suppresses
-    this module's stderr warning for callers that print their own
-    context-specific one (bench names per_batch_us / payload.harness)."""
+    SanitizeError.  The report object is yielded so callers can record
+    the count either way; ``quiet=True`` suppresses this module's stderr
+    warning for callers that print their own context-specific one."""
 
     def __init__(self, label: str,
                  raise_on_trip: Optional[bool] = None,

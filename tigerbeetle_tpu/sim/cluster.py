@@ -377,7 +377,7 @@ class SimClient:
         # Overload-control accounting: explicit busy replies back the
         # client off (jittered exponential + the server hint, mirroring
         # client.py); latencies record send->reply ticks for every
-        # completed request (the admitted-p99 the bench sweep reports).
+        # completed request.
         from ..vsr.timeout import Timeout
 
         self._busy_backoff = Timeout(
@@ -706,7 +706,7 @@ class SimCluster:
             # Counters from queues retired by crash() (the queue's items
             # die with the replica, but its accounting must survive into
             # overload_stats() or the flood's heaviest window vanishes
-            # from the oracles and the bench sweep).
+            # from the oracles).
             self._admission_retired = {
                 "admitted": 0, "shed": 0, "depth_peak": 0,
                 "shed_by_class": {},
@@ -1275,7 +1275,7 @@ class SimCluster:
         return ids
 
     def overload_stats(self) -> dict:
-        """Governor accounting for oracles, metrics, and the bench sweep."""
+        """Governor accounting for oracles and metrics."""
         if self.overload is None:
             return {}
         shed_by_class: Dict[str, int] = {}

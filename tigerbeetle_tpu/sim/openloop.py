@@ -24,8 +24,7 @@ a pure function of the constructor arguments, independent of cluster
 timing, so a pinned VOPR seed replays bit-identically and two runs of the
 same seed produce byte-identical traffic (asserted by
 tests/test_byzantine.py).  The generator is the default traffic for the
-byzantine and overload VOPR kinds (sim/vopr.py) and drives the
-``bench.py --workload zipf`` sweep.
+byzantine and overload VOPR kinds (sim/vopr.py).
 """
 
 from __future__ import annotations

@@ -5,10 +5,6 @@ must lower THE SAME program: a batch derived
 inside jit from the batch index, in the flagship bench's workload shape.
 Two hand-rolled copies drifted within a day of each other (different
 amount formulas, post lanes keeping ledger/code); one definition cannot.
-
-bench.py keeps its own generator on purpose: its device generator is
-lock-stepped with a HOST-side numpy mirror for the parity check
-(gen_batch_np), a coupling these tools do not carry.
 """
 
 from __future__ import annotations
@@ -21,7 +17,7 @@ from ..ops.state_machine import TF_PENDING, TF_POST
 
 def gen_plain(b, *, lanes, count, n_accounts, id_base=1 << 35):
     """Plain-transfer batch derived from batch index ``b`` (a traced
-    uint64): the flagship workload shape (bench.py mix_workload)."""
+    uint64): uniform accounts, mixed amounts."""
     lane = jnp.arange(lanes, dtype=jnp.uint64)
     gid = b.astype(jnp.uint64) * jnp.uint64(count) + lane
     h1 = u128.mix64(gid, jnp.uint64(0x1234))

@@ -3,7 +3,7 @@
 Sums an accounts-table balance field over the FULL u128 (lo + (hi << 64),
 arbitrary-precision Python ints) — lo-limb-only sums would pass
 compensating lo errors or a divergence carried into hi limbs (VERDICT r4
-weak #5).  Used by bench.py, __graft_entry__.py's dryrun, and
+weak #5).  Used by __graft_entry__.py's dryrun and
 sim/cluster.py's check_conservation so the oracle has exactly one
 definition.  Reference oracle: src/testing/cluster/storage_checker.zig's
 byte-level determinism checks + the double-entry invariant.

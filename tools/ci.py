@@ -10,7 +10,7 @@ ones green):
 
   tidy         lint/ban/citation checks (seconds)
   lint         tools/tblint static analysis over tigerbeetle_tpu + tools
-               + tests + bench.py (tracer safety, VOPR determinism,
+               + tests (tracer safety, VOPR determinism,
                u128/wire invariants, donation/size-class/lane-race/
                shard-rep discipline); fails on any finding or any stale
                suppression (--check-suppressions)
@@ -20,9 +20,8 @@ ones green):
   kernel       JAX commit kernels + differential suites + queries + sharding
   consensus    VOPR model + real-code seeds, durability, adversary, fuzz
   obs          observability smoke (tools/obs_smoke.py): VOPR status grid,
-               traced+metered serving run, mini-bench with TB_TRACE +
-               --metrics-json; asserts the artifacts parse and carry the
-               expected span/series names
+               traced+metered serving run; asserts the artifacts parse and
+               carry the expected span/series names
   sync         state-sync smoke (tools/sync_smoke.py): small-divergence
                incremental rejoin byte win + byte identity vs the full
                transfer at TB_SHARDS {0,2}, corrupt-chunk detect+rotate,
@@ -65,12 +64,12 @@ TIERS = {
     ),
     "lint": dict(
         # Static analysis, not pytest: exits non-zero on any new finding
-        # OR any stale suppression.  Covers tests/ and bench.py too
-        # (tests/fixtures holds the deliberate violations and is pruned).
+        # OR any stale suppression.  Covers tests/ too (tests/fixtures
+        # holds the deliberate violations and is pruned).
         # (tests/test_tblint.py separately proves the rules themselves.)
         cmd=["-m", "tools.tblint", "--check-suppressions",
              "--exclude", "tests/fixtures",
-             "tigerbeetle_tpu", "tools", "tests", "bench.py"],
+             "tigerbeetle_tpu", "tools", "tests"],
     ),
     "unit": dict(
         files=[
@@ -111,19 +110,11 @@ TIERS = {
     ),
     "obs": dict(
         # Observability smoke, not pytest: tiny VOPR seed with the status
-        # grid, a traced+metered serving run, and a mini-bench with
-        # TB_TRACE + --metrics-json — asserting the trace JSON and metrics
-        # snapshot parse and carry the expected span/series names.
+        # grid and a traced+metered serving run — asserting the trace
+        # JSON and metrics snapshot parse and carry the expected
+        # span/series names.
         # Artifacts: METRICS.json + OBS_SMOKE.json at the repo root.
         cmd=["tools/obs_smoke.py"],
-    ),
-    "pipeline": dict(
-        # Pipelined commit engine smoke (docs/commit_pipeline.md): runs
-        # bench.py --pipeline-depth 1,2 on CPU and asserts depth-1 and
-        # depth-2 report identical reply/ledger digests AND that the
-        # occupancy/stall counters landed in METRICS.json.
-        # Artifact: PIPELINE_SMOKE.json at the repo root.
-        cmd=["tools/pipeline_smoke.py"],
     ),
     "scrub": dict(
         # Device fault domain smoke (docs/fault_domains.md): one seeded
@@ -132,47 +123,12 @@ TIERS = {
         # Artifact: SCRUB_SMOKE.json at the repo root.
         cmd=["tools/scrub_smoke.py"],
     ),
-    "overload": dict(
-        # Overload fault domain smoke (docs/fault_domains.md): busy-reply
-        # round trip against the real consensus cluster at 2x offered
-        # load, priority-preserving shed (client class only), and the
-        # overload.* series in the registry snapshot.
-        # Artifact: OVERLOAD_SMOKE.json at the repo root.
-        cmd=["tools/overload_smoke.py"],
-    ),
     "waves": dict(
         # Wave-scheduler smoke (docs/waves.md): waves on/off identity on a
         # Zipfian two-phase mix, the kernel-level pass-bound certification
         # (2 -> 1 passes on a conflict-free batch), and the waves.* series
         # asserted in METRICS.json.  Artifact: WAVES_SMOKE.json.
         cmd=["tools/waves_smoke.py"],
-    ),
-    "sharded": dict(
-        # Sharded live commit path smoke (docs/sharding.md): TB_SHARDS=0
-        # bit-identity against the pinned PIPELINE_SMOKE reply/digest
-        # identity, sharded-vs-single digest parity on a pinned mixed
-        # workload (shards 0/2/8 incl. the sequential fallback), and the
-        # sharding.* series asserted in METRICS.json.
-        # Artifact: SHARDED_SMOKE.json at the repo root.
-        cmd=["tools/sharded_smoke.py"],
-    ),
-    "merkle": dict(
-        # Merkle commitment tree smoke (docs/commitments.md): TB_MERKLE-off
-        # bit-identity against the pinned PIPELINE_SMOKE reply/digest
-        # identity, merkle-armed on-path identity + maintained-root-vs-
-        # numpy-oracle, proof round-trip + tamper rejection, SDC detection
-        # by root mismatch with the mirror off, and the merkle.* series
-        # asserted in METRICS.json.  Artifact: MERKLE_SMOKE.json.
-        cmd=["tools/merkle_smoke.py"],
-    ),
-    "async": dict(
-        # Async sharded commit engine smoke (docs/commit_pipeline.md +
-        # docs/sharding.md composition): the pinned pipeline workload
-        # replayed under TB_SHARDS=2 at depths {1,2,4} must reproduce
-        # PIPELINE_SMOKE/SHARDED_SMOKE's pinned replies_sha + digest,
-        # and the pipeline.shard.* occupancy counters must land in
-        # METRICS.json.  Artifact: ASYNC_SMOKE.json at the repo root.
-        cmd=["tools/async_smoke.py"],
     ),
     "sanitize": dict(
         # TB_SANITIZE runtime sanitizer smoke (docs/tblint.md): steady
@@ -225,29 +181,6 @@ TIERS = {
         # Artifact: AUTH_SMOKE.json at the repo root.
         cmd=["tools/auth_smoke.py"],
     ),
-    "trace": dict(
-        # Causal-tracing smoke (docs/tracing.md): one merged Perfetto
-        # flow per sampled request across >= 3 replica pid rows of a
-        # SimCluster (client.request -> consensus -> replica.execute ->
-        # replica.reply -> client.reply), depth-1 attribution stage sums
-        # reconciling within 10% of measured wall, trace-off
-        # replies/digest identity with sampling at 1/1, and a failing
-        # VOPR seed through the real CLI writing per-replica
-        # flight-recorder dumps next to the viz grid.
-        # Artifacts: TRACE_FLOW.json + TRACE_SMOKE.json at the repo root.
-        cmd=["tools/trace_smoke.py"],
-    ),
-    "fusion": dict(
-        # Cross-batch conflict fusion + deferred commitment lane smoke
-        # (docs/commit_pipeline.md fusion section, docs/commitments.md
-        # deferred-lane section): runs bench.py with all four knob arms
-        # (off/fuse/async/both) and asserts every arm is byte-identical
-        # to off, the knob-off pipeline sweep still matches the
-        # PIPELINE_SMOKE pin, a dispatch actually fused wider than one
-        # batch, and the fuse.* / merkle.lane.* series landed in
-        # METRICS.json.  Artifact: FUSION_SMOKE.json at the repo root.
-        cmd=["tools/fusion_smoke.py"],
-    ),
     "reconfig": dict(
         # Live-reshaping fault domain smoke (docs/reconfiguration.md):
         # standby promotion load-bearing through a post-flip primary
@@ -280,23 +213,20 @@ TIERS = {
             "test_scrub_off_bug_is_caught",
             "tests/test_sharded.py::test_sharded_full_kernel_two_phase_parity",
             "tests/test_sharded.py::test_sharded_full_kernel_random_stream",
-            # Sharded LIVE commit path (PR 8): the machine-mode parity
-            # pass, the cross-shard/zipf/two-phase differential matrix,
-            # the structural surfaces (growth/checkpoint/waves/scrub),
-            # and the pinned VOPR seed under TB_SHARDS=2 — all @slow
-            # (8-device compiles), so they run whole here.
-            "tests/test_sharded_machine.py::test_sharded_machine_parity_mixed",
-            "tests/test_sharded_machine.py::TestShardedDifferential",
+            # Sharded LIVE commit path (PR 8): the cross-shard-fraction
+            # differential matrix, the structural surfaces (growth/
+            # checkpoint/waves/scrub), and the pinned VOPR seed under
+            # TB_SHARDS=2 — all @slow (8-device compiles), so they run
+            # whole here.
+            "tests/test_sharded_machine.py::TestShardedDifferential::"
+            "test_cross_fraction_vs_model",
             "tests/test_sharded_machine.py::TestShardedStructural",
             "tests/test_sharded_machine.py::TestVoprSharded",
-            # Async sharded commit engine (PR 11): the composed
-            # depth x shard x merkle matrix, the grouped/deferred mesh
-            # differentials, the pipeline.shard.* metrics proof, and the
-            # pinned VOPR seed under TB_PIPELINE=2 x TB_SHARDS=2 — all
-            # @slow (sharded shard_map compiles), so they run whole here.
+            # Async sharded commit engine (PR 11): the grouped/deferred
+            # mesh differentials and the pinned VOPR seed under
+            # TB_PIPELINE=2 x TB_SHARDS=2 — @slow (sharded shard_map
+            # compiles), so they run whole here.
             "tests/test_async_sharded.py::TestMachineComposition",
-            "tests/test_async_sharded.py::test_pipeline_shard_metrics_recorded",
-            "tests/test_async_sharded.py::TestReplicaComposition",
             "tests/test_async_sharded.py::TestVoprComposed",
             # PR 18 tier-1 budget tranche: the next ~150s of slowest
             # tier-1 tests moved to @slow (scan-path balancing parity,
@@ -325,11 +255,8 @@ TIERS = {
             "tests/test_scan_builder.py::TestMaintenance::"
             "test_account_scans",
             # Cross-batch fusion + deferred commitment lane (PR 18): the
-            # sharded differential cells (mesh compiles) and the pinned
-            # VOPR seed under TB_FUSE=1 x TB_MERKLE_ASYNC=1 — @slow, so
-            # they run whole here.
-            "tests/test_fusion.py::TestFusionDifferential::"
-            "test_vs_model_and_off_path_sharded",
+            # pinned VOPR seed under TB_FUSE=1 x TB_MERKLE_ASYNC=1 —
+            # @slow, so it runs whole here.
             "tests/test_fusion.py::TestVoprFused",
             "tests/test_merkle.py::TestMerkleProofs::test_proof_kinds_sharded",
             "tests/test_block_repair.py::"
@@ -398,8 +325,6 @@ TIERS = {
             "test_incremental_matches_rebuild",
             "tests/test_scan_builder.py::TestColdTier::"
             "test_scan_sees_evicted_transfers",
-            "tests/test_transfer_full.py::TestStaticTripParity::"
-            "test_scan_and_while_paths_identical",
             "tests/test_cold_consensus.py::"
             "test_tiered_cluster_converges_with_evictions",
             "tests/test_scan_builder.py::TestPrefixScans::"
@@ -424,8 +349,6 @@ TIERS = {
             # matrix still covers them.
             "tests/test_scan_path.py::TestSequentialTransfers::"
             "test_balance_limits",
-            "tests/test_merkle.py::TestRootOracle::"
-            "test_root_vs_oracle_mixed_stream",
             "tests/test_waves.py::TestWavesDifferential::"
             "test_forced_conflict_collapses_to_chain_path",
             "tests/test_queries.py::TestGetAccountHistory::"
@@ -450,10 +373,9 @@ TIERS = {
     ),
 }
 ORDER = [
-    "tidy", "lint", "unit", "kernel", "consensus", "obs", "pipeline",
-    "scrub", "merkle", "overload", "waves", "sharded", "async",
-    "sanitize", "sync", "byzantine", "mc", "auth", "trace", "fusion",
-    "reconfig", "integration",
+    "tidy", "lint", "unit", "kernel", "consensus", "obs", "scrub", "waves",
+    "sanitize", "sync", "byzantine", "mc", "auth", "reconfig",
+    "integration",
 ]
 
 

@@ -149,8 +149,8 @@ def main(argv=None) -> int:
         assert w == cold.create_transfers(batch(200 + 16 * b))
         served_mid_split += 1
     # ...then the flood drains and the split pumps to cutover (the same
-    # settle discipline as the vopr schedule and bench.py's reconfig
-    # payload — a 100% write duty cycle never quiesces by design).
+    # settle discipline as the vopr schedule — a 100% write duty cycle
+    # never quiesces by design).
     pumps = 0
     while live.reshard_active:
         live.reshard_step(1)
