@@ -3,7 +3,9 @@ request in flight (a TigerBeetle session's protocol limit), each sending its
 own queue of requests built before the window opened.
 
 Inside the window a session only sends, receives and notes two clock
-readings per request.
+readings per request.  A caller that has to follow a run from another thread
+(the traced run's profiler cue) hands `run_queues` a `Progress`, which then
+also counts the answered requests; without one nothing is counted.
 """
 
 from __future__ import annotations
@@ -12,7 +14,7 @@ import dataclasses
 import math
 import threading
 import time
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 
 @dataclasses.dataclass
@@ -26,6 +28,47 @@ class Sent:
     t_reply: float        # ... after the reply was decoded
     codes: Optional[list]  # the reply's (index, code) pairs; None on error
     error: Optional[str] = None
+
+
+class Progress:
+    """How far one `run_queues` call has come, for a watcher on another
+    thread: seconds since the run began, requests answered, and whether every
+    session has ended."""
+
+    def __init__(self) -> None:
+        self._changed = threading.Condition()
+        self.began: Optional[float] = None   # time.monotonic()
+        self.answered = 0
+        self.finished = False
+
+    def begin(self) -> None:
+        with self._changed:
+            self.began = time.monotonic()
+            self._changed.notify_all()
+
+    def reply(self) -> None:
+        with self._changed:
+            self.answered += 1
+            self._changed.notify_all()
+
+    def finish(self) -> None:
+        with self._changed:
+            self.finished = True
+            self._changed.notify_all()
+
+    def wait(self, due: Callable[[float, int], bool], wake_s: float
+             ) -> Tuple[float, int]:
+        """Block until `due(seconds since the run began, answered)` holds or
+        the run has finished; returns those two as they then stand.  `due` is
+        asked again at every reply and once `wake_s` seconds have passed."""
+        with self._changed:
+            while True:
+                at_s = (0.0 if self.began is None
+                        else time.monotonic() - self.began)
+                if (self.began is not None and due(at_s, self.answered)
+                        ) or self.finished:
+                    return at_s, self.answered
+                self._changed.wait(wake_s - at_s if wake_s > at_s else None)
 
 
 def connect(port: int, sessions: int, seed: int, timeout_s: float) -> list:
@@ -52,11 +95,13 @@ def _send(clients, queues, s: int, k: int) -> Sent:
 
 
 def run_queues(clients: Sequence, queues: Sequence[list],
-               seconds: Optional[float] = None) -> List[Sent]:
+               seconds: Optional[float] = None,
+               progress: Optional[Progress] = None) -> List[Sent]:
     """Every session sends its queue in order, all sessions at once, each its
     next request as soon as its reply has come, until its queue is empty or —
     with `seconds` — the time is up (a request in flight then is waited
-    for).  A session stops at its first error."""
+    for).  A session stops at its first error.  `progress`, where given, is
+    told of the run's begin, of every answered request and of its end."""
     deadline = time.monotonic() + (seconds or 0.0)
     records: List[List[Sent]] = [[] for _ in queues]
 
@@ -67,13 +112,21 @@ def run_queues(clients: Sequence, queues: Sequence[list],
             records[s].append(_send(clients, queues, s, k))
             if records[s][-1].error:
                 break
+            if progress is not None:
+                progress.reply()
 
     threads = [threading.Thread(target=session, args=(s,), daemon=True)
                for s in range(len(queues))]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
+    if progress is not None:
+        progress.begin()
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        if progress is not None:
+            progress.finish()
     return [r for per_session in records for r in per_session]
 
 

@@ -114,6 +114,37 @@ def _self_time_by_op(ops: list, modules: list) -> Dict[str, List[float]]:
     return out
 
 
+def _loop_trips(ops: list, modules: list) -> List[int]:
+    """For each program execution of `modules` (in time order): the trips of
+    the longest `while` directly under it, 0 where it holds none.  A loop's
+    body runs each of its operations once a trip, so the trips are the count
+    most of the operations directly under the `while` share (its condition's
+    run once more)."""
+    starts = [s for _n, s, _d in modules]
+    loops: List[Dict[int, list]] = [{} for _ in modules]  # start -> [dur, {}]
+    stack: List[list] = []            # [end, the loop's slot or None]
+    for name, start, dur in sorted(ops, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][0] <= start:
+            stack.pop()
+        slot = None
+        if not stack:
+            at = bisect.bisect_right(starts, start) - 1
+            if at >= 0 and name.lstrip("%").startswith("while"):
+                slot = loops[at].setdefault(start, [dur, {}])
+        elif len(stack) == 1 and stack[0][1] is not None:
+            counts = stack[0][1][1]
+            counts[name] = counts.get(name, 0) + 1
+        stack.append([start + dur, slot])
+    trips = []
+    for found in loops:
+        counts = max(found.values(), key=lambda v: v[0])[1] if found else {}
+        tally: Dict[int, int] = {}
+        for n in counts.values():
+            tally[n] = tally.get(n, 0) + 1
+        trips.append(max(tally, key=lambda n: (tally[n], -n)) if tally else 0)
+    return trips
+
+
 def _top(sums: Dict[str, List[float]]) -> List[list]:
     ranked = sorted(sums.items(), key=lambda kv: -kv[1][0])[:TOP]
     return [[name, seconds] for name, (seconds, _n) in ranked]
@@ -122,8 +153,10 @@ def _top(sums: Dict[str, List[float]]) -> List[list]:
 def reduce(events: dict) -> dict:
     """busy_s, window_s, program_s (all program executions, summed, averaged
     over devices), programs / ops ({name: [seconds, count]}, device 0's; an
-    operation's seconds are its self time), device_ops and idle_gaps for the
-    result line's `breakdown`."""
+    operation's seconds are its self time), executions (device 0's program
+    executions in time order: [name, start_ns, dur_ns, trips of its longest
+    loop]) inside device_span_ns (device 0's first event's start, its last
+    one's end), device_ops and idle_gaps for the result line's `breakdown`."""
     first, last = events["span_ns"]
     devices = events["devices"]
     if not devices:
@@ -151,13 +184,19 @@ def reduce(events: dict) -> dict:
             gaps.append([f"before:{nxt}", (s1 - e0) / 1e9])
     gaps.sort(key=lambda g: -g[1])
     programs = _sums(modules)
-    op_sums = _self_time_by_op(lines0.get(OPS_LINE) or [], modules)
+    ops0 = lines0.get(OPS_LINE) or []
+    op_sums = _self_time_by_op(ops0, modules)
+    events0 = ops0 + modules
     return {
         "busy_s": sum(busy) / len(busy),
         "window_s": (last - first) / 1e9,
         "program_s": sum(program) / len(program),
         "devices": len(devices),
         "programs": programs,
+        "executions": [[name, start, dur, trips] for (name, start, dur), trips
+                       in zip(modules, _loop_trips(ops0, modules))],
+        "device_span_ns": [min(e[1] for e in events0),
+                           max(e[1] + e[2] for e in events0)],
         "ops": op_sums,
         "device_ops": _top(op_sums),
         "idle_gaps": gaps[:TOP],

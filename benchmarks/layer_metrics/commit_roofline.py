@@ -4,27 +4,28 @@ committed requests must move (`harness/bytes_model.py`, over the device's
 published HBM bandwidth) over the device time of every program execution.
 The bound is HBM bandwidth; the kernels do no matrix arithmetic.
 
-Requests are counted by route between the trace-start and trace-stop
-snapshots: fast and grouped requests carry `batch` plain or pending lanes,
-general requests the mix's resolving lanes (`batch` where the mix has none).
+Requests and device time are `kernel_ms_per_batch`'s, counted on the trace's
+own clock (`harness/commit_programs.py`): fast and grouped requests carry
+`batch` plain or pending lanes, general requests the mix's resolving lanes
+(`batch` where the mix has none).
 """
 
-from benchmarks.harness import bytes_model, snapshots
+from benchmarks.harness import bytes_model, commit_programs
 
 
 def read(run):
-    s, trace, mix = run["snapshots"], run["trace"], run["mix"]
-    if run["peaks"] is None or trace is None or trace["program_s"] <= 0:
+    trace, mix = run["trace"], run["mix"]
+    if run["peaks"] is None or trace is None:
         return None
-    a, b = s["trace_start"], s["trace_stop"]
-    fast = (snapshots.counter(a, b, "ops.route.fast")
-            + snapshots.counter(a, b, "ops.route.grouped"))
-    general = snapshots.counter(a, b, "ops.route.general")
+    whole = commit_programs.whole_requests(trace)
+    if whole is None or whole["program_s"] <= 0:
+        return None
     share = mix.get("resolve")
     resolve_lanes = mix["batch"] if share is None else (
         mix["batch"] * share["post_pct"] // 100
         + mix["batch"] * share["void_pct"] // 100)
-    moved = (fast * mix["batch"] * bytes_model.fast_lane_bytes()
-             + general * resolve_lanes * bytes_model.resolve_lane_bytes())
+    moved = (whole["fast"] * mix["batch"] * bytes_model.fast_lane_bytes()
+             + whole["general"] * resolve_lanes
+             * bytes_model.resolve_lane_bytes())
     least_s = moved / run["peaks"]["hbm_bytes_per_s"]
-    return 100.0 * least_s / trace["program_s"]
+    return 100.0 * least_s / whole["program_s"]

@@ -1,17 +1,18 @@
-"""Device milliseconds per execution of the general (Jacobi) commit program
-inside the profiler's window: the `XLA Modules` events of
-`jit_create_transfers_full*` (the trace's `programs`), seconds over count.
-One execution commits one resolving request; the secondary index's programs
-that follow it are not in it (`kernel_ms_per_batch` has everything)."""
+"""Device milliseconds per WHOLE execution of the general (Jacobi) commit
+program inside the profiler's window: the `XLA Modules` events of
+`jit_create_transfers_full*` that neither edge of the trace cut, seconds over
+count.  One execution commits one resolving request; the secondary index's
+programs that follow it are not in it (`kernel_ms_per_batch` has everything).
+"""
 
-PROGRAM = "create_transfers_full"
+from benchmarks.harness import commit_programs
 
 
 def executions(trace):
-    """(device seconds, executions) of the general program in a reduced
-    trace; (0.0, 0) where it never ran there."""
-    found = [v for name, v in trace["programs"].items() if PROGRAM in name]
-    return sum(v[0] for v in found), sum(v[1] for v in found)
+    """(device seconds, executions) of the general program's whole
+    executions in a reduced trace; (0.0, 0) where it never ran whole there."""
+    found = commit_programs.whole_executions(trace, commit_programs.GENERAL)
+    return sum(e[2] for e in found) / 1e9, len(found)
 
 
 def read(run):

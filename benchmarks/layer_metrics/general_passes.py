@@ -1,8 +1,9 @@
-"""Mean Jacobi passes a committed general request took to its fixpoint, over
-the window (`waves.jacobi_passes`, read back with the kernel's flags).  On a
-TPU the pass loop has a static trip (4 passes, then 4 more only where the
-first 4 did not settle it): this is the count up to and including the pass
-that stabilized, not the passes the device ran."""
+"""Mean Jacobi passes the device RAN for a committed general request, over
+the window (`waves.jacobi_passes`, read back with the kernel's flags).  The
+pass loop is one gated scan on every backend: a pass runs only while the
+loop's own exit has not been met, so this counts the passes executed (1.0
+where every batch's wave bound proves one pass enough); the aux pass from the
+final iterate is not in it."""
 
 from benchmarks.harness import snapshots
 

@@ -1,6 +1,6 @@
 """Share of the HBM roofline reached by the general (Jacobi) commit program
 inside the profiler's window: the least time the chip could take to move the
-bytes its executions must move (each carries the mix's resolving lanes, a
+bytes its whole executions must move (each carries the mix's resolving lanes, a
 post or a void each: `harness/bytes_model.resolve_lane_bytes()`, over the
 device's published HBM bandwidth) over that program's own device time.  The
 bound is HBM bandwidth; the kernel does no matrix arithmetic.  The index

@@ -37,7 +37,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmarks.harness import check, drive, procs, trace_reduce
+from benchmarks.harness import check, drive, procs, trace_cue, trace_reduce
 from benchmarks.harness.peaks import peaks_of
 from benchmarks.harness.server import Server
 
@@ -130,7 +130,27 @@ def client_side(window: list, allowed: set):
         "batch_p95_ms": drive.latency_quantile_ms(window, 0.95),
         "batch_max_ms": drive.latency_quantile_ms(window, 1.0),
     }
+    if done:  # which request `batch_max_ms` was, and when it was sent
+        slowest = max(done, key=lambda r: r.t_reply - r.t_send)
+        seen["batch_max_request"] = {
+            "session": slowest.session, "index": slowest.index,
+            "operation": slowest.operation,
+            "sent_at_s": slowest.t_send - first_send}
     return len(failed), end_to_end, seen
+
+
+def trace_placement(placed: dict, window: list) -> dict:
+    """Where the profiler window lay in the measured window: seconds after
+    the window's first send at which its two cues were sent, the requests
+    answered by then, and when the window's last reply came."""
+    first_send = min(r.t_send for r in window)
+    return {
+        "trace_opened_at_s": placed["opened"] - first_send,
+        "trace_closed_at_s": placed["closed"] - first_send,
+        "trace_requests_answered": placed["answered"],
+        "trace_start_cue_s": placed["started"] - placed["opened"],
+        "window_ended_at_s": max(r.t_reply for r in window) - first_send,
+    }
 
 
 def server_side(loaded: dict, snaps: dict, reduced: dict, window: list,
@@ -214,33 +234,33 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
         # -- the window ----------------------------------------------------
         snaps: Dict[str, dict] = {}
         trace_dir = os.path.join(workdir, "trace")
-        tracer: Optional[threading.Thread] = None
+        progress: Optional[drive.Progress] = None   # the untraced run: none
         if trace:
+            # One profiler window inside the measured window, cued by the
+            # window's own progress (`harness/trace_cue.py`).
             snaps["open"] = server.cue("snapshot")
-            trace_errors: list = []
+            progress = drive.Progress()
+            cue = trace_cue.TraceCue.for_window(
+                sum(len(q) for q in plan["window"]), len(plan["window"]),
+                seconds)
+            placed: dict = {}
 
             def traced_span() -> None:
-                # One profiler window of a few seconds inside the measured
-                # window, with a registry snapshot on either side.
                 try:
-                    time.sleep(0.4 * seconds)
-                    snaps["trace_start"] = server.cue("snapshot")
-                    server.cue("trace_start", dir=trace_dir)
-                    time.sleep(min(5.0, 0.25 * seconds))
-                    snaps["trace_stop"] = server.cue("snapshot")
-                    server.cue("trace_stop", timeout_s=300.0)
+                    placed.update(trace_cue.place(
+                        server, progress, cue, trace_dir))
                 except Exception as err:  # re-raised on the main thread
-                    trace_errors.append(err)
+                    placed["error"] = err
 
             tracer = threading.Thread(target=traced_span, daemon=True)
             tracer.start()
-        t_open = time.monotonic()
-        out["setup_s"] = t_open - T_PROCESS_START
-        window = drive.run_queues(clients, plan["window"], seconds=seconds)
-        if tracer is not None:
+        out["setup_s"] = time.monotonic() - T_PROCESS_START
+        window = drive.run_queues(clients, plan["window"], seconds=seconds,
+                                  progress=progress)
+        if trace:
             tracer.join()
-            if trace_errors:
-                raise trace_errors[0]
+            if "error" in placed:
+                raise placed["error"]
             snaps["close"] = server.cue("snapshot")
         require(window, "the window sent no request")
 
@@ -290,8 +310,15 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
         out["numbers"] = numbers
         out["correct"] = check.verdict(numbers)
         if trace:
+            where = trace_placement(placed, window)
+            obs.update(where)
             xplane = trace_reduce.find_xplane(trace_dir)
-            out["trace"] = trace_reduce.reduce(trace_reduce.read_events(xplane))
+            try:
+                out["trace"] = trace_reduce.reduce(
+                    trace_reduce.read_events(xplane))
+            except ValueError as err:
+                raise BenchFailure(f"{err}; the profiler's place in the "
+                                   f"window: {json.dumps(where)}") from err
             out["per_layer"], seen = server_side(
                 loaded, snaps, out["trace"], window, peaks)
             obs.update(seen)
@@ -349,20 +376,25 @@ def result_line(loaded: dict, out: dict, trace: bool) -> dict:
         line["device"]["window_s"] = out["trace"]["window_s"]
         line["breakdown"] = {"device_ops": out["trace"]["device_ops"],
                              "idle_gaps": out["trace"]["idle_gaps"]}
+    # Last in the line: every number `correct` compared, beside its limit.
+    line["compared"] = {name: {"value": value, "limit": limit}
+                        for name, (value, limit) in out["numbers"].items()}
     return line
 
 
 def print_report(out: dict, trace: bool) -> None:
-    """Every number compared beside its limit, then the observations."""
-    for name, (value, limit) in out["numbers"].items():
-        print(f"compared {name}: {value}"
-              + ("" if limit is None else f" (limit {limit})"), flush=True)
+    """The observations on stdout; every number compared beside its limit as
+    the last lines of stderr."""
     if trace:
         print("end_to_end_while_traced: " + json.dumps(out["end_to_end"]))
         print("programs: " + json.dumps(
             sorted(out["trace"]["programs"].items(),
                    key=lambda kv: -kv[1][0])[:12]))
     print("observations: " + json.dumps(out["observations"]), flush=True)
+    for name, (value, limit) in out["numbers"].items():
+        print(f"compared {name}: {value}"
+              + ("" if limit is None else f" (limit {limit})"),
+              file=sys.stderr, flush=True)
 
 
 def _on_signal(signum, _frame) -> None:

@@ -4,10 +4,12 @@
 environment and (for the traced rehearsal) a stand-in for the device plane
 are passed from here, the way `tests/test_chip_smoke.py` drives
 `chip_smoke.py`.  TB_GROUP_COMMIT=1 steers the child onto the grouped
-dispatch, which is the default only on a TPU.  Prints one JSON object.
+dispatch, which is the default only on a TPU.  Prints one JSON object; its
+`run_queues_progress` says, for each `drive.run_queues` call the run made,
+whether it was handed a `Progress`.
 
     python cpu_cell.py <root> <workload> <seed> <seconds> <trace> \
-        [--server-main FILE] [--expect-platform NAME]
+        [--server-main FILE] [--expect-platform NAME] [--no-device-plane]
 """
 
 import argparse
@@ -43,14 +45,24 @@ def main(argv) -> int:
         p.add_argument(name)
     p.add_argument("--server-main", default=None)
     p.add_argument("--expect-platform", default="cpu")
+    p.add_argument("--no-device-plane", action="store_true",
+                   help="read the CPU trace as it is: it has no device plane")
     args = p.parse_args(argv)
     root = args.root
     sys.path.insert(0, root)
     from benchmarks import run
-    from benchmarks.harness import trace_reduce
+    from benchmarks.harness import drive, trace_reduce
     from tigerbeetle_tpu import jaxenv
 
-    trace_reduce.read_events = _cpu_threads_as_device
+    if not args.no_device_plane:
+        trace_reduce.read_events = _cpu_threads_as_device
+    run_queues, with_progress = drive.run_queues, []
+
+    def recording(*a, progress=None, **kw):
+        with_progress.append(progress is not None)
+        return run_queues(*a, progress=progress, **kw)
+
+    drive.run_queues = recording
     env = jaxenv.child_env(cpu=True, n_devices=1)
     env["TB_GROUP_COMMIT"] = "1"
     loaded = run.load_cell(root, args.workload)
@@ -61,7 +73,8 @@ def main(argv) -> int:
             server_main=args.server_main)
     keep = ("correct", "numbers", "attempted", "failed", "end_to_end",
             "per_layer", "observations", "device", "memory_peak_bytes")
-    print(json.dumps({k: out[k] for k in keep if k in out}))
+    print(json.dumps(dict({k: out[k] for k in keep if k in out},
+                          run_queues_progress=with_progress)))
     return 0
 
 

@@ -102,3 +102,39 @@ def test_no_result_where_only_the_benchmark_is_present(tmp_path):
         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert done.returncode != 0
     assert done.stdout.strip() == ""
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_the_result_line_has_the_contracts_keys_and_compared_last(
+        bench, trace):
+    """`result_line` on a made-up run of each cell: the driver's keys, a
+    metric only where its reader read something, and every number compared
+    beside its limit under the line's last key."""
+    from benchmarks import run
+
+    for cell in bench["workloads"]:
+        loaded = {"bench": bench, "cell": cell}
+        listed = [m["name"] for m in run.metrics_for(
+            bench, "per_layer" if trace else "end_to_end", cell["name"])]
+        assert "group_scan_fill" not in listed
+        values = {name: 1.5 for name in listed}
+        silent = listed[-1] if trace else None
+        values[silent] = None
+        out = {"correct": True, "attempted": 248, "failed": 0,
+               "per_layer": values, "end_to_end": values,
+               "memory_peak_bytes": 1 << 31,
+               "device": {"platform": "tpu", "device_kind": "TPU v5 lite",
+                          "count": 1},
+               "trace": {"busy_s": 4.5, "window_s": 5.0, "device_ops": [],
+                         "idle_gaps": []},
+               "numbers": {"requests_compared": (512, None),
+                           "account_rows_differing": (0, 0)}}
+        line = json.loads(json.dumps(run.result_line(loaded, out, trace)))
+        assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
+                                  "device"]
+        assert list(line)[-1] == "compared"
+        assert line["compared"] == {
+            "requests_compared": {"value": 512, "limit": None},
+            "account_rows_differing": {"value": 0, "limit": 0}}
+        assert set(line["metrics"]) == set(listed) - {silent}
+        assert ("busy_s" in line["device"]) == trace == ("breakdown" in line)

@@ -1,4 +1,4 @@
-"""The ten per-layer readers of the request timeline: on recorded snapshots
+"""The nine per-layer readers of the request timeline: on recorded snapshots
 (a traced `tiny-plain` CPU rehearsal's, trimmed to the series they read), a
 number where the series exist and None where they do not, as in a program
 without the spans; and the CPU rehearsal reporting all of them."""
@@ -18,7 +18,6 @@ NEW_METRICS = (
     "server_request_ms", "unseen_by_server_ms", "admission_wait_ms",
     "pickup_arriving", "commit_host_ms", "results_wait_ms",
     "serving_thread_busy_pct", "lane_execute_ms", "lane_join_ms",
-    "group_scan_fill",
 )
 INTERVALS = ("ingress", "admission_wait", "commit_host", "results_wait",
              "barrier_wait", "reply_release")
@@ -68,9 +67,6 @@ def test_readers_on_recorded_snapshots(recorded):
     def delta(counter):
         return closed["counters"][counter] - opened["counters"][counter]
 
-    # 12 requests in 5 scans of 32 steps.
-    assert (delta("ops.group.batches"), delta("ops.group.steps")) == (12, 160)
-    assert got["group_scan_fill"] == pytest.approx(100.0 * 12 / 160)
     busy = delta("serve.busy_us")
     assert got["serving_thread_busy_pct"] == pytest.approx(
         100.0 * busy / 10e6)
@@ -103,7 +99,7 @@ def plain_traced(cpu_cell):
     return cpu_cell("tiny-plain", 3000000017, 4, 1)
 
 
-def test_cpu_rehearsal_reports_all_ten(plain_traced):
+def test_cpu_rehearsal_reports_all_nine(plain_traced):
     rc, out, err = plain_traced
     assert rc == 0, err[-3000:]
     assert out["correct"] is True
@@ -112,7 +108,6 @@ def test_cpu_rehearsal_reports_all_ten(plain_traced):
         assert isinstance(layer.get(name), float), (name, layer.get(name))
     assert layer["server_request_ms"] > 0
     assert layer["unseen_by_server_ms"] > -1.0
-    assert 0 < layer["group_scan_fill"] <= 100
     assert 0 < layer["serving_thread_busy_pct"] <= 101
     # A mean of the window's requests cannot pass their longest.
     assert (layer["server_request_ms"] + layer["unseen_by_server_ms"]

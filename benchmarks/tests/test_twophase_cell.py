@@ -86,8 +86,9 @@ def _histogram(count, total):
 @pytest.fixture
 def run(cell):
     """A window of 10 general commits of 120 ms each with a device wait of
-    100 ms, 2 passes each; a profiler window with 4 executions of the
-    general program, 0.5 s of device time in all."""
+    100 ms, 2 passes each; a profiler window with 4 whole executions of the
+    general program, 0.5 s of device time in all, one more that was under way
+    when the profiler opened (60 ms of it seen) and one it closed on (40)."""
     opened = {"counters": {}, "gauges": {}, "histograms": {
         "txtrace.stage.general_commit": _histogram(5, 5 * 90e3),
         "txtrace.stage.full_sync": _histogram(5, 5 * 70e3),
@@ -96,9 +97,15 @@ def run(cell):
         "txtrace.stage.general_commit": _histogram(15, 5 * 90e3 + 10 * 120e3),
         "txtrace.stage.full_sync": _histogram(15, 5 * 70e3 + 10 * 100e3),
         "waves.jacobi_passes": _histogram(15, 5 + 20)}}
-    trace = {"program_s": 1.5, "programs": {
-        "jit__group_fast_dispatch_impl": [1.0, 2],
-        "jit_create_transfers_full_impl": [0.5, 4]}}
+    ms = 1_000_000
+    general = "jit_create_transfers_full_impl"
+    trace = {"program_s": 1.6, "device_span_ns": [0, 2000 * ms], "executions": [
+        [general, 0, 60 * ms, 0],
+        ["jit__group_fast_dispatch_impl", 100 * ms, 500 * ms, 7],
+        [general, 700 * ms, 120 * ms, 0], [general, 900 * ms, 130 * ms, 0],
+        ["jit__group_fast_dispatch_impl", 1100 * ms, 500 * ms, 7],
+        [general, 1650 * ms, 110 * ms, 0], [general, 1800 * ms, 140 * ms, 0],
+        [general, 1960 * ms, 40 * ms, 0]]}
     return {"snapshots": {"open": opened, "trace_start": opened,
                           "trace_stop": closed, "close": closed},
             "trace": trace, "window": [], "mix": cell["mix"],
@@ -142,7 +149,8 @@ def test_none_where_there_is_nothing_to_read(run, name):
     (the parent commit; a plain mix): nothing is read, nothing raises."""
     for snap in run["snapshots"].values():
         snap["histograms"] = {}
-    run["trace"]["programs"].pop("jit_create_transfers_full_impl")
+    run["trace"]["executions"] = [e for e in run["trace"]["executions"]
+                                  if "create_transfers_full" not in e[0]]
     assert _read(name, run) is None
     run["trace"] = None
     run["peaks"] = None
