@@ -231,3 +231,160 @@ def test_claim_parity_forced_home_collisions():
     # Lane order == placement order within the shared cluster.
     slots = np.asarray(got)
     assert (np.diff(slots.astype(np.int64)) > 0).all()
+
+
+# ---------------------------------------------------------------------------
+# uint64 columns are written by halves (two one-operand uint32 scatters):
+# the tables must not know.
+# ---------------------------------------------------------------------------
+
+HIGH_HALF = [1 << 32, 1 << 63, (1 << 64) - 1, (1 << 32) - 1, 0]
+WRITE_COLS = {"a": jnp.uint64, "b": jnp.uint64, "c": jnp.uint32,
+              "h": jnp.uint16}
+
+
+def _random_table(rng, capacity):
+    """A table whose every array holds noise (high halves included)."""
+    def u64(n):
+        return rng.integers(0, 1 << 64, size=n, dtype=np.uint64)
+
+    cols = {
+        name: (u64(capacity) if dt == jnp.uint64 else
+               rng.integers(0, 1 << 16, size=capacity).astype(dt))
+        for name, dt in WRITE_COLS.items()
+    }
+    return ht.Table(
+        key_lo=jnp.asarray(u64(capacity)), key_hi=jnp.asarray(u64(capacity)),
+        tombstone=jnp.asarray(rng.random(capacity) < 0.1),
+        cols={k: jnp.asarray(v) for k, v in cols.items()},
+        count=jnp.uint64(12345), probe_overflow=jnp.bool_(False),
+    )
+
+
+def _as_numpy(table):
+    return {
+        "key_lo": np.asarray(table.key_lo).copy(),
+        "key_hi": np.asarray(table.key_hi).copy(),
+        "tombstone": np.asarray(table.tombstone).copy(),
+        "count": int(table.count),
+        **{"col." + k: np.asarray(v).copy() for k, v in table.cols.items()},
+    }
+
+
+def _lane_values(rng, n, dtype=np.uint64):
+    vals = rng.integers(0, 1 << 64, size=n, dtype=np.uint64)
+    vals[: len(HIGH_HALF)] = np.array(HIGH_HALF, np.uint64)
+    return vals.astype(dtype)
+
+
+@pytest.mark.parametrize("lanes", [64, 8190])
+@pytest.mark.parametrize(
+    "op", ["write_rows", "scatter_cols", "remove_to_tombstone"])
+def test_writes_by_halves_match_a_numpy_oracle(op, lanes):
+    """write_rows / scatter_cols / remove_to_tombstone against plain numpy
+    fancy assignment: values that use the high half, masked lanes, sentinel
+    (dropped) lanes, a full 8190-lane batch."""
+    rng = np.random.default_rng(lanes * 7 + len(op))
+    capacity = 1 << 15
+    table = _random_table(rng, capacity)
+    want = _as_numpy(table)
+
+    slot = rng.choice(capacity, size=lanes, replace=False).astype(np.uint64)
+    mask = rng.random(lanes) < 0.8
+    mask[: len(HIGH_HALF)] = True
+    dropped = rng.random(lanes) < 0.1            # sentinel lanes
+    dropped[: len(HIGH_HALF)] = False
+    slot_in = np.where(dropped, np.uint64(capacity), slot)
+    live = mask & ~dropped
+    at = slot[live].astype(np.int64)
+    key_lo, key_hi = _lane_values(rng, lanes), _lane_values(rng, lanes)[::-1]
+    rows = {
+        "a": _lane_values(rng, lanes), "b": _lane_values(rng, lanes)[::-1],
+        "c": _lane_values(rng, lanes, np.uint32),
+        "h": _lane_values(rng, lanes, np.uint16),
+    }
+
+    if op == "write_rows":
+        def run(t):
+            return ht.write_rows(
+                t, jnp.asarray(key_lo), jnp.asarray(key_hi),
+                jnp.asarray(slot_in), jnp.asarray(mask),
+                {k: jnp.asarray(v) for k, v in rows.items()},
+            )
+        want["key_lo"][at], want["key_hi"][at] = key_lo[live], key_hi[live]
+        want["tombstone"][at] = False
+        for name, vals in rows.items():
+            want["col." + name][at] = vals[live]
+        want["count"] += int(live.sum())
+    elif op == "scatter_cols":
+        updates = {k: rows[k] for k in ("a", "c")}     # "b", "h" untouched
+        def run(t):
+            return ht.scatter_cols(
+                t, jnp.asarray(slot_in), jnp.asarray(mask),
+                {k: jnp.asarray(v) for k, v in updates.items()},
+            )
+        for name, vals in updates.items():
+            want["col." + name][at] = vals[live]
+    else:
+        def run(t):
+            return ht.remove_to_tombstone(
+                t, jnp.asarray(slot_in), jnp.asarray(live))
+        want["key_lo"][at] = want["key_hi"][at] = 0
+        want["tombstone"][at] = True
+        want["count"] -= int(live.sum())
+
+    got = _as_numpy(jax.jit(run)(table))
+    assert got.keys() == want.keys()
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+        assert np.asarray(got[name]).dtype == np.asarray(want[name]).dtype
+
+
+def _scatter_operands(stablehlo_text):
+    """The result types of every stablehlo.scatter in a lowered program's
+    text: one per operand (inputs and results correspond)."""
+    import re
+
+    out = []
+    for found in re.finditer(r'"stablehlo\.scatter"', stablehlo_text):
+        end = stablehlo_text.index("}) : (", found.end())
+        signature = stablehlo_text[end:stablehlo_text.index("\n", end)]
+        out.extend(re.findall(r"tensor<([^>]*)>", signature.split("->")[1]))
+    return out
+
+
+@pytest.mark.parametrize("program", ["fast", "grouped", "general"])
+def test_no_commit_program_scatters_a_64_bit_operand(program):
+    """The guard: a TPU compiles a 64-bit scatter to ONE scatter with two
+    32-bit operands, 10-18x as slow an index as two one-operand scatters
+    (hash_table docstring).  None may come back into the commit programs
+    (the general kernel as served: no history rows)."""
+    from tigerbeetle_tpu import machine, types
+    from tigerbeetle_tpu.ops import state_machine as sm
+    from tigerbeetle_tpu.ops import transfer_full as tf
+
+    lanes = 256
+    led = jax.eval_shape(lambda: sm.make_ledger(1 << 10, 1 << 12, 1 << 8))
+    u64 = jax.ShapeDtypeStruct((), jnp.uint64)
+    cols = types.to_soa(np.zeros(1, dtype=types.TRANSFER_DTYPE))
+
+    def batch(lead=()):
+        return {k: jax.ShapeDtypeStruct(lead + (lanes,), v.dtype)
+                for k, v in cols.items()}
+
+    if program == "fast":
+        lowered = jax.jit(sm.create_transfers_impl).lower(
+            led, batch(), u64, u64)
+    elif program == "grouped":
+        k = machine.TpuStateMachine.GROUP_K
+        kvec = jax.ShapeDtypeStruct((k,), jnp.uint64)
+        lowered = machine._group_fast_dispatch.lower(
+            led, batch((k,)), kvec, kvec)
+    else:
+        lowered = tf.create_transfers_full.lower(
+            led, batch(), u64, u64, None, None, max_passes=8,
+            has_postvoid=True, has_history=False, use_waves=True)
+    operands = _scatter_operands(lowered.as_text())
+    assert len(operands) > 20, "the parser lost the program's scatters"
+    wide = [t for t in operands if t.endswith(("i64", "f64"))]
+    assert not wide, f"64-bit scatter operands: {wide}"

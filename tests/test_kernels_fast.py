@@ -266,3 +266,56 @@ class TestCreateTransfersKernel:
             )
             run_transfers(dev, ref, batch, wall=7000 * (i + 1))
             assert dev.balances_snapshot() == ref.balances_snapshot(), f"batch {i}"
+
+
+@pytest.mark.parametrize("lanes,amounts", [
+    (64, "max"), (64, "random"), (8192, "max"), (8192, "edges"),
+])
+def test_balance_plan_sums_16_bit_limbs_exactly(lanes, amounts):
+    """balance_plan's per-account u128 deltas against Python integers: every
+    leg on a handful of accounts, amounts that fill all four 16-bit limbs
+    (2^64-1 on every lane: 16,384 terms a limb), masked lanes."""
+    import jax.numpy as jnp
+
+    from tigerbeetle_tpu.ops import state_machine as sm
+
+    rng = np.random.default_rng(lanes)
+    if amounts == "max":
+        amt = np.full(lanes, (1 << 64) - 1, np.uint64)
+    elif amounts == "edges":
+        amt = rng.choice(np.array(
+            [0xFFFF, 0x10000, (1 << 32) - 1, 1 << 32, 1 << 48, 1 << 63,
+             (1 << 64) - 1], np.uint64), size=lanes)
+    else:
+        amt = rng.integers(0, 1 << 64, size=lanes, dtype=np.uint64)
+    accounts = 3 if amounts == "max" else 40
+    dr = rng.integers(0, accounts, size=lanes).astype(np.uint64)
+    cr = ((dr + 1 + rng.integers(0, accounts - 1, size=lanes).astype(
+        np.uint64)) % np.uint64(accounts)).astype(np.uint64)
+    ok = rng.random(lanes) < 0.9
+    pending = rng.random(lanes) < 0.3
+    sentinel = 1 << 12
+
+    plan = sm.balance_plan(
+        jnp.asarray(dr), jnp.asarray(cr), jnp.asarray(ok), jnp.asarray(amt),
+        jnp.asarray(pending), sentinel)
+
+    want = {}
+    for i in range(lanes):
+        if not ok[i]:
+            continue
+        kind = "pending" if pending[i] else "posted"
+        for slot, side in ((int(dr[i]), "debits_"), (int(cr[i]), "credits_")):
+            key = (slot, side + kind)
+            want[key] = want.get(key, 0) + int(amt[i])
+
+    s_slot, head = np.asarray(plan.s_slot), np.asarray(plan.head)
+    got = {}
+    for field, (d_lo, d_hi) in plan.deltas.items():
+        d_lo, d_hi = np.asarray(d_lo), np.asarray(d_hi)
+        assert d_lo.dtype == d_hi.dtype == np.uint64
+        for at in np.flatnonzero(head):
+            total = int(d_lo[at]) | (int(d_hi[at]) << 64)
+            if total:
+                got[(int(s_slot[at]), field)] = total
+    assert got == want

@@ -85,10 +85,11 @@ def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
     of ``counts`` (a group's batches lead the stack and none is empty) or
     at GROUP_K, so every group length runs the ONE program the
     (GROUP_K, lanes) shapes compile to.  On the chip a step over an empty
-    batch costs most of what a full one does (its table-sized work does
-    not depend on the count: one TPU v5 lite, PERF.md section 5), so the
-    rows past the group are not run at all: their codes stay the zeros
-    the buffer starts with and are never read.
+    batch costs most of what a full one does (its gathers and scatters
+    run over every lane whatever the count, dropped or not: one TPU v5
+    lite, PERF.md section 5), so the rows past the group are not run at
+    all: their codes stay the zeros the buffer starts with and are never
+    read.
 
     Besides (ledger, codes) it returns the transfers probe_overflow flag
     widened into a FRESH uint32 buffer (the deferred readback handle must

@@ -17,6 +17,16 @@ Design:
   unplaced lanes probing the same slot, the lowest batch index wins; losers
   advance their probe. Deterministic (a pure function of the batch).
 
+- A uint64 column is WRITTEN by halves: two independent one-operand uint32
+  scatters (``_set_at``).  A TPU holds a 64-bit array as a pair of 32-bit
+  arrays and compiles a 64-bit scatter to ONE scatter with two operands,
+  which ran 1.02 ms over 8192 indices of a 2^23-slot column where the two
+  one-operand uint32 scatters run 0.09-0.10 ms each (one TPU v5 lite,
+  PERF.md PR 32).  Extracting the high half is the one conversion that
+  compiler does not fold away (a table-sized ``hi >> 0`` pass, 0.05 ms a
+  2^23 column); carrying the halves through the grouped loop, to pay it
+  once a dispatch, was built and bought nothing end to end (same place).
+
 All entry points are shape-stable and jit-traceable.
 """
 
@@ -30,6 +40,31 @@ import jax.numpy as jnp
 from flax import struct
 
 from ..u128 import mix64
+
+
+def _halves(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(low, high) uint32 halves of a uint64 array."""
+    return x.astype(jnp.uint32), (x >> jnp.uint64(32)).astype(jnp.uint32)
+
+
+def _joined(lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """The uint64 array of two uint32 halves.  ``* 2**32`` and not ``<< 32``:
+    the TPU compiler folds the product into "the pair (lo, hi)" and leaves
+    a shift behind as an elementwise pass."""
+    return lo.astype(jnp.uint64) | hi.astype(jnp.uint64) * jnp.uint64(1 << 32)
+
+
+def _set_at(col: jax.Array, idx: jax.Array, val) -> jax.Array:
+    """``col.at[idx].set(val, mode="drop")``; a uint64 column as two
+    one-operand uint32 scatters, low half and high half (module docstring:
+    the chip's two-operand scatter costs five times the two together)."""
+    if col.dtype != jnp.uint64:
+        return col.at[idx].set(val, mode="drop")
+    lo, hi = _halves(col)
+    val_lo, val_hi = _halves(jnp.asarray(val, jnp.uint64))
+    return _joined(
+        lo.at[idx].set(val_lo, mode="drop"), hi.at[idx].set(val_hi, mode="drop")
+    )
 
 
 @struct.dataclass
@@ -50,6 +85,8 @@ class Table:
 
 def make_table(capacity: int, col_specs: Dict[str, jnp.dtype]) -> Table:
     assert capacity & (capacity - 1) == 0, "capacity must be a power of two"
+    # claim_slots carries slots (and the sentinel, == capacity) as uint32.
+    assert capacity <= 1 << 31, "capacity must fit a uint32 slot"
     return Table(
         key_lo=jnp.zeros((capacity,), jnp.uint64),
         key_hi=jnp.zeros((capacity,), jnp.uint64),
@@ -216,7 +253,7 @@ def claim_slots(
         # (lanes sharing a slot always share a home — see docstring).
         is_winner = rank == next_rank[gid]
         win = unplaced & ~occupied & is_winner
-        claimed = jnp.where(win, cur, claimed)
+        claimed = jnp.where(win, cur.astype(jnp.uint32), claimed)
         # Winners' slots are unique, but two winners may share a WORD:
         # distinct bits make the add an OR with no carries.
         occ = occ.at[jnp.where(win, word, nwords)].add(
@@ -231,7 +268,11 @@ def claim_slots(
 
     offset0 = jnp.zeros((n,), jnp.uint64)
     unplaced0 = insert_mask
-    claimed0 = jnp.full((n,), sentinel, jnp.uint64)
+    # Slots ride the loops as uint32 (a slot is below the capacity, the
+    # sentinel IS the capacity, make_table bounds it by 2^31) and widen at
+    # exit: the narrow loop's scatter of a uint64 ``claimed`` was the
+    # chip's two-operand form (hash_table docstring).
+    claimed0 = jnp.full((n,), capacity, jnp.uint32)
     overflow0 = jnp.bool_(False)
     next_rank0 = jnp.zeros((n,), jnp.int32)
 
@@ -270,7 +311,9 @@ def claim_slots(
         occupied = ((occ[word] >> bit) & jnp.uint32(1)).astype(jnp.bool_)
         is_winner = rank_w == next_rank[gid_w]
         win = unplaced_w & ~occupied & is_winner
-        claimed = claimed.at[jnp.where(win, idx, n)].set(cur, mode="drop")
+        claimed = claimed.at[jnp.where(win, idx, n)].set(
+            cur.astype(jnp.uint32), mode="drop"
+        )
         occ = occ.at[jnp.where(win, word, nwords)].add(
             jnp.uint32(1) << bit, mode="drop"
         )
@@ -287,7 +330,7 @@ def claim_slots(
         (occ, offset[idx_safe], unplaced[idx_safe] & active,
          claimed, overflow, next_rank),
     )
-    return claimed, overflow
+    return claimed.astype(jnp.uint64), overflow
 
 
 def write_rows(
@@ -300,14 +343,15 @@ def write_rows(
 ) -> Table:
     """Write keys + value columns at slots from claim_slots (unique across
     the batch by construction); ``write_mask`` may be narrower than the
-    claim mask (e.g. a commit flag zeroed it)."""
+    claim mask (e.g. a commit flag zeroed it).  uint64 columns are written
+    by halves (``_set_at``: 2 x 0.09-0.10 ms a column, not 1.02, on the chip)."""
     sentinel = jnp.uint64(table.capacity)
     scatter_idx = jnp.where(write_mask & (claimed < sentinel), claimed, sentinel)
-    key_lo_new = table.key_lo.at[scatter_idx].set(key_lo, mode="drop")
-    key_hi_new = table.key_hi.at[scatter_idx].set(key_hi, mode="drop")
-    tomb_new = table.tombstone.at[scatter_idx].set(False, mode="drop")
+    key_lo_new = _set_at(table.key_lo, scatter_idx, key_lo)
+    key_hi_new = _set_at(table.key_hi, scatter_idx, key_hi)
+    tomb_new = _set_at(table.tombstone, scatter_idx, False)
     cols_new = {
-        name: table.cols[name].at[scatter_idx].set(rows[name], mode="drop")
+        name: _set_at(table.cols[name], scatter_idx, rows[name])
         for name in table.cols
     }
     inserted = jnp.sum((scatter_idx < sentinel).astype(jnp.uint64))
@@ -354,12 +398,14 @@ def scatter_cols(
     """Scatter updated value columns back at ``slot`` where ``valid``.
 
     Slots must be unique among valid lanes (callers pre-combine per-slot
-    updates — see the segment reduction in the commit kernel)."""
+    updates — see the segment reduction in the commit kernel).  uint64
+    columns are written by halves (``_set_at``: the chip's two-operand
+    scatter took 1.22 ms over 16384 balance slots)."""
     sentinel = jnp.uint64(table.capacity)
     idx = jnp.where(valid, slot, sentinel)
     cols = dict(table.cols)
     for name, val in updates.items():
-        cols[name] = cols[name].at[idx].set(val, mode="drop")
+        cols[name] = _set_at(cols[name], idx, val)
     return table.replace(cols=cols)
 
 
@@ -389,8 +435,9 @@ def remove_to_tombstone(table: Table, slot: jax.Array, valid: jax.Array) -> Tabl
     idx = jnp.where(valid, slot, sentinel)
     removed = jnp.sum(valid.astype(jnp.uint64))
     return table.replace(
-        key_lo=table.key_lo.at[idx].set(jnp.uint64(0), mode="drop"),
-        key_hi=table.key_hi.at[idx].set(jnp.uint64(0), mode="drop"),
-        tombstone=table.tombstone.at[idx].set(True, mode="drop"),
+        key_lo=_set_at(table.key_lo, idx, jnp.uint64(0)),
+        key_hi=_set_at(table.key_hi, idx, jnp.uint64(0)),
+        tombstone=_set_at(table.tombstone, idx, True),
         count=table.count - removed,
     )
+

@@ -13,8 +13,8 @@ These kernels execute the whole 8190-event batch as data-parallel device code:
   against it with the exists ladder), mirroring in-order execution;
 - linked chains become a segmented first-failure propagation
   (state_machine.zig:1015-1082);
-- balance updates become exact u128 segment-sums via 32-bit limbs (no carries
-  are lost: limb partial sums of <= 8190 u32 terms fit u64), applied with one
+- balance updates become exact u128 segment-sums via 16-bit limbs (no carries
+  are lost: limb partial sums of <= 2*8190 terms stay under 2^30), applied with one
   deterministic scatter per column.
 
 Preconditions (enforced by the host dispatcher in machine.py, which otherwise
@@ -579,12 +579,15 @@ def balance_plan(
     pending: jax.Array,
     sentinel,
 ) -> BalancePlan:
-    """Exact u128 per-account balance deltas via 32-bit limb segment sums.
+    """Exact u128 per-account balance deltas via 16-bit limb segment sums.
 
     Replaces the reference's two sequential balance updates per event
     (state_machine.zig:1330-1338) with sort + segment-sum: limb partial sums of
-    <= 2*8190 u32 terms fit u64 exactly, so no carries are lost."""
+    <= 2*8190 terms below 2^16 stay under 2^30, so a uint32 holds them and
+    no carries are lost.  (uint32 sums, not uint64 sums of 32-bit limbs: a
+    64-bit scatter-add is the chip's two-operand form, hash_table docstring.)"""
     n = ok.shape[0]
+    assert 2 * n <= 1 << 16, "a 16-bit limb's sum must fit uint32"
     sent = jnp.uint64(sentinel)
     ok2 = jnp.concatenate([ok, ok])
     slots2 = jnp.concatenate([dr_slot, cr_slot])
@@ -608,12 +611,10 @@ def balance_plan(
     gid = jnp.cumsum(head.astype(jnp.int32)) - 1
     gid = jnp.where(s_live, gid, 2 * n)  # dead lanes -> dummy segment
 
-    a0 = s_amt & jnp.uint64(0xFFFFFFFF)
-    a1 = s_amt >> jnp.uint64(32)
+    limbs = u128.limbs16(s_amt)
 
-    # ONE fused segment-sum over a (2N, 8) matrix — (field, limb) pairs as
-    # columns — instead of eight independent passes over the leg arrays
-    # (each limb column is a u64 sum of <= 2*8190 u32 terms: exact).
+    # ONE fused segment-sum over a (2N, 16) matrix — (field, limb) pairs as
+    # columns — instead of sixteen independent passes over the leg arrays.
     fields = (
         ("debits_pending", s_is_dr & s_pending),
         ("debits_posted", s_is_dr & ~s_pending),
@@ -623,21 +624,17 @@ def balance_plan(
     cols = []
     for _name, mask in fields:
         m = mask & s_live
-        cols.append(jnp.where(m, a0, 0))
-        cols.append(jnp.where(m, a1, 0))
-    stacked = jnp.stack(cols, axis=1)  # (2N, 8)
+        cols.extend(jnp.where(m, limb, jnp.uint32(0)) for limb in limbs)
+    stacked = jnp.stack(cols, axis=1)  # (2N, 16) uint32
     summed = jax.ops.segment_sum(stacked, gid, num_segments=2 * n + 1)
-    per_leg = summed[gid]  # (2N, 8) gathered back to leg domain
+    per_leg = summed[gid].astype(jnp.uint64)  # gathered back to leg domain
 
     deltas = {}
     for i, (field, _mask) in enumerate(fields):
-        sa0_l = per_leg[:, 2 * i]
-        sa1_l = per_leg[:, 2 * i + 1]
-        low_part = (sa1_l & jnp.uint64(0xFFFFFFFF)) << jnp.uint64(32)
-        d_lo = sa0_l + low_part
-        carry = (d_lo < low_part).astype(jnp.uint64)
-        d_hi = (sa1_l >> jnp.uint64(32)) + carry
-        deltas[field] = (d_lo, d_hi)
+        s0, s1, s2, s3 = (per_leg[:, 4 * i + k] for k in range(4))
+        deltas[field] = u128.from_limbs32(
+            s0 + (s1 << jnp.uint64(16)), s2 + (s3 << jnp.uint64(16))
+        )
     return BalancePlan(s_slot=s_slot, head=head, deltas=deltas)
 
 

@@ -110,7 +110,6 @@ FLAG_GROW_TRANSFERS = 4
 FLAG_GROW_POSTED = 8
 FLAG_COLD = 16  # an id/pending_id may live in the cold spill: host resolves
 
-_U32MASK = jnp.uint64(0xFFFFFFFF)
 _U64MAX = jnp.uint64(0xFFFF_FFFF_FFFF_FFFF)
 
 # Result codes whose value depends on account balances (clamps, overflow
@@ -248,13 +247,6 @@ def _group_winner(idx: IdIndex, ok: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return winner_g, winner_g[idx.group_of_lane]
 
 
-def _limbs_to_u128(lo_limb: jax.Array, hi_limb: jax.Array) -> U128:
-    """Recombine 32-bit limb sums (each < 2**47 for <=32k terms) into u128."""
-    low = lo_limb + ((hi_limb & _U32MASK) << jnp.uint64(32))
-    carry = (low < lo_limb).astype(jnp.uint64)
-    return U128(low, (hi_limb >> jnp.uint64(32)) + carry)
-
-
 class _LegBalances(NamedTuple):
     """Per-leg exact account state around each event (sorted leg domain),
     plus the scatter set (final value of every touched slot)."""
@@ -349,28 +341,18 @@ def _leg_balances(
     # per-row DMAs on TPU); run bases come from a columnwise cummax —
     # exclusive sums at run heads are nondecreasing down the array, so
     # max-carry propagates each run's base with no gather.
-    m16 = jnp.uint64(0xFFFF)
-
-    def parts(d):
-        return [
-            (d & m16).astype(jnp.uint32),
-            ((d >> jnp.uint64(16)) & m16).astype(jnp.uint32),
-            ((d >> jnp.uint64(32)) & m16).astype(jnp.uint32),
-            (d >> jnp.uint64(48)).astype(jnp.uint32),
-        ]
-
     # The pv subtraction streams (void/post releasing a pending) exist only
     # when the batch can carry post/void lanes: a static has_postvoid=False
     # shrinks the stacked scan from 24 to 16 columns (1/3 less cumsum +
     # cummax work on the hot plain/limits shapes).
-    streams = [parts(dp_add[leg_order])]
+    streams = [u128.limbs16(dp_add[leg_order])]
     if has_postvoid:
-        streams.append(parts(dp_sub[leg_order]))
-    streams.append(parts(dpo_add[leg_order]))
-    streams.append(parts(cp_add[leg_order]))
+        streams.append(u128.limbs16(dp_sub[leg_order]))
+    streams.append(u128.limbs16(dpo_add[leg_order]))
+    streams.append(u128.limbs16(cp_add[leg_order]))
     if has_postvoid:
-        streams.append(parts(cp_sub[leg_order]))
-    streams.append(parts(cpo_add[leg_order]))
+        streams.append(u128.limbs16(cp_sub[leg_order]))
+    streams.append(u128.limbs16(cpo_add[leg_order]))
     if has_postvoid:
         col_dp, col_dpo, col_cp, col_cpo = 0, 8, 12, 20
     else:
@@ -401,9 +383,9 @@ def _leg_balances(
         )
 
         def at(limbs):
-            add = _limbs_to_u128(recombine(limbs, col), recombine(limbs, col + 2))
+            add = u128.from_limbs32(recombine(limbs, col), recombine(limbs, col + 2))
             sub = (
-                _limbs_to_u128(recombine(limbs, col + 4), recombine(limbs, col + 6))
+                u128.from_limbs32(recombine(limbs, col + 4), recombine(limbs, col + 6))
                 if has_sub else U128(zeros2n, zeros2n)
             )
             added, ov = u128.add(start, add)

@@ -115,6 +115,27 @@ def select(pred: jnp.ndarray, a: U128, b: U128) -> U128:
     return U128(jnp.where(pred, a.lo, b.lo), jnp.where(pred, a.hi, b.hi))
 
 
+def limbs16(x: jnp.ndarray) -> list:
+    """The four 16-bit limbs of a uint64 array, low first, as uint32: sums
+    of up to 2^15 of them stay below 2^31, so scans and scatter-adds can run
+    in native 32-bit (a TPU emulates 64-bit ones as uint32 pairs)."""
+    m16 = jnp.uint64(0xFFFF)
+    return [
+        (x & m16).astype(jnp.uint32),
+        ((x >> jnp.uint64(16)) & m16).astype(jnp.uint32),
+        ((x >> jnp.uint64(32)) & m16).astype(jnp.uint32),
+        (x >> jnp.uint64(48)).astype(jnp.uint32),
+    ]
+
+
+def from_limbs32(lo_limb: jnp.ndarray, hi_limb: jnp.ndarray) -> U128:
+    """lo_limb + hi_limb * 2^32 as u128, for uint64 sums of 32-bit limbs
+    (each < 2**47 for <= 32k terms)."""
+    low = lo_limb + ((hi_limb & jnp.uint64(0xFFFFFFFF)) << jnp.uint64(32))
+    carry = (low < lo_limb).astype(jnp.uint64)
+    return U128(low, (hi_limb >> jnp.uint64(32)) + carry)
+
+
 def mix64(lo: jnp.ndarray, hi: jnp.ndarray) -> jnp.ndarray:
     """Mix a u128 key's lanes into one well-distributed u64 (for hashing).
 
