@@ -1,6 +1,7 @@
 """Stable device names: the `tb/<phase>` scopes of the programs the accepted
 cells' profiler windows show (the fast kernel, the grouped scan, the general
-kernel, the index).
+kernel, the index) and of the sharded commit programs (`start --shards N`),
+whose names a trace must also tell apart.
 
 An operation's `op_name` in a device trace carries the `jax.named_scope` it
 was traced under, so the scopes must be in each program's lowered text; and
@@ -12,12 +13,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh
 
+from benchmarks.harness import commit_programs
 from test_pipeline import LANES, batch, make_machine, make_model
 from tigerbeetle_tpu import machine, types
 from tigerbeetle_tpu.ops import index
 from tigerbeetle_tpu.ops import state_machine as sm
 from tigerbeetle_tpu.ops import transfer_full as tf
+from tigerbeetle_tpu.parallel import sharded
 from tigerbeetle_tpu.testing import model as M
 
 
@@ -30,6 +34,12 @@ def _soa(lead=()):
     cols = types.to_soa(np.zeros(1, dtype=types.TRANSFER_DTYPE))
     return {k: jax.ShapeDtypeStruct(lead + (LANES,), v.dtype)
             for k, v in cols.items()}
+
+
+def _mesh4():
+    if len(jax.devices()) < 4:
+        pytest.skip(f"needs 4 devices, have {len(jax.devices())}")
+    return Mesh(np.array(jax.devices()[:4]), (sharded.AXIS,))
 
 
 def _lowered(program):
@@ -50,6 +60,14 @@ def _lowered(program):
         return tf.create_transfers_full.lower(
             led, _soa(), u64, u64, max_passes=8, has_postvoid=True,
             has_history=False, use_waves=True)
+    if program.startswith("sharded"):
+        mesh = _mesh4()
+        steps = sharded.machine_steps(mesh, 8)
+        led = jax.eval_shape(lambda: sharded.make_sharded_ledger(
+            mesh, 1 << 10, 1 << 12, 1 << 10))
+        step = steps["fast_probed" if program == "sharded_fast"
+                     else "full_waves"]
+        return step.lower(led, _soa(), u64, u64)
     if program == "index_build":
         return index.build_runs.lower(led, ids, ids, ok)
     assert program == "index_merge"
@@ -65,11 +83,41 @@ def _lowered(program):
                  "tb/full_apply", "tb/full_posted")),
     ("index_build", ("tb/index_probe", "tb/index_sort")),
     ("index_merge", ("tb/index_merge",)),
+    ("sharded_fast", ("tb/shard_gather", "tb/shard_combine", "tb/validate",
+                      "tb/balance", "tb/insert")),
+    ("sharded_general", ("tb/shard_gather", "tb/shard_combine",
+                         "tb/full_waves", "tb/full_pass", "tb/full_apply",
+                         "tb/full_posted")),
 ])
 def test_scopes_are_in_the_lowered_text(program, scopes):
     text = _lowered(program).as_text(debug_info=True)
     for scope in scopes:
         assert scope in text, f"{program}: no {scope} in the lowered text"
+
+
+def test_sharded_programs_have_names_of_their_own():
+    """Every builder's inner function is `step`: a trace would show each as
+    `jit_step`.  The commit twins' names contain the single-device
+    programs' (`benchmarks/harness/commit_programs.py` finds them by it)."""
+    mesh = _mesh4()
+    programs = dict(sharded.machine_steps(mesh, 8))
+    programs.update({f"merkle_{k}": v
+                     for k, v in sharded.merkle_steps(mesh).items()})
+    for table in ("accounts", "transfers"):
+        programs[f"lookup_{table}"] = sharded.sharded_lookup(mesh, table)
+    names = {key: step.__name__ for key, step in programs.items()}
+    assert len(set(names.values())) == len(names), names
+    assert all(n.startswith("sharded_") for n in names.values()), names
+    assert names["fast_probed"] == "sharded_create_transfers_fast_probed"
+    assert names["full"] == "sharded_create_transfers_full"
+    for key in ("fast_probed", "full", "full_waves"):
+        assert commit_programs.commits([f"jit_{names[key]}", 0, 0, 0])
+    assert commit_programs.GENERAL not in names["fast_probed"]
+    assert names["full_waves"] == "sharded_create_transfers_full_waves"
+    assert names["lookup_transfers"] == "sharded_lookup_transfers"
+    text = _lowered("sharded_fast").as_text()
+    assert "jit_sharded_create_transfers_fast_probed" in text
+    assert "jit_step" not in text
 
 
 def test_scoped_programs_answer_as_the_model_does():

@@ -837,10 +837,18 @@ def _cmd_start(args) -> int:
     # server must still land them even without --metrics-json.
     _install_sigterm_atexit()
 
-    if args.overload_control:
-        # One knob for every layer (consensus shed points, both buses):
-        # the env twin is what VsrReplica/ReplicaServer constructors read.
-        os.environ["TB_OVERLOAD"] = "1"
+    def export_options() -> None:
+        """The options whose env twins the constructors read, written once
+        every option has been accepted: a refused `start` leaves the
+        environment as it found it."""
+        if args.overload_control:
+            # One knob for every layer (consensus shed points, both
+            # buses): what VsrReplica/ReplicaServer constructors read.
+            os.environ["TB_OVERLOAD"] = "1"
+        if args.shards is not None:
+            # What the TpuStateMachine constructor reads (the machine is
+            # built inside Replica/VsrReplica).
+            os.environ["TB_SHARDS"] = str(max(0, args.shards))
 
     if args.shards is not None:
         if args.shards < 0 or (
@@ -866,9 +874,6 @@ def _cmd_start(args) -> int:
                   f"devices, {args.backend[2]} visible "
                   f"({args.backend[0]})", file=sys.stderr)
             return 1
-        # The env twin is what the TpuStateMachine constructor reads (the
-        # machine is built inside Replica/VsrReplica).
-        os.environ["TB_SHARDS"] = str(max(0, args.shards))
 
     if args.merkle:
         env_iv = os.environ.get("TB_SCRUB_INTERVAL", "")
@@ -927,6 +932,7 @@ def _cmd_start(args) -> int:
                       "engine failed to build", file=sys.stderr)
                 return 1
 
+        export_options()
         replica = VsrReplica(
             args.path, ledger_config=ledger_config, aof_path=args.aof,
             process_config=process_config, host_engine=bool(args.engine),
@@ -1002,6 +1008,7 @@ def _cmd_start(args) -> int:
         and not args.merkle
         and os.environ.get("TB_MERKLE", "") != "1"
     )
+    export_options()
     replica = Replica(args.path, ledger_config=ledger_config,
                       aof_path=args.aof, hot_transfers_capacity_max=hot_max,
                       process_config=process_config, host_engine=use_engine,

@@ -1784,9 +1784,12 @@ class TpuStateMachine:
         if self._canon is None:
             from .parallel import sharded as shard_mod
 
-            self._canon = shard_mod.unshard_ledger(
-                self._ledger, self._shard_mesh
-            )
+            # The whole rebuild: every shard's arrays to the host, the
+            # host-side re-placement, the upload to device 0.
+            with txtrace.stage("unshard"):
+                self._canon = shard_mod.unshard_ledger(
+                    self._ledger, self._shard_mesh
+                )
             if _obs.enabled:
                 _obs.counter("sharding.unshards").inc()
         return self._canon

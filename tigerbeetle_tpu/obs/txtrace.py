@@ -68,6 +68,7 @@ STAGES = (
     "full_sync",        # ... the general route's blocking device wait
     "index_append",     # ... its secondary-index maintenance
     "merkle_refresh",   # ... touched-path leaf->root update kernels
+    "unshard",          # machine: --shards' canonical copy, rebuilt for a read
     "wal_write",        # replica: journal appends of the group
     "wal_fsync",        # replica: the fsync barrier (io pool thread)
     "pipeline_flush",   # bus: flush because the request queue idled

@@ -34,6 +34,13 @@ Admission: history-flagged accounts stay single-chip (history is an
 append-ordered log, not a hash-partitioned table) — the kernel routes such
 batches instead of applying; cold tiering is likewise a single-chip
 concern (no bloom on the mesh path).
+
+Device names (docs/tracing.md; metadata only): every jitted program here has
+a name of its own (``_named_jit``: ``jit_sharded_create_transfers_fast_probed``
+and so on; the commit twins' names contain their single-device twins'), and
+the phases carry ``tb/shard_gather`` (the masked local probes and gathers),
+``tb/shard_combine`` (the psums) and, after the exchange, the single-device
+kernels' own scope names.
 """
 
 from __future__ import annotations
@@ -64,6 +71,14 @@ from ..ops.state_machine import (
 from ..jaxenv import shard_map  # noqa: F401  (re-export)
 
 AXIS = "shard"
+
+
+def _named_jit(step, name: str, **jit_kwargs):
+    """jit ``step`` as the program ``jit_<name>``: every builder's inner
+    function is called ``step``, and a device trace names a program by its
+    function (docs/tracing.md)."""
+    step.__name__ = step.__qualname__ = name
+    return jax.jit(step, **jit_kwargs)
 
 
 def make_sharded_ledger(
@@ -156,25 +171,29 @@ class _ShardGather:
     """Per-shard masked probe + psum combine for one key set."""
 
     def __init__(self, table: ht.Table, lo, hi, n_shards: int, shift: int):
-        my = jax.lax.axis_index(AXIS).astype(jnp.uint64)
-        h = mix64(lo, hi)
-        self.owner_mask = (h & jnp.uint64(n_shards - 1)) == my
-        look = ht.lookup(table, lo, hi, MAX_PROBE, hash_shift=shift)
-        local_cap = table.capacity
-        self.found_l = look.found & self.owner_mask
-        self.slot_l = look.slot
-        self.overflow_l = look.overflow  # local probe exhaustion (bool)
-        gslot = my * jnp.uint64(local_cap) + look.slot
-        self.found = (
-            jax.lax.psum(self.found_l.astype(jnp.uint32), AXIS) > 0
-        )
-        self.gslot = _psum_one_owner(
-            jnp.where(self.found_l, gslot, jnp.uint64(0))
-        )
+        with jax.named_scope("tb/shard_gather"):
+            my = jax.lax.axis_index(AXIS).astype(jnp.uint64)
+            h = mix64(lo, hi)
+            self.owner_mask = (h & jnp.uint64(n_shards - 1)) == my
+            look = ht.lookup(table, lo, hi, MAX_PROBE, hash_shift=shift)
+            local_cap = table.capacity
+            self.found_l = look.found & self.owner_mask
+            self.slot_l = look.slot
+            self.overflow_l = look.overflow  # local probe exhaustion (bool)
+            gslot = my * jnp.uint64(local_cap) + look.slot
+        with jax.named_scope("tb/shard_combine"):
+            self.found = (
+                jax.lax.psum(self.found_l.astype(jnp.uint32), AXIS) > 0
+            )
+            self.gslot = _psum_one_owner(
+                jnp.where(self.found_l, gslot, jnp.uint64(0))
+            )
 
     def rows(self, table: ht.Table) -> Dict[str, jax.Array]:
-        local = ht.gather_cols(table, self.slot_l, self.found_l)
-        return {k: _psum_one_owner(v) for k, v in local.items()}
+        with jax.named_scope("tb/shard_gather"):
+            local = ht.gather_cols(table, self.slot_l, self.found_l)
+        with jax.named_scope("tb/shard_combine"):
+            return {k: _psum_one_owner(v) for k, v in local.items()}
 
 
 def sharded_create_transfers(mesh: Mesh, probed: bool = False):
@@ -220,33 +239,42 @@ def sharded_create_transfers(mesh: Mesh, probed: bool = False):
             e=ex_g.rows(tr),
         )
 
-        # Replicated validation (identical on every shard).
-        codes, ok, ts, pending = sm.transfer_codes(batch, ctx, count, timestamp)
+        # Replicated validation (identical on every shard).  The phases
+        # carry the single-device kernel's scope names (docs/tracing.md):
+        # the same work, after the exchange.
+        with jax.named_scope("tb/validate"):
+            codes, ok, ts, pending = sm.transfer_codes(
+                batch, ctx, count, timestamp
+            )
 
         # Balance plan over global slots, applied owner-locally.
-        global_cap = local_acc_cap * n_shards
-        plan = sm.balance_plan(
-            ctx.dr_slot, ctx.cr_slot, ok,
-            batch["amount_lo"], pending, global_cap,
-        )
-        my = jax.lax.axis_index(AXIS).astype(jnp.uint64)
-        base = my * jnp.uint64(local_acc_cap)
-        in_range = (plan.s_slot >= base) & (
-            plan.s_slot < base + jnp.uint64(local_acc_cap)
-        )
-        local_plan = sm.BalancePlan(
-            s_slot=jnp.where(in_range, plan.s_slot - base, jnp.uint64(local_acc_cap)),
-            head=plan.head & in_range,
-            deltas=plan.deltas,
-        )
-        accounts = sm.apply_balance_plan(acc, local_plan)
+        with jax.named_scope("tb/balance"):
+            global_cap = local_acc_cap * n_shards
+            plan = sm.balance_plan(
+                ctx.dr_slot, ctx.cr_slot, ok,
+                batch["amount_lo"], pending, global_cap,
+            )
+            my = jax.lax.axis_index(AXIS).astype(jnp.uint64)
+            base = my * jnp.uint64(local_acc_cap)
+            in_range = (plan.s_slot >= base) & (
+                plan.s_slot < base + jnp.uint64(local_acc_cap)
+            )
+            local_plan = sm.BalancePlan(
+                s_slot=jnp.where(
+                    in_range, plan.s_slot - base, jnp.uint64(local_acc_cap)
+                ),
+                head=plan.head & in_range,
+                deltas=plan.deltas,
+            )
+            accounts = sm.apply_balance_plan(acc, local_plan)
 
         # Owner-local transfer inserts.
-        rows = sm.transfer_rows(batch, count, timestamp)
-        transfers, _ = ht.insert(
-            tr, batch["id_lo"], batch["id_hi"],
-            ok & ex_g.owner_mask, rows, MAX_PROBE, hash_shift=shift,
-        )
+        with jax.named_scope("tb/insert"):
+            rows = sm.transfer_rows(batch, count, timestamp)
+            transfers, _ = ht.insert(
+                tr, batch["id_lo"], batch["id_hi"],
+                ok & ex_g.owner_mask, rows, MAX_PROBE, hash_shift=shift,
+            )
 
         out = ledger.replace(accounts=accounts, transfers=transfers)
         if probed:
@@ -272,7 +300,11 @@ def sharded_create_transfers(mesh: Mesh, probed: bool = False):
             check_vma=False,
         )(ledger, batch, count, timestamp)
 
-    return jax.jit(step, donate_argnames=("ledger",))
+    return _named_jit(
+        step,
+        "sharded_create_transfers_fast" + ("_probed" if probed else ""),
+        donate_argnames=("ledger",),
+    )
 
 
 def sharded_create_transfers_full(
@@ -377,6 +409,7 @@ def sharded_create_transfers_full(
         postedT_found = postedT_g.found & p_tab_found
         postedT_val = postedT_g.rows(posted_t)["fulfillment"]
 
+        @jax.named_scope("tb/shard_combine")
         def any_shard(local_bool):
             return jax.lax.psum(local_bool.astype(jnp.uint32), AXIS) > 0
 
@@ -421,20 +454,23 @@ def sharded_create_transfers_full(
         )
 
         # Owner-local claims (insert-probe overflow routes with nothing
-        # applied, exactly like single-chip).
-        t_claim, t_ovf = ht.claim_slots(
-            tr, batch["id_lo"], batch["id_hi"],
-            plan.ok & ex_g.owner_mask, MAX_PROBE, hash_shift=shift,
-        )
+        # applied, exactly like single-chip).  Claims and writes carry the
+        # single-device general kernel's scope names.
+        with jax.named_scope("tb/full_apply"):
+            t_claim, t_ovf = ht.claim_slots(
+                tr, batch["id_lo"], batch["id_hi"],
+                plan.ok & ex_g.owner_mask, MAX_PROBE, hash_shift=shift,
+            )
         my = jax.lax.axis_index(AXIS).astype(jnp.uint64)
-        pk_owner = (
-            mix64(plan.posted_key, jnp.zeros_like(plan.posted_key))
-            & jnp.uint64(n_shards - 1)
-        ) == my
-        p_claim, p_ovf = ht.claim_slots(
-            posted_t, plan.posted_key, jnp.zeros_like(plan.posted_key),
-            plan.pv_ok & pk_owner, MAX_PROBE, hash_shift=shift,
-        )
+        with jax.named_scope("tb/full_posted"):
+            pk_owner = (
+                mix64(plan.posted_key, jnp.zeros_like(plan.posted_key))
+                & jnp.uint64(n_shards - 1)
+            ) == my
+            p_claim, p_ovf = ht.claim_slots(
+                posted_t, plan.posted_key, jnp.zeros_like(plan.posted_key),
+                plan.pv_ok & pk_owner, MAX_PROBE, hash_shift=shift,
+            )
         kflags = (
             probe_grow
             | route
@@ -450,31 +486,34 @@ def sharded_create_transfers_full(
         commit = kflags == jnp.uint32(0)
 
         # Balance scatter: global slot runs, owner-local writes.
-        local_cap = acc.capacity
-        base = my * jnp.uint64(local_cap)
-        in_range = (plan.s_slot >= base) & (
-            plan.s_slot < base + jnp.uint64(local_cap)
-        )
-        scat = plan.scat & commit & in_range
-        sentinel = jnp.uint64(local_cap)
-        accounts = ht.scatter_cols(
-            acc, jnp.where(scat, plan.s_slot - base, sentinel), scat,
-            plan.bal_incl,
-        )
+        with jax.named_scope("tb/full_apply"):
+            local_cap = acc.capacity
+            base = my * jnp.uint64(local_cap)
+            in_range = (plan.s_slot >= base) & (
+                plan.s_slot < base + jnp.uint64(local_cap)
+            )
+            scat = plan.scat & commit & in_range
+            sentinel = jnp.uint64(local_cap)
+            accounts = ht.scatter_cols(
+                acc, jnp.where(scat, plan.s_slot - base, sentinel), scat,
+                plan.bal_incl,
+            )
 
-        ins_rows = {
-            name: plan.row[name].astype(dt)
-            for name, dt in TRANSFER_COLS.items()
-        }
-        transfers = ht.write_rows(
-            tr, batch["id_lo"], batch["id_hi"], t_claim,
-            plan.ok & commit & ex_g.owner_mask, ins_rows,
-        )
-        posted_out = ht.write_rows(
-            posted_t, plan.posted_key, jnp.zeros_like(plan.posted_key),
-            p_claim, plan.pv_ok & commit & pk_owner,
-            {"fulfillment": jnp.where(plan.post, jnp.uint32(1), jnp.uint32(2))},
-        )
+            ins_rows = {
+                name: plan.row[name].astype(dt)
+                for name, dt in TRANSFER_COLS.items()
+            }
+            transfers = ht.write_rows(
+                tr, batch["id_lo"], batch["id_hi"], t_claim,
+                plan.ok & commit & ex_g.owner_mask, ins_rows,
+            )
+        with jax.named_scope("tb/full_posted"):
+            posted_out = ht.write_rows(
+                posted_t, plan.posted_key, jnp.zeros_like(plan.posted_key),
+                p_claim, plan.pv_ok & commit & pk_owner,
+                {"fulfillment": jnp.where(
+                    plan.post, jnp.uint32(1), jnp.uint32(2))},
+            )
 
         out = ledger.replace(
             accounts=accounts, transfers=transfers, posted=posted_out
@@ -499,7 +538,11 @@ def sharded_create_transfers_full(
             check_vma=False,  # see sharded_create_transfers' justification
         )(ledger, batch, count, timestamp)
 
-    return jax.jit(step, donate_argnames=("ledger",))
+    return _named_jit(
+        step,
+        "sharded_create_transfers_full" + ("_waves" if use_waves else ""),
+        donate_argnames=("ledger",),
+    )
 
 
 def sharded_lookup(mesh: Mesh, table_name: str):
@@ -530,7 +573,7 @@ def sharded_lookup(mesh: Mesh, table_name: str):
             check_vma=False,  # see sharded_create_transfers' justification
         )(ledger, id_lo, id_hi)
 
-    return jax.jit(step)
+    return _named_jit(step, f"sharded_lookup_{table_name}")
 
 
 def sharded_create_accounts(mesh: Mesh):
@@ -543,14 +586,17 @@ def sharded_create_accounts(mesh: Mesh):
         g = _ShardGather(acc, batch["id_lo"], batch["id_hi"], n_shards, shift)
         lane = jnp.arange(batch["id_lo"].shape[0], dtype=jnp.int32)
         valid = lane < count.astype(jnp.int32)
-        codes, ok = sm.account_codes(
-            batch, g.found & valid, g.rows(acc), count
-        )
-        rows = sm.account_rows(batch, count, timestamp)
-        accounts, _ = ht.insert(
-            acc, batch["id_lo"], batch["id_hi"],
-            ok & g.owner_mask, rows, MAX_PROBE, hash_shift=shift,
-        )
+        existing = g.rows(acc)
+        with jax.named_scope("tb/validate"):
+            codes, ok = sm.account_codes(
+                batch, g.found & valid, existing, count
+            )
+        with jax.named_scope("tb/insert"):
+            rows = sm.account_rows(batch, count, timestamp)
+            accounts, _ = ht.insert(
+                acc, batch["id_lo"], batch["id_hi"],
+                ok & g.owner_mask, rows, MAX_PROBE, hash_shift=shift,
+            )
         return ledger.replace(accounts=accounts), codes
 
     def step(ledger, batch, count, timestamp):
@@ -567,7 +613,9 @@ def sharded_create_accounts(mesh: Mesh):
             check_vma=False,
         )(ledger, batch, count, timestamp)
 
-    return jax.jit(step, donate_argnames=("ledger",))
+    return _named_jit(
+        step, "sharded_create_accounts", donate_argnames=("ledger",)
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -604,7 +652,7 @@ def sharded_scrub_digest(mesh: Mesh):
         )(ledger)
 
     # Deliberately NOT donated: the scrub must never consume the ledger.
-    return jax.jit(step)
+    return _named_jit(step, "sharded_scrub_digest")
 
 
 # ---------------------------------------------------------------------------
@@ -744,7 +792,7 @@ def merkle_steps(mesh: Mesh) -> Dict[str, object]:
 
     forest_specs = jax.tree_util.tree_map(lambda _: P(AXIS), mk.Forest(0, 0, 0))
 
-    def wrap_update(fn):
+    def wrap_update(fn, name):
         def step(forest, ledger, *keys):
             return shard_map(
                 fn,
@@ -755,16 +803,24 @@ def merkle_steps(mesh: Mesh) -> Dict[str, object]:
                 check_vma=False,  # see sharded_create_transfers
             )(forest, ledger, *keys)
 
-        return jax.jit(step, donate_argnames=("forest",))
+        return _named_jit(
+            step, f"sharded_merkle_{name}", donate_argnames=("forest",)
+        )
 
     steps = {
         # build/verify/roots deliberately NOT donated (reads).
-        "build": jax.jit(build),
-        "verify": jax.jit(verify),
-        "roots": jax.jit(roots),
-        "update_accounts": wrap_update(upd_accounts_local),
-        "update_transfers": wrap_update(upd_transfers_local(False)),
-        "update_transfers_pv": wrap_update(upd_transfers_local(True)),
+        "build": _named_jit(build, "sharded_merkle_build"),
+        "verify": _named_jit(verify, "sharded_merkle_verify"),
+        "roots": _named_jit(roots, "sharded_merkle_roots"),
+        "update_accounts": wrap_update(
+            upd_accounts_local, "update_accounts"
+        ),
+        "update_transfers": wrap_update(
+            upd_transfers_local(False), "update_transfers"
+        ),
+        "update_transfers_pv": wrap_update(
+            upd_transfers_local(True), "update_transfers_pv"
+        ),
     }
     _STEP_CACHE[key] = steps
     return steps
