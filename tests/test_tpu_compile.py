@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from tigerbeetle_tpu import machine, types
+from tigerbeetle_tpu.ops import index
 from tigerbeetle_tpu.ops import state_machine as sm
 from tigerbeetle_tpu.ops import transfer_full as tf
 from tigerbeetle_tpu.parallel import sharded
@@ -102,9 +103,20 @@ def _one_chip_lowerings(topo):
             has_postvoid=has_postvoid, has_history=False, use_waves=True,
         )
 
+    lanes = jax.ShapeDtypeStruct((LANES,), jnp.uint64, sharding=one)
+    ok = jax.ShapeDtypeStruct((LANES,), jnp.bool_, sharding=one)
     return {
         "fast": lambda: sm.create_transfers_fast.jitted.lower(
             led, batch, u64, u64),
+        "index_build": lambda: index.build_runs.lower(
+            {name: lanes for name in sm.INDEX_KEY_COLS}, lanes, lanes, ok),
+        "index_build_row": lambda: index.build_runs.lower(
+            *jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    (k,) + x.shape, x.dtype, sharding=one),
+                ({name: lanes for name in sm.INDEX_KEY_COLS}, lanes, lanes,
+                 ok)),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one)),
         "grouped": lambda: machine._group_fast_dispatch.lower(
             led, _soa(types.TRANSFER_DTYPE, one, lead=(k,)), kvec, kvec),
         "grouped_padded_scan": lambda: jax.jit(
@@ -140,7 +152,8 @@ def _sharded_lowering(topo, make_step):
 
 @pytest.mark.parametrize("program", [
     "fast", "grouped", "full_scan_plain", "full_scan_postvoid",
-    "sharded_fast_4", "sharded_full_scan_4",
+    "sharded_fast_4", "sharded_full_scan_4", "index_build",
+    "index_build_row",
 ])
 def test_compiles_for_v5e(topo, no_persistent_cache, program):
     if program == "sharded_fast_4":
@@ -163,6 +176,9 @@ def test_compiles_for_v5e(topo, no_persistent_cache, program):
         # The cross-shard context exchange is a psum: the compiler must
         # have put an all-reduce in.
         assert "all-reduce" in compiled.as_text()
+    if program.startswith("index_build"):
+        # A level-0 run is sorted from keys it is handed: no probe loop.
+        assert " while(" not in compiled.as_text()
 
 
 def test_grouped_loop_needs_no_more_temp_than_the_padded_scan(

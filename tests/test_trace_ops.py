@@ -7,6 +7,7 @@ to fail here."""
 import gzip
 
 import importlib.util
+import json
 import os
 
 import pytest
@@ -21,6 +22,50 @@ def trace_ops():
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def _tool(name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, "tools", name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_the_timeline_bills_each_gap_to_the_commit_program_before_it(
+        tmp_path, capsys):
+    """tools/trace_timeline.py: what the device ran behind each commit
+    program, and the idle in short gaps (the device waiting for the host's
+    next enqueue) apart from the long ones (no closure enqueued)."""
+    ms = 1e6
+    executions = [                                   # name, start, dur, trips
+        ["jit_convert_element_type", 0, 0.002 * ms, 0],   # before any commit
+        ["jit_create_transfers_fast_probed_impl", 1 * ms, 30 * ms, 21],
+        ["jit_build_runs", 31.2 * ms, 2 * ms, 0],         # 0.2 ms: short
+        ["jit__group_fast_dispatch_impl", 50 * ms, 100 * ms, 6],  # 16.8: long
+        ["jit_dynamic_slice", 150.2 * ms, 0.002 * ms, 0],
+        ["jit_build_runs", 150.5 * ms, 2 * ms, 0],
+        ["jit__merge", 154.5 * ms, 4 * ms, 0],            # 2.0 ms: long
+    ]
+    timeline = _tool("trace_timeline")
+    rows = timeline.timeline(list(reversed(executions)))  # any order in
+    assert [(r["program"], r["trips"], r["behind"]) for r in rows] == [
+        ("jit_create_transfers_fast_probed_impl", 21, 1),
+        ("jit__group_fast_dispatch_impl", 6, 3)]
+    lone, loop = rows
+    assert lone["ms"] == 30 and lone["behind_ms"] == 2
+    assert lone["short_gaps_ms"] == pytest.approx(0.2)
+    assert lone["long_gaps_ms"] == pytest.approx(16.8)
+    assert loop["behind_ms"] == pytest.approx(6.002)
+    assert loop["short_gaps_ms"] == pytest.approx(0.2 + 0.298)
+    assert loop["long_gaps_ms"] == pytest.approx(2.0)
+
+    path = tmp_path / "run.json"
+    path.write_text(json.dumps({"trace": {"executions": executions}}))
+    assert timeline.main(["trace_timeline.py", str(path)]) == 0
+    last = capsys.readouterr().out.splitlines()[-1]
+    assert last.startswith("2 commit programs, 7 requests, 4 programs behind")
+    assert timeline.main(["trace_timeline.py"]) == 2
 
 
 def test_a_while_does_not_count_its_body(trace_ops):

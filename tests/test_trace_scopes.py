@@ -9,6 +9,8 @@ they are metadata only, so the programs still answer as `testing/model.py`
 does.  The lookups carry none yet: their scopes come with the cell whose
 window reaches them."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,8 +18,11 @@ import pytest
 from jax.sharding import Mesh
 
 from benchmarks.harness import commit_programs
-from test_pipeline import LANES, batch, make_machine, make_model
+from test_pipeline import (
+    CFG, LANES, accounts_batch, batch, make_machine, make_model,
+)
 from tigerbeetle_tpu import machine, types
+from tigerbeetle_tpu.obs.metrics import registry
 from tigerbeetle_tpu.ops import index
 from tigerbeetle_tpu.ops import state_machine as sm
 from tigerbeetle_tpu.ops import transfer_full as tf
@@ -69,7 +74,16 @@ def _lowered(program):
                      else "full_waves"]
         return step.lower(led, _soa(), u64, u64)
     if program == "index_build":
-        return index.build_runs.lower(led, ids, ids, ok)
+        keys = {name: ids for name in sm.INDEX_KEY_COLS}
+        return index.build_runs.lower(keys, ids, ids, ok)
+    if program == "index_build_row":  # a row of a grouped dispatch's outputs
+        ids, ok = (jax.ShapeDtypeStruct((k,) + x.shape, x.dtype)
+                   for x in (ids, ok))
+        keys = {name: ids for name in sm.INDEX_KEY_COLS}
+        return index.build_runs.lower(
+            keys, ids, ids, ok, jax.ShapeDtypeStruct((), jnp.int32))
+    if program == "index_probe":
+        return index.probe_keys.lower(led, ids, ids, ok)
     assert program == "index_merge"
     level = _shapes(index._sentinel_level(LANES))
     return index._merge_jit.lower([level, level])
@@ -81,7 +95,9 @@ def _lowered(program):
                  "tb/insert")),
     ("general", ("tb/full_gather", "tb/full_waves", "tb/full_pass",
                  "tb/full_apply", "tb/full_posted")),
-    ("index_build", ("tb/index_probe", "tb/index_sort")),
+    ("index_build", ("tb/index_sort",)),
+    ("index_build_row", ("tb/index_sort",)),
+    ("index_probe", ("tb/index_probe",)),
     ("index_merge", ("tb/index_merge",)),
     ("sharded_fast", ("tb/shard_gather", "tb/shard_combine", "tb/validate",
                       "tb/balance", "tb/insert")),
@@ -93,6 +109,20 @@ def test_scopes_are_in_the_lowered_text(program, scopes):
     text = _lowered(program).as_text(debug_info=True)
     for scope in scopes:
         assert scope in text, f"{program}: no {scope} in the lowered text"
+
+
+def test_build_runs_reads_no_table():
+    """A level-0 run is sorted from the keys the commit program returned:
+    `build_runs` holds no probe loop and no operand of a table's size (the
+    helper, for the routes without kernel keys, holds both)."""
+    table = f"tensor<{1 << 12}x"  # _lowered's transfers table
+    for program in ("index_build", "index_build_row"):
+        text = _lowered(program).as_text(debug_info=True)
+        assert "tb/index_probe" not in text
+        assert "stablehlo.while" not in text and table not in text
+        assert "stablehlo.gather" in text  # the sort's permutations, of LANES
+    text = _lowered("index_probe").as_text()
+    assert "stablehlo.while" in text and table in text
 
 
 def test_sharded_programs_have_names_of_their_own():
@@ -165,3 +195,50 @@ def test_scoped_programs_answer_as_the_model_does():
     f["account_id_lo"], f["limit"], f["flags"] = 1, 100, 3
     assert [int(r["id_lo"]) for r in m.get_account_transfers(f)] == [
         t.id for t in ref.get_account_transfers(1, 0, 0, 100, 3)]
+
+
+@pytest.mark.parametrize("mode", ["lazy_index", "shards4"])
+def test_a_lazy_index_appends_no_run_and_rebuilds_for_a_query(mode):
+    """Under `lazy_index`, and under `--shards` (whose ledger no
+    single-device program could read), every commit route resets the index
+    and appends nothing, keyed or probed; a query rebuilds it whole."""
+    if mode == "shards4":
+        _mesh4()
+        m = make_machine(shards=4)
+    else:
+        m = machine.TpuStateMachine(
+            dataclasses.replace(CFG, lazy_index=True), batch_lanes=LANES)
+        assert m.create_accounts(accounts_batch(), wall_clock_ns=1000) == []
+    m.group_device_commit = True
+    ref = make_model()
+
+    def model(b):
+        return ref.create_transfers([M.transfer_from_row(r) for r in b])
+
+    with registry.enabled_scope():
+        lone = batch(1000, 20)
+        (got,) = m.commit_fast_deferred(
+            lone, m.prepare("create_transfers", len(lone), 0)).resolve()
+        assert got == model(lone)
+        run = [batch(2000, 9), batch(3000, 12)]
+        tss = [m.prepare("create_transfers", len(b), 0) for b in run]
+        assert m.commit_group_fast(run, tss) == [model(b) for b in run]
+        pending = batch(4000, 10, flags=int(types.TransferFlags.PENDING))
+        assert m.create_transfers(pending) == model(pending)
+        post = types.transfers_array([
+            types.transfer(
+                id=5000 + i, pending_id=4000 + i, ledger=1, code=10,
+                flags=int(types.TransferFlags.POST_PENDING_TRANSFER))
+            for i in range(6)
+        ])
+        assert m.create_transfers(post) == model(post)  # the general kernel
+        assert registry.counter("ops.route.general").value == 1
+        assert registry.counter("index.runs.keyed").value == 0
+        assert registry.counter("index.runs.probed").value == 0
+    assert m.index.stale and not m.index.occupied
+    f = np.zeros(1, dtype=types.ACCOUNT_FILTER_DTYPE)[0]
+    f["account_id_lo"], f["limit"], f["flags"] = 1, 100, 3
+    want = [t.id for t in ref.get_account_transfers(1, 0, 0, 100, 3)]
+    assert len(want) > 4
+    assert [int(r["id_lo"]) for r in m.get_account_transfers(f)] == want
+    assert not m.index.stale and any(m.index.occupied)

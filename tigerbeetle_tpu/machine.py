@@ -45,6 +45,7 @@ from . import types
 from .config import LedgerConfig
 from .obs.metrics import registry as _obs
 from .obs.txtrace import txtrace
+from .ops import index as index_ops
 from .ops import merkle as merkle_ops
 from .ops import scrub as scrub_ops
 from .ops import state_machine as sm
@@ -94,14 +95,17 @@ def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
     Besides (ledger, codes) it returns the transfers probe_overflow flag
     widened into a FRESH uint32 buffer (the deferred readback handle must
     be able to fetch it after a later dispatch donates the ledger; riding
-    the commit dispatch it costs zero extra syncs) and the stacked id
-    columns, so the dispatch closure's index maintenance never holds the
-    whole 17-column stacked SoA alive past the kernel call.  ``stacked``
-    itself is deliberately NOT donated: on XLA-CPU jax.device_put may
-    alias the numpy staging buffers straight into these device arrays
-    (the _stage_group zero-copy note), and a donated alias would let XLA
-    scribble scratch into the pooled staging set behind the dirty-row
-    tracking's back."""
+    the commit dispatch it costs zero extra syncs) and what the dispatch
+    closure's index maintenance needs, so it never holds the whole
+    17-column stacked SoA alive past the kernel call and never reads the
+    table back: the stacked id columns and, per batch, ``sm.index_keys``
+    (the stacked account columns and the timestamps each trip stored) and
+    ``sm.written_lanes`` (all False for the rows past the group).
+    ``stacked`` itself is deliberately NOT donated: on XLA-CPU
+    jax.device_put may alias the numpy staging buffers straight into these
+    device arrays (the _stage_group zero-copy note), and a donated alias
+    would let XLA scribble scratch into the pooled staging set behind the
+    dirty-row tracking's back."""
     steps_max = counts.shape[0]
 
     def row(i):
@@ -133,6 +137,8 @@ def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
     return (
         ledger, codes, ledger.transfers.probe_overflow.astype(jnp.uint32),
         stacked["id_lo"], stacked["id_hi"],
+        jax.vmap(sm.index_keys)(stacked, counts, timestamps),
+        jax.vmap(sm.written_lanes)(codes, counts),
     )
 
 
@@ -479,9 +485,7 @@ class TpuStateMachine:
         self._balance_bound = 0
         # Secondary index for get_account_transfers (ops/index.py): derived
         # state, rebuilt from the table after restore/state-sync.
-        from .ops.index import TransferIndex
-
-        self.index = TransferIndex(base=batch_lanes)
+        self.index = index_ops.TransferIndex(base=batch_lanes)
         # Every index rebuild (incl. the stale fallback inside query) must
         # also cover the cold-tier runs, or restarts drop evicted
         # transfers from query results.
@@ -2013,7 +2017,7 @@ class TpuStateMachine:
                 # It donates its batch, so the cached zero-count template
                 # gets a throwaway copy here.
                 soa_probe = {k: v.copy() for k, v in soa_t.items()}
-                self.ledger, codes_p, _ovf, _il, _ih = (
+                self.ledger, codes_p, *_ = (
                     sm.create_transfers_fast_probed(
                         self.ledger, soa_probe, jnp.uint64(0), jnp.uint64(1)
                     )
@@ -2029,7 +2033,7 @@ class TpuStateMachine:
                     for key, v in soa_t.items()
                 }
                 zeros = jnp.zeros((self.GROUP_K,), jnp.uint64)
-                self.ledger, codes_g, _govf, _gil, _gih = (
+                self.ledger, codes_g, *_ = (
                     _group_fast_dispatch(self.ledger, stacked, zeros,
                                          zeros + 1)
                 )
@@ -2272,7 +2276,7 @@ class TpuStateMachine:
             if kflags == 0:
                 results = self._full_commit_success(
                     soa, codes, count, pv_count, hist_count, timestamp,
-                    wave_host,
+                    wave_host, keys=r[-1],
                 )
                 # Deferred tier rebalance: eviction is only safe BETWEEN
                 # batches (mid-loop it would invalidate the certification
@@ -2329,11 +2333,14 @@ class TpuStateMachine:
         return kflags, wave_host
 
     def _full_commit_success(self, soa, codes, count, pv_count, hist_count,
-                             timestamp, wave_host):
+                             timestamp, wave_host, keys=None):
         """Post-commit bookkeeping of a COMMITTED general-kernel batch
         (kflags == 0), shared by both dispatch loops.  Only committed
         batches feed the wave occupancy series — a routed or retried
-        attempt applied nothing and would overstate them."""
+        attempt applied nothing and would overstate them — and only a
+        committed attempt's ``keys`` (the index key columns its kernel
+        wrote; None from the sharded loop, whose index is lazy) reach the
+        index."""
         if wave_host is not None:
             self._record_wave_metrics(wave_host)
         if _obs.enabled:
@@ -2345,7 +2352,7 @@ class TpuStateMachine:
         self._posted_bound += pv_count
         self._history_bound += hist_count
         with txtrace.stage("index_append"):
-            self._index_append(soa, codes, count)
+            self._index_append(soa, codes, count, keys)
         results = self._compress(codes, count)
         self._update_commit_timestamp(codes, count, timestamp)
         return results
@@ -3155,9 +3162,11 @@ class TpuStateMachine:
         merkle_closure = self._merkle_forest is not None and not self.merkle_async
 
         def dispatch():
-            # Growth + dispatch + index maintenance stay ONE unit so the
-            # FIFO lane preserves the ledger chain (the appends need THIS
-            # ledger live).
+            # Growth + dispatch + index maintenance stay ONE unit on the
+            # FIFO lane: it preserves the ledger chain and the order of the
+            # index's runs.  The appends read the keys the program returns,
+            # not the ledger (a FieldIndex of ops/scan_builder.py, where a
+            # query has made one, still probes THIS ledger).
             with txtrace.stage("grow", seq=seq):
                 self._grow_if_needed(transfers_need=need)
             # The ONE-worker FIFO lane orders every ledger write, and the
@@ -3165,14 +3174,12 @@ class TpuStateMachine:
             # (or lane.shutdown(wait=True) in reset paths).
             with txtrace.stage("dispatch", seq=seq, n=k):
                 (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
-                 id_lo, id_hi) = _group_fast_dispatch(
+                 id_lo, id_hi, keys, ok) = _group_fast_dispatch(
                     self.ledger, stacked, cnt, tss
                 )
             with txtrace.stage("index_append", seq=seq, n=k):
                 for j in range(k):
-                    self._index_append_device(
-                        id_lo[j], id_hi[j], codes[j], counts[j],
-                    )
+                    self._index_append_device(keys, id_lo, id_hi, ok, row=j)
             if merkle_closure:
                 # Commitment updates ride the ledger chain on the lane,
                 # PER BATCH: one key-size class per workload shape, so
@@ -3261,9 +3268,7 @@ class TpuStateMachine:
                         self.ledger, soas[j], cnts[j], tss[j]
                     )
                 with txtrace.stage("index_append", seq=seq):
-                    self._index_append_device(
-                        soas[j]["id_lo"], soas[j]["id_hi"], codes, counts[j]
-                    )
+                    self._index_lazy_reset()
                 if merkle_closure:
                     self._merkle_update_transfers_batches([batches[j]])
                 codes_out.append(codes)
@@ -3386,9 +3391,7 @@ class TpuStateMachine:
                         self.ledger, soa, cnt, ts
                     )
                 with txtrace.stage("index_append", seq=seq):
-                    self._index_append_device(
-                        soa["id_lo"], soa["id_hi"], codes, count
-                    )
+                    self._index_lazy_reset()
                 if merkle_closure:
                     self._merkle_update_transfers_batches([batch])
                 if _obs.enabled:
@@ -3401,15 +3404,15 @@ class TpuStateMachine:
                 # The probed kernel donates BOTH the ledger and the staged
                 # SoA (the pad columns become scratch instead of pinned
                 # inputs); index maintenance uses the passed-through id
-                # columns — the donated ``soa`` dict must not be touched
-                # after this call.
+                # and key columns — the donated ``soa`` dict must not be
+                # touched after this call.
                 with txtrace.stage("dispatch", seq=seq):
                     (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
-                     id_lo, id_hi) = sm.create_transfers_fast_probed(
+                     id_lo, id_hi, keys, ok) = sm.create_transfers_fast_probed(
                         self.ledger, soa, cnt, ts
                     )
                 with txtrace.stage("index_append", seq=seq):
-                    self._index_append_device(id_lo, id_hi, codes, count)
+                    self._index_append_device(keys, id_lo, id_hi, ok)
                 if merkle_closure:
                     # Commitment update rides the ledger chain; keys come
                     # from the retained HOST batch (the staged SoA was
@@ -3828,18 +3831,26 @@ class TpuStateMachine:
         self._update_commit_timestamp(codes, count, timestamp)
         return results
 
-    def _index_append_device(self, id_lo, id_hi, codes_dev, count) -> None:
-        """_index_append with a device-resident ok mask: runs INSIDE a
-        dispatch-lane closure, right after its kernel, where self.ledger is
-        guaranteed live (a deferred handle's resolve may run while a later
-        dispatch has already donated this ledger's buffers)."""
-        if self.config.lazy_index or self._shard_mesh is not None:
-            if not self.index.stale:
-                self.index.reset()
-            self.scans_transfers.reset()
+    def _index_lazy_reset(self) -> bool:
+        """Bulk-ingest mode (and sharded mode, whose ledger no single-device
+        FieldIndex probe could read): invalidate the index instead of
+        maintaining it; the next query rebuilds from the canonical table
+        (+cold runs) in one shot.  True if this machine runs that way."""
+        if not (self.config.lazy_index or self._shard_mesh is not None):
+            return False
+        if not self.index.stale:
+            self.index.reset()
+        self.scans_transfers.reset()
+        return True
+
+    def _index_append_device(self, keys, id_lo, id_hi, ok, row=None) -> None:
+        """The index append of a dispatch-lane closure, right after its
+        kernel, from what that kernel returned: the index key columns, the
+        id columns and the lanes it wrote (a grouped dispatch: all of them
+        stacked, and ``row`` the batch).  No mask and no slice is taken
+        here: on a TPU each would be a dispatch the device waits for."""
+        if self._index_lazy_reset():
             return
-        lane = jnp.arange(self.batch_lanes, dtype=jnp.uint64)
-        ok_dev = (codes_dev == 0) & (lane < jnp.uint64(count))
         watching = self._sanitize and self._sanitize_compile_base is not None
 
         def _index_events():
@@ -3849,33 +3860,44 @@ class TpuStateMachine:
             )
 
         ev0 = _index_events() if watching else 0
-        self.index.append_batch(self.ledger, id_lo, id_hi, ok_dev)
+        if _obs.enabled:
+            _obs.counter("index.runs.keyed").inc()
+        self.index.append_batch(keys, id_lo, id_hi, ok, row)
         if self.scans_transfers.indexes:
-            self.scans_transfers.append_batch(
-                self.ledger, id_lo, id_hi, ok_dev
-            )
+            # self.ledger is live here: a deferred handle's resolve may run
+            # after a later dispatch has donated this ledger's buffers.
+            if row is not None:
+                id_lo, id_hi, ok = id_lo[row], id_hi[row], ok[row]
+            self.scans_transfers.append_batch(self.ledger, id_lo, id_hi, ok)
         if watching and _index_events() != ev0:
             # A Bentley–Saxe carry reached a NEW power-of-two level: its
             # first merge/fill legitimately jit-compiles (bounded:
             # log(rows) levels ever).  Same grace as a table growth.
             self._sanitize_grace = True
 
-    def _index_append(self, soa: dict, codes: np.ndarray, count: int) -> None:
-        if self.config.lazy_index or self._shard_mesh is not None:
-            # Bulk-ingest mode (and sharded mode, whose per-batch appends
-            # would otherwise probe the sharded layout with single-device
-            # kernels): invalidate instead of maintaining; the next query
-            # rebuilds from the canonical table (+cold runs) in one shot.
-            if not self.index.stale:
-                self.index.reset()
-            self.scans_transfers.reset()
+    def _index_append(
+        self, soa: dict, codes: np.ndarray, count: int, keys=None
+    ) -> None:
+        """The index append of a blocking route, from host codes.  ``keys``
+        are the index key columns the route's kernel returned; a route whose
+        kernel returns none (the sequential path, the unprobed fast kernel)
+        reads them back from the transfers table by id."""
+        if self._index_lazy_reset():
             return
         ok = np.zeros(self.batch_lanes, dtype=bool)
         ok[:count] = codes[:count] == 0
         ok_dev = jnp.asarray(ok)
-        self.index.append_batch(
-            self.ledger, soa["id_lo"], soa["id_hi"], ok_dev
-        )
+        probed = keys is None
+        if _obs.enabled:
+            _obs.counter(
+                "index.runs.probed" if probed else "index.runs.keyed"
+            ).inc()
+        written = ok_dev
+        if probed:
+            keys, written = index_ops.probe_keys(
+                self.ledger, soa["id_lo"], soa["id_hi"], ok_dev
+            )
+        self.index.append_batch(keys, soa["id_lo"], soa["id_hi"], written)
         if self.scans_transfers.indexes:
             self.scans_transfers.append_batch(
                 self.ledger, soa["id_lo"], soa["id_hi"], ok_dev

@@ -21,6 +21,17 @@ one batched id lookup.  Sentinel entries (account id 2^128-1, an id that can
 never exist: id_must_not_be_int_max) pad partial runs and sort after every
 real entry.
 
+Where a run's keys come from: a level-0 run is sorted from the key columns
+(sm.INDEX_KEY_COLS: both sides' account ids and the stored timestamp) of the
+rows a commit program just wrote, which the program returns beside its codes
+(sm.create_transfers_fast_probed, machine._group_fast_dispatch,
+tf.create_transfers_full), so an append reads no table; the fast programs
+return the written-lanes mask too, and build_runs picks a grouped dispatch's
+row itself, so such an append is one dispatch.  A route whose kernel
+returns none (the sequential path, the unprobed create_transfers_fast) reads
+them back by id through probe_keys, the one place this module walks the
+transfers table per batch.
+
 The index is DERIVED state: it is not checkpointed; restarts and state sync
 rebuild it from the transfers table in one shot (rebuild()).
 """
@@ -59,23 +70,44 @@ def _sort_level(lvl: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
 
 
 @jax.jit
-def build_runs(
+def probe_keys(
     ledger: sm.Ledger, id_lo: jax.Array, id_hi: jax.Array, ok: jax.Array
-) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
-    """Sorted level-0 runs (debit side, credit side) for a just-committed
-    batch: gather the stored rows by id and key them by each side's account."""
+) -> Tuple[Dict[str, jax.Array], jax.Array]:
+    """(keys, use) for build_runs, read back from the transfers table by id:
+    for a route whose commit kernel does not return the keys it stored."""
     with jax.named_scope("tb/index_probe"):
         look = ht.lookup(ledger.transfers, id_lo, id_hi, sm.MAX_PROBE)
         use = ok & look.found
         rows = ht.gather_cols(ledger.transfers, look.slot, use)
+    return {name: rows[name] for name in sm.INDEX_KEY_COLS}, use
+
+
+@jax.jit
+def build_runs(
+    keys: Dict[str, jax.Array], id_lo: jax.Array, id_hi: jax.Array,
+    ok: jax.Array, row=None,
+) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
+    """Sorted level-0 runs (debit side, credit side) for a just-committed
+    batch: ``keys`` are the sm.INDEX_KEY_COLS of the rows the commit wrote
+    (from its kernel, or from probe_keys), ``ok`` the lanes it wrote; each
+    side is keyed by its account and sorted.  With ``row`` the arguments are
+    a grouped dispatch's stacked outputs and the batch is that row of each:
+    selected here, inside the one program, because on a TPU every slice the
+    host takes is a dispatch of its own that the device waits for."""
+    if row is not None:
+        keys, id_lo, id_hi, ok = jax.tree_util.tree_map(
+            lambda col: jax.lax.dynamic_index_in_dim(
+                col, row, keepdims=False),
+            (keys, id_lo, id_hi, ok),
+        )
 
     def side(acct_field):
         lvl = {
-            "acct_lo": jnp.where(use, rows[acct_field + "_lo"], jnp.uint64(U64M)),
-            "acct_hi": jnp.where(use, rows[acct_field + "_hi"], jnp.uint64(U64M)),
-            "ts": jnp.where(use, rows["timestamp"], jnp.uint64(U64M)),
-            "tid_lo": jnp.where(use, id_lo, jnp.uint64(U64M)),
-            "tid_hi": jnp.where(use, id_hi, jnp.uint64(U64M)),
+            "acct_lo": jnp.where(ok, keys[acct_field + "_lo"], jnp.uint64(U64M)),
+            "acct_hi": jnp.where(ok, keys[acct_field + "_hi"], jnp.uint64(U64M)),
+            "ts": jnp.where(ok, keys["timestamp"], jnp.uint64(U64M)),
+            "tid_lo": jnp.where(ok, id_lo, jnp.uint64(U64M)),
+            "tid_hi": jnp.where(ok, id_hi, jnp.uint64(U64M)),
         }
         with jax.named_scope("tb/index_sort"):
             return _sort_level(lvl)
@@ -255,12 +287,12 @@ class TransferIndex:
             self.shape_class_events += 1  # new size class: first-use jits
 
     def append_batch(
-        self, ledger: sm.Ledger, id_lo: jax.Array, id_hi: jax.Array,
-        ok: jax.Array,
+        self, keys: Dict[str, jax.Array], id_lo: jax.Array, id_hi: jax.Array,
+        ok: jax.Array, row=None,
     ) -> None:
         if self.stale:
             return  # rebuilt wholesale on next query
-        dr_run, cr_run = build_runs(ledger, id_lo, id_hi, ok)
+        dr_run, cr_run = build_runs(keys, id_lo, id_hi, ok, row)
         k = 0
         while k < len(self.occupied) and self.occupied[k]:
             k += 1

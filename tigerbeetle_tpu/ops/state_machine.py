@@ -107,6 +107,15 @@ TRANSFER_COLS = {
     "timestamp": jnp.uint64,
 }
 
+# The stored columns the secondary index keys a transfer by (ops/index.py): a
+# commit program returns them for the rows it wrote, so the index never reads
+# the table back.
+INDEX_KEY_COLS = (
+    "debit_account_id_lo", "debit_account_id_hi",
+    "credit_account_id_lo", "credit_account_id_hi",
+    "timestamp",
+)
+
 # Posted groove: pending-transfer timestamp -> fulfillment (1 posted, 2 voided)
 # (state_machine.zig:1471-1479).
 POSTED_COLS = {"fulfillment": jnp.uint32}
@@ -725,7 +734,8 @@ def create_transfers_fast_probed_impl(
     batch: Dict[str, jax.Array],
     count: jax.Array,
     timestamp: jax.Array,
-) -> Tuple[Ledger, jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> Tuple[Ledger, jax.Array, jax.Array, jax.Array, jax.Array,
+           Dict[str, jax.Array], jax.Array]:
     """Fast kernel + the transfers probe_overflow flag as a third output.
 
     The overflow flag is widened to a FRESH uint32 buffer (never aliased
@@ -738,16 +748,20 @@ def create_transfers_fast_probed_impl(
 
     The BATCH is donated along with the ledger (its ~1 MB of pad-SoA
     columns become scratch/output space instead of live inputs pinned for
-    the whole dispatch); the id columns the caller's index maintenance
-    needs are passed through as outputs, which may alias the donated
-    buffers.  Callers must hand this kernel a per-dispatch staged SoA
+    the whole dispatch); what the caller's index maintenance needs is
+    passed through as outputs, which may alias the donated buffers: the
+    id columns, ``index_keys`` (the account columns and the timestamps
+    the kernel stored) and ``written_lanes`` (the lanes it stored a row
+    for), so the append costs the host one dispatch and no mask or slice
+    of its own.  Callers must hand this kernel a per-dispatch staged SoA
     (machine._pad_soa with count > 0, or an explicit copy) — never the
     cached zero-count template."""
     id_lo, id_hi = batch["id_lo"], batch["id_hi"]
+    keys = index_keys(batch, count, timestamp)
     ledger, codes = create_transfers_impl(ledger, batch, count, timestamp)
     return (
         ledger, codes, ledger.transfers.probe_overflow.astype(jnp.uint32),
-        id_lo, id_hi,
+        id_lo, id_hi, keys, written_lanes(codes, count),
     )
 
 
@@ -767,6 +781,22 @@ def transfer_rows(
         name: (batch[name] if name != "timestamp" else ts).astype(dt)
         for name, dt in TRANSFER_COLS.items()
     }
+
+
+def index_keys(
+    batch: Dict[str, jax.Array], count: jax.Array, timestamp: jax.Array
+) -> Dict[str, jax.Array]:
+    """INDEX_KEY_COLS of the rows ``transfer_rows`` stores for this batch:
+    what a fast commit program hands the secondary index."""
+    rows = transfer_rows(batch, count, timestamp)
+    return {name: rows[name] for name in INDEX_KEY_COLS}
+
+
+def written_lanes(codes: jax.Array, count: jax.Array) -> jax.Array:
+    """The lanes a commit stored a row for: result code 0, inside the
+    batch (the secondary index's ``ok`` mask)."""
+    lane = jnp.arange(codes.shape[0], dtype=jnp.uint64)
+    return (codes == 0) & (lane < count)
 
 
 def _exists_ladder_transfers(

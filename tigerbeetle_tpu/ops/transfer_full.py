@@ -86,6 +86,7 @@ from .state_machine import (
     AF_CREDITS_MUST_NOT_EXCEED_DEBITS,
     AF_DEBITS_MUST_NOT_EXCEED_CREDITS,
     AF_HISTORY,
+    INDEX_KEY_COLS,
     Ledger,
     MAX_PROBE,
     NS_PER_S,
@@ -1281,8 +1282,10 @@ def create_transfers_full_impl(
     has_history: bool = True,
     use_waves: bool = False,
 ) -> Tuple[jax.Array, ...]:
-    """Returns (ledger', codes uint32[N], flags uint32 scalar), plus a
-    fourth wave-profile vector when ``use_waves`` (see below).
+    """Returns (ledger', codes uint32[N], flags uint32 scalar), a fourth
+    wave-profile vector when ``use_waves`` (see below), and LAST the
+    INDEX_KEY_COLS of the rows it wrote (a post or void lane: the PENDING
+    transfer's accounts), for the secondary index (ops/index.py).
 
     flags == 0: the batch was applied and ``codes`` are the final results.
     flags != 0: NOTHING was applied (ledger' == ledger value-wise); the host
@@ -1295,7 +1298,7 @@ def create_transfers_full_impl(
     Jacobi passes on batches the conflict index certifies, and a FOURTH
     return — int32[11] = (passes, wave_bound, hist[9 wave-depth buckets])
     — for the metrics surface.  Off compiles exactly the pre-waves
-    program with the three-tuple return.
+    program, without that vector.
     """
     n = batch["id_lo"].shape[0]
     lane = jnp.arange(n, dtype=jnp.int32)
@@ -1399,13 +1402,14 @@ def create_transfers_full_impl(
     out = Ledger(
         accounts=accounts, transfers=transfers, posted=posted, history=history
     )
+    keys = {name: ins_rows[name] for name in INDEX_KEY_COLS}
     if use_waves:
         wave_vec = jnp.concatenate([
             plan.passes.reshape(1), plan.wave_bound.reshape(1),
             plan.wave_hist,
         ])
-        return out, plan.codes, kflags, wave_vec
-    return out, plan.codes, kflags
+        return out, plan.codes, kflags, wave_vec, keys
+    return out, plan.codes, kflags, keys
 
 
 def _exists_regular(t, e, t_amount: U128, n) -> jax.Array:

@@ -114,7 +114,7 @@ def main() -> None:
         def multi(led, fails, b0):
             def body(i, c):
                 led2, f = c
-                led2, codes, kflags = tf.create_transfers_full_impl(
+                led2, codes, *_ = tf.create_transfers_full_impl(
                     led2, gen(b0 + i.astype(jnp.uint64)),
                     jnp.uint64(COUNT), jnp.uint64(1 << 20) + b0,
                     has_postvoid=has_postvoid, has_history=False,
