@@ -70,8 +70,9 @@ def _lowered(program):
         steps = sharded.machine_steps(mesh, 8)
         led = jax.eval_shape(lambda: sharded.make_sharded_ledger(
             mesh, 1 << 10, 1 << 12, 1 << 10))
-        step = steps["fast_probed" if program == "sharded_fast"
-                     else "full_waves"]
+        step = steps[{"sharded_fast": "fast_probed",
+                      "sharded_general": "full_waves",
+                      "sharded_general_no_waves": "full"}[program]]
         return step.lower(led, _soa(), u64, u64)
     if program == "index_build":
         keys = {name: ids for name in sm.INDEX_KEY_COLS}
@@ -104,11 +105,46 @@ def _lowered(program):
     ("sharded_general", ("tb/shard_gather", "tb/shard_combine",
                          "tb/full_waves", "tb/full_pass", "tb/full_apply",
                          "tb/full_posted")),
+    ("sharded_general_no_waves", ("tb/shard_gather", "tb/shard_combine",
+                                  "tb/full_pass", "tb/full_apply",
+                                  "tb/full_posted")),
 ])
 def test_scopes_are_in_the_lowered_text(program, scopes):
     text = _lowered(program).as_text(debug_info=True)
     for scope in scopes:
         assert scope in text, f"{program}: no {scope} in the lowered text"
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("use_waves", [False, True])
+def test_every_psum_of_the_sharded_general_program_is_a_combine(use_waves):
+    """The exchange of `sharded_create_transfers_full` is its psums (found
+    flags, global slots, row columns, the overflow and claim flags): each
+    one is traced under `tb/shard_combine`, so a device trace shows the
+    collectives' share by scope as `shard_general_collective_pct` shows it
+    by operation."""
+    mesh = _mesh4()
+    step = sharded.machine_steps(mesh, 8)["full_waves" if use_waves
+                                          else "full"]
+    led = jax.eval_shape(lambda: sharded.make_sharded_ledger(
+        mesh, 1 << 10, 1 << 12, 1 << 10))
+    u64 = jax.ShapeDtypeStruct((), jnp.uint64)
+    jaxpr = jax.make_jaxpr(step)(led, _soa(), u64, u64)
+    psums = [eqn for eqn in _equations(jaxpr.jaxpr)
+             if eqn.primitive.name.startswith("psum")]
+    assert len(psums) > 100          # 7 key sets' found, slots and columns
+    for eqn in psums:
+        assert "tb/shard_combine" in str(eqn.source_info.name_stack), eqn
 
 
 def test_build_runs_reads_no_table():

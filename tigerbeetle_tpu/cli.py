@@ -94,7 +94,11 @@ def main(argv=None) -> int:
                               "pending transfer.  Every table still "
                               "doubles at load 0.5, and each growth "
                               "recompiles the commit kernels: size for "
-                              "twice the rows you expect")
+                              "twice the rows you expect; under --shards "
+                              "the posted table grows at load 0.25 (its "
+                              "keys' owners are not known to the host): "
+                              "size for four times the rows, one power of "
+                              "two above the one-chip size")
     p_start.add_argument("--aof", default=None, metavar="PATH",
                          help="append-only audit log of committed prepares")
     p_start.add_argument("--statsd", default=None, metavar="HOST:PORT",

@@ -2387,8 +2387,6 @@ class TpuStateMachine:
         byte-identical to the single-device kernels; linked chains, in-batch
         pending refs, and history accounts fall back to the sequential path
         exactly like the wave scheduler's unschedulable exit."""
-        from .ops import transfer_full as tf
-
         if self._tiering or self.cold.count:
             # The mesh kernels carry no bloom, so a cold (evicted) id
             # would silently read as not-found there.  Tiered transfer
@@ -2400,15 +2398,18 @@ class TpuStateMachine:
 
         self._note_cross_shard(batch, count)
         self._note_shard_inserts("transfers", batch, count)
-        cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
         if self._fast_path_ok(batch):
             if _obs.enabled:
                 _obs.counter("ops.route.fast").inc()
-            self._grow_if_needed(transfers=count)
-            soa = self._pad_soa(batch)
-            self.ledger, codes = self._shard_steps["fast"](
-                self.ledger, soa, cnt, ts
-            )
+            with txtrace.stage("grow"):
+                self._grow_if_needed(transfers=count)
+            with txtrace.stage("stage_h2d"):
+                soa = self._pad_soa(batch)
+                cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
+            with txtrace.stage("dispatch"):
+                self.ledger, codes = self._shard_steps["fast"](
+                    self.ledger, soa, cnt, ts
+                )
             codes, overflow = self._d2h_codes(
                 codes, self.ledger.transfers.probe_overflow
             )
@@ -2424,15 +2425,30 @@ class TpuStateMachine:
             self._update_commit_timestamp(codes, count, timestamp)
             return results
 
+        with txtrace.stage("general_commit", n=count):
+            return self._sharded_commit_general(batch, timestamp, count)
+
+    def _sharded_commit_general(
+        self, batch: np.ndarray, timestamp: int, count: int
+    ) -> List[Tuple[int, int]]:
+        """The sharded general (Jacobi) program's route, blocking on the
+        calling thread like ``_commit_general``, under the same spans: one
+        shard_map dispatch and one device sync per attempt."""
+        from .ops import transfer_full as tf
+
         pv_count, hist_count = self._transfer_growth_counts(batch)
-        self._grow_if_needed(
-            transfers=count, posted=pv_count, history=hist_count
-        )
-        soa = self._pad_soa(batch)
+        with txtrace.stage("grow"):
+            self._grow_if_needed(
+                transfers=count, posted=pv_count, history=hist_count
+            )
+        with txtrace.stage("stage_h2d"):
+            soa = self._pad_soa(batch)
+            cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
         use_waves = self.waves_enabled
         step = self._shard_steps["full_waves" if use_waves else "full"]
         for _attempt in range(8):
-            r = step(self.ledger, soa, cnt, ts)
+            with txtrace.stage("dispatch"):
+                r = step(self.ledger, soa, cnt, ts)
             self.ledger, codes, kflags = r[0], r[1], r[2]
             wave_vec = r[3] if use_waves else None
             kflags, wave_host = self._full_kflags_sync(kflags, wave_vec)

@@ -80,9 +80,10 @@ STAGES = (
 
 # Stages that only ever run INSIDE another stage's block on the same
 # thread: ``device_execute``'s children.  A sum over stages that wants
-# wall time leaves them out; on the general route
-# alone ``stage_h2d`` is nested too (the staging is part of the blocking
-# closure), so such a sum over general requests counts it twice.  The
+# wall time leaves them out; on the blocking routes alone (the general
+# route, single-device or sharded, and the sharded blocking fast route)
+# ``stage_h2d`` is nested too (the staging is part of the blocking
+# closure), so such a sum over those requests counts it twice.  The
 # bus's sections below hold the replica's and the machine's
 # serving-thread spans the same way: a sum that takes the sections takes
 # nothing else of that thread.

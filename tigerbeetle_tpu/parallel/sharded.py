@@ -40,7 +40,11 @@ a name of its own (``_named_jit``: ``jit_sharded_create_transfers_fast_probed``
 and so on; the commit twins' names contain their single-device twins'), and
 the phases carry ``tb/shard_gather`` (the masked local probes and gathers),
 ``tb/shard_combine`` (the psums) and, after the exchange, the single-device
-kernels' own scope names.
+kernels' own scope names.  In the general program
+(``jit_sharded_create_transfers_full[_waves]``) the masks over the combined
+rows and the kflags word assembled from the psums are ``tb/shard_combine``
+too; its core keeps ``tb/full_waves|full_pass``, its owner-local claims and
+writes ``tb/full_apply|full_posted``.
 """
 
 from __future__ import annotations
@@ -360,31 +364,34 @@ def sharded_create_transfers_full(
     def local_step(ledger: Ledger, batch, count, timestamp):
         acc, tr, posted_t = ledger.accounts, ledger.transfers, ledger.posted
         n = batch["id_lo"].shape[0]
-        lane = jnp.arange(n, dtype=jnp.int32)
-        valid = lane < count.astype(jnp.int32)
-        postvoid = (
-            ((batch["flags"] & TF_POST) != 0) | ((batch["flags"] & TF_VOID) != 0)
-        ) & valid
+        with jax.named_scope("tb/shard_gather"):
+            lane = jnp.arange(n, dtype=jnp.int32)
+            valid = lane < count.astype(jnp.int32)
+            postvoid = (
+                ((batch["flags"] & TF_POST) != 0)
+                | ((batch["flags"] & TF_VOID) != 0)
+            ) & valid
+
+        @jax.named_scope("tb/shard_combine")
+        def masked(rows, keep):
+            # The combined rows, zeroed where the core must see "no row":
+            # part of the exchange's result, like the psums it follows.
+            return {k: jnp.where(keep, v, jnp.zeros_like(v))
+                    for k, v in rows.items()}
 
         ex_g = _ShardGather(tr, batch["id_lo"], batch["id_hi"], n_shards, shift)
         # Zero-mask by `valid` exactly like the single-chip gather
         # (ex_found = found & valid there): every current consumer is gated
         # on ex_found anyway, but an unmasked row would be a latent
         # byte-parity divergence if e_tab ever gains another consumer.
-        e_tab = {
-            k: jnp.where(ex_g.found & valid, v, jnp.zeros_like(v))
-            for k, v in ex_g.rows(tr).items()
-        }
+        e_tab = masked(ex_g.rows(tr), ex_g.found & valid)
         p_g = _ShardGather(
             tr, batch["pending_id_lo"], batch["pending_id_hi"], n_shards, shift
         )
         p_tab_found = p_g.found & postvoid
         # Zero-mask rows exactly like the single-chip gather (mask includes
         # postvoid): the core treats zeros as "no row".
-        p_tab = {
-            k: jnp.where(p_tab_found, v, jnp.zeros_like(v))
-            for k, v in p_g.rows(tr).items()
-        }
+        p_tab = masked(p_g.rows(tr), p_tab_found)
 
         drT_g = _ShardGather(
             acc, batch["debit_account_id_lo"], batch["debit_account_id_hi"],
@@ -413,36 +420,34 @@ def sharded_create_transfers_full(
         def any_shard(local_bool):
             return jax.lax.psum(local_bool.astype(jnp.uint32), AXIS) > 0
 
-        probe_grow = (
-            jnp.where(
-                any_shard(drT_g.overflow_l | crT_g.overflow_l
-                          | pdr_g.overflow_l | pcr_g.overflow_l),
-                jnp.uint32(tf.FLAG_GROW_ACCOUNTS), jnp.uint32(0),
+        def flag_if_any(local_bool, flag):
+            return jnp.where(
+                any_shard(local_bool), jnp.uint32(flag), jnp.uint32(0)
             )
-            | jnp.where(
-                any_shard(ex_g.overflow_l | p_g.overflow_l),
-                jnp.uint32(tf.FLAG_GROW_TRANSFERS), jnp.uint32(0),
-            )
-            | jnp.where(
-                any_shard(postedT_g.overflow_l),
-                jnp.uint32(tf.FLAG_GROW_POSTED), jnp.uint32(0),
-            )
-        )
 
-        ctx = tf.GatherCtx(
-            ex_found=ex_g.found & valid,
-            e_tab=e_tab,
-            p_tab_found=p_tab_found,
-            p_tab=p_tab,
-            drT=_view(drT_g, acc, drT_g.found & valid),
-            crT=_view(crT_g, acc, crT_g.found & valid),
-            pdr=_view(pdr_g, acc, pdr_g.found & p_tab_found),
-            pcr=_view(pcr_g, acc, pcr_g.found & p_tab_found),
-            postedT_found=postedT_found,
-            postedT_val=postedT_val,
-            probe_grow=probe_grow,
-            accounts_capacity=jnp.uint64(acc.capacity * n_shards),
-        )
+        with jax.named_scope("tb/shard_combine"):
+            probe_grow = (
+                flag_if_any(drT_g.overflow_l | crT_g.overflow_l
+                            | pdr_g.overflow_l | pcr_g.overflow_l,
+                            tf.FLAG_GROW_ACCOUNTS)
+                | flag_if_any(ex_g.overflow_l | p_g.overflow_l,
+                              tf.FLAG_GROW_TRANSFERS)
+                | flag_if_any(postedT_g.overflow_l, tf.FLAG_GROW_POSTED)
+            )
+            ctx = tf.GatherCtx(  # the found masks of the combined context
+                ex_found=ex_g.found & valid,
+                e_tab=e_tab,
+                p_tab_found=p_tab_found,
+                p_tab=p_tab,
+                drT=_view(drT_g, acc, drT_g.found & valid),
+                crT=_view(crT_g, acc, crT_g.found & valid),
+                pdr=_view(pdr_g, acc, pdr_g.found & p_tab_found),
+                pcr=_view(pcr_g, acc, pcr_g.found & p_tab_found),
+                postedT_found=postedT_found,
+                postedT_val=postedT_val,
+                probe_grow=probe_grow,
+                accounts_capacity=jnp.uint64(acc.capacity * n_shards),
+            )
         plan = tf._kernel_core(
             ctx, batch, count, timestamp, max_passes, use_waves=use_waves
         )
@@ -471,19 +476,14 @@ def sharded_create_transfers_full(
                 posted_t, plan.posted_key, jnp.zeros_like(plan.posted_key),
                 plan.pv_ok & pk_owner, MAX_PROBE, hash_shift=shift,
             )
-        kflags = (
-            probe_grow
-            | route
-            | jnp.where(
-                any_shard(t_ovf), jnp.uint32(tf.FLAG_GROW_TRANSFERS),
-                jnp.uint32(0),
+        with jax.named_scope("tb/shard_combine"):
+            kflags = (
+                probe_grow
+                | route
+                | flag_if_any(t_ovf, tf.FLAG_GROW_TRANSFERS)
+                | flag_if_any(p_ovf, tf.FLAG_GROW_POSTED)
             )
-            | jnp.where(
-                any_shard(p_ovf), jnp.uint32(tf.FLAG_GROW_POSTED),
-                jnp.uint32(0),
-            )
-        )
-        commit = kflags == jnp.uint32(0)
+            commit = kflags == jnp.uint32(0)
 
         # Balance scatter: global slot runs, owner-local writes.
         with jax.named_scope("tb/full_apply"):
