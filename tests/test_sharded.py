@@ -34,10 +34,10 @@ def mesh():
     return Mesh(devs, (sharded.AXIS,))
 
 
-def pad_soa(batch, lanes=LANES):
-    padded = np.zeros(lanes, dtype=batch.dtype)
-    padded[: len(batch)] = batch
-    return {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
+def staged(mesh, batch, timestamp, lanes=LANES):
+    """The operands a sharded step takes after the ledger: the batch padded
+    to ``lanes``, its count and its timestamp, replicated on the mesh."""
+    return sharded.stage_batch(mesh, batch, lanes, int(timestamp))
 
 
 def snapshot_sharded(ledger):
@@ -80,7 +80,7 @@ def test_sharded_matches_single_chip(mesh):
     accounts = gen.accounts_batch(32)
     want_res = single.create_accounts(accounts, wall_clock_ns=1000)
     got_ledger, got_codes = acc_step(
-        ledger, pad_soa(accounts), jnp.uint64(32), jnp.uint64(single.prepare_timestamp)
+        ledger, *staged(mesh, accounts, single.prepare_timestamp)
     )
     ledger = got_ledger
     codes = np.asarray(got_codes)[:32]
@@ -94,9 +94,7 @@ def test_sharded_matches_single_chip(mesh):
         )
         want_res = single.create_transfers(batch, wall_clock_ns=0)
         ts += len(batch)
-        ledger, got_codes = tr_step(
-            ledger, pad_soa(batch), jnp.uint64(len(batch)), jnp.uint64(ts)
-        )
+        ledger, got_codes = tr_step(ledger, *staged(mesh, batch, ts))
         codes = np.asarray(got_codes)[: len(batch)]
         got_res = [(int(i), int(codes[i])) for i in np.nonzero(codes)[0]]
         assert got_res == want_res, f"batch {b}"
@@ -123,16 +121,12 @@ def test_sharded_lookup_matches_single_chip(mesh):
     accounts = gen.accounts_batch(24)
     single.create_accounts(accounts, wall_clock_ns=1000)
     ledger, _ = acc_step(
-        ledger, pad_soa(accounts), jnp.uint64(24),
-        jnp.uint64(single.prepare_timestamp),
+        ledger, *staged(mesh, accounts, single.prepare_timestamp)
     )
     batch = gen.transfers_batch(80, invalid_rate=0.0, dup_rate=0.0,
                                 pending_rate=0.0)
     single.create_transfers(batch)
-    ledger, _ = tr_step(
-        ledger, pad_soa(batch), jnp.uint64(len(batch)),
-        jnp.uint64(single.prepare_timestamp),
-    )
+    ledger, _ = tr_step(ledger, *staged(mesh, batch, single.prepare_timestamp))
 
     # Mixed present/absent ids, replicated over the mesh.
     ids = [int(i) for i in accounts["id_lo"][:8]] + [999_999, 0]
@@ -186,8 +180,7 @@ def test_sharded_full_kernel_two_phase_parity(mesh):
     accounts = types.accounts_array(rows)
     want = single.create_accounts(accounts, wall_clock_ns=1000)
     ledger, codes = acc_step(
-        ledger, pad_soa(accounts), jnp.uint64(16),
-        jnp.uint64(single.prepare_timestamp),
+        ledger, *staged(mesh, accounts, single.prepare_timestamp)
     )
     codes = np.asarray(codes)[:16]
     assert [(int(i), int(codes[i])) for i in np.nonzero(codes)[0]] == want
@@ -201,8 +194,7 @@ def test_sharded_full_kernel_two_phase_parity(mesh):
         batch = types.transfers_array([types.transfer(**s) for s in specs])
         want_res = single.create_transfers(batch, wall_clock_ns=0)
         nonlocal_led, got_codes, kflags = full_step(
-            ledger, pad_soa(batch), jnp.uint64(len(batch)),
-            jnp.uint64(single.prepare_timestamp),
+            ledger, *staged(mesh, batch, single.prepare_timestamp)
         )
         assert int(kflags) == 0, f"unexpected route: kflags={int(kflags)}"
         c = np.asarray(got_codes)[: len(batch)]
@@ -271,15 +263,13 @@ def test_sharded_full_kernel_routes_history(mesh):
                       flags=types.AccountFlags.HISTORY),
         types.account(id=2, ledger=1, code=10),
     ])
-    ledger, _ = acc_step(ledger, pad_soa(accounts), jnp.uint64(2), jnp.uint64(10))
+    ledger, _ = acc_step(ledger, *staged(mesh, accounts, 10))
     batch = types.transfers_array([
         types.transfer(id=50, debit_account_id=1, credit_account_id=2,
                        amount=5, ledger=1, code=1),
     ])
     before = snapshot_sharded(ledger)
-    ledger, codes, kflags = full_step(
-        ledger, pad_soa(batch), jnp.uint64(1), jnp.uint64(100)
-    )
+    ledger, codes, kflags = full_step(ledger, *staged(mesh, batch, 100))
     assert int(kflags) & tf.FLAG_SEQ
     assert snapshot_sharded(ledger) == before
 
@@ -309,8 +299,7 @@ def test_sharded_full_kernel_random_stream(mesh, seed):
     ])
     single.create_accounts(accounts, wall_clock_ns=1000)
     ledger, _ = acc_step(
-        ledger, pad_soa(accounts), jnp.uint64(n_acc),
-        jnp.uint64(single.prepare_timestamp),
+        ledger, *staged(mesh, accounts, single.prepare_timestamp)
     )
 
     next_id = 9000
@@ -356,8 +345,7 @@ def test_sharded_full_kernel_random_stream(mesh, seed):
         batch = types.transfers_array([types.transfer(**s) for s in specs])
         want = single.create_transfers(batch, wall_clock_ns=0)
         led2, got_codes, kflags = full_step(
-            ledger, pad_soa(batch), jnp.uint64(len(batch)),
-            jnp.uint64(single.prepare_timestamp),
+            ledger, *staged(mesh, batch, single.prepare_timestamp)
         )
         if int(kflags) != 0:
             # Routed (deep cascade): the mesh wrapper applies nothing; the

@@ -1,0 +1,174 @@
+"""Under `--shards` a batch is staged once, already replicated on the mesh
+(`machine._stage_sharded`, `parallel/sharded.stage_batch`; PERF.md PR 38).
+
+Four things the serving path leans on, on each of the four sharded transfer
+routes (the lone deferred request, the grouped run, the blocking fast
+request, the blocking general one): the operands a commit program is handed
+are in place on every chip of the mesh; a request after `warmup()` finds its
+program compiled, because warm-up stages as the routes do; every sharded
+request took that staging (`sharding.staged`); and a staged batch is zero
+beyond its count, the pad contract the kernels rely on."""
+
+import jax
+import numpy as np
+import pytest
+
+from tigerbeetle_tpu import jaxenv, types
+from tigerbeetle_tpu.config import LedgerConfig
+from tigerbeetle_tpu.machine import TpuStateMachine
+from tigerbeetle_tpu.obs.metrics import registry
+from tigerbeetle_tpu.obs.txtrace import txtrace
+from tigerbeetle_tpu.parallel import sharded
+
+LANES = 64
+SHARDS = 4
+N_ACCOUNTS = 16
+PENDING = types.TransferFlags.PENDING
+ROUTES = ["lone_deferred", "grouped_run", "blocking_fast", "blocking_general"]
+
+
+def _machine():
+    if len(jax.devices()) < SHARDS:
+        pytest.skip(f"needs {SHARDS} devices, have {len(jax.devices())}")
+    m = TpuStateMachine(
+        LedgerConfig(accounts_capacity_log2=9, transfers_capacity_log2=12,
+                     posted_capacity_log2=8),
+        batch_lanes=LANES, shards=SHARDS)
+    m.group_device_commit = True
+    assert m.pipeline_depth == 2 and m.waves_enabled
+    return m
+
+
+def _accounts():
+    return types.accounts_array([
+        types.account(id=i + 1, ledger=1, code=10) for i in range(N_ACCOUNTS)
+    ])
+
+
+def _transfers(first_id, n, flags=0):
+    return types.transfers_array([
+        types.transfer(
+            id=first_id + i, debit_account_id=1 + i % N_ACCOUNTS,
+            credit_account_id=1 + (i + 3) % N_ACCOUNTS, amount=3 + i % 5,
+            ledger=1, code=10, flags=flags,
+        )
+        for i in range(n)
+    ])
+
+
+def _serve(m, route, first_id):
+    """One request (three on the grouped route) of ``route``, as the
+    serving loop sends it; returns how many batches it committed."""
+    if route == "lone_deferred":
+        batch = _transfers(first_id, 10)
+        handle = m.commit_fast_deferred(
+            batch, m.prepare("create_transfers", len(batch), 0))
+        assert handle.resolve() == [[]]
+        return 1
+    if route == "grouped_run":
+        batches = [_transfers(first_id + 100 * j, 7 + j) for j in range(3)]
+        stamps = [m.prepare("create_transfers", len(b), 0) for b in batches]
+        assert m.commit_group_fast(batches, stamps) == [[], [], []]
+        return 3
+    flags = PENDING if route == "blocking_general" else 0
+    assert m.create_transfers(_transfers(first_id, 10, flags)) == []
+    return 1
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_a_commit_program_finds_its_operands_in_place(route):
+    m = _machine()
+    assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
+    mesh_devices = set(m._shard_mesh.devices.flat)
+    seen = []
+
+    def recording(step):
+        def call(ledger, *operands):
+            seen.append(operands)
+            return step(ledger, *operands)
+        return call
+
+    # The machine's own view of the process-wide step cache, wrapped.
+    m._shard_steps = {k: recording(v) for k, v in m._shard_steps.items()}
+    batches = _serve(m, route, 10_000)
+    assert len(seen) == batches
+    for operands in seen:
+        cols64, cols32, meta = operands
+        assert cols64.shape == (14, LANES) and cols64.dtype == np.uint64
+        assert cols32.shape == (5, LANES) and cols32.dtype == np.uint32
+        assert meta.shape == (2,) and meta.dtype == np.uint64
+        for x in operands:
+            assert x.committed
+            assert x.sharding.is_fully_replicated
+            assert set(x.sharding.device_set) == mesh_devices
+    assert m._ledger_is_sharded
+
+
+@pytest.mark.parametrize("route", ROUTES)
+def test_the_first_request_after_warmup_compiles_nothing(route):
+    assert jaxenv.instrument_compiles()
+    m = _machine()
+    m.warmup()
+    steps = m._shard_steps
+    warmed = {k: steps[k]._cache_size()
+              for k in ("accounts", "fast", "fast_probed", "full_waves")}
+    assert all(n >= 1 for n in warmed.values()), warmed
+    with registry.enabled_scope():
+        before = registry.snapshot()["counters"].get("jit.compiles", 0)
+        assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
+        _serve(m, route, 20_000)
+        after = registry.snapshot()["counters"].get("jit.compiles", 0)
+    # Neither a second executable of a sharded program (one keyed on
+    # operands staged otherwise than warm-up's) nor any other compile.
+    assert {k: steps[k]._cache_size() for k in warmed} == warmed
+    assert after - before == 0
+
+
+def test_every_sharded_request_is_staged_on_the_mesh():
+    m = _machine()
+    with registry.enabled_scope(), txtrace.attribution_scope():
+        assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
+        batches = sum(
+            _serve(m, route, 30_000 + 1_000 * i)
+            for i, route in enumerate(ROUTES + ROUTES[::-1])
+        )
+        counters = registry.snapshot()["counters"]
+        totals = txtrace.stage_totals()
+    assert batches == 2 * (1 + 3 + 1 + 1)
+    assert counters["sharding.batches"] == batches
+    assert counters["sharding.staged"] == batches + 1   # the account request
+    assert counters.get("sharding.seq_fallbacks", 0) == 0
+    # Each staging sits inside a `stage_h2d` span: one a request, the
+    # grouped run's three under one.
+    assert totals["stage_h2d"]["count"] == 1 + 2 * len(ROUTES)
+
+
+@pytest.mark.parametrize("n", [0, 1, 37, LANES])
+@pytest.mark.parametrize("dtype", [types.ACCOUNT_DTYPE, types.TRANSFER_DTYPE],
+                         ids=["accounts", "transfers"])
+def test_a_staged_batch_is_zero_beyond_its_count(dtype, n):
+    if len(jax.devices()) < SHARDS:
+        pytest.skip(f"needs {SHARDS} devices, have {len(jax.devices())}")
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:SHARDS]), (sharded.AXIS,))
+    rng = np.random.default_rng(n)
+    batch = np.zeros(n, dtype=dtype)
+    for name in dtype.names:   # every field non-zero in every lane
+        info = np.iinfo(dtype.fields[name][0])
+        batch[name] = rng.integers(1, info.max, n, dtype=info.dtype)
+    timestamp = 7_000_000_000_000 + n
+    staged = sharded.stage_batch(mesh, batch, LANES, timestamp)
+    columns, count, stamp = sharded._unstage(dtype, *staged)
+    assert (int(count), int(stamp)) == (n, timestamp)
+    # What `_pad_soa` gave: each column by name, widened as `to_soa`
+    # widens it, its lanes beyond the count zero.
+    padded = np.zeros(LANES, dtype=dtype)
+    padded[:n] = batch
+    want = types.to_soa(padded)
+    assert set(columns) == set(want) and len(columns) == 19
+    for name, column in columns.items():
+        got = np.asarray(column)
+        assert got.dtype == want[name].dtype and got.shape == (LANES,)
+        assert np.array_equal(got, want[name]), name
+        assert not got[n:].any() and got[:n].all(), name

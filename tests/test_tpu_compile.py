@@ -144,10 +144,14 @@ def _sharded_lowering(topo, make_step):
         accounts=table(led.accounts), transfers=table(led.transfers),
         posted=table(led.posted), history=_on(led.history, repl),
     )
-    u64 = jax.ShapeDtypeStruct((), jnp.uint64, sharding=repl)
-    return make_step(mesh).lower(
-        led, _soa(types.TRANSFER_DTYPE, repl), u64, u64
+    # The staged operands (sharded.stage_batch): the batch's 14 uint64
+    # columns, its 5 narrower ones, (count, timestamp), replicated.
+    staged = (
+        jax.ShapeDtypeStruct((14, LANES), jnp.uint64, sharding=repl),
+        jax.ShapeDtypeStruct((5, LANES), jnp.uint32, sharding=repl),
+        jax.ShapeDtypeStruct((2,), jnp.uint64, sharding=repl),
     )
+    return make_step(mesh).lower(led, *staged)
 
 
 @pytest.mark.parametrize("program", [

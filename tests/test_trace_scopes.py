@@ -47,6 +47,14 @@ def _mesh4():
     return Mesh(np.array(jax.devices()[:4]), (sharded.AXIS,))
 
 
+def _staged():
+    """`sharded.stage_batch`'s operands as shapes: the batch's 14 uint64
+    columns, its 5 narrower ones, (count, timestamp)."""
+    return (jax.ShapeDtypeStruct((14, LANES), jnp.uint64),
+            jax.ShapeDtypeStruct((5, LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((2,), jnp.uint64))
+
+
 def _lowered(program):
     led = jax.eval_shape(lambda: sm.make_ledger(1 << 10, 1 << 12, 1 << 10,
                                                 1 << 10))
@@ -73,7 +81,7 @@ def _lowered(program):
         step = steps[{"sharded_fast": "fast_probed",
                       "sharded_general": "full_waves",
                       "sharded_general_no_waves": "full"}[program]]
-        return step.lower(led, _soa(), u64, u64)
+        return step.lower(led, *_staged())
     if program == "index_build":
         keys = {name: ids for name in sm.INDEX_KEY_COLS}
         return index.build_runs.lower(keys, ids, ids, ok)
@@ -138,8 +146,7 @@ def test_every_psum_of_the_sharded_general_program_is_a_combine(use_waves):
                                           else "full"]
     led = jax.eval_shape(lambda: sharded.make_sharded_ledger(
         mesh, 1 << 10, 1 << 12, 1 << 10))
-    u64 = jax.ShapeDtypeStruct((), jnp.uint64)
-    jaxpr = jax.make_jaxpr(step)(led, _soa(), u64, u64)
+    jaxpr = jax.make_jaxpr(step)(led, *_staged())
     psums = [eqn for eqn in _equations(jaxpr.jaxpr)
              if eqn.primitive.name.startswith("psum")]
     assert len(psums) > 100          # 7 key sets' found, slots and columns

@@ -1945,30 +1945,35 @@ class TpuStateMachine:
         if self._ledger_is_sharded:
             # Warm the sharded commit kernels (accounts, fast, the full
             # variant for the current waves setting): one zero-count
-            # dispatch each, state value-identical.
-            soa_a = self._pad_soa(np.zeros(0, dtype=types.ACCOUNT_DTYPE))
-            self.ledger, codes_a = self._shard_steps["accounts"](
-                self.ledger, soa_a, jnp.uint64(0), jnp.uint64(1)
+            # dispatch each, state value-identical.  Staged exactly as the
+            # served routes stage (jit keys an executable on each operand's
+            # sharding: a warm-up that handed other operands would leave
+            # the served program to compile inside a client's request).
+            staged_a = self._stage_sharded(
+                np.zeros(0, dtype=types.ACCOUNT_DTYPE), 1
             )
-            soa_t = self._pad_soa(np.zeros(0, dtype=types.TRANSFER_DTYPE))
+            self.ledger, codes_a = self._shard_steps["accounts"](
+                self.ledger, *staged_a
+            )
+            staged_t = self._stage_sharded(
+                np.zeros(0, dtype=types.TRANSFER_DTYPE), 1
+            )
             self.ledger, codes_f = self._shard_steps["fast"](
-                self.ledger, soa_t, jnp.uint64(0), jnp.uint64(1)
+                self.ledger, *staged_t
             )
             if self.pipeline_depth > 1 or self.group_device_commit:
                 # The async sharded engine dispatches the PROBED sharded
                 # step — deferred at depth >= 2 AND blocking grouped runs
                 # (commit_group_fast routes through it at any depth); a
-                # client must never pay its compile mid-request.  Batch
-                # is not donated, so the cached zero template is safe.
-                r = self._shard_steps["fast_probed"](
-                    self.ledger, soa_t, jnp.uint64(0), jnp.uint64(1)
-                )
+                # client must never pay its compile mid-request.  The batch
+                # is not donated, so the staged operands serve every step.
+                r = self._shard_steps["fast_probed"](self.ledger, *staged_t)
                 self.ledger = r[0]
                 np.asarray(r[1]), np.asarray(r[2])
             step = self._shard_steps[
                 "full_waves" if self.waves_enabled else "full"
             ]
-            r = step(self.ledger, soa_t, jnp.uint64(0), jnp.uint64(1))
+            r = step(self.ledger, *staged_t)
             self.ledger = r[0]
             np.asarray(codes_a), np.asarray(codes_f), np.asarray(r[1])
             return
@@ -2084,6 +2089,22 @@ class TpuStateMachine:
         padded[:n] = batch
         return {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
 
+    def _stage_sharded(self, batch: np.ndarray, timestamp: int) -> tuple:
+        """The ONE staging of the mesh (``parallel/sharded.stage_batch``):
+        the operands every ``self._shard_steps`` commit program takes after
+        the ledger, padded to ``batch_lanes`` and already replicated on the
+        mesh.  ``_pad_soa`` stays the single-device kernels' own: they
+        DONATE what it stages and hand index keys back from it; the sharded
+        programs donate the ledger alone and keep a lazy index, so nothing
+        reads these operands after the dispatch."""
+        from .parallel import sharded as shard_mod
+
+        if _obs.enabled:
+            _obs.counter("sharding.staged").inc()
+        return shard_mod.stage_batch(
+            self._shard_mesh, batch, self.batch_lanes, timestamp
+        )
+
     @staticmethod
     def _compress(codes: np.ndarray, count: int) -> List[Tuple[int, int]]:
         codes = codes[:count]
@@ -2167,14 +2188,17 @@ class TpuStateMachine:
             self._history_accounts_possible = True
         if bool((batch["flags"] & _LIMIT_FLAGS).any()):
             self._limit_accounts_possible = True
-        soa = self._pad_soa(batch)
         if self._ledger_is_sharded:
             # Same codes, owner-local inserts (parallel/sharded.py); the
             # probe_overflow check below reads the per-shard lane vector.
+            with txtrace.stage("stage_h2d"):
+                staged = self._stage_sharded(batch, timestamp)
+            soa = None  # the scan sets reset under shards: nothing reads it
             self.ledger, codes = self._shard_steps["accounts"](
-                self.ledger, soa, jnp.uint64(count), jnp.uint64(timestamp)
+                self.ledger, *staged
             )
         else:
+            soa = self._pad_soa(batch)
             self.ledger, codes = sm.create_accounts(
                 self.ledger, soa, jnp.uint64(count), jnp.uint64(timestamp)
             )
@@ -2404,11 +2428,10 @@ class TpuStateMachine:
             with txtrace.stage("grow"):
                 self._grow_if_needed(transfers=count)
             with txtrace.stage("stage_h2d"):
-                soa = self._pad_soa(batch)
-                cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
+                staged = self._stage_sharded(batch, timestamp)
             with txtrace.stage("dispatch"):
                 self.ledger, codes = self._shard_steps["fast"](
-                    self.ledger, soa, cnt, ts
+                    self.ledger, *staged
                 )
             codes, overflow = self._d2h_codes(
                 codes, self.ledger.transfers.probe_overflow
@@ -2420,7 +2443,7 @@ class TpuStateMachine:
                 )
             if _obs.enabled:
                 _obs.counter("sharding.batches").inc()
-            self._index_append(soa, codes, count)
+            self._index_lazy_reset()
             results = self._compress(codes, count)
             self._update_commit_timestamp(codes, count, timestamp)
             return results
@@ -2442,21 +2465,21 @@ class TpuStateMachine:
                 transfers=count, posted=pv_count, history=hist_count
             )
         with txtrace.stage("stage_h2d"):
-            soa = self._pad_soa(batch)
-            cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
+            staged = self._stage_sharded(batch, timestamp)
         use_waves = self.waves_enabled
         step = self._shard_steps["full_waves" if use_waves else "full"]
         for _attempt in range(8):
             with txtrace.stage("dispatch"):
-                r = step(self.ledger, soa, cnt, ts)
+                r = step(self.ledger, *staged)
             self.ledger, codes, kflags = r[0], r[1], r[2]
             wave_vec = r[3] if use_waves else None
             kflags, wave_host = self._full_kflags_sync(kflags, wave_vec)
             if kflags == 0:
                 if _obs.enabled:
                     _obs.counter("sharding.batches").inc()
+                # soa=None: the index append is a reset under shards.
                 return self._full_commit_success(
-                    soa, codes, count, pv_count, hist_count, timestamp,
+                    None, codes, count, pv_count, hist_count, timestamp,
                     wave_host,
                 )
             if kflags & tf.FLAG_SEQ:
@@ -3234,15 +3257,19 @@ class TpuStateMachine:
                                    deferred):
         """Grouped/deferred commit stacking over the mesh (the async
         sharded engine, docs/sharding.md composition section): the run's
-        batches are staged H2D on the serving thread, then ONE dispatch-
-        lane closure drives the cached ``sharded.machine_steps``
-        fast_probed program once per batch — per-batch shard_map dispatch
-        (the loop-grouped single-device program would re-trace per mesh
-        layout; the per-shard lanes are the parallelism lever here) with
-        the ledger chain threaded through, growth snapshotted at submit,
-        and ONE deferred D2H readback (codes + per-shard overflow lanes)
-        for the whole run.  Results are bit-identical to committing the
-        run batch by batch through the blocking sharded fast path."""
+        batches are staged H2D on the serving thread, each ONCE and already
+        replicated on the mesh (``_stage_sharded``: 2.5 ms a batch and 1.3
+        ms an enqueue on a four-chip v5e host, where 19 device-0 columns
+        cost 7.3 and the enqueue that re-placed them on four chips 11.2;
+        PERF.md PR 38), then ONE dispatch-lane closure drives the cached
+        ``sharded.machine_steps`` fast_probed program once per batch —
+        per-batch shard_map dispatch (the loop-grouped single-device
+        program would re-trace per mesh layout; the per-shard lanes are the
+        parallelism lever here) with the ledger chain threaded through,
+        growth snapshotted at submit, and ONE deferred D2H readback (codes
+        + per-shard overflow lanes) for the whole run.  Results are
+        bit-identical to committing the run batch by batch through the
+        blocking sharded fast path."""
         k = len(batches)
         total = 0
         owner_sum = np.zeros(max(self.shards, 1), np.int64)
@@ -3258,10 +3285,10 @@ class TpuStateMachine:
             _obs.counter("ops.group.steps").inc(k)
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d", n=k):
-            # Serving-thread staging.
-            soas = [self._pad_soa(b) for b in batches]
-            cnts = [jnp.uint64(c) for c in counts]
-            tss = [jnp.uint64(t) for t in timestamps]
+            # Serving-thread staging: K puts, each already on the mesh.
+            staged = [
+                self._stage_sharded(b, t) for b, t in zip(batches, timestamps)
+            ]
         # Submit-time growth snapshot (see commit_group_fast / the
         # shard_bounds note in _grow_if_needed).
         need = self._transfers_bound + total
@@ -3281,7 +3308,7 @@ class TpuStateMachine:
                 # FIFO lane worker, serving-thread reads behind the join.
                 with txtrace.stage("dispatch", seq=seq):
                     self.ledger, codes, overflow = step(  # tblint: ignore[lane-race] FIFO+join
-                        self.ledger, soas[j], cnts[j], tss[j]
+                        self.ledger, *staged[j]
                     )
                 with txtrace.stage("index_append", seq=seq):
                     self._index_lazy_reset()
@@ -3382,8 +3409,12 @@ class TpuStateMachine:
             owners = self._note_shard_inserts("transfers", batch, count)
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d"):
-            soa = self._pad_soa(batch)  # staged on the serving thread
-            cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
+            # Staged on the serving thread, for the route's own programs.
+            if self._ledger_is_sharded:
+                staged = self._stage_sharded(batch, timestamp)
+            else:
+                soa = self._pad_soa(batch)
+                cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
         # Snapshot the growth target pre-submit (see _grow_if_needed).
         need = self._transfers_bound + count
         self._transfers_bound += count
@@ -3395,16 +3426,15 @@ class TpuStateMachine:
             step = self._shard_steps["fast_probed"]
 
             def dispatch():
-                # The sharded probed step donates only the ledger (the
-                # replicated batch may alias pooled host buffers); the
-                # overflow lanes ride a fresh output.
+                # The sharded probed step donates only the ledger, never
+                # the staged batch; the overflow lanes ride a fresh output.
                 with txtrace.stage("grow", seq=seq):
                     self._grow_if_needed(
                         transfers_need=need, shard_bounds=snap
                     )
                 with txtrace.stage("dispatch", seq=seq):
                     self.ledger, codes, overflow = step(
-                        self.ledger, soa, cnt, ts
+                        self.ledger, *staged
                     )
                 with txtrace.stage("index_append", seq=seq):
                     self._index_lazy_reset()

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import types
 from ..u128 import mix64
 from ..ops import hash_table as ht
 from ..ops import state_machine as sm
@@ -154,8 +155,49 @@ def _specs_like(tree):
     return jax.tree_util.tree_map(lambda _: P(AXIS), tree)
 
 
-def _replicated_like(tree):
-    return jax.tree_util.tree_map(lambda _: P(), tree)
+@functools.lru_cache(maxsize=None)
+def _staged_names(dtype: np.dtype) -> Tuple[tuple, tuple]:
+    """A wire dtype's fields by staged width: the ``uint64`` columns, and the
+    narrower ones (``uint32`` on the device: ``types.to_soa``'s widening)."""
+    wide = tuple(n for n in dtype.names if dtype.fields[n][0] == np.uint64)
+    return wide, tuple(n for n in dtype.names if n not in wide)
+
+
+def stage_batch(mesh: Mesh, batch: np.ndarray, lanes: int, timestamp: int):
+    """Stage one host batch for a sharded commit step: the operands every
+    step takes after the ledger, put ONCE and already replicated on ``mesh``,
+    so that the step's dispatch finds each one in place on every chip.
+
+    Returns ``(cols64, cols32, meta)``: the batch's ``uint64`` columns as the
+    rows of one ``uint64[14, lanes]`` buffer, its narrower ones as the rows
+    of one ``uint32[5, lanes]``, both in the dtype's field order and zero
+    beyond ``len(batch)`` (the pad contract the kernels rely on), and
+    ``meta = uint64[2]`` = (count, timestamp): 3 transfers a chip in ONE
+    ``device_put`` where 19 column puts to device 0 and two eager scalars
+    were re-placed on every chip at every call (PERF.md PR 38).  The host
+    arrays are fresh for each batch, so nothing can refill one under a
+    transfer that still reads it; the steps slice the columns back out by
+    name (``_unstage``) inside the program."""
+    n = len(batch)
+    assert n <= lanes, "batch exceeds configured lanes"
+    wide, narrow = _staged_names(batch.dtype)
+    cols64 = np.zeros((len(wide), lanes), np.uint64)
+    cols32 = np.zeros((len(narrow), lanes), np.uint32)
+    for i, name in enumerate(wide):
+        cols64[i, :n] = batch[name]
+    for i, name in enumerate(narrow):
+        cols32[i, :n] = batch[name]
+    meta = np.array([n, timestamp], np.uint64)
+    return jax.device_put((cols64, cols32, meta), NamedSharding(mesh, P()))
+
+
+def _unstage(dtype: np.dtype, cols64, cols32, meta):
+    """Inside a step: ``stage_batch``'s operands back as (the batch's columns
+    by name, count, timestamp), what the single-device kernels take."""
+    wide, narrow = _staged_names(dtype)
+    batch = {name: cols64[i] for i, name in enumerate(wide)}
+    batch.update({name: cols32[i] for i, name in enumerate(narrow)})
+    return batch, meta[0], meta[1]
 
 
 def _psum_one_owner(x):
@@ -203,9 +245,8 @@ class _ShardGather:
 def sharded_create_transfers(mesh: Mesh, probed: bool = False):
     """Build the jitted sharded create_transfers step for ``mesh``.
 
-    Returns fn(ledger, batch, count, timestamp) -> (ledger, codes), with the
-    ledger sharded per make_sharded_ledger and batch/count/timestamp
-    replicated.
+    Returns fn(ledger, *stage_batch(...)) -> (ledger, codes), with the
+    ledger sharded per make_sharded_ledger and the staged batch replicated.
 
     ``probed`` (STATIC) additionally returns the per-shard transfers
     probe_overflow lanes widened into a FRESH uint32[n_shards] output —
@@ -216,7 +257,10 @@ def sharded_create_transfers(mesh: Mesh, probed: bool = False):
     n_shards = mesh.devices.size
     shift = n_shards.bit_length() - 1
 
-    def local_step(ledger: Ledger, batch, count, timestamp):
+    def local_step(ledger: Ledger, cols64, cols32, meta):
+        batch, count, timestamp = _unstage(
+            types.TRANSFER_DTYPE, cols64, cols32, meta
+        )
         acc, tr = ledger.accounts, ledger.transfers
         local_acc_cap = acc.capacity
 
@@ -287,14 +331,14 @@ def sharded_create_transfers(mesh: Mesh, probed: bool = False):
             return out, codes, transfers.probe_overflow.astype(jnp.uint32)
         return out, codes
 
-    def step(ledger, batch, count, timestamp):
+    def step(ledger, cols64, cols32, meta):
         out_specs = (_specs_like(ledger), P())
         if probed:
             out_specs = out_specs + (P(AXIS),)
         return shard_map(
             local_step,
             mesh=mesh,
-            in_specs=(_specs_like(ledger), _replicated_like(batch), P(), P()),
+            in_specs=(_specs_like(ledger), P(), P(), P()),
             out_specs=out_specs,
             # vma-checking is off because ht.lookup's probe while_loop mixes
             # replicated (keys) and shard-varying (table) carry values; the
@@ -302,7 +346,7 @@ def sharded_create_transfers(mesh: Mesh, probed: bool = False):
             # Correctness is covered by byte-parity vs single-chip in
             # tests/test_sharded.py instead.
             check_vma=False,
-        )(ledger, batch, count, timestamp)
+        )(ledger, cols64, cols32, meta)
 
     return _named_jit(
         step,
@@ -333,7 +377,7 @@ def sharded_create_transfers_full(
     exact docs/waves.md semantics, now on the mesh path.  On, a FOURTH
     replicated int32[11] wave-profile vector is returned.
 
-    Returns fn(ledger, batch, count, timestamp) -> (ledger, codes, kflags
+    Returns fn(ledger, *stage_batch(...)) -> (ledger, codes, kflags
     [, wave_vec]).
     """
     from ..ops import transfer_full as _tf
@@ -361,7 +405,10 @@ def sharded_create_transfers_full(
             },
         )
 
-    def local_step(ledger: Ledger, batch, count, timestamp):
+    def local_step(ledger: Ledger, cols64, cols32, meta):
+        batch, count, timestamp = _unstage(
+            types.TRANSFER_DTYPE, cols64, cols32, meta
+        )
         acc, tr, posted_t = ledger.accounts, ledger.transfers, ledger.posted
         n = batch["id_lo"].shape[0]
         with jax.named_scope("tb/shard_gather"):
@@ -526,17 +573,17 @@ def sharded_create_transfers_full(
             return out, plan.codes, kflags, wave_vec
         return out, plan.codes, kflags
 
-    def step(ledger, batch, count, timestamp):
+    def step(ledger, cols64, cols32, meta):
         out_specs = (_specs_like(ledger), P(), P())
         if use_waves:
             out_specs = out_specs + (P(),)
         return shard_map(
             local_step,
             mesh=mesh,
-            in_specs=(_specs_like(ledger), _replicated_like(batch), P(), P()),
+            in_specs=(_specs_like(ledger), P(), P(), P()),
             out_specs=out_specs,
             check_vma=False,  # see sharded_create_transfers' justification
-        )(ledger, batch, count, timestamp)
+        )(ledger, cols64, cols32, meta)
 
     return _named_jit(
         step,
@@ -577,11 +624,15 @@ def sharded_lookup(mesh: Mesh, table_name: str):
 
 
 def sharded_create_accounts(mesh: Mesh):
-    """Jitted sharded create_accounts step for ``mesh``."""
+    """Jitted sharded create_accounts step for ``mesh``:
+    fn(ledger, *stage_batch(...)) -> (ledger, codes)."""
     n_shards = mesh.devices.size
     shift = n_shards.bit_length() - 1
 
-    def local_step(ledger: Ledger, batch, count, timestamp):
+    def local_step(ledger: Ledger, cols64, cols32, meta):
+        batch, count, timestamp = _unstage(
+            types.ACCOUNT_DTYPE, cols64, cols32, meta
+        )
         acc = ledger.accounts
         g = _ShardGather(acc, batch["id_lo"], batch["id_hi"], n_shards, shift)
         lane = jnp.arange(batch["id_lo"].shape[0], dtype=jnp.int32)
@@ -599,11 +650,11 @@ def sharded_create_accounts(mesh: Mesh):
             )
         return ledger.replace(accounts=accounts), codes
 
-    def step(ledger, batch, count, timestamp):
+    def step(ledger, cols64, cols32, meta):
         return shard_map(
             local_step,
             mesh=mesh,
-            in_specs=(_specs_like(ledger), _replicated_like(batch), P(), P()),
+            in_specs=(_specs_like(ledger), P(), P(), P()),
             out_specs=(_specs_like(ledger), P()),
             # vma-checking is off because ht.lookup's probe while_loop mixes
             # replicated (keys) and shard-varying (table) carry values; the
@@ -611,7 +662,7 @@ def sharded_create_accounts(mesh: Mesh):
             # Correctness is covered by byte-parity vs single-chip in
             # tests/test_sharded.py instead.
             check_vma=False,
-        )(ledger, batch, count, timestamp)
+        )(ledger, cols64, cols32, meta)
 
     return _named_jit(
         step, "sharded_create_accounts", donate_argnames=("ledger",)
