@@ -78,7 +78,7 @@ class Served:
             await self._stop.wait()
             await self.server.close()
 
-        asyncio.run(main())
+        asyncio.run(main(), loop_factory=bus_mod.ServingLoop)  # run_server's
 
     def client(self, k: int) -> Client:
         return Client(self.address, cluster=CLUSTER, config=CONFIG,
@@ -211,6 +211,111 @@ def test_everything_off_costs_no_clock_no_record_no_observation(
     assert served.server._group_seq == 0
 
 
+# -- (b2) the serving thread's three states -----------------------------------
+
+
+SOCKET_READ_LOST = (
+    "no `socket_read` span for a readable socket: net/bus.py's ServingLoop "
+    "overrides asyncio's PRIVATE BaseSelectorEventLoop._add_reader (CPython "
+    "3.12); if this Python registers its readers another way, wrap that")
+
+
+def _serving_states(snapshot):
+    """(busy, socket wait, device wait, top-level spans outside the four
+    sections) of the serving thread, in microseconds."""
+    counters, histograms = snapshot["counters"], snapshot["histograms"]
+
+    def self_us(*names):
+        return sum(counters.get("txtrace.self_us.serving." + name, 0)
+                   for name in names)
+
+    return (counters.get("serve.busy_us", 0),
+            self_us("loop_wait"),
+            self_us("dispatch_wait", "readback", "full_sync"),
+            histograms.get("txtrace.stage.socket_read", {"sum": 0})["sum"])
+
+
+def test_the_loop_threads_time_is_sections_selector_and_socket_reads(served):
+    """Every instant of the bus's loop thread is in a section, in the
+    selector (`loop_wait`) or in a readable socket's callback: together
+    within 10 % of the elapsed time under load, and an idle server's time
+    is almost all `loop_wait`."""
+    with registry.enabled_scope():
+        before, t0 = registry.snapshot(), time.perf_counter()
+        codes = drive(served, rounds=24)
+        elapsed_us = (time.perf_counter() - t0) * 1e6
+        loaded = registry.snapshot()
+        assert all(c == [] for per in codes for c in per)
+        busy, loop_wait, device_wait, reads = (
+            b - a for a, b in zip(_serving_states(before),
+                                  _serving_states(loaded)))
+        assert busy > 0 and loop_wait > 0
+        assert reads > 0, SOCKET_READ_LOST
+        assert busy + loop_wait + reads == pytest.approx(elapsed_us, rel=0.10)
+        assert 0 < device_wait <= busy
+        # On one thread the self times sum to the top-level durations.
+        selfs = sum(
+            loaded["counters"][name] - before["counters"].get(name, 0)
+            for name in loaded["counters"]
+            if name.startswith("txtrace.self_us.serving."))
+        assert selfs == pytest.approx(busy + loop_wait + reads, rel=0.005)
+
+        t0 = time.perf_counter()
+        time.sleep(0.3)             # nobody connected: the loop sleeps
+        # A span is observed when it closes: end the selector's one wait.
+        woken = threading.Event()
+        served._loop.call_soon_threadsafe(woken.set)
+        assert woken.wait(10)
+        idle_us = (time.perf_counter() - t0) * 1e6
+        idle = registry.snapshot()
+        busy, loop_wait, device_wait, reads = (
+            b - a for a, b in zip(_serving_states(loaded),
+                                  _serving_states(idle)))
+        # (the closing connections' last callbacks may still fall in here,
+        # and the one wait began before the snapshot that opens this phase)
+        assert loop_wait == pytest.approx(idle_us, rel=0.10)
+        assert busy + reads <= 0.05 * idle_us and device_wait == 0
+
+
+def test_off_the_timed_selector_reads_no_clock_and_opens_no_span(monkeypatch):
+    """Off, `select` is the plain call (one branch) and a readable socket's
+    callback the plain callback; on, a poll opens no span and a wait one."""
+    assert not txtrace.active
+    clock = CountingClock()
+    monkeypatch.setattr(txtrace_mod, "time", clock)
+    opened = []
+    stage = txtrace.stage
+    monkeypatch.setattr(
+        txtrace, "stage", lambda name, **kw: opened.append(name) or stage(
+            name, **kw))
+    loop = bus_mod.ServingLoop()
+    try:
+        a, b = socket.socketpair()
+        got = []
+        loop.add_reader(a, lambda: got.append(a.recv(16)))
+        b.send(b"x")
+        loop.call_later(0.02, loop.stop)
+        loop.run_forever()          # a read, then one real wait
+        assert got == [b"x"] and opened == [] and clock.calls == 0
+        with registry.enabled_scope():
+            b.send(b"y")
+            loop.call_soon(loop.stop)
+            loop.run_forever()      # callbacks ready: polls only
+            assert got == [b"x", b"y"]
+            assert opened == ["socket_read"], SOCKET_READ_LOST
+            loop.call_later(0.02, loop.stop)
+            loop.run_forever()
+            assert opened[1:] and set(opened[1:]) == {"loop_wait"}
+            counters = registry.snapshot()["counters"]
+            assert counters["txtrace.self_us.serving.loop_wait"] >= 15_000
+            assert "serve.busy_us" not in counters
+        loop.remove_reader(a)
+        a.close()
+        b.close()
+    finally:
+        loop.close()
+
+
 # -- (c) served results are byte-identical on, traced and off ------------------
 
 
@@ -339,6 +444,9 @@ def test_profile_holds_nested_ordered_spans_on_their_threads(tmp_path):
         for name, _s, _e, stats in line:
             assert stats["seq"] == 41, (name, stats)
     assert order[0][3]["n"] == 3
+    # ... and its thread's role, so a line of the profile says who it is.
+    for line, role in ((serving, "serving"), (lane, "lane"), (io, "io")):
+        assert {e[3]["role"] for e in by_line[line]} == {role}
 
 
 # -- (f) the counters follow a scripted sequence of arrivals -------------------
@@ -369,7 +477,7 @@ def test_counters_follow_scripted_arrivals(served):
         assert counters["pipeline.flush.idle"] == 3
         assert counters["pipeline.groups"] - base["pipeline.groups"] == 3
         # Lone requests ride the fast kernel, not the grouped scan.
-        assert "ops.group.steps" not in counters
+        assert "ops.group.batches" not in counters
 
         # A frame whose header is in and whose body is not: the connection
         # counts as arriving at every pickup until the body comes.
@@ -411,7 +519,7 @@ def test_counters_follow_scripted_arrivals(served):
             assert snap["histograms"][f"txtrace.stage.{name}"]["count"] > 0
 
 
-def test_group_scan_counters_count_useful_and_run_steps(tmp_path):
+def test_group_scan_counter_counts_the_batches_held(tmp_path):
     h = ReplicaHarness(str(tmp_path), "scan", depth=2, group=True)
     try:
         clients = [0x800 + i for i in range(3)]
@@ -427,12 +535,9 @@ def test_group_scan_counters_count_useful_and_run_steps(tmp_path):
                         for k, c in enumerate(clients[:width])]
                 h.serve(reqs)[1].result()
                 counters = registry.snapshot()["counters"]
-                # 3 steps for 3, then 2 more for 2 more (never GROUP_K a
-                # group); a lone request rides the fast kernel and moves
-                # neither.
-                want = {0: (3, 3), 1: (5, 5), 2: (5, 5)}[n]
-                assert (counters["ops.group.batches"],
-                        counters["ops.group.steps"]) == want
+                # 3 for a group of 3, then 2 more for 2 more; a lone
+                # request rides the fast kernel and does not move it.
+                assert counters["ops.group.batches"] == (3, 5, 5)[n]
     finally:
         h.close()
 

@@ -19,7 +19,7 @@ from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
 from tigerbeetle_tpu.obs import txtrace as txtrace_mod
 from tigerbeetle_tpu.obs.metrics import registry
-from tigerbeetle_tpu.obs.txtrace import NESTED_STAGES, STAGES, txtrace
+from tigerbeetle_tpu.obs.txtrace import STAGES, txtrace
 from tigerbeetle_tpu.vsr.replica import Replica
 
 # -- start's table options -----------------------------------------------------
@@ -288,10 +288,17 @@ def test_one_general_request_one_span_with_its_children(warm_machine):
         snapshot = registry.snapshot()
     counters, histograms = snapshot["counters"], snapshot["histograms"]
     assert {k: v["count"] for k, v in totals.items()} == dict.fromkeys(
-        ("device_execute", "general_commit") + CHILDREN, 1)
+        ("device_execute", "route", "general_commit") + CHILDREN, 1)
     assert set(totals) <= set(STAGES)
-    assert set(CHILDREN) - {"stage_h2d"} <= set(NESTED_STAGES)
-    assert "general_commit" in NESTED_STAGES
+    # One thread, one top-level span: the self times sum to its duration
+    # (`stage_h2d`, a child here, is counted once), and the route's own
+    # time is what its five children leave of it.
+    assert sum(v["self_us"] for v in totals.values()) == pytest.approx(
+        totals["device_execute"]["us"], abs=1.0)
+    assert totals["general_commit"]["self_us"] == pytest.approx(
+        totals["general_commit"]["us"]
+        - sum(totals[c]["us"] for c in CHILDREN), abs=1.0)
+    assert all(totals[c]["self_us"] == totals[c]["us"] for c in CHILDREN)
     # Nested: the route inside the closure, the children inside the route.
     assert totals["device_execute"]["us"] >= totals["general_commit"]["us"]
     assert totals["general_commit"]["us"] >= sum(

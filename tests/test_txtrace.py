@@ -9,6 +9,7 @@ dumps, VOPR failing seeds carrying per-replica history).
 """
 
 import json
+import threading
 import time
 
 import pytest
@@ -16,8 +17,8 @@ import pytest
 from tigerbeetle_tpu import types
 from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
+from tigerbeetle_tpu.obs.metrics import registry
 from tigerbeetle_tpu.obs.txtrace import (
-    NESTED_STAGES,
     REPLICA_PID_BASE,
     STAGES,
     Blackbox,
@@ -163,6 +164,12 @@ def test_stage_free_when_inactive(monkeypatch, site):
     monkeypatch.setattr(
         txtrace_mod, "_StageSpan",
         lambda *a: pytest.fail("an inactive stage built a span"))
+
+    class NoThreadState:
+        def __getattr__(self, name):
+            pytest.fail(f"an inactive stage read the thread's {name}")
+
+    monkeypatch.setattr(txtrace_mod, "_thread", NoThreadState())
     off = txtrace.stage(**site)
     assert off is txtrace.stage("grow") is txtrace_mod._STAGE_OFF
     with off:
@@ -190,9 +197,165 @@ def test_stage_nests_and_bills_each_name_once():
     assert totals["device_execute"]["us"] >= (
         totals["grow"]["us"] + totals["dispatch"]["us"])
     assert set(totals) <= set(STAGES)
-    # A sum that wants wall time skips the nested names.
-    assert sum(v["us"] for k, v in totals.items()
-               if k not in NESTED_STAGES) == totals["device_execute"]["us"]
+    # A sum that wants wall time takes the self times: on one thread they
+    # add up to the top-level span, and a parent's leaves its children out.
+    assert sum(v["self_us"] for v in totals.values()) == pytest.approx(
+        totals["device_execute"]["us"], abs=0.5)
+    assert totals["device_execute"]["self_us"] == pytest.approx(
+        totals["device_execute"]["us"] - totals["grow"]["us"]
+        - totals["dispatch"]["us"], abs=0.5)
+    assert totals["grow"]["self_us"] == totals["grow"]["us"] >= 2000
+
+
+def _self_counters(snapshot):
+    prefix = "txtrace.self_us."
+    return {name[len(prefix):]: value
+            for name, value in snapshot["counters"].items()
+            if name.startswith(prefix)}
+
+
+def test_self_times_of_one_thread_sum_to_its_top_level_spans():
+    """`stage_h2d` is top-level on the grouped route and a child on the
+    blocking ones: either way its time is counted once."""
+    with registry.enabled_scope(), txtrace.attribution_scope():
+        with txtrace.stage("commit_group"):
+            with txtrace.stage("stage_h2d"):          # top-level staging
+                time.sleep(0.002)
+            with txtrace.stage("device_execute"):
+                with txtrace.stage("general_commit"):
+                    with txtrace.stage("stage_h2d"):  # the route's own
+                        time.sleep(0.003)
+                    with txtrace.stage("full_sync"):
+                        time.sleep(0.001)
+            time.sleep(0.001)
+        with txtrace.stage("reply_release"):
+            time.sleep(0.001)
+        totals = txtrace.stage_totals()
+        snapshot = registry.snapshot()
+    top = totals["commit_group"]["us"] + totals["reply_release"]["us"]
+    assert sum(v["self_us"] for v in totals.values()) == pytest.approx(
+        top, abs=1.0)
+    assert totals["stage_h2d"]["count"] == 2
+    assert totals["stage_h2d"]["self_us"] == totals["stage_h2d"]["us"] >= 5000
+    assert totals["general_commit"]["self_us"] == pytest.approx(
+        totals["general_commit"]["us"] - 3000 - 1000, abs=900)
+    assert 1000 <= totals["commit_group"]["self_us"] < 2000
+    # The registry keeps the same self times, by the thread's role (this
+    # one: any thread that no pool named is `serving`), whole microseconds.
+    selfs = _self_counters(snapshot)
+    assert set(selfs) == {"serving." + name for name in totals}
+    for name, v in totals.items():
+        assert selfs["serving." + name] == pytest.approx(
+            v["self_us"], abs=v["count"])
+    # `txtrace.stage.<name>` holds what it held: durations, one a span.
+    assert snapshot["histograms"]["txtrace.stage.stage_h2d"]["count"] == 2
+    assert snapshot["counters"]["serve.busy_us"] == pytest.approx(top, abs=2)
+
+
+def test_an_exception_unwinds_the_span_stack():
+    from tigerbeetle_tpu.obs import txtrace as txtrace_mod
+
+    with txtrace.attribution_scope():
+        with pytest.raises(RuntimeError):
+            with txtrace.stage("commit_group"):
+                with txtrace.stage("prepare"):
+                    time.sleep(0.001)
+                    raise RuntimeError("mid-span")
+        assert txtrace_mod._thread.stack == []
+        with txtrace.stage("reply_release"):  # a new top-level span
+            time.sleep(0.001)
+        totals = txtrace.stage_totals()
+    assert {k: v["count"] for k, v in totals.items()} == {
+        "commit_group": 1, "prepare": 1, "reply_release": 1}
+    assert totals["commit_group"]["self_us"] == pytest.approx(
+        totals["commit_group"]["us"] - totals["prepare"]["us"], abs=0.5)
+    assert totals["reply_release"]["self_us"] == totals["reply_release"]["us"]
+
+
+@pytest.mark.parametrize("thread_name, role", [
+    ("tb-dispatch_0", "lane"),
+    ("tb-wal-fsync_0", "io"),
+    ("tb-checkpoint", "checkpoint"),
+    ("Thread-7 (serve)", "serving"),
+])
+def test_a_span_lands_under_its_threads_role(thread_name, role):
+    """The same name on two threads: one histogram of durations as before,
+    the self time by role, and for `device_execute` alone (a deferred
+    closure against a blocking commit) the duration by role too."""
+    def work():
+        with txtrace.stage("device_execute", seq=5):
+            with txtrace.stage("dispatch", seq=5):
+                time.sleep(0.001)
+            with txtrace.stage("merkle_refresh", seq=5):
+                pass
+
+    with registry.enabled_scope():
+        worker = threading.Thread(target=work, name=thread_name)
+        worker.start()
+        worker.join()
+        work()                      # and here, on the main thread
+        snapshot = registry.snapshot()
+    histograms = snapshot["histograms"]
+    roles = {role, "serving"}
+    both = 2 if role == "serving" else 1
+    assert set(_self_counters(snapshot)) == {
+        f"{r}.{name}" for r in roles
+        for name in ("device_execute", "dispatch", "merkle_refresh")}
+    by_role = sorted(n for n in histograms if n.count(".") == 3)
+    assert by_role == sorted(
+        f"txtrace.stage.device_execute.{r}" for r in roles)
+    assert histograms["txtrace.stage.dispatch"]["count"] == 2
+    assert histograms["txtrace.stage.device_execute"]["count"] == 2
+    assert histograms[
+        f"txtrace.stage.device_execute.{role}"]["count"] == both
+    assert sum(histograms[f"txtrace.stage.device_execute.{r}"]["sum"]
+               for r in roles) == pytest.approx(
+        histograms["txtrace.stage.device_execute"]["sum"])
+
+
+def test_device_wait_counts_the_serving_threads_own_sleep_only():
+    """Device wait is the SELF time of `dispatch_wait`, `readback` and
+    `full_sync` on role `serving` (`serving_work_pct` sums those three
+    counters): a lane thread's lands under its own role, a child's time is
+    not counted twice, and the selector's span is neither busy nor wait."""
+    def lane():
+        with txtrace.stage("readback"):
+            time.sleep(0.002)
+
+    with registry.enabled_scope(), txtrace.attribution_scope():
+        with txtrace.stage("pipeline_flush"):
+            with txtrace.stage("dispatch_wait"):
+                time.sleep(0.002)
+            with txtrace.stage("readback"):
+                time.sleep(0.001)
+            with txtrace.stage("phase_b"):
+                time.sleep(0.001)
+        with txtrace.stage("full_sync"):
+            with txtrace.stage("grow"):        # not a wait: left out
+                time.sleep(0.002)
+            time.sleep(0.001)
+        totals = txtrace.stage_totals()
+        worker = threading.Thread(target=lane, name="tb-dispatch_0")
+        worker.start()
+        worker.join()
+        busy = registry.snapshot()["counters"]["serve.busy_us"]
+        txtrace.stage_observe("loop_wait", 1500.0)
+        counters = registry.snapshot()["counters"]
+    selfs = _self_counters({"counters": counters})
+    wait = sum(selfs["serving." + name]
+               for name in ("dispatch_wait", "readback", "full_sync"))
+    want = (totals["dispatch_wait"]["us"] + totals["readback"]["us"]
+            + totals["full_sync"]["self_us"])
+    assert wait == pytest.approx(want, abs=3)
+    assert 4000 <= wait < totals["full_sync"]["us"] + (
+        totals["dispatch_wait"]["us"] + totals["readback"]["us"])
+    assert selfs["lane.readback"] >= 2000
+    assert selfs["serving.loop_wait"] == 1500
+    assert counters["serve.busy_us"] == busy == int(
+        totals["pipeline_flush"]["us"])
+    # No second series says what the self times say already.
+    assert {n for n in counters if n.startswith("serve.")} == {
+        "serve.busy_us"}
 
 
 def test_machine_commit_bills_device_execute():

@@ -2244,14 +2244,18 @@ class TpuStateMachine:
         if self._engine is not None:
             return self._engine_commit("create_transfers", batch, timestamp)
 
-        self._note_balance_bound(batch)
+        with txtrace.stage("route"):
+            self._note_balance_bound(batch)
+            fast = not (
+                self.force_sequential or self._ledger_is_sharded
+            ) and self._fast_path_ok(batch)
         if self.force_sequential:
             return self._sequential("create_transfers", batch, timestamp)
 
         if self._ledger_is_sharded:
             return self._sharded_commit_transfers(batch, timestamp, count)
 
-        if self._fast_path_ok(batch):
+        if fast:
             return self._commit_fast(batch, timestamp, count)
 
         with txtrace.stage("general_commit", n=count):
@@ -2420,9 +2424,11 @@ class TpuStateMachine:
             # throughput while the tier is active.
             return self._sequential("create_transfers", batch, timestamp)
 
-        self._note_cross_shard(batch, count)
-        self._note_shard_inserts("transfers", batch, count)
-        if self._fast_path_ok(batch):
+        with txtrace.stage("route"):
+            self._note_cross_shard(batch, count)
+            self._note_shard_inserts("transfers", batch, count)
+            fast = self._fast_path_ok(batch)
+        if fast:
             if _obs.enabled:
                 _obs.counter("ops.route.fast").inc()
             with txtrace.stage("grow"):
@@ -3154,11 +3160,12 @@ class TpuStateMachine:
         # ratchet the monotonic bound toward the 2^126 threshold and
         # permanently cost the fast path (ADVICE r4).
         bound0 = self._balance_bound
-        for b in batches:
-            self._note_balance_bound(b)
-            if not self._fast_path_ok(b):
-                self._balance_bound = bound0
-                return None
+        with txtrace.stage("route", n=len(batches)):
+            for b in batches:
+                self._note_balance_bound(b)
+                if not self._fast_path_ok(b):
+                    self._balance_bound = bound0
+                    return None
         if timestamps[-1] > self.prepare_timestamp:
             # Replay/backup parity with commit_batch's clock catch-up.
             self.prepare_timestamp = timestamps[-1]
@@ -3174,9 +3181,8 @@ class TpuStateMachine:
             )
         k = len(batches)
         if _obs.enabled:
-            # Useful steps over steps run: the loop runs one a batch.
+            # Batches held: the loop runs one step a batch.
             _obs.counter("ops.group.batches").inc(k)
-            _obs.counter("ops.group.steps").inc(k)
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d", n=k):
             stacked, stage = self._stage_group(batches)
@@ -3273,16 +3279,16 @@ class TpuStateMachine:
         k = len(batches)
         total = 0
         owner_sum = np.zeros(max(self.shards, 1), np.int64)
-        for b, c in zip(batches, counts):
-            self._note_cross_shard(b, c)
-            owners = self._note_shard_inserts("transfers", b, c)
-            if owners is not None:
-                owner_sum += owners
-            total += c
+        with txtrace.stage("route", n=k):
+            for b, c in zip(batches, counts):
+                self._note_cross_shard(b, c)
+                owners = self._note_shard_inserts("transfers", b, c)
+                if owners is not None:
+                    owner_sum += owners
+                total += c
         if _obs.enabled:
             # K per-batch dispatches, no padded steps on this route.
             _obs.counter("ops.group.batches").inc(k)
-            _obs.counter("ops.group.steps").inc(k)
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d", n=k):
             # Serving-thread staging: K puts, each already on the mesh.
@@ -3386,14 +3392,15 @@ class TpuStateMachine:
         ):
             return None
         bound0 = self._balance_bound
-        self._note_balance_bound(batch)
-        if not self._fast_path_ok(batch):
-            # The blocking fallback re-notes the batch itself; leaving this
-            # note in place would double-count it against the monotonic
-            # bound (same discipline as commit_group_fast's mid-run
-            # refusal).
-            self._balance_bound = bound0
-            return None
+        with txtrace.stage("route"):
+            self._note_balance_bound(batch)
+            if not self._fast_path_ok(batch):
+                # The blocking fallback re-notes the batch itself; leaving
+                # this note in place would double-count it against the
+                # monotonic bound (same discipline as commit_group_fast's
+                # mid-run refusal).
+                self._balance_bound = bound0
+                return None
         if timestamp > self.prepare_timestamp:
             # Replay/backup parity with commit_batch's clock catch-up.
             self.prepare_timestamp = timestamp
@@ -3405,8 +3412,9 @@ class TpuStateMachine:
             )
         owners = None
         if self._ledger_is_sharded:
-            self._note_cross_shard(batch, count)
-            owners = self._note_shard_inserts("transfers", batch, count)
+            with txtrace.stage("route"):
+                self._note_cross_shard(batch, count)
+                owners = self._note_shard_inserts("transfers", batch, count)
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d"):
             # Staged on the serving thread, for the route's own programs.

@@ -29,7 +29,8 @@ from ..obs.txtrace import txtrace
 from ..vsr import overload, wire
 from ..vsr.consensus import VsrReplica
 from .bus import (
-    STATSD_FLUSH_INTERVAL_S, FrameError, _count_reject, read_message,
+    STATSD_FLUSH_INTERVAL_S, FrameError, ServingLoop, _count_reject,
+    read_message,
 )
 
 log = logging.getLogger("tigerbeetle_tpu.net.cluster")
@@ -564,6 +565,6 @@ def run_cluster_server(
         await server.serve_forever()
 
     try:
-        asyncio.run(main())
+        asyncio.run(main(), loop_factory=ServingLoop)
     except KeyboardInterrupt:
         pass

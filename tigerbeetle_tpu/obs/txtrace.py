@@ -20,7 +20,14 @@ the registry is on), in the in-process total table that
 ``stage_totals()`` returns, and — while a ``jax.profiler``
 session is on — as a ``tb.<name>`` event on that thread's line of the
 profile's host plane, on the device trace's clock.  Spans nest and
-overlap; they do not sum.  *Request intervals*: the bus stamps every
+overlap; they do not sum, but their SELF times do, a thread: a span's
+duration minus what the spans opened inside it on the same thread took
+(one stack a thread; ``txtrace.self_us.<role>.<name>``, the role being
+the thread's: ``serving``, ``lane``, ``io``, ``checkpoint``).  The
+serving thread's selector is a span too (``loop_wait``, net/bus.py), so
+every instant of that thread is socket wait, device wait (the self time
+of ``dispatch_wait``, ``readback`` and ``full_sync`` there) or work.
+*Request intervals*: the bus stamps every
 commit group (``GroupTimeline``) and at reply release observes, once per
 request, six consecutive ``txtrace.request.*`` intervals that sum to the
 request's time in the server exactly.
@@ -57,9 +64,11 @@ REPLICA_PID_BASE = 1 << 18
 # The thread-span vocabulary, in pipeline order (docs/tracing.md has the
 # site, the thread and the bounds of each).  ``tb.<name>`` in a profile.
 STAGES = (
+    "socket_read",      # bus: the loop's callback for a readable socket
     "ingress_verify",   # bus: body checksum of one ingress frame
     "commit_group",     # bus: the synchronous replica call for one group
     "prepare",          # replica: header assign + hash chain per request
+    "route",            # machine: a run's eligibility checks, at submit
     "stage_h2d",        # machine: staging fill + device_put of a run
     "device_execute",   # machine: the commit closure (lane thread if deferred)
     "general_commit",   # ... the general kernel's blocking route, whole
@@ -76,26 +85,44 @@ STAGES = (
     "readback",         # machine: deferred D2H resolve (codes readback)
     "phase_b",          # replica: bookkeeping + reply build per op
     "reply_release",    # bus: reply writes of one group
+    "loop_wait",        # bus: the event loop asleep in its selector
 )
 
-# Stages that only ever run INSIDE another stage's block on the same
-# thread: ``device_execute``'s children.  A sum over stages that wants
-# wall time leaves them out; on the blocking routes alone (the general
-# route, single-device or sharded, and the sharded blocking fast route)
-# ``stage_h2d`` is nested too (the staging is part of the blocking
-# closure), so such a sum over those requests counts it twice.  The
-# bus's sections below hold the replica's and the machine's
-# serving-thread spans the same way: a sum that takes the sections takes
-# nothing else of that thread.
-NESTED_STAGES = ("general_commit", "grow", "dispatch", "full_sync",
-                 "index_append", "merkle_refresh")
-
 # The serving thread's top-level synchronous sections (bus sites, never
-# nested in one another): their durations add up to ``serve.busy_us``,
-# which over a window is the one serving thread's occupancy.
+# nested in one another): their durations add up to ``serve.busy_us``.
+# The thread is "busy" in them while it blocks on the device;
+# ``loop_wait`` is not one of them.
 SERVING_SECTIONS = frozenset(
     ("ingress_verify", "commit_group", "pipeline_flush", "reply_release")
 )
+
+# The one name whose duration the registry also keeps by role,
+# ``txtrace.stage.device_execute.<role>``: a deferred closure on the lane
+# thread (`lane_closure_ms`), a blocking commit on the serving thread
+# (`blocking_commit_ms`).  The other names that run on both threads are
+# split by ``txtrace.self_us.<role>.<name>``.
+BY_ROLE_STAGE = "device_execute"
+
+# A thread's role, by the name its pool or its starter gave it; any other
+# thread (the bus's loop thread; the main thread of a test, a tool or a
+# simulator) is ``serving``.
+_ROLE_PREFIXES = (("tb-dispatch", "lane"), ("tb-wal-fsync", "io"),
+                  ("tb-checkpoint", "checkpoint"))
+
+
+class _ThreadSpans(threading.local):
+    """One thread's role and its stack of open spans; made at the thread's
+    first ACTIVE span (``threading.local`` runs ``__init__`` once a thread)."""
+
+    def __init__(self) -> None:
+        name = threading.current_thread().name
+        self.role = next(
+            (role for prefix, role in _ROLE_PREFIXES
+             if name.startswith(prefix)), "serving")
+        self.stack: List["_StageSpan"] = []
+
+
+_thread = _ThreadSpans()
 
 # Consecutive intervals of one request inside the server; they sum to
 # ``total`` exactly (integer microseconds of one clock).
@@ -164,9 +191,11 @@ class GroupTimeline:
 
 class _StageSpan:
     """One open ``txtrace.stage`` block: a TraceMe annotation held open
-    (the profiler's clock) around a perf_counter_ns duration."""
+    (the profiler's clock) around a perf_counter_ns duration, and a frame
+    on its thread's stack: the spans that close inside it add their
+    durations to ``_children_us``, and what is left is its self time."""
 
-    __slots__ = ("_tx", "_name", "_annotation", "_t0")
+    __slots__ = ("_tx", "_name", "_annotation", "_t0", "_children_us")
 
     def __init__(self, tx, name: str, annotation) -> None:
         self._tx = tx
@@ -174,13 +203,20 @@ class _StageSpan:
         self._annotation = annotation
 
     def __enter__(self) -> None:
+        self._children_us = 0.0
+        _thread.stack.append(self)
         self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
 
     def __exit__(self, *exc) -> bool:
         us = (time.perf_counter_ns() - self._t0) / 1e3
         self._annotation.__exit__(*exc)
-        self._tx.stage_observe(self._name, us)
+        stack = _thread.stack
+        stack.pop()
+        if stack:
+            stack[-1]._children_us += us
+        self._tx.stage_observe(self._name, us, us - self._children_us,
+                               _thread.role)
         return False
 
 
@@ -231,8 +267,8 @@ class TxTracer:
         self._trace_annotation = None  # jax.profiler's, on first use
         self._seq = 0
         self._lock = threading.Lock()
-        # name -> [count, total_us]; plain dict + lock (stage sites are
-        # hot-path-adjacent, but only ever taken when attribution is on).
+        # name -> [count, total_us, self_us]; plain dict + lock (stage sites
+        # are hot-path-adjacent, but only ever taken when attribution is on).
         self._stages: Dict[str, List[float]] = {}
         self._pids_named: set = set()
 
@@ -335,31 +371,46 @@ class TxTracer:
 
     # -- stage ledger (attribution) ------------------------------------------
 
-    def stage_observe(self, name: str, us: float) -> None:
-        """Record one thread-span duration (``stage`` calls this; a site
-        that has a duration already guards on ``txtrace.active`` BEFORE
-        reading any clock)."""
+    def stage_observe(self, name: str, us: float,
+                      self_us: Optional[float] = None,
+                      role: Optional[str] = None) -> None:
+        """Record one thread span: its duration, its self time (default:
+        all of it, a span with no child) and its thread's role (default:
+        the calling thread's).  ``stage`` calls this; a site that has a
+        duration already guards on ``txtrace.active`` BEFORE reading any
+        clock."""
+        if self_us is None:
+            self_us = us
+        if role is None:
+            role = _thread.role
         if _obs.enabled:
             _obs.histogram(f"txtrace.stage.{name}", "us").observe(us)
+            _obs.counter(f"txtrace.self_us.{role}.{name}").inc(
+                int(self_us + 0.5))
+            if name == BY_ROLE_STAGE:
+                _obs.histogram(
+                    f"txtrace.stage.{name}.{role}", "us").observe(us)
             if name in SERVING_SECTIONS:
                 _obs.counter("serve.busy_us").inc(int(us))
         if self.attribution:
             with self._lock:
                 slot = self._stages.get(name)
                 if slot is None:
-                    slot = self._stages[name] = [0, 0.0]
+                    slot = self._stages[name] = [0, 0.0, 0.0]
                 slot[0] += 1
                 slot[1] += us
+                slot[2] += self_us
 
     def stage(self, name: Optional[str], seq: int = 0, n: int = 0):
         """Timed thread span, the only way a commit-path site times a
         block.  Inactive (or ``name`` None) it hands back one shared no-op:
         no clock read, nothing allocated.  Active it observes
-        ``txtrace.stage.<name>`` and holds a ``tb.<name>`` TraceMe
-        annotation open for the block, with the group's sequence number
-        (``seq``, default: the group the serving thread is in; sites that
-        run later or on another thread pass the one they captured at
-        submit) and, where the site knows it, a count ``n``."""
+        ``txtrace.stage.<name>`` and its self time, and holds a
+        ``tb.<name>`` TraceMe annotation open for the block, with the
+        group's sequence number (``seq``, default: the group the serving
+        thread is in; sites that run later or on another thread pass the
+        one they captured at submit), where the site knows it a count
+        ``n``, and the thread's ``role``."""
         if name is None or not self.active:
             return _STAGE_OFF
         annotation = self._trace_annotation
@@ -371,7 +422,7 @@ class TxTracer:
 
             annotation = self._trace_annotation = TraceAnnotation
         return _StageSpan(self, name, annotation(
-            "tb." + name, seq=seq or self.group_seq, n=n
+            "tb." + name, seq=seq or self.group_seq, n=n, role=_thread.role
         ))
 
     def group_begin(self, seq: int) -> GroupTimeline:
@@ -390,11 +441,14 @@ class TxTracer:
             _obs.histogram(series, "us").observe(us)
 
     def stage_totals(self) -> Dict[str, dict]:
-        """Accumulated {stage: {count, us}} since the last reset."""
+        """Accumulated {stage: {count, us, self_us}} since the last reset.
+        Over the spans of one thread the ``self_us`` sum to the ``us`` of
+        its top-level spans."""
         with self._lock:
             return {
-                name: {"count": c, "us": round(us, 1)}
-                for name, (c, us) in sorted(self._stages.items())
+                name: {"count": c, "us": round(us, 1),
+                       "self_us": round(self_us, 1)}
+                for name, (c, us, self_us) in sorted(self._stages.items())
             }
 
     def reset_stages(self) -> None:
