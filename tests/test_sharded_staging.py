@@ -9,6 +9,8 @@ program compiled, because warm-up stages as the routes do; every sharded
 request took that staging (`sharding.staged`); and a staged batch is zero
 beyond its count, the pad contract the kernels rely on."""
 
+import threading
+
 import jax
 import numpy as np
 import pytest
@@ -138,9 +140,142 @@ def test_every_sharded_request_is_staged_on_the_mesh():
     assert counters["sharding.batches"] == batches
     assert counters["sharding.staged"] == batches + 1   # the account request
     assert counters.get("sharding.seq_fallbacks", 0) == 0
-    # Each staging sits inside a `stage_h2d` span: one a request, the
-    # grouped run's three under one.
-    assert totals["stage_h2d"]["count"] == 1 + 2 * len(ROUTES)
+    # Each staging sits inside a `stage_h2d` span of its own: one a
+    # request, and one a batch of the grouped run (staged right before its
+    # own enqueue, PR 40), which alone count as staged on the lane.
+    assert totals["stage_h2d"]["count"] == 1 + batches
+    assert counters["sharding.staged.lane"] == 2 * 3
+
+
+def _run(first_id, k):
+    """A run of ``k`` batches whose codes are not all OK: batch j's lane 2
+    debits an account that does not exist, its lane 4 repeats lane 3's id
+    with another amount."""
+    batches = []
+    for j in range(k):
+        batch = _transfers(first_id + 100 * j, 9 + j)
+        batch["debit_account_id_lo"][2] = 999
+        batch["id_lo"][4] = batch["id_lo"][3]
+        batches.append(batch)
+    return batches
+
+
+def _recorded(m, monkeypatch, events):
+    """Every staging and every enqueue of ``m`` appended to ``events`` as
+    (what, the thread's name), in the order they happen."""
+    stage_batch = sharded.stage_batch
+
+    def staging(*args):
+        events.append(("stage", threading.current_thread().name))
+        return stage_batch(*args)
+
+    def recording(step):
+        def call(ledger, *operands):
+            events.append(("dispatch", threading.current_thread().name))
+            return step(ledger, *operands)
+        return call
+
+    monkeypatch.setattr(sharded, "stage_batch", staging)
+    m._shard_steps = {k: recording(v) for k, v in m._shard_steps.items()}
+
+
+@pytest.mark.parametrize("k", [2, 7])
+def test_a_grouped_run_is_staged_on_the_lane_batch_by_batch(k, monkeypatch):
+    m = _machine()
+    assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
+    batches = _run(40_000, k)
+    stamps = [m.prepare("create_transfers", len(b), 0) for b in batches]
+    events = []
+    _recorded(m, monkeypatch, events)
+    with registry.enabled_scope(), txtrace.attribution_scope():
+        before = registry.snapshot()
+        handle = m.commit_group_fast(batches, stamps, deferred=True)
+        handle.resolve()
+        after = registry.snapshot()
+
+    def rose(kind, name):
+        return after[kind].get(name, 0) - before[kind].get(name, 0)
+
+    # Stage 0, dispatch 0, stage 1, dispatch 1, ...: each batch staged
+    # right before its own enqueue, and all of it on the lane's one thread.
+    assert [what for what, _ in events] == ["stage", "dispatch"] * k
+    assert {thread.split("_")[0] for _, thread in events} == {"tb-dispatch"}
+    assert threading.current_thread().name.split("_")[0] != "tb-dispatch"
+    assert rose("counters", "sharding.staged.lane") == k
+    assert rose("counters", "sharding.staged") == k
+    assert rose("counters", "sharding.batches") == k
+    # The spans say the same: K of them, their self time the lane's alone.
+    spans = after["histograms"]["txtrace.stage.stage_h2d"]["count"]
+    spans -= before["histograms"].get(
+        "txtrace.stage.stage_h2d", {"count": 0})["count"]
+    assert spans == k
+    assert rose("counters", "txtrace.self_us.lane.stage_h2d") > 0
+    assert rose("counters", "txtrace.self_us.serving.stage_h2d") == 0
+
+
+@pytest.mark.parametrize("k", [2, 7])
+def test_a_grouped_run_equals_the_blocking_route_batch_by_batch(k):
+    grouped, blocking = _machine(), _machine()
+    overflow = {"grouped": [], "blocking": []}
+    step = grouped._shard_steps["fast_probed"]
+
+    def probed(ledger, *operands):
+        out = step(ledger, *operands)
+        overflow["grouped"].append(np.asarray(out[2]))
+        return out
+
+    grouped._shard_steps = dict(grouped._shard_steps, fast_probed=probed)
+    for m in (grouped, blocking):
+        assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
+    batches = _run(50_000, k)
+    stamps = [grouped.prepare("create_transfers", len(b), 0) for b in batches]
+    got = grouped.commit_group_fast(batches, stamps, deferred=True).resolve()
+    want = []
+    for batch in batches:
+        want.append(blocking.create_transfers(batch))
+        overflow["blocking"].append(
+            np.asarray(blocking.ledger.transfers.probe_overflow))
+    assert got == want and all(len(r) == 2 for r in got)
+    assert len(overflow["grouped"]) == k
+    for mine, theirs in zip(overflow["grouped"], overflow["blocking"]):
+        assert mine.shape == (SHARDS,) and np.array_equal(mine, theirs)
+    ids = list(range(1, N_ACCOUNTS + 1))
+    mine, theirs = grouped.lookup_accounts(ids), blocking.lookup_accounts(ids)
+    for name in ("debits_posted_lo", "credits_posted_lo",
+                 "debits_pending_lo", "credits_pending_lo"):
+        assert np.array_equal(mine[name], theirs[name]), name
+    assert mine["debits_posted_lo"].sum() > 0
+    sample = [int(b["id_lo"][0]) for b in batches]
+    assert np.array_equal(
+        grouped.lookup_transfers(sample)["amount_lo"],
+        blocking.lookup_transfers(sample)["amount_lo"])
+
+
+def test_a_staging_that_fails_on_the_lane_fails_the_resolve(monkeypatch):
+    m = _machine()
+    assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
+    batches = _run(60_000, 3)
+    stamps = [m.prepare("create_transfers", len(b), 0) for b in batches]
+    stage_batch, calls = sharded.stage_batch, []
+
+    def failing(*args):
+        calls.append(threading.current_thread().name)
+        if len(calls) == 2:
+            raise ValueError("staging failed")
+        return stage_batch(*args)
+
+    monkeypatch.setattr(sharded, "stage_batch", failing)
+    handle = m.commit_group_fast(batches, stamps, deferred=True)
+    # The submit itself staged nothing and raised nothing: the failure is
+    # the lane's, and comes out of the handle as a failed enqueue does.
+    with pytest.raises(ValueError, match="staging failed"):
+        handle.resolve()
+    assert len(calls) == 2 and calls[0].startswith("tb-dispatch")
+    # The chain stands where the last good execution left it: batch 0 is
+    # in the ledger, batches 1 and 2 are not, and the next commit works.
+    found = m.lookup_transfers([int(b["id_lo"][0]) for b in batches])
+    assert [int(i) for i in found["id_lo"]] == [int(batches[0]["id_lo"][0])]
+    assert m.create_transfers(_transfers(70_000, 5)) == []
 
 
 @pytest.mark.parametrize("n", [0, 1, 37, LANES])

@@ -237,9 +237,12 @@ def test_self_times_of_one_thread_sum_to_its_top_level_spans():
         top, abs=1.0)
     assert totals["stage_h2d"]["count"] == 2
     assert totals["stage_h2d"]["self_us"] == totals["stage_h2d"]["us"] >= 5000
-    assert totals["general_commit"]["self_us"] == pytest.approx(
-        totals["general_commit"]["us"] - 3000 - 1000, abs=900)
-    assert 1000 <= totals["commit_group"]["self_us"] < 2000
+    # A span's self time leaves its children out.  A sleep lasts at least
+    # what it was asked for and, on a loaded machine, milliseconds more: the
+    # children's sleeps bound a self time from above, never to +-0.9 ms.
+    general, group = totals["general_commit"], totals["commit_group"]
+    assert 0 <= general["self_us"] <= general["us"] - 3000 - 1000
+    assert 1000 <= group["self_us"] <= group["us"] - 2000 - 3000 - 1000
     # The registry keeps the same self times, by the thread's role (this
     # one: any thread that no pool named is `serving`), whole microseconds.
     selfs = _self_counters(snapshot)

@@ -2096,7 +2096,13 @@ class TpuStateMachine:
         mesh.  ``_pad_soa`` stays the single-device kernels' own: they
         DONATE what it stages and hand index keys back from it; the sharded
         programs donate the ledger alone and keep a lazy index, so nothing
-        reads these operands after the dispatch."""
+        reads these operands after the dispatch.  Runs on the thread that
+        enqueues the program right after: the serving thread on the blocking
+        routes and for a lone deferred request, the LANE thread for every
+        batch of a grouped run (``_commit_group_fast_sharded``).  Safe
+        there: ``stage_batch`` allocates fresh host arrays a call, and what
+        it reads of the machine (``_shard_mesh``, ``batch_lanes``) changes
+        only at a reshard's cutover, which runs between commits."""
         from .parallel import sharded as shard_mod
 
         if _obs.enabled:
@@ -3262,20 +3268,37 @@ class TpuStateMachine:
     def _commit_group_fast_sharded(self, batches, timestamps, counts,
                                    deferred):
         """Grouped/deferred commit stacking over the mesh (the async
-        sharded engine, docs/sharding.md composition section): the run's
-        batches are staged H2D on the serving thread, each ONCE and already
-        replicated on the mesh (``_stage_sharded``: 2.5 ms a batch and 1.3
-        ms an enqueue on a four-chip v5e host, where 19 device-0 columns
-        cost 7.3 and the enqueue that re-placed them on four chips 11.2;
-        PERF.md PR 38), then ONE dispatch-lane closure drives the cached
-        ``sharded.machine_steps`` fast_probed program once per batch —
-        per-batch shard_map dispatch (the loop-grouped single-device
-        program would re-trace per mesh layout; the per-shard lanes are the
-        parallelism lever here) with the ledger chain threaded through,
-        growth snapshotted at submit, and ONE deferred D2H readback (codes
-        + per-shard overflow lanes) for the whole run.  Results are
-        bit-identical to committing the run batch by batch through the
-        blocking sharded fast path."""
+        sharded engine, docs/sharding.md composition section): ONE
+        dispatch-lane closure stages each batch of the run and enqueues the
+        cached ``sharded.machine_steps`` fast_probed program on it, one
+        batch after the other: stage 0, dispatch 0, stage 1, dispatch 1,
+        ...  Under ``--shards`` a run is K separate executions, not one
+        loop over a stack, and execution j needs batch j alone: staged
+        right before its own enqueue (``_stage_sharded``: 2.5-4 ms a batch
+        and 1.3-1.8 ms an enqueue on a four-chip v5e host, against 22 ms of
+        device time an execution), the device starts on batch 0 while the
+        lane stages the others; staged up front at submit it would sit
+        through all K stagings before the first execution (27-30 ms of a 45
+        ms gap a run; PERF.md PR 40).  The one-chip grouped route is ONE
+        loop over the stacked batches, needs all K before it can start and
+        keeps its staging at submit (``commit_group_fast``).
+
+        What stays at SUBMIT, on the serving thread: the ``route`` pass, the
+        growth snapshot and ``_transfers_bound``.  What rides the lane, in
+        FIFO order: growth, then per batch the staging, the per-batch
+        shard_map dispatch (the loop-grouped single-device program would
+        re-trace per mesh layout; the per-shard lanes are the parallelism
+        lever here) with the ledger chain threaded through, the lazy
+        index's reset and the Merkle update; ONE deferred D2H readback
+        (codes + per-shard overflow lanes) serves the whole run.
+        ``deferred=False`` runs the same closure inline.  The closure reads
+        ``batches[j]`` on the lane (the staging, the Merkle update): the
+        caller must leave the batch bodies alone until the handle resolves.
+        A staging that fails on the lane surfaces as a failed enqueue does:
+        out of the handle's ``resolve``; the ledger chain stands where the
+        last good execution left it.  Results are bit-identical to
+        committing the run batch by batch through the blocking sharded fast
+        path."""
         k = len(batches)
         total = 0
         owner_sum = np.zeros(max(self.shards, 1), np.int64)
@@ -3290,11 +3313,6 @@ class TpuStateMachine:
             # K per-batch dispatches, no padded steps on this route.
             _obs.counter("ops.group.batches").inc(k)
         seq = txtrace.group_seq  # for the closure's spans on the lane
-        with txtrace.stage("stage_h2d", n=k):
-            # Serving-thread staging: K puts, each already on the mesh.
-            staged = [
-                self._stage_sharded(b, t) for b, t in zip(batches, timestamps)
-            ]
         # Submit-time growth snapshot (see commit_group_fast / the
         # shard_bounds note in _grow_if_needed).
         need = self._transfers_bound + total
@@ -3310,11 +3328,15 @@ class TpuStateMachine:
                 self._grow_if_needed(transfers_need=need, shard_bounds=snap)
             codes_out, ovf_out = [], []
             for j in range(k):
+                # Staged HERE, right before its own enqueue: the device
+                # runs batch j-1 meanwhile.
+                with txtrace.stage("stage_h2d", seq=seq):
+                    staged = self._stage_sharded(batches[j], timestamps[j])
                 # Same handoff as the single-device closure above: ONE
                 # FIFO lane worker, serving-thread reads behind the join.
                 with txtrace.stage("dispatch", seq=seq):
                     self.ledger, codes, overflow = step(  # tblint: ignore[lane-race] FIFO+join
-                        self.ledger, *staged[j]
+                        self.ledger, *staged
                     )
                 with txtrace.stage("index_append", seq=seq):
                     self._index_lazy_reset()
@@ -3324,6 +3346,7 @@ class TpuStateMachine:
                 ovf_out.append(overflow)
             if _obs.enabled:
                 _obs.counter("sharding.batches").inc(k)
+                _obs.counter("sharding.staged.lane").inc(k)
             return tuple(codes_out), tuple(ovf_out)
 
         armed_mirror = self._scrub_mirror is not None
