@@ -465,6 +465,7 @@ class TpuStateMachine:
                 cfg.posted_capacity,
                 cfg.history_capacity,
             )
+        self._report_table_bytes(self._ledger)
         self.prepare_timestamp = 0
         self.commit_timestamp = 0
         # Host-side upper bounds on live rows (for growth decisions without
@@ -3735,18 +3736,19 @@ class TpuStateMachine:
         live read here would make the growth moment timing-dependent.
         ``shard_bounds`` is the per-shard twin (a submit-time snapshot of
         _shard_insert_bounds) for the same reason."""
-        led = self.ledger
+        led = before = self.ledger
+        accounts_need = self._accounts_bound + accounts
+        if transfers_need is None:
+            transfers_need = self._transfers_bound + transfers
         cap = self._shard_peak_floor("accounts", self._target_capacity(
-            led.accounts.capacity, self._accounts_bound + accounts
+            led.accounts.capacity, accounts_need
         ), bounds=shard_bounds)
         if cap != led.accounts.capacity:
             led = led.replace(
                 accounts=self._table_grow(led.accounts, "accounts", cap)
             )
         cap = self._shard_peak_floor("transfers", self._target_capacity(
-            led.transfers.capacity,
-            transfers_need if transfers_need is not None
-            else self._transfers_bound + transfers,
+            led.transfers.capacity, transfers_need,
         ), bounds=shard_bounds)
         if cap != led.transfers.capacity:
             hot_max = self.hot_transfers_capacity_max
@@ -3788,6 +3790,28 @@ class TpuStateMachine:
                 self._sanitize_grace = True  # new history capacity class
                 self._sanitize_soft = True
         self.ledger = led
+        if _obs.enabled:
+            # Rows bound over slots, as this growth check saw them (a bound
+            # only overestimates: failed events count as rows).
+            _obs.gauge("ledger.accounts.load").set(
+                accounts_need / led.accounts.capacity)
+            _obs.gauge("ledger.transfers.load").set(
+                transfers_need / led.transfers.capacity)
+            if led is not before:
+                self._report_table_bytes(led)
+
+    @staticmethod
+    def _report_table_bytes(ledger) -> None:
+        """Gauge ``ledger.table_bytes``: the bytes of the three tables'
+        arrays (keys, tombstones, value columns), set where tables are made
+        or grown."""
+        if not _obs.enabled or ledger is None:
+            return
+        _obs.gauge("ledger.table_bytes").set(sum(
+            col.nbytes
+            for t in (ledger.accounts, ledger.transfers, ledger.posted)
+            for col in (t.key_lo, t.key_hi, t.tombstone, *t.cols.values())
+        ))
 
     def _grow_flagged(self, kflags: int) -> None:
         from .ops import transfer_full as tf
@@ -3821,6 +3845,7 @@ class TpuStateMachine:
                 )
             )
         self.ledger = led
+        self._report_table_bytes(led)
 
     def _sequential(
         self, operation: str, batch: np.ndarray, timestamp: int
@@ -4307,6 +4332,7 @@ class TpuStateMachine:
         # predate bound tracking still trigger growth correctly (one sync at
         # restart is fine).
         led = self.ledger
+        self._report_table_bytes(led)
 
         def _count(table) -> int:
             # Layout-agnostic: sharded tables carry per-shard count vectors.

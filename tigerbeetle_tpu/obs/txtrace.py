@@ -85,6 +85,10 @@ STAGES = (
     "readback",         # machine: deferred D2H resolve (codes readback)
     "phase_b",          # replica: bookkeeping + reply build per op
     "reply_release",    # bus: reply writes of one group
+    "checkpoint_capture",  # replica: a checkpoint's inline half, whole
+    "checkpoint_d2h",   # ... every table column copied to the host
+    "checkpoint_digest",  # ... the ledger's digest (a device program + wait)
+    "checkpoint_write",  # replica: forest files, fsync, superblock (bg thread)
     "loop_wait",        # bus: the event loop asleep in its selector
 )
 

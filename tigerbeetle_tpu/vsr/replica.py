@@ -539,11 +539,7 @@ class Replica:
                 return [session.reply_bytes]
             return []
 
-        self._checkpoint_poll()
-        if self.op + 1 > self.op_prepare_max:
-            # WAL full until the in-flight checkpoint lands (op_prepare_max
-            # backpressure): drop, the client retries.
-            return []
+        self._checkpoint_land_if_wal_full(1)
         if self.async_checkpoint:
             # Server mode: overlap the WAL fsync with the device kernel
             # (the prefetch-stage role, SURVEY §2 #16 — the reference
@@ -617,7 +613,7 @@ class Replica:
         execution."""
         out: List[List[bytes]] = [[] for _ in requests]
         admitted: List[Tuple[int, wire.Operation, np.ndarray, bytes]] = []
-        self._checkpoint_poll()
+        self._checkpoint_land_if_wal_full(len(requests))
         self._scrub_poll()  # group boundary: the scrub cadence's home
         # Clients with an op in the still-pending group: their session
         # state (request number, stored reply) is not yet updated, so a
@@ -672,10 +668,6 @@ class Replica:
                 if session.reply_bytes:
                     out[i] = [session.reply_bytes]
                 continue
-            # Each admitted request takes exactly one op; preparation is
-            # deferred past admission, so count the queue, not just op+1.
-            if self.op + len(admitted) + 1 > self.op_prepare_max:
-                continue  # WAL full: drop, client retries
             admitted.append((i, operation, header, body))
         if not admitted:
             # No new commits — but duplicate-resend replies above may belong
@@ -1862,7 +1854,13 @@ class Replica:
     def _checkpoint_capture(self):
         """The inline half of a checkpoint: everything that must be
         consistent with THIS commit_min — evictions, session snapshot,
-        device→host ledger snapshot, digest, clocks."""
+        device→host ledger snapshot, digest, clocks.  Span
+        ``checkpoint_capture`` (children ``checkpoint_d2h``,
+        ``checkpoint_digest``): the serving thread is held for all of it."""
+        with txtrace.stage("checkpoint_capture"):
+            return self._checkpoint_capture_inner()
+
+    def _checkpoint_capture_inner(self):
         # Tiering: spill the older half of the hot transfers window when it
         # is filling (deterministic: driven by the committed op stream; the
         # runs written here become durable with this checkpoint's manifest).
@@ -1895,14 +1893,22 @@ class Replica:
         # TB_SHARDS the live ledger is owner-partitioned, and a checkpoint
         # must restore into ANY shard config (deterministic conversion, so
         # replica checkpoint file checksums stay cluster-comparable).
-        arrays = checkpoint_mod.ledger_to_arrays(m.checkpoint_ledger())
+        with txtrace.stage("checkpoint_d2h"):
+            arrays = checkpoint_mod.ledger_to_arrays(m.checkpoint_ledger())
+        with txtrace.stage("checkpoint_digest"):
+            ledger_digest = m.digest()
+        if _obs.enabled:
+            _obs.counter("replica.checkpoint.captures").inc()
+            _obs.counter("replica.checkpoint.bytes").inc(
+                sum(a.nbytes for a in arrays.values())
+            )
         fields = dict(
             view=self.view,
             log_view=getattr(self, "log_view", self.view),
             commit_min=self.commit_min,
             commit_max=self.op,
             log_adopted_op=getattr(self, "_log_adopted_op", 0),
-            ledger_digest=m.digest(),
+            ledger_digest=ledger_digest,
             prepare_timestamp=m.prepare_timestamp,
             commit_timestamp=m.commit_timestamp,
             # Cold runs superseded as of THIS capture: the only ones whose
@@ -1917,7 +1923,12 @@ class Replica:
     def _checkpoint_write(self, arrays, meta, fields) -> SuperBlockState:
         """The expensive half (file writes + fsync + superblock): safe off
         the serving thread — it touches only the captured host snapshot,
-        the forest files, and distinct storage zones."""
+        the forest files, and distinct storage zones.  Span
+        ``checkpoint_write``, on whichever thread runs it."""
+        with txtrace.stage("checkpoint_write"):
+            return self._checkpoint_write_inner(arrays, meta, fields)
+
+    def _checkpoint_write_inner(self, arrays, meta, fields) -> SuperBlockState:
         # Session replies live in the client_replies zone; make them durable
         # before the superblock references their sizes.
         self.storage.sync()
@@ -2049,10 +2060,6 @@ class Replica:
         t0 = time.monotonic()  # tblint: ignore[nondet]
         arrays, meta, fields = self._checkpoint_capture()
         dt = time.monotonic() - t0  # tblint: ignore[nondet]
-        if _obs.enabled:
-            _obs.histogram("replica.checkpoint_capture_ms", "ms").observe(
-                dt * 1e3
-            )
         if dt > 0.05:
             dbg = getattr(self, "_debug", None)
             if dbg is not None:
@@ -2064,6 +2071,11 @@ class Replica:
         import threading
 
         self._ckpt_error = None
+        # 1 while a write is running or a capture waits behind one: set
+        # here, cleared by the write's own thread as it ends (adoption waits
+        # for the serving thread's next poll; the files are durable by then).
+        if _obs.enabled:
+            _obs.gauge("replica.checkpoint.inflight").set(1)
 
         def work():
             # Handoff protocol: the serving thread reads _ckpt_result/
@@ -2076,6 +2088,8 @@ class Replica:
                 self._ckpt_result = (state, garbage)  # tblint: ignore[lane-race] is_alive gate
             except Exception as err:  # noqa: BLE001 — surfaced at poll
                 self._ckpt_error = err  # tblint: ignore[lane-race] is_alive gate
+            if _obs.enabled and not self._ckpt_queue:
+                _obs.gauge("replica.checkpoint.inflight").set(0)
 
         t = threading.Thread(
             target=work, name="tb-checkpoint", daemon=True
@@ -2122,6 +2136,28 @@ class Replica:
         while self._ckpt_thread is not None:
             self._ckpt_thread.join()
             self._checkpoint_poll()  # adopts; starts the next queued write
+
+    def _checkpoint_land_if_wal_full(self, incoming: int) -> None:
+        """`_checkpoint_poll`, and where ``incoming`` more ops (one per
+        request at hand, admitted or not) would pass `op_prepare_max`, make
+        the room first: settle the pending pipelined group (its commits may
+        be what a due capture waits behind), then wait for the checkpoint
+        write in flight and adopt it.  The WAL has journal_slot_count -
+        vsr_checkpoint_interval - 1 ops of room past a capture (40 with the
+        production journal) and a write of a state of gigabytes outlasts
+        them; a request dropped for a full WAL would cost a single-replica
+        client its whole timeout (it resends only then), the join costs the
+        rest of the write.  After it the checkpoint is at the last capture,
+        less than an interval behind: a group has room (net/bus.py
+        GROUP_MAX is 32)."""
+        self._checkpoint_poll()
+        if self.op + incoming > self.op_prepare_max:
+            if _obs.enabled:
+                _obs.counter("replica.checkpoint.wal_full_waits").inc()
+            self.pipeline_flush()
+            self._checkpoint_drain()
+        assert self.op + incoming <= self.op_prepare_max, (
+            self.op, incoming, self.op_prepare_max)
 
     # -- device fault domain (docs/fault_domains.md) --------------------------
 
