@@ -234,6 +234,208 @@ def test_claim_parity_forced_home_collisions():
 
 
 # ---------------------------------------------------------------------------
+# lookup probes in two phases (all lanes, then the open tail at window
+# width): its result must be the single loop's, bit for bit.
+# ---------------------------------------------------------------------------
+
+
+def _lookup_single_loop_reference(table, key_lo, key_hi, max_probe,
+                                  hash_shift=0):
+    """The single-loop probe protocol lookup ran before it narrowed, kept as
+    the parity oracle: every open lane reads home+i on trip i, until no lane
+    is open or i == max_probe.  Returns (found, slot, overflow, open lanes
+    before each trip)."""
+    from tigerbeetle_tpu.u128 import mix64
+
+    t_lo, t_hi = np.asarray(table.key_lo), np.asarray(table.key_hi)
+    tomb = np.asarray(table.tombstone)
+    mask = np.uint64(table.capacity - 1)
+    home = np.asarray(mix64(key_lo, key_hi) >> jnp.uint64(hash_shift)) & mask
+    lo, hi = np.asarray(key_lo), np.asarray(key_hi)
+    done = (lo == 0) & (hi == 0)
+    found = np.zeros(len(lo), bool)
+    slot = np.zeros(len(lo), np.uint64)
+    open_by_trip = []
+    i = 0
+    while (~done).any() and i < max_probe:
+        open_by_trip.append(int((~done).sum()))
+        cur = (home + np.uint64(i)) & mask
+        match = ~done & (t_lo[cur] == lo) & (t_hi[cur] == hi) & ~tomb[cur]
+        empty = ~done & (t_lo[cur] == 0) & (t_hi[cur] == 0) & ~tomb[cur]
+        found |= match
+        slot = np.where(match, cur, slot)
+        done |= match | empty
+        i += 1
+    return found, slot, bool((~done).any()), open_by_trip
+
+
+def _homes(keys, capacity, hash_shift=0):
+    """Home slots of the test's keys (key_hi = key_lo ^ 1, so that both
+    halves are compared)."""
+    from tigerbeetle_tpu.u128 import mix64
+
+    k = jnp.asarray(keys, jnp.uint64)
+    return (np.asarray(mix64(k, k ^ jnp.uint64(1)) >> jnp.uint64(hash_shift))
+            & np.uint64(capacity - 1)).astype(np.int64)
+
+
+def _key_table(t_lo, tomb):
+    """The table of a key_lo column (key_hi = key_lo ^ 1 where occupied)."""
+    return ht.Table(
+        key_lo=jnp.asarray(t_lo),
+        key_hi=jnp.asarray(np.where(t_lo != 0, t_lo ^ np.uint64(1), t_lo)),
+        tombstone=jnp.asarray(tomb), cols={},
+        count=jnp.uint64(np.count_nonzero(t_lo)),
+        probe_overflow=jnp.bool_(False),
+    )
+
+
+def _probe_table(capacity, keys, hash_shift=0, tombstoned=()):
+    """A table holding ``keys`` by plain linear-probing insertion, then
+    ``tombstoned`` of them cleared to tombstones."""
+    t_lo = np.zeros(capacity, np.uint64)
+    at = {}
+    for key, slot in zip(keys.tolist(), _homes(keys, capacity, hash_shift)):
+        while t_lo[slot]:
+            slot = (slot + 1) & (capacity - 1)
+        t_lo[slot] = key
+        at[key] = slot
+    tomb = np.zeros(capacity, bool)
+    for key in np.asarray(tombstoned).tolist():
+        t_lo[at[key]] = 0
+        tomb[at[key]] = True
+    return _key_table(t_lo, tomb)
+
+
+def _loaded(rng, n, load, capacity=1 << 16, hash_shift=0, nulls=0.0,
+            tombstones=0.0):
+    """A table at ``load`` and ``n`` lanes: half present keys, half absent,
+    ``nulls`` of them zeroed, the last five lanes padding."""
+    universe = rng.permutation(np.unique(rng.integers(
+        1, 1 << 40, size=capacity + n, dtype=np.uint64)))[
+            :int(capacity * load) + n]
+    present, absent = universe[:-n], universe[-n:]
+    removed = present[rng.random(len(present)) < tombstones]
+    table = _probe_table(capacity, present, hash_shift, removed)
+    lanes = np.where(rng.random(n) < 0.5, rng.choice(present, size=n), absent)
+    if nulls:
+        lanes[rng.random(n) < nulls] = 0
+        lanes[-5:] = 0
+    return table, lanes[None, :]
+
+
+def _cluster(rng, n, depth, probing, capacity=1 << 12):
+    """One run of ``depth`` occupied slots and ``probing`` lanes whose home
+    is its first slots: a third of them present at the run's far end, the
+    rest absent (they resolve at the empty slot behind it); the other lanes
+    have their homes elsewhere, some of them present there."""
+    cands = np.arange(1, 1 << 19, dtype=np.uint64)
+    homes = _homes(cands, capacity)
+    start = 100
+    at_head = cands[(homes >= start) & (homes < start + 16)][:probing]
+    assert len(at_head) == probing
+    elsewhere = cands[homes > start + 2 * depth][: n - probing]
+    deep = at_head[: probing // 3]
+    t_lo = np.zeros(capacity, np.uint64)
+    _, first = np.unique(_homes(elsewhere, capacity), return_index=True)
+    t_lo[_homes(elsewhere[first[::2]], capacity)] = elsewhere[first[::2]]
+    t_lo[start:start + depth] = np.uint64(1 << 40) + np.arange(
+        depth, dtype=np.uint64)
+    t_lo[start + depth - len(deep):start + depth] = deep
+    lanes = rng.permutation(np.concatenate([at_head, elsewhere]))
+    return _key_table(t_lo, np.zeros(capacity, bool)), lanes[None, :]
+
+
+def _lookup_case(name):
+    """(table, keys[K, n], max_probe, hash_shift, expectations) of a case."""
+    rng = np.random.default_rng(sum(name.encode()))
+    expect = {}
+    max_probe, hash_shift = MAX_PROBE, 0
+    if name.startswith("load_"):
+        table, keys = _loaded(rng, 8192, float(name[5:]))
+        expect = {"narrow_trips": 1}
+    elif name == "nulls_and_padding":
+        table, keys = _loaded(rng, 8190, 0.3, nulls=0.3)
+    elif name == "tombstones":
+        table, keys = _loaded(rng, 8192, 0.4, tombstones=0.3)
+    elif name == "hash_shift_2":
+        hash_shift = 2
+        table, keys = _loaded(rng, 8192, 0.3, hash_shift=2)
+    elif name.startswith("lanes_"):
+        table, keys = _loaded(rng, int(name[6:]), 0.3, capacity=1 << 14)
+    elif name == "in_a_while_loop":
+        table, keys = _loaded(rng, 8190, 0.3, nulls=0.1)
+        keys = np.stack([keys[0], rng.permutation(keys[0]), keys[0][::-1]])
+    else:
+        # window = 1024 at 8192 lanes.  The run's absent lanes resolve on
+        # trips depth-14 .. depth+1 (the empty slot behind it).
+        depth, probing, max_probe, expect = {
+            "wide_phase_runs_long": (
+                1100, 1500, 1 << 11, {"wide_trips": 1024, "overflow": False}),
+            "max_probe_in_wide_phase": (
+                1100, 1500, 64, {"wide_trips": 64, "overflow": True}),
+            "max_probe_in_narrow_phase": (
+                300, 500, 128, {"narrow_trips": 100, "overflow": True}),
+            "last_trip_is_max_probe": (300, 500, 301, {"overflow": False}),
+            "one_trip_short_of_max_probe": (
+                300, 500, 300, {"overflow": True}),
+        }[name]
+        table, keys = _cluster(rng, 8192, depth, probing)
+    return table, keys, max_probe, hash_shift, expect
+
+
+@jax.jit
+def _lookups_in_a_while_loop(table, lo, hi):
+    """The grouped dispatch's shape: one lookup a trip of a lax.while_loop."""
+    k, n = lo.shape
+
+    def body(state):
+        i, found, slot, overflow = state
+        res = ht.lookup(table, lo[i], hi[i], MAX_PROBE)
+        return (i + 1, found.at[i].set(res.found), slot.at[i].set(res.slot),
+                overflow.at[i].set(res.overflow))
+
+    return jax.lax.while_loop(
+        lambda state: state[0] < k, body,
+        (jnp.int32(0), jnp.zeros((k, n), jnp.bool_),
+         jnp.zeros((k, n), jnp.uint64), jnp.zeros((k,), jnp.bool_)),
+    )[1:]
+
+
+@pytest.mark.parametrize("case", [
+    "load_0.05", "load_0.3", "load_0.49", "nulls_and_padding", "tombstones",
+    "wide_phase_runs_long", "max_probe_in_wide_phase",
+    "max_probe_in_narrow_phase", "last_trip_is_max_probe",
+    "one_trip_short_of_max_probe", "lanes_1", "lanes_63", "lanes_64",
+    "lanes_8192", "hash_shift_2", "in_a_while_loop",
+])
+def test_two_phase_lookup_matches_the_single_loop(case):
+    """found, slot and overflow of the two-phase lookup against the single
+    loop it replaced, and that each case drives the phase its name says."""
+    table, keys, max_probe, hash_shift, expect = _lookup_case(case)
+    lo = jnp.asarray(keys, jnp.uint64)
+    hi = jnp.where(lo != 0, lo ^ jnp.uint64(1), lo)
+    if case == "in_a_while_loop":
+        got = _lookups_in_a_while_loop(table, lo, hi)
+    else:
+        res = ht.lookup(table, lo[0], hi[0], max_probe, hash_shift)
+        got = (res.found[None], res.slot[None], res.overflow[None])
+    window = ht._window(keys.shape[1])
+    for k in range(keys.shape[0]):
+        found, slot, overflow, open_by_trip = _lookup_single_loop_reference(
+            table, lo[k], hi[k], max_probe, hash_shift)
+        np.testing.assert_array_equal(np.asarray(got[0][k]), found)
+        np.testing.assert_array_equal(np.asarray(got[1][k]), slot)
+        assert np.asarray(got[1]).dtype == np.uint64
+        assert bool(got[2][k]) == overflow
+        assert len(found) == 1 or (found.any() and not found.all())
+        wide = sum(c > window for c in open_by_trip)
+        assert wide >= expect.get("wide_trips", 0)
+        assert len(open_by_trip) - wide >= expect.get("narrow_trips", 0)
+        assert overflow == expect.get("overflow", overflow)
+
+
+# ---------------------------------------------------------------------------
 # uint64 columns are written by halves (two one-operand uint32 scatters):
 # the tables must not know.
 # ---------------------------------------------------------------------------

@@ -6,8 +6,9 @@ whose names a trace must also tell apart.
 An operation's `op_name` in a device trace carries the `jax.named_scope` it
 was traced under, so the scopes must be in each program's lowered text; and
 they are metadata only, so the programs still answer as `testing/model.py`
-does.  The lookups carry none yet: their scopes come with the cell whose
-window reaches them."""
+does.  The read programs (`lookup_accounts`, `lookup_transfers`) carry none
+of their own yet (their scopes come with the cell whose window reaches
+them); what they show is `ht.lookup`'s three parts."""
 
 import dataclasses
 
@@ -98,24 +99,28 @@ def _lowered(program):
     return index._merge_jit.lower([level, level])
 
 
+# ht.lookup's own parts, innermost under whichever scope calls it.
+LOOKUP = ("tb/lookup_wide", "tb/lookup_compact", "tb/lookup_narrow")
+
+
 @pytest.mark.parametrize("program,scopes", [
-    ("fast", ("tb/probe", "tb/validate", "tb/balance", "tb/insert")),
+    ("fast", ("tb/probe", "tb/validate", "tb/balance", "tb/insert") + LOOKUP),
     ("grouped", ("tb/group_step", "tb/probe", "tb/validate", "tb/balance",
-                 "tb/insert")),
+                 "tb/insert") + LOOKUP),
     ("general", ("tb/full_gather", "tb/full_waves", "tb/full_pass",
-                 "tb/full_apply", "tb/full_posted")),
+                 "tb/full_apply", "tb/full_posted") + LOOKUP),
     ("index_build", ("tb/index_sort",)),
     ("index_build_row", ("tb/index_sort",)),
-    ("index_probe", ("tb/index_probe",)),
+    ("index_probe", ("tb/index_probe",) + LOOKUP),
     ("index_merge", ("tb/index_merge",)),
     ("sharded_fast", ("tb/shard_gather", "tb/shard_combine", "tb/validate",
-                      "tb/balance", "tb/insert")),
+                      "tb/balance", "tb/insert") + LOOKUP),
     ("sharded_general", ("tb/shard_gather", "tb/shard_combine",
                          "tb/full_waves", "tb/full_pass", "tb/full_apply",
-                         "tb/full_posted")),
+                         "tb/full_posted") + LOOKUP),
     ("sharded_general_no_waves", ("tb/shard_gather", "tb/shard_combine",
                                   "tb/full_pass", "tb/full_apply",
-                                  "tb/full_posted")),
+                                  "tb/full_posted") + LOOKUP),
 ])
 def test_scopes_are_in_the_lowered_text(program, scopes):
     text = _lowered(program).as_text(debug_info=True)

@@ -16,6 +16,15 @@ Design:
 - Batched insert resolves intra-batch slot collisions by lane order: among
   unplaced lanes probing the same slot, the lowest batch index wins; losers
   advance their probe. Deterministic (a pure function of the batch).
+- Both probe loops run in TWO PHASES over the same per-lane state: all N
+  lanes only while more than a window of them (``_window``: 1024 of 8192)
+  is still open, then one compaction and the surviving tail at window
+  width.  Linear probing has a geometric tail, a trip costs its gathers at
+  the width it runs, and a 1024-index gather costs the chip a sixth of an
+  8192-index one (0.009-0.017 against 0.066-0.100 ms), a scatter a
+  sixteenth (PERF.md PR 42, PR 32): ``claim_slots`` since PR 11,
+  ``lookup`` since PR 42.  Results are those of the single loop, bit for
+  bit (the tests keep both single loops as numpy oracles).
 
 - A uint64 column is WRITTEN by halves: two independent one-operand uint32
   scatters (``_set_at``).  A TPU holds a 64-bit array as a pair of 32-bit
@@ -103,6 +112,29 @@ class LookupResult(NamedTuple):
     overflow: jax.Array  # bool scalar — some lane exhausted max_probe
 
 
+def _window(n: int) -> int:
+    """Static width of a probe loop's narrow phase over ``n`` lanes: small
+    enough that a narrow trip is ~an order cheaper than a wide one, large
+    enough that the wide phase exits after the first few probes at load
+    <= 0.5 (the open lanes decay geometrically with probe depth)."""
+    return min(n, max(64, n // 8))
+
+
+def _compact_lanes(open_lanes: jax.Array, window: int) -> jax.Array:
+    """int32[window]: the indices of the first ``window`` set lanes of
+    ``open_lanes``, in lane order; fill lanes carry ``n`` (out of range:
+    dropped by scatters).  ``jnp.nonzero(size=window, fill_value=n)`` by
+    hand, in int32: under x64 jnp.nonzero cumsums in int64, which a TPU
+    emulates as a u32-pair reduce-window whose scoped-vmem stack the v5e
+    compiler cannot place inside the grouped dispatch's scan
+    (RESOURCE_EXHAUSTED at compile)."""
+    n = open_lanes.shape[0]
+    pos = jnp.cumsum(open_lanes.astype(jnp.int32)) - 1
+    return jnp.full((window,), n, jnp.int32).at[
+        jnp.where(open_lanes & (pos < window), pos, window)
+    ].set(jnp.arange(n, dtype=jnp.int32), mode="drop")
+
+
 @functools.partial(jax.jit, static_argnames=("max_probe", "hash_shift"))
 def lookup(
     table: Table,
@@ -115,37 +147,84 @@ def lookup(
 
     ``hash_shift`` discards low hash bits before slotting — sharded tables use
     the low bits as the owner-shard index (parallel/sharded.py) and the rest
-    for the local slot, so shard-local probes never cross devices."""
+    for the local slot, so shard-local probes never cross devices.
+
+    TWO-PHASE probing, as ``claim_slots``: the loop trips until the LAST
+    lane has met its row or an empty slot, but linear probing has a
+    geometric tail (at load 0.25-0.49 at most an eighth of 8192 lanes is
+    open after 2-5 trips, the last after 9-29), and a trip costs its five
+    gathers at whatever width it runs.  So a wide phase (all N lanes) runs
+    only while more than ``_window(N)`` lanes are open, ONE compaction
+    gathers the open lanes' homes and keys, a narrow phase finishes them at
+    window width from the trip the wide phase stopped at, and one uint32
+    scatter merges their slots back.  No resolved lane ever rejoins and
+    every open lane advances one slot a trip in either phase, so the trip
+    on which a lane resolves, and with it ``found``, ``slot`` and
+    ``overflow``, is that of the single loop (tests/test_hash_table.py
+    keeps the single loop as a numpy oracle).  Scopes ``tb/lookup_wide``,
+    ``tb/lookup_compact`` (compaction and merge) and ``tb/lookup_narrow``
+    name the parts in a profile (docs/tracing.md)."""
     capacity = table.capacity
+    n = key_lo.shape[0]
+    window = _window(n)
     mask = jnp.uint64(capacity - 1)
     home = (mix64(key_lo, key_hi) >> jnp.uint64(hash_shift)) & mask
+    # A found slot rides the loops as uint32, "not found" as the capacity
+    # (as ``claimed`` in claim_slots), so the merge is ONE one-operand
+    # 32-bit scatter; it widens at exit.
+    sentinel = jnp.uint32(capacity)
+
+    def cond_above(open_limit):
+        def cond(state):
+            i, done, _ = state
+            return (jnp.sum(~done, dtype=jnp.int32) > open_limit) & (
+                i < max_probe)
+        return cond
+
+    def body_over(home, key_lo, key_hi):
+        def body(state):
+            i, done, slot = state
+            cur = (home + jnp.uint64(i)) & mask
+            t_lo = table.key_lo[cur]
+            t_hi = table.key_hi[cur]
+            tomb = table.tombstone[cur]
+            match = ~done & (t_lo == key_lo) & (t_hi == key_hi) & ~tomb
+            empty = ~done & (t_lo == 0) & (t_hi == 0) & ~tomb
+            slot = jnp.where(match, cur.astype(jnp.uint32), slot)
+            return i + 1, done | match | empty, slot
+        return body
 
     # Lanes probing key 0 (invalid id / padding lanes) resolve immediately.
     is_null = (key_lo == 0) & (key_hi == 0)
+    with jax.named_scope("tb/lookup_wide"):
+        i, done, slot = jax.lax.while_loop(
+            cond_above(window), body_over(home, key_lo, key_hi),
+            (jnp.int32(0), is_null, jnp.full((n,), sentinel)),
+        )
 
-    def cond(state):
-        i, done, _, _ = state
-        return jnp.any(~done) & (i < max_probe)
+    # Exactly the open lanes (<= window unless the wide phase ended at
+    # max_probe, in which case the narrow loop runs no trip, the window is
+    # full of open lanes and overflow reads true, as it must).
+    with jax.named_scope("tb/lookup_compact"):
+        idx = _compact_lanes(~done, window)
+        active = idx < n
+        idx_safe = jnp.where(active, idx, 0)
+        home_w, lo_w, hi_w = home[idx_safe], key_lo[idx_safe], key_hi[idx_safe]
 
-    def body(state):
-        i, done, found, slot = state
-        cur = (home + jnp.uint64(i)) & mask
-        t_lo = table.key_lo[cur]
-        t_hi = table.key_hi[cur]
-        tomb = table.tombstone[cur]
-        match = ~done & (t_lo == key_lo) & (t_hi == key_hi) & ~tomb
-        empty = ~done & (t_lo == 0) & (t_hi == 0) & ~tomb
-        found = found | match
-        slot = jnp.where(match, cur, slot)
-        done = done | match | empty
-        return i + 1, done, found, slot
+    with jax.named_scope("tb/lookup_narrow"):
+        _, done_w, slot_w = jax.lax.while_loop(
+            cond_above(0), body_over(home_w, lo_w, hi_w),
+            (i, ~active, jnp.full((window,), sentinel)),
+        )
 
-    i0 = jnp.int32(0)
-    done0 = is_null
-    found0 = jnp.zeros_like(is_null)
-    slot0 = jnp.zeros_like(home)
-    i, done, found, slot = jax.lax.while_loop(cond, body, (i0, done0, found0, slot0))
-    return LookupResult(found=found, slot=slot, overflow=jnp.any(~done))
+    with jax.named_scope("tb/lookup_compact"):
+        slot = slot.at[idx].set(slot_w, mode="drop")
+    found = slot != sentinel
+    return LookupResult(
+        found=found,
+        slot=jnp.where(found, slot, 0).astype(jnp.uint64),
+        overflow=jnp.any(~done_w),
+    )
 
 
 def claim_slots(
@@ -232,11 +311,7 @@ def claim_slots(
     )
     nwords = jnp.uint64(occ0.shape[0])
 
-    # Static compaction width: small enough that the narrow phase is ~an
-    # order cheaper per trip, large enough that the wide phase exits after
-    # the first few probes at load <= 0.5 (the unplaced count decays
-    # geometrically with probe depth).
-    window = min(n, max(64, n // 8))
+    window = _window(n)
 
     def wide_cond(state):
         _, _, unplaced, _, overflow, _ = state
@@ -285,14 +360,7 @@ def claim_slots(
     # the wide phase exited on overflow, in which case the narrow cond is
     # already false and the truncation is inert).  Fill lanes carry index
     # n: inactive in the narrow body, dropped by its scatters.
-    # (jnp.nonzero(size=window, fill_value=n) by hand, in int32: under x64
-    # jnp.nonzero cumsums in int64, which a TPU emulates as a u32-pair
-    # reduce-window whose scoped-vmem stack the v5e compiler cannot place
-    # inside the grouped dispatch's scan — RESOURCE_EXHAUSTED at compile.)
-    pos_u = jnp.cumsum(unplaced.astype(jnp.int32)) - 1
-    idx = jnp.full((window,), n, jnp.int32).at[
-        jnp.where(unplaced & (pos_u < window), pos_u, window)
-    ].set(jnp.arange(n, dtype=jnp.int32), mode="drop")
+    idx = _compact_lanes(unplaced, window)
     active = idx < n
     idx_safe = jnp.where(active, idx, 0)
     home_w = home[idx_safe]
