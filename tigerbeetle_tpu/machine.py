@@ -2337,6 +2337,8 @@ class TpuStateMachine:
             if kflags & tf.FLAG_SEQ:
                 # Order-dependent batch (balancing / limit accounts / deep
                 # intra-batch chains): exact sequential execution.
+                if _obs.enabled:
+                    _obs.counter("ops.general.seq_handovers").inc()
                 return self._sequential("create_transfers", batch, timestamp)
             # Probe overflow despite load management (hash clustering):
             # grow the flagged tables and retry — the kernel applied nothing.
@@ -2389,6 +2391,8 @@ class TpuStateMachine:
         with txtrace.stage("index_append"):
             self._index_append(soa, codes, count, keys)
         results = self._compress(codes, count)
+        if _obs.enabled:
+            _obs.counter("ops.general.rejected_lanes").inc(len(results))
         self._update_commit_timestamp(codes, count, timestamp)
         return results
 
@@ -2397,6 +2401,10 @@ class TpuStateMachine:
         kernel's int32[11] = (passes, bound, hist[9]) profile vector."""
         passes, bound = int(wave_host[0]), int(wave_host[1])
         hist = [int(v) for v in wave_host[2:]]
+        # Every lane but wave 0's is a hazard lane: one whose result a limit,
+        # a balancing clamp or a near-overflow balance makes depend on
+        # earlier lanes of its batch (`_kernel_core`'s `hazard`).
+        _obs.counter("ops.general.limit_lanes").inc(sum(hist[1:]))
         if bound > 0:
             _obs.counter("waves.batches_scheduled").inc()
             _obs.histogram("waves.bound_passes", "passes").observe(bound)
@@ -2499,6 +2507,8 @@ class TpuStateMachine:
                 # Order-dependent (linked / balancing-chain / limit
                 # cascade), in-batch pending refs, or history accounts:
                 # the unschedulable exit.
+                if _obs.enabled:
+                    _obs.counter("ops.general.seq_handovers").inc()
                 return self._sequential("create_transfers", batch, timestamp)
             # No FLAG_COLD on the mesh path (tiering is single-device);
             # remaining bits are probe-overflow growth requests.
