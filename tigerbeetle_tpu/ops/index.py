@@ -15,6 +15,16 @@ is O(log N) sorts of geometric sizes; a query binary-searches every level
 (static unroll) and gathers a bounded candidate window, so query cost is
 O(levels · K) — FLAT in table capacity.
 
+What a sort moves: the rows themselves.  _sort_level orders a level by three
+stable single-key passes (ts, then acct_lo, then acct_hi: jnp.lexsort's
+order) and each pass carries the other four columns through its sorts as
+payload, so neither build_runs nor a merge holds a permutation or a gather.
+On a TPU v5e a gather is priced by the index, ~7 ns each whatever the array,
+and a sort of the same rows at a seventh of ONE one-column gather: until
+PR 45 three argsorts and the 18 gathers of their permutations were 96.8 % of
+every merge (383.0 of 395.5 ms in 42 executions) and 97.5 % of build_runs
+(call `a44`'s kept profile; PERF.md section 5, "Per operation").
+
 Entries carry the transfer id (not its table slot) so hash-table growth
 rehashes never invalidate the index; query results are resolved to rows with
 one batched id lookup.  Sentinel entries (account id 2^128-1, an id that can
@@ -57,16 +67,42 @@ def _sentinel_level(capacity: int) -> Dict[str, jax.Array]:
     return lvl
 
 
+# The three stable passes of _sort_level, least significant key first.
+_PASSES = ("ts", "acct_lo", "acct_hi")
+
+
 def _sort_level(lvl: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     """Order by (acct_hi, acct_lo, ts) — jnp.lexsort's order, as three
-    stable single-key passes: one three-key u64 sort (six emulated u32
-    compares per comparison on a TPU) takes the v5e compiler 43 s at 16 K
-    rows and grows with the level, which a client pays inside its request
-    the first time a level fills; the single-key form compiles in seconds."""
-    order = jnp.argsort(lvl["ts"], stable=True)
-    order = order[jnp.argsort(lvl["acct_lo"][order], stable=True)]
-    order = order[jnp.argsort(lvl["acct_hi"][order], stable=True)]
-    return {name: lvl[name][order] for name in COLS}
+    stable single-key passes that MOVE THE ROWS THEMSELVES: a pass sorts its
+    key column once beside each of the other four columns, one stable
+    two-operand ``lax.sort`` a column (a stable sort's permutation goes by
+    the keys alone, so the four agree), and hands all five on, already
+    moved.  No argsort, no permutation, no gather.
+
+    Why not a permutation: on a v5e a one-column gather costs 7-10 ns an
+    index whatever the array and a sort of the same rows a fraction of ONE
+    such gather: the 18 gathers that three argsorts needed were 97 % of every
+    merge (call `a44`'s kept profile), and a level of 16,384 random rows
+    took 2.157 ms that way against 0.221 this way, one of 2,097,152 rows
+    373.4 against 68.0 (PR 45's probe, tools/index_sort_probe.py, call `a45`;
+    PERF.md section 5 "Per operation").  Why a column at a time and not one
+    sort with four payload columns, which is twice as fast again on the
+    device (0.112 and 36.2 ms): COMPILE time, which a client pays inside its
+    request the first time a level fills and a cold start pays for every
+    level.  The v5e compiler's time for a sort grows faster than the sort's
+    operand count, and it compiles a program's identical sorts once: these
+    twelve (u64, u64) sorts compile as the three argsorts did (12.1 s at
+    16,384 rows and 53.7 at 2,097,152 against 12.4 and 54.6), three sorts of
+    five u64 operands in 23.6 and 126.9 s, one three-key sort in 39.7 s at
+    16,384 (same probe, the chip's host)."""
+    for key in _PASSES:
+        moved = {}
+        for name in COLS:
+            if name != key:
+                moved[key], moved[name] = jax.lax.sort(
+                    (lvl[key], lvl[name]), num_keys=1, is_stable=True)
+        lvl = moved
+    return {name: lvl[name] for name in COLS}
 
 
 @jax.jit
