@@ -1,11 +1,7 @@
-"""donation fixtures: use-after-donate, pooled-buffer donation, and the
-device_put staging alias — plus clean and suppressed instances."""
+"""donation fixtures: use-after-donate, plus clean and suppressed
+instances."""
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-
-_POOL = []
 
 
 def _commit_impl(ledger, batch):
@@ -15,29 +11,10 @@ def _commit_impl(ledger, batch):
 _commit = jax.jit(_commit_impl, donate_argnames=("ledger",))
 
 
-def _stage_acquire():
-    if _POOL:
-        return _POOL.pop()
-    return np.zeros((8, 64), np.uint64)
-
-
 def use_after_donate(ledger, batch):
     new_ledger, codes = _commit(ledger, batch)
     total = ledger.sum()  # BAD: ledger was donated above
     return new_ledger, codes, total
-
-
-def donate_pooled_template(self, batch):
-    template = self._pad_soa_zero[0]
-    led, codes = _commit(template, batch)  # BAD: cached template donated
-    return led, codes
-
-
-def donate_staging_alias(batch):
-    staged = _stage_acquire()
-    cols = jax.device_put(staged)
-    led, codes = _commit(cols, batch)  # BAD: device_put may alias the pool
-    return led, codes
 
 
 def clean_rebind(ledger, batch):

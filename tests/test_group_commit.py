@@ -16,6 +16,7 @@ import pytest
 from tigerbeetle_tpu import machine, types
 from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
+from tigerbeetle_tpu.ops import staging
 
 LANES = 64
 CFG = LedgerConfig(
@@ -111,13 +112,14 @@ class TestMachineGroupParity:
         real = machine._group_fast_dispatch
         seen = []
 
-        def planted_and_poisoned(ledger, stacked, counts, timestamps):
-            k = int(np.count_nonzero(np.asarray(counts)))
-            stacked = {name: col.at[k + 1].set(col[0])
-                       for name, col in stacked.items()}
-            stacked["id_lo"] = stacked["id_lo"].at[k + 1].add(5_000_000)
+        def planted_and_poisoned(ledger, cols64, cols32, meta):
+            k = int(np.count_nonzero(np.asarray(meta[0])))
+            cols64 = cols64.at[k + 1].set(cols64[0])
+            id_lo = staging.column_row(types.TRANSFER_DTYPE, "id_lo")
+            cols64 = cols64.at[k + 1, id_lo].add(5_000_000)
             ledger, codes, *rest = real(
-                ledger, stacked, counts.at[k + 1].set(counts[0]), timestamps
+                ledger, cols64, cols32.at[k + 1].set(cols32[0]),
+                meta.at[0, k + 1].set(meta[0, 0]),
             )
             seen.append((k, np.asarray(codes)))
             return (ledger, codes.at[k:].set(0xFFFFFFFF), *rest)
@@ -126,23 +128,31 @@ class TestMachineGroupParity:
                             planted_and_poisoned)
         grouped = make_machine(True)
         serial = make_machine(False)
-        for n, k in enumerate((3, 7)):
+        for n, k in enumerate((3, 6, 9)):
             res_g, res_s = self._commit_both(
                 grouped, serial, self._run_of(k, 10_000 * (n + 1))
             )
             assert res_g == res_s
         assert grouped.digest() == serial.digest()
-        assert [k for k, _ in seen] == [3, 7]
+        assert [k for k, _ in seen] == [3, 6, 9]
         for k, codes in seen:
-            assert codes.shape[0] == TpuStateMachine.GROUP_K
+            # The stack's leading dimension goes by the run's length.
+            assert codes.shape[0] == grouped._group_rows(k)
             assert codes[:k].any() and not codes[k:].any()
+        assert [codes.shape[0] for _, codes in seen] == [
+            TpuStateMachine.GROUP_ROWS_SHORT, TpuStateMachine.GROUP_ROWS_SHORT,
+            TpuStateMachine.GROUP_K,
+        ]
 
-    def test_every_run_length_reuses_one_compiled_program(self):
+    def test_every_run_length_reuses_one_of_two_compiled_programs(self):
+        """One program a leading dimension of the staged stack (8 rows for
+        a run of at most 8, GROUP_K beyond), whatever the run's length."""
         grouped = make_machine(True)
         serial = make_machine(False)
         self._commit_both(grouped, serial, self._run_of(2))
+        self._commit_both(grouped, serial, self._run_of(9, 5_000))
         warmed = machine._group_fast_dispatch._cache_size()
-        assert warmed >= 1
+        assert warmed >= 2
         for n, k in enumerate((3, 7, 8, TpuStateMachine.GROUP_K, 2)):
             res_g, res_s = self._commit_both(
                 grouped, serial, self._run_of(k, 10_000 * (n + 2))
@@ -208,6 +218,70 @@ class TestReplicaGroupParity:
         (reply,) = replies[0]
         rh, _cmd = wire.decode_header(reply[:wire.HEADER_SIZE])
         return int(rh["commit"])  # session = register op
+
+    def test_twelve_sessions_give_a_run_past_the_short_stack(self, tmp_path):
+        """With more than `GROUP_ROWS_SHORT` sessions a commit group holds
+        a run the short stack cannot take: the run is ONE dispatch on the
+        `GROUP_K`-row stack (one put of `GROUP_K` rows), and answers as the
+        same requests committed one by one.  No benchmark cell has more
+        than 8 sessions, so this side of `_group_rows` is held here, on
+        the CPU, and timed only by `tools/stage_probe.py` (ROADMAP B-I)."""
+        from tigerbeetle_tpu.obs.metrics import registry
+
+        n_sessions = 12
+        short, long_ = TpuStateMachine.GROUP_ROWS_SHORT, TpuStateMachine.GROUP_K
+        assert short < n_sessions <= long_
+        outs = {}
+        for group in (False, True):
+            r, wire = self._serve(tmp_path, f"s{int(group)}", group)
+            clients = [0x200 + i for i in range(n_sessions)]
+            sessions = {c: self._register(r, wire, c) for c in clients}
+            accounts = types.accounts_array([
+                types.account(id=i + 1, ledger=1, code=10) for i in range(16)
+            ])
+            replies, fsync = r.on_request_group_pipelined([self._request(
+                wire, clients[0], sessions[clients[0]], 1,
+                wire.Operation.create_accounts, accounts.tobytes(),
+            )])
+            if fsync is not None:
+                fsync.result()
+            reqs = [
+                self._request(
+                    wire, c, sessions[c], 2 if i == 0 else 1,
+                    wire.Operation.create_transfers,
+                    batch(10_000 * (i + 1), 5 + i).tobytes(),
+                )
+                for i, c in enumerate(clients)
+            ]
+            with registry.enabled_scope():
+                replies, fsync = r.on_request_group_pipelined(reqs)
+                if fsync is not None:
+                    fsync.result()
+                counters = registry.snapshot()["counters"]
+            outs[group] = [rl[0][256:] for rl in replies]
+            # (Each replica stamps from its own clock: balances and the
+            # count of rows written are compared, not the digest.)
+            balances = r.machine.lookup_accounts(list(range(1, 17)))
+            outs[(group, "state")] = (
+                balances[["debits_posted_lo", "credits_posted_lo"]].tolist(),
+                r.machine.lookup_transfers(
+                    [10_000 * (i + 1) + j for i in range(n_sessions)
+                     for j in range(5 + i)]).size,
+            )
+            if group:
+                rows = staging.stage_group(
+                    [batch(1, 1)], LANES, [1], long_)
+                assert counters["ops.group.batches"] == n_sessions
+                assert counters["ops.dispatch"] == 1
+                assert counters["stage.puts"] == 1
+                assert counters["stage.bytes"] == sum(
+                    a.nbytes for a in rows)
+            r.close()
+        assert len(outs[True]) == n_sessions
+        assert outs[True] == outs[False]
+        assert outs[(True, "state")] == outs[(False, "state")]
+        assert outs[(True, "state")][1] == sum(
+            5 + i for i in range(n_sessions))
 
     def test_mixed_group_bitwise_parity(self, tmp_path):
         outs = {}

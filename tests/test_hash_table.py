@@ -567,24 +567,20 @@ def test_no_commit_program_scatters_a_64_bit_operand(program):
 
     lanes = 256
     led = jax.eval_shape(lambda: sm.make_ledger(1 << 10, 1 << 12, 1 << 8))
-    u64 = jax.ShapeDtypeStruct((), jnp.uint64)
-    cols = types.to_soa(np.zeros(1, dtype=types.TRANSFER_DTYPE))
 
-    def batch(lead=()):
-        return {k: jax.ShapeDtypeStruct(lead + (lanes,), v.dtype)
-                for k, v in cols.items()}
+    def staged(lead=()):  # staging.stage_batch's / stage_group's operands
+        return (jax.ShapeDtypeStruct(lead + (14, lanes), jnp.uint64),
+                jax.ShapeDtypeStruct(lead + (5, lanes), jnp.uint32),
+                jax.ShapeDtypeStruct((2,) + lead, jnp.uint64))
 
     if program == "fast":
-        lowered = jax.jit(sm.create_transfers_impl).lower(
-            led, batch(), u64, u64)
+        lowered = sm.create_transfers_fast.jitted.lower(led, *staged())
     elif program == "grouped":
         k = machine.TpuStateMachine.GROUP_K
-        kvec = jax.ShapeDtypeStruct((k,), jnp.uint64)
-        lowered = machine._group_fast_dispatch.lower(
-            led, batch((k,)), kvec, kvec)
+        lowered = machine._group_fast_dispatch.lower(led, *staged((k,)))
     else:
         lowered = tf.create_transfers_full.lower(
-            led, batch(), u64, u64, None, None, max_passes=8,
+            led, *staged(), None, None, max_passes=8,
             has_postvoid=True, has_history=False, use_waves=True)
     operands = _scatter_operands(lowered.as_text())
     assert len(operands) > 20, "the parser lost the program's scatters"

@@ -8,11 +8,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from tigerbeetle_tpu import jaxenv, types
 from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
+from tigerbeetle_tpu.ops import staging
 from tigerbeetle_tpu.ops import state_machine as sm
 from tigerbeetle_tpu.parallel import sharded
 from tigerbeetle_tpu.testing.workload import WorkloadGen
@@ -37,7 +38,9 @@ def mesh():
 def staged(mesh, batch, timestamp, lanes=LANES):
     """The operands a sharded step takes after the ledger: the batch padded
     to ``lanes``, its count and its timestamp, replicated on the mesh."""
-    return sharded.stage_batch(mesh, batch, lanes, int(timestamp))
+    return staging.stage_batch(
+        batch, lanes, int(timestamp), NamedSharding(mesh, PartitionSpec())
+    )
 
 
 def snapshot_sharded(ledger):

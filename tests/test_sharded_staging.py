@@ -1,5 +1,5 @@
 """Under `--shards` a batch is staged once, already replicated on the mesh
-(`machine._stage_sharded`, `parallel/sharded.stage_batch`; PERF.md PR 38).
+(`machine._stage_sharded`, `ops/staging.stage_batch`; PERF.md PR 38).
 
 Four things the serving path leans on, on each of the four sharded transfer
 routes (the lone deferred request, the grouped run, the blocking fast
@@ -20,6 +20,7 @@ from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
 from tigerbeetle_tpu.obs.metrics import registry
 from tigerbeetle_tpu.obs.txtrace import txtrace
+from tigerbeetle_tpu.ops import staging as staging_mod
 from tigerbeetle_tpu.parallel import sharded
 
 LANES = 64
@@ -163,7 +164,7 @@ def _run(first_id, k):
 def _recorded(m, monkeypatch, events):
     """Every staging and every enqueue of ``m`` appended to ``events`` as
     (what, the thread's name), in the order they happen."""
-    stage_batch = sharded.stage_batch
+    stage_batch = staging_mod.stage_batch
 
     def staging(*args):
         events.append(("stage", threading.current_thread().name))
@@ -175,7 +176,7 @@ def _recorded(m, monkeypatch, events):
             return step(ledger, *operands)
         return call
 
-    monkeypatch.setattr(sharded, "stage_batch", staging)
+    monkeypatch.setattr(staging_mod, "stage_batch", staging)
     m._shard_steps = {k: recording(v) for k, v in m._shard_steps.items()}
 
 
@@ -256,7 +257,7 @@ def test_a_staging_that_fails_on_the_lane_fails_the_resolve(monkeypatch):
     assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
     batches = _run(60_000, 3)
     stamps = [m.prepare("create_transfers", len(b), 0) for b in batches]
-    stage_batch, calls = sharded.stage_batch, []
+    stage_batch, calls = staging_mod.stage_batch, []
 
     def failing(*args):
         calls.append(threading.current_thread().name)
@@ -264,7 +265,7 @@ def test_a_staging_that_fails_on_the_lane_fails_the_resolve(monkeypatch):
             raise ValueError("staging failed")
         return stage_batch(*args)
 
-    monkeypatch.setattr(sharded, "stage_batch", failing)
+    monkeypatch.setattr(staging_mod, "stage_batch", failing)
     handle = m.commit_group_fast(batches, stamps, deferred=True)
     # The submit itself staged nothing and raised nothing: the failure is
     # the lane's, and comes out of the handle as a failed enqueue does.
@@ -284,7 +285,7 @@ def test_a_staging_that_fails_on_the_lane_fails_the_resolve(monkeypatch):
 def test_a_staged_batch_is_zero_beyond_its_count(dtype, n):
     if len(jax.devices()) < SHARDS:
         pytest.skip(f"needs {SHARDS} devices, have {len(jax.devices())}")
-    from jax.sharding import Mesh
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     mesh = Mesh(np.array(jax.devices()[:SHARDS]), (sharded.AXIS,))
     rng = np.random.default_rng(n)
@@ -293,10 +294,11 @@ def test_a_staged_batch_is_zero_beyond_its_count(dtype, n):
         info = np.iinfo(dtype.fields[name][0])
         batch[name] = rng.integers(1, info.max, n, dtype=info.dtype)
     timestamp = 7_000_000_000_000 + n
-    staged = sharded.stage_batch(mesh, batch, LANES, timestamp)
-    columns, count, stamp = sharded._unstage(dtype, *staged)
+    staged = staging_mod.stage_batch(
+        batch, LANES, timestamp, NamedSharding(mesh, PartitionSpec()))
+    columns, count, stamp = staging_mod.unstage(dtype, *staged)
     assert (int(count), int(stamp)) == (n, timestamp)
-    # What `_pad_soa` gave: each column by name, widened as `to_soa`
+    # What the kernels' bodies take: each column by name, widened as `to_soa`
     # widens it, its lanes beyond the count zero.
     padded = np.zeros(LANES, dtype=dtype)
     padded[:n] = batch

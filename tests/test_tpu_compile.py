@@ -88,18 +88,29 @@ def _padded_scan_dispatch(ledger, stacked, counts, timestamps):
     )
 
 
+def _staged(sharding, rows=None):
+    """The staged operands (``staging.stage_batch``): the batch's 14 uint64
+    columns, its 5 narrower ones, (count, timestamp); with ``rows``, the
+    grouped stack of that many (``staging.stage_group``)."""
+    lead = () if rows is None else (rows,)
+    return (
+        jax.ShapeDtypeStruct(lead + (14, LANES), jnp.uint64, sharding=sharding),
+        jax.ShapeDtypeStruct(lead + (5, LANES), jnp.uint32, sharding=sharding),
+        jax.ShapeDtypeStruct((2,) + lead, jnp.uint64, sharding=sharding),
+    )
+
+
 def _one_chip_lowerings(topo):
     one = SingleDeviceSharding(topo.devices[0])
     led = _on(jax.eval_shape(lambda: sm.make_ledger(ACC, TR, POSTED, HIST)),
               one)
-    u64 = jax.ShapeDtypeStruct((), jnp.uint64, sharding=one)
-    batch = _soa(types.TRANSFER_DTYPE, one)
     k = machine.TpuStateMachine.GROUP_K
+    short = machine.TpuStateMachine.GROUP_ROWS_SHORT
     kvec = jax.ShapeDtypeStruct((k,), jnp.uint64, sharding=one)
 
     def full(has_postvoid):
         return lambda: tf.create_transfers_full.lower(
-            led, batch, u64, u64, None, None, max_passes=8,
+            led, *_staged(one), None, None, max_passes=8,
             has_postvoid=has_postvoid, has_history=False, use_waves=True,
         )
 
@@ -107,7 +118,7 @@ def _one_chip_lowerings(topo):
     ok = jax.ShapeDtypeStruct((LANES,), jnp.bool_, sharding=one)
     return {
         "fast": lambda: sm.create_transfers_fast.jitted.lower(
-            led, batch, u64, u64),
+            led, *_staged(one)),
         "index_build": lambda: index.build_runs.lower(
             {name: lanes for name in sm.INDEX_KEY_COLS}, lanes, lanes, ok),
         "index_build_row": lambda: index.build_runs.lower(
@@ -118,7 +129,9 @@ def _one_chip_lowerings(topo):
                  ok)),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=one)),
         "grouped": lambda: machine._group_fast_dispatch.lower(
-            led, _soa(types.TRANSFER_DTYPE, one, lead=(k,)), kvec, kvec),
+            led, *_staged(one, k)),
+        "grouped_short": lambda: machine._group_fast_dispatch.lower(
+            led, *_staged(one, short)),
         "grouped_padded_scan": lambda: jax.jit(
             _padded_scan_dispatch, donate_argnames=("ledger",)
         ).lower(led, _soa(types.TRANSFER_DTYPE, one, lead=(k,)), kvec, kvec),
@@ -144,18 +157,12 @@ def _sharded_lowering(topo, make_step):
         accounts=table(led.accounts), transfers=table(led.transfers),
         posted=table(led.posted), history=_on(led.history, repl),
     )
-    # The staged operands (sharded.stage_batch): the batch's 14 uint64
-    # columns, its 5 narrower ones, (count, timestamp), replicated.
-    staged = (
-        jax.ShapeDtypeStruct((14, LANES), jnp.uint64, sharding=repl),
-        jax.ShapeDtypeStruct((5, LANES), jnp.uint32, sharding=repl),
-        jax.ShapeDtypeStruct((2,), jnp.uint64, sharding=repl),
-    )
-    return make_step(mesh).lower(led, *staged)
+    return make_step(mesh).lower(led, *_staged(repl))
 
 
 @pytest.mark.parametrize("program", [
-    "fast", "grouped", "full_scan_plain", "full_scan_postvoid",
+    "fast", "grouped", "grouped_short", "full_scan_plain",
+    "full_scan_postvoid",
     "sharded_fast_4", "sharded_full_scan_4", "index_build",
     "index_build_row",
 ])

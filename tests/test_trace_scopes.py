@@ -36,43 +36,35 @@ def _shapes(tree):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
 
 
-def _soa(lead=()):
-    cols = types.to_soa(np.zeros(1, dtype=types.TRANSFER_DTYPE))
-    return {k: jax.ShapeDtypeStruct(lead + (LANES,), v.dtype)
-            for k, v in cols.items()}
-
-
 def _mesh4():
     if len(jax.devices()) < 4:
         pytest.skip(f"needs 4 devices, have {len(jax.devices())}")
     return Mesh(np.array(jax.devices()[:4]), (sharded.AXIS,))
 
 
-def _staged():
-    """`sharded.stage_batch`'s operands as shapes: the batch's 14 uint64
-    columns, its 5 narrower ones, (count, timestamp)."""
-    return (jax.ShapeDtypeStruct((14, LANES), jnp.uint64),
-            jax.ShapeDtypeStruct((5, LANES), jnp.uint32),
-            jax.ShapeDtypeStruct((2,), jnp.uint64))
+def _staged(rows=None):
+    """`staging.stage_batch`'s operands as shapes: the batch's 14 uint64
+    columns, its 5 narrower ones, (count, timestamp); with ``rows``,
+    `staging.stage_group`'s stack of that many."""
+    lead = () if rows is None else (rows,)
+    return (jax.ShapeDtypeStruct(lead + (14, LANES), jnp.uint64),
+            jax.ShapeDtypeStruct(lead + (5, LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((2,) + lead, jnp.uint64))
 
 
 def _lowered(program):
     led = jax.eval_shape(lambda: sm.make_ledger(1 << 10, 1 << 12, 1 << 10,
                                                 1 << 10))
-    u64 = jax.ShapeDtypeStruct((), jnp.uint64)
     ids = jax.ShapeDtypeStruct((LANES,), jnp.uint64)
     ok = jax.ShapeDtypeStruct((LANES,), jnp.bool_)
     k = machine.TpuStateMachine.GROUP_K
-    kvec = jax.ShapeDtypeStruct((k,), jnp.uint64)
     if program == "fast":
-        return sm.create_transfers_fast_probed.jitted.lower(
-            led, _soa(), u64, u64)
+        return sm.create_transfers_fast_probed.jitted.lower(led, *_staged())
     if program == "grouped":
-        return machine._group_fast_dispatch.lower(
-            led, _soa(lead=(k,)), kvec, kvec)
+        return machine._group_fast_dispatch.lower(led, *_staged(k))
     if program == "general":  # the two-phase variant the machine serves
         return tf.create_transfers_full.lower(
-            led, _soa(), u64, u64, max_passes=8, has_postvoid=True,
+            led, *_staged(), max_passes=8, has_postvoid=True,
             has_history=False, use_waves=True)
     if program.startswith("sharded"):
         mesh = _mesh4()

@@ -389,8 +389,7 @@ _LOOP_CASES = {
 
 
 def _loop_ledger():
-    import jax.numpy as jnp
-
+    from tigerbeetle_tpu.ops import staging
     from tigerbeetle_tpu.ops import state_machine as sm
 
     acc = types.accounts_array([
@@ -401,12 +400,9 @@ def _loop_ledger():
         )
         for i in range(_LOOP_ACCOUNTS)
     ])
-    padded = np.zeros(_LOOP_LANES, dtype=types.ACCOUNT_DTYPE)
-    padded[: len(acc)] = acc
-    soa = {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
-    n = jnp.uint64(_LOOP_ACCOUNTS)
     led, codes = sm.create_accounts(
-        sm.make_ledger(1 << 6, 1 << 8, 1 << 6), soa, n, n
+        sm.make_ledger(1 << 6, 1 << 8, 1 << 6),
+        *staging.stage_batch(acc, _LOOP_LANES, _LOOP_ACCOUNTS),
     )
     assert not np.asarray(codes)[:_LOOP_ACCOUNTS].any()
     return led

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from tigerbeetle_tpu import types
 from tigerbeetle_tpu.config import LedgerConfig
 from tigerbeetle_tpu.machine import TpuStateMachine
+from tigerbeetle_tpu.ops import staging
 from tigerbeetle_tpu.ops import state_machine as sm
 from tigerbeetle_tpu.ops import transfer_full as tf
 from tigerbeetle_tpu.testing import model as M
@@ -237,8 +238,9 @@ class TestWaveBound:
         acc["code"][:n] = 10
         for i in limits:
             acc["flags"][i] = types.AccountFlags.DEBITS_MUST_NOT_EXCEED_CREDITS
-        soa = {k: jnp.asarray(v) for k, v in types.to_soa(acc).items()}
-        led, _ = sm.create_accounts(led, soa, jnp.uint64(n), jnp.uint64(n))
+        led, _ = sm.create_accounts(
+            led, *staging.stage_batch(acc[:n], len(acc), n)
+        )
         return led, n
 
     def _plan(self, led, batch, count, ts):

@@ -48,6 +48,7 @@ from .obs.txtrace import txtrace
 from .ops import index as index_ops
 from .ops import merkle as merkle_ops
 from .ops import scrub as scrub_ops
+from .ops import staging
 from .ops import state_machine as sm
 from .ops.scrub import (  # re-exported: the replica's fault-domain surface
     DEVICE_FAULT_TYPES, DeviceStateUnrecoverable, SimulatedDeviceFault,
@@ -76,44 +77,44 @@ U64_MAX = (1 << 64) - 1
 QUERY_ROWS_MAX = ((1 << 20) - 256) // 128
 
 
-def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
-    """Run the fast commit kernel over the leading batches of a GROUP_K
-    stack, one loop step per batch the group HOLDS: one device dispatch,
-    batch order preserved, ledger threaded through the carry (see
+def _group_fast_dispatch_impl(ledger, cols64, cols32, meta):
+    """Run the fast commit kernel over the leading batches of a staged
+    stack (``staging.stage_group``: ``uint64[rows, 14, lanes]``,
+    ``uint32[rows, 5, lanes]``, ``meta = uint64[2, rows]``), one loop step
+    per batch the group HOLDS: one device dispatch, batch order preserved,
+    ledger threaded through the carry (see
     TpuStateMachine.commit_group_fast).
 
     The trip count is a run-time value: the loop ends at the first zero
-    of ``counts`` (a group's batches lead the stack and none is empty) or
-    at GROUP_K, so every group length runs the ONE program the
-    (GROUP_K, lanes) shapes compile to.  On the chip a step over an empty
-    batch costs most of what a full one does (its gathers and scatters
-    run over every lane whatever the count, dropped or not: one TPU v5
-    lite, PERF.md section 5), so the rows past the group are not run at
-    all: their codes stay the zeros the buffer starts with and are never
-    read.
+    of the counts (a group's batches lead the stack and none is empty) or
+    at ``rows``, so every group length that fits a stack runs the ONE
+    program that stack's shapes compile to.  On the chip a step over an
+    empty batch costs most of what a full one does (its gathers and
+    scatters run over every lane whatever the count, dropped or not: one
+    TPU v5 lite, PERF.md section 5), so the rows past the group are not
+    run at all: their codes stay the zeros the buffer starts with and are
+    never read.
 
     Besides (ledger, codes) it returns the transfers probe_overflow flag
     widened into a FRESH uint32 buffer (the deferred readback handle must
     be able to fetch it after a later dispatch donates the ledger; riding
     the commit dispatch it costs zero extra syncs) and what the dispatch
-    closure's index maintenance needs, so it never holds the whole
-    17-column stacked SoA alive past the kernel call and never reads the
-    table back: the stacked id columns and, per batch, ``sm.index_keys``
-    (the stacked account columns and the timestamps each trip stored) and
-    ``sm.written_lanes`` (all False for the rows past the group).
-    ``stacked`` itself is deliberately NOT donated: on XLA-CPU
-    jax.device_put may alias the numpy staging buffers straight into these
-    device arrays (the _stage_group zero-copy note), and a donated alias
-    would let XLA scribble scratch into the pooled staging set behind the
-    dirty-row tracking's back."""
-    steps_max = counts.shape[0]
+    closure's index maintenance needs, so it never slices a staged operand
+    on the host and never reads the table back: the stacked id columns
+    and, per batch, ``sm.index_keys`` (the stacked account columns and the
+    timestamps each trip stored) and ``sm.written_lanes`` (all False for
+    the rows past the group).  The staged operands are NOT donated (the
+    sharded steps' rule, ``ops/staging.py``)."""
+    steps_max = meta.shape[1]
+    counts, timestamps = meta[0], meta[1]
 
     def row(i):
-        soa = {
-            name: jax.lax.dynamic_index_in_dim(col, i, keepdims=False)
-            for name, col in stacked.items()
-        }
-        return soa, counts[i], timestamps[i]
+        return staging.unstage(
+            types.TRANSFER_DTYPE,
+            jax.lax.dynamic_index_in_dim(cols64, i, keepdims=False),
+            jax.lax.dynamic_index_in_dim(cols32, i, keepdims=False),
+            jax.lax.dynamic_index_in_dim(meta, i, axis=1, keepdims=False),
+        )
 
     def holds_a_batch(carry):
         i = carry[0]
@@ -130,9 +131,14 @@ def _group_fast_dispatch_impl(ledger, stacked, counts, timestamps):
 
     # Result codes are uint32 lanes (a step of another dtype fails the
     # trace at the update below).
-    codes = jnp.zeros(stacked["id_lo"].shape, jnp.uint32)
+    codes = jnp.zeros((steps_max, cols64.shape[2]), jnp.uint32)
     _, ledger, codes = jax.lax.while_loop(
         holds_a_batch, step, (jnp.int32(0), ledger, codes)
+    )
+    # The whole stack by column name: [rows, lanes] each.
+    stacked, _, _ = staging.unstage(
+        types.TRANSFER_DTYPE, jnp.moveaxis(cols64, 1, 0),
+        jnp.moveaxis(cols32, 1, 0), meta,
     )
     return (
         ledger, codes, ledger.transfers.probe_overflow.astype(jnp.uint32),
@@ -197,18 +203,17 @@ class DeviceCommitHandle:
     """
 
     __slots__ = ("_machine", "_result", "_stacked", "_counts",
-                 "_timestamps", "_stage", "_resolved", "join_wait_s",
+                 "_timestamps", "_resolved", "join_wait_s",
                  "_batches", "_recovered", "_deferred", "_seq")
 
     def __init__(self, machine, result, counts, timestamps,
-                 stacked: bool, stage=None, batches=None,
+                 stacked: bool, batches=None,
                  deferred: bool = False) -> None:
         self._machine = machine
         self._result = result        # (codes, overflow) | Future of one
         self._stacked = stacked      # True: leading per-batch dim
         self._counts = counts
         self._timestamps = timestamps
-        self._stage = stage          # staging buffer set to release on resolve
         self._resolved = False
         self._deferred = deferred    # counted in the machine's in-flight depth
         # The bus's group this run belongs to: resolve() runs inside a
@@ -228,10 +233,10 @@ class DeviceCommitHandle:
         return len(self._counts)
 
     def discard(self) -> None:
-        """Abort path: QUIESCE the dispatch (join it, swallow its error)
-        and release the staging set — an orphaned closure left running on
-        the lane would keep mutating machine.ledger concurrently with the
-        serving thread after the caller dropped this handle."""
+        """Abort path: QUIESCE the dispatch (join it, swallow its error) —
+        an orphaned closure left running on the lane would keep mutating
+        machine.ledger concurrently with the serving thread after the
+        caller dropped this handle."""
         if self._resolved:
             return
         self._resolved = True
@@ -244,9 +249,6 @@ class DeviceCommitHandle:
                 self._result.result()
             except BaseException:  # tblint: ignore[swallow] abort quiesce
                 pass
-        if self._stage is not None:
-            self._machine._stage_release(self._stage)
-            self._stage = None
 
     def resolve(self) -> List[List[Tuple[int, int]]]:
         assert not self._resolved, "commit handle resolved twice"
@@ -280,12 +282,6 @@ class DeviceCommitHandle:
             return self._recovered
         finally:
             m._inflight_untrack(self)
-            if self._stage is not None:
-                # The dispatch completed (or failed terminally): either
-                # way its H2D reads are over — the staging set must go
-                # back on the free-list, not leak with the handle.
-                m._stage_release(self._stage)
-                self._stage = None
         if _overflow_any(overflow):
             # Load-factor management keeps this unreachable; losing inserts
             # silently is the one unacceptable outcome, so fail loud (the
@@ -525,18 +521,14 @@ class TpuStateMachine:
         self._evictions = 0
         # Commit pipeline (docs/commit_pipeline.md): bounded deferred-
         # readback depth (TB_PIPELINE; resolved lazily so tests can set the
-        # env per-instance), plus the cached host staging buffers for the
-        # grouped H2D upload and the zero-count pad-SoA template.
+        # env per-instance).
         self._pipeline_depth: Optional[int] = None
         # Wave scheduler (TB_WAVES; docs/waves.md), lazy like the depth.
         self._waves_enabled: Optional[bool] = None
-        self._stage_pool: List[tuple] = []  # free staging sets (_stage_acquire)
-        self._pad_soa_zero: dict = {}
         self._lane = None  # FIFO dispatch-lane executor (see _dispatch_lane)
-        # TB_SANITIZE=1 (sanitize.py, test/CI-only): poison released
-        # staging sets, guard the cached zero templates, and trip on
-        # post-warmup recompiles in the serving path.  One bool read at
-        # init; sanitize-off runs take none of the branches.
+        # TB_SANITIZE=1 (sanitize.py, test/CI-only): trip on post-warmup
+        # recompiles in the serving path.  One bool read at init;
+        # sanitize-off runs take none of the branches.
         self._sanitize = _san.enabled()
         # jaxenv.compile_count() as of the last known-legitimate compile
         # point (warmup / growth); None until warmup() arms it.
@@ -974,15 +966,12 @@ class TpuStateMachine:
                     for b, ts in zip(handle._batches, handle._timestamps)
                 ]
                 handle._recovered = results
-                if handle._stage is not None:
-                    self._stage_release(handle._stage)
-                    handle._stage = None
         except BaseException:
             # Recovery itself failed (e.g. escalating to the durable-state
             # rebuild): the not-yet-recovered handles are already
-            # untracked — quiesce them and release their staging sets so
-            # nothing leaks; the caller's pipeline abort (or the direct
-            # caller) sees the escalation, never a dangling handle.
+            # untracked — quiesce them; the caller's pipeline abort (or
+            # the direct caller) sees the escalation, never a dangling
+            # handle.
             for handle in pending:
                 if handle._recovered is not None:
                     continue
@@ -991,9 +980,6 @@ class TpuStateMachine:
                         handle._result.result()
                     except BaseException:  # tblint: ignore[swallow] quiesced fault
                         pass
-                if handle._stage is not None:
-                    self._stage_release(handle._stage)
-                    handle._stage = None
             raise
         self.device_recoveries += 1
         if _obs.enabled:
@@ -1589,14 +1575,12 @@ class TpuStateMachine:
 
     def quarantine(self) -> None:
         """Quarantine the in-flight device pipeline: drain the FIFO dispatch
-        lane (joining any running closure) and invalidate the cached staging
-        buffers and the zero-count pad-SoA template — after a failed or
-        corrupted dispatch chain, every cached device buffer is suspect."""
+        lane (joining any running closure).  Nothing staged is cached (a
+        request's operands are fresh and die with its dispatch), so there
+        is no device buffer of the commit path to invalidate."""
         lane, self._lane = self._lane, None
         if lane is not None:
             lane.shutdown(wait=True)
-        self._stage_pool.clear()
-        self._pad_soa_zero.clear()
 
     def _rematerialize_from_mirror(self) -> None:
         """Rebuild the device ledger (fresh buffers) from the authoritative
@@ -1982,11 +1966,11 @@ class TpuStateMachine:
 
         # The kernels donate the ledger buffers: adopt the returned ledger
         # (a zero-count batch applies nothing, so it is value-identical).
-        soa_a = self._pad_soa(np.zeros(0, dtype=types.ACCOUNT_DTYPE))
-        self.ledger, codes_a = sm.create_accounts(
-            self.ledger, soa_a, jnp.uint64(0), jnp.uint64(1)
-        )
-        soa_t = self._pad_soa(np.zeros(0, dtype=types.TRANSFER_DTYPE))
+        # Staged exactly as the served routes stage.
+        staged_a = self._stage(np.zeros(0, dtype=types.ACCOUNT_DTYPE), 1)
+        self.ledger, codes_a = sm.create_accounts(self.ledger, *staged_a)
+        empty = np.zeros(0, dtype=types.TRANSFER_DTYPE)
+        staged_t = self._stage(empty, 1)
         cold_checked = (
             jnp.zeros((self.batch_lanes,), jnp.bool_) if self._tiering else None
         )
@@ -2000,50 +1984,42 @@ class TpuStateMachine:
         # would charge every history-free server two extra compiles.)
         for has_postvoid in (False, True):
             r = tf.create_transfers_full(
-                self.ledger, soa_t, jnp.uint64(0), jnp.uint64(1),
-                self._bloom_dev, cold_checked,
+                self.ledger, *staged_t, self._bloom_dev, cold_checked,
                 max_passes=self.config.jacobi_max_passes,
                 has_postvoid=has_postvoid,
                 has_history=self._history_accounts_possible,
                 use_waves=self.waves_enabled,
             )
             self.ledger, codes_t, kflags = r[0], r[1], r[2]
-        if self._fast_path_ok(np.zeros(0, dtype=types.TRANSFER_DTYPE)):
+        if self._fast_path_ok(empty):
             # Only pay the extra compile when the fast path is reachable
             # (tiering / restored limit flags / blown balance bound disable
             # it for the process lifetime).
             self.ledger, codes_f = sm.create_transfers_fast(
-                self.ledger, soa_t, jnp.uint64(0), jnp.uint64(1)
+                self.ledger, *staged_t
             )
             np.asarray(codes_f)
             if self.pipeline_depth > 1:
                 # The pipelined serving engine dispatches the PROBED
                 # variant (overflow rides the codes readback in a fresh
                 # buffer); a client must never pay its compile mid-request.
-                # It donates its batch, so the cached zero-count template
-                # gets a throwaway copy here.
-                soa_probe = {k: v.copy() for k, v in soa_t.items()}
-                self.ledger, codes_p, *_ = (
-                    sm.create_transfers_fast_probed(
-                        self.ledger, soa_probe, jnp.uint64(0), jnp.uint64(1)
-                    )
+                self.ledger, codes_p, *_ = sm.create_transfers_fast_probed(
+                    self.ledger, *staged_t
                 )
                 np.asarray(codes_p)
             if self.group_device_commit:
-                # The grouped dispatch is a distinct program, ONE for
-                # every group length (its shapes are GROUP_K's; zero counts
-                # run zero steps); a client must never pay its compile
-                # mid-group.
-                stacked = {
-                    key: jnp.stack([v] * self.GROUP_K)
-                    for key, v in soa_t.items()
-                }
-                zeros = jnp.zeros((self.GROUP_K,), jnp.uint64)
-                self.ledger, codes_g, *_ = (
-                    _group_fast_dispatch(self.ledger, stacked, zeros,
-                                         zeros + 1)
-                )
-                np.asarray(codes_g)
+                # The grouped dispatch is a distinct program for each of
+                # the stack's two leading dimensions (_group_rows), ONE for
+                # every group length that fits it (a zero count runs zero
+                # steps); a client must never pay a compile mid-group.
+                for rows in sorted({self._group_rows(2),
+                                    self._group_rows(self.GROUP_K)}):
+                    self.ledger, codes_g, *_ = _group_fast_dispatch(
+                        self.ledger, *staging.stage_group(
+                            [empty], self.batch_lanes, [1], rows
+                        )
+                    )
+                    np.asarray(codes_g)
         np.asarray(codes_a), np.asarray(codes_t), int(kflags)
 
     # -- prepare (state_machine.zig:503-512) --------------------------------
@@ -2057,59 +2033,32 @@ class TpuStateMachine:
 
     # -- batch plumbing ------------------------------------------------------
 
-    def _pad_soa(self, batch: np.ndarray) -> dict:
-        n = len(batch)
-        assert n <= self.batch_lanes, "batch exceeds configured lanes"
-        if n == 0:
-            # Zero-count pads recur on every grouped commit (and warmup):
-            # the device columns are immutable, so one cached template
-            # replaces a fresh alloc + H2D per batch.  Keyed by
-            # (dtype, pipeline depth): each depth's warmup/serving variant
-            # set owns its template, so flipping the depth (tests, the CLI
-            # --pipeline-depth, a re-warm) never evicts or re-materializes
-            # another depth's — and a template handed to a BATCH-DONATING
-            # kernel variant must always be copied first
-            # (create_transfers_fast_probed's contract).
-            key = (batch.dtype, self.pipeline_depth)
-            cached = self._pad_soa_zero.get(key)
-            if cached is not None and self._sanitize:
-                # A template handed to a batch-donating kernel without a
-                # copy shows up as nonzero columns HERE, at the next
-                # commit — not at the next digest mismatch.
-                _san.template_guard(
-                    cached, where=f"_pad_soa_zero[{key!r}]"
-                )
-            if cached is None:
-                padded = np.zeros(self.batch_lanes, dtype=batch.dtype)
-                cached = {
-                    k: jnp.asarray(v) for k, v in types.to_soa(padded).items()
-                }
-                self._pad_soa_zero[key] = cached
-            return cached
-        padded = np.zeros(self.batch_lanes, dtype=batch.dtype)
-        padded[:n] = batch
-        return {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
+    def _stage(self, batch: np.ndarray, timestamp: int) -> tuple:
+        """The ONE staging of a request (``ops/staging.stage_batch``): the
+        operands every one-chip commit program takes after the ledger,
+        padded to ``batch_lanes``, in one ``device_put`` to the default
+        device.  Fresh host arrays a call: nothing is pooled, so nothing
+        can be refilled under a transfer (or an XLA-CPU alias) that still
+        reads it, and no program donates them."""
+        return staging.stage_batch(batch, self.batch_lanes, timestamp)
 
     def _stage_sharded(self, batch: np.ndarray, timestamp: int) -> tuple:
-        """The ONE staging of the mesh (``parallel/sharded.stage_batch``):
-        the operands every ``self._shard_steps`` commit program takes after
-        the ledger, padded to ``batch_lanes`` and already replicated on the
-        mesh.  ``_pad_soa`` stays the single-device kernels' own: they
-        DONATE what it stages and hand index keys back from it; the sharded
-        programs donate the ledger alone and keep a lazy index, so nothing
-        reads these operands after the dispatch.  Runs on the thread that
-        enqueues the program right after: the serving thread on the blocking
-        routes and for a lone deferred request, the LANE thread for every
-        batch of a grouped run (``_commit_group_fast_sharded``).  Safe
-        there: ``stage_batch`` allocates fresh host arrays a call, and what
-        it reads of the machine (``_shard_mesh``, ``batch_lanes``) changes
-        only at a reshard's cutover, which runs between commits."""
-        from .parallel import sharded as shard_mod
+        """``_stage``'s twin on the mesh: the same operands, already
+        replicated on it, for every ``self._shard_steps`` commit program.
+        Runs on the thread that enqueues the program right after: the
+        serving thread on the blocking routes and for a lone deferred
+        request, the LANE thread for every batch of a grouped run
+        (``_commit_group_fast_sharded``).  Safe there: ``stage_batch``
+        allocates fresh host arrays a call, and what it reads of the
+        machine (``_shard_mesh``, ``batch_lanes``) changes only at a
+        reshard's cutover, which runs between commits."""
+        from jax.sharding import NamedSharding, PartitionSpec
 
         if _obs.enabled:
             _obs.counter("sharding.staged").inc()
-        return shard_mod.stage_batch(
-            self._shard_mesh, batch, self.batch_lanes, timestamp
+        return staging.stage_batch(
+            batch, self.batch_lanes, timestamp,
+            NamedSharding(self._shard_mesh, PartitionSpec()),
         )
 
     @staticmethod
@@ -2200,15 +2149,13 @@ class TpuStateMachine:
             # probe_overflow check below reads the per-shard lane vector.
             with txtrace.stage("stage_h2d"):
                 staged = self._stage_sharded(batch, timestamp)
-            soa = None  # the scan sets reset under shards: nothing reads it
             self.ledger, codes = self._shard_steps["accounts"](
                 self.ledger, *staged
             )
         else:
-            soa = self._pad_soa(batch)
-            self.ledger, codes = sm.create_accounts(
-                self.ledger, soa, jnp.uint64(count), jnp.uint64(timestamp)
-            )
+            with txtrace.stage("stage_h2d"):
+                staged = self._stage(batch, timestamp)
+            self.ledger, codes = sm.create_accounts(self.ledger, *staged)
         codes, overflow = self._d2h_codes(
             codes, self.ledger.accounts.probe_overflow
         )
@@ -2217,7 +2164,7 @@ class TpuStateMachine:
             # Load-factor management keeps this unreachable; losing inserts
             # silently is the one unacceptable outcome, so fail loud.
             raise RuntimeError("accounts probe overflow during insert")
-        self._scan_append_accounts(soa, codes, count)
+        self._scan_append_accounts(staged, codes, count)
         results = self._compress(codes, count)
         self._update_commit_timestamp(codes, count, timestamp)
         return results
@@ -2281,12 +2228,11 @@ class TpuStateMachine:
                 transfers=count, posted=pv_count, history=hist_count
             )
         with txtrace.stage("stage_h2d"):
-            soa = self._pad_soa(batch)
+            staged = self._stage(batch, timestamp)
             cold_checked = (
                 jnp.zeros((self.batch_lanes,), jnp.bool_)
                 if self._tiering else None
             )
-            count_dev, timestamp_dev = jnp.uint64(count), jnp.uint64(timestamp)
         # STATIC phase hints: a batch with no post/void lanes skips the
         # four pending-side probe loops and the posted write; a ledger that
         # provably holds no HISTORY-flagged account skips the 21-column
@@ -2297,8 +2243,7 @@ class TpuStateMachine:
         for _attempt in range(8):
             with txtrace.stage("dispatch"):
                 r = tf.create_transfers_full(
-                    self.ledger, soa, count_dev, timestamp_dev,
-                    self._bloom_dev, cold_checked,
+                    self.ledger, *staged, self._bloom_dev, cold_checked,
                     max_passes=self.config.jacobi_max_passes,
                     has_postvoid=has_postvoid, has_history=has_history,
                     use_waves=use_waves,
@@ -2310,8 +2255,8 @@ class TpuStateMachine:
             kflags, wave_host = self._full_kflags_sync(kflags, wave_vec)
             if kflags == 0:
                 results = self._full_commit_success(
-                    soa, codes, count, pv_count, hist_count, timestamp,
-                    wave_host, keys=r[-1],
+                    codes, count, pv_count, hist_count, timestamp,
+                    wave_host, index_feed=r[-4:],
                 )
                 # Deferred tier rebalance: eviction is only safe BETWEEN
                 # batches (mid-loop it would invalidate the certification
@@ -2369,15 +2314,15 @@ class TpuStateMachine:
             _obs.histogram("ops.dispatch_wait_us", "us").observe(wait * 1e6)
         return kflags, wave_host
 
-    def _full_commit_success(self, soa, codes, count, pv_count, hist_count,
-                             timestamp, wave_host, keys=None):
+    def _full_commit_success(self, codes, count, pv_count, hist_count,
+                             timestamp, wave_host, index_feed=None):
         """Post-commit bookkeeping of a COMMITTED general-kernel batch
         (kflags == 0), shared by both dispatch loops.  Only committed
         batches feed the wave occupancy series — a routed or retried
         attempt applied nothing and would overstate them — and only a
-        committed attempt's ``keys`` (the index key columns its kernel
-        wrote; None from the sharded loop, whose index is lazy) reach the
-        index."""
+        committed attempt's ``index_feed`` (the id columns, the index key
+        columns its kernel wrote and the lanes it wrote them for; None
+        from the sharded loop, whose index is lazy) reaches the index."""
         if wave_host is not None:
             self._record_wave_metrics(wave_host)
         if _obs.enabled:
@@ -2389,7 +2334,10 @@ class TpuStateMachine:
         self._posted_bound += pv_count
         self._history_bound += hist_count
         with txtrace.stage("index_append"):
-            self._index_append(soa, codes, count, keys)
+            if index_feed is None:
+                self._index_lazy_reset()
+            else:
+                self._index_append_device(*index_feed)
         results = self._compress(codes, count)
         if _obs.enabled:
             _obs.counter("ops.general.rejected_lanes").inc(len(results))
@@ -2498,10 +2446,9 @@ class TpuStateMachine:
             if kflags == 0:
                 if _obs.enabled:
                     _obs.counter("sharding.batches").inc()
-                # soa=None: the index append is a reset under shards.
+                # No index feed: the index append is a reset under shards.
                 return self._full_commit_success(
-                    None, codes, count, pv_count, hist_count, timestamp,
-                    wave_host,
+                    codes, count, pv_count, hist_count, timestamp, wave_host,
                 )
             if kflags & tf.FLAG_SEQ:
                 # Order-dependent (linked / balancing-chain / limit
@@ -3079,67 +3026,24 @@ class TpuStateMachine:
             self._dispatch_lane().submit(staged) if deferred else staged()
         )
 
-    # The cap on a grouped dispatch's run, and the leading dimension of its
-    # stacked operands: ONE jit variant (warmed at startup) whatever the
-    # group's length.  A group pads the stack with zero-count rows and the
-    # program's loop stops at the first of them, so a group of k costs k
-    # steps (a padded step would cost most of what a full one does: the
-    # kernel's table-sized work does not depend on the count).  Amortizing
-    # a run over one dispatch + one readback keeps the device serving path
-    # off the per-dispatch host round trip.
+    # The cap on a grouped dispatch's run.  A group pads its stack with
+    # zero-count rows and the program's loop stops at the first of them, so
+    # a group of k costs k steps (a padded step would cost most of what a
+    # full one does: the kernel's table-sized work does not depend on the
+    # count).  Amortizing a run over one dispatch + one readback keeps the
+    # device serving path off the per-dispatch host round trip.
     GROUP_K = 32
+    # The stack's leading dimension for a SHORT run: the upload goes by the
+    # stack's bytes, not the run's (1.08 MB a row), and with 8 sessions no
+    # run is longer than 8.  One jit variant for each of the two leading
+    # dimensions, both warmed at startup.
+    GROUP_ROWS_SHORT = 8
 
-    def _stage_acquire(self):
-        """One cached staging buffer set for the grouped H2D upload, from
-        the free-list (or freshly allocated when every cached set is still
-        referenced by an in-flight dispatch): jax may alias a numpy buffer
-        straight into the device transfer (zero-copy on XLA-CPU), so a set
-        must never be refilled while a dispatch that reads it is in flight
-        — DeviceCommitHandle.resolve releases the set back here."""
-        if self._stage_pool:
-            return self._stage_pool.pop()
-        bufs = {}
-        for name in types.TRANSFER_DTYPE.names:
-            dt = types.TRANSFER_DTYPE.fields[name][0]
-            if dt == np.uint16:
-                dt = np.dtype(np.uint32)  # to_soa's widening
-            bufs[name] = np.zeros((self.GROUP_K, self.batch_lanes), dt)
-        return (bufs, [0] * self.GROUP_K)
-
-    def _stage_release(self, stage) -> None:
-        if self._sanitize:
-            # Donation poisoning: anything still reading this set after
-            # release (the runtime use-after-donate) sees 0xA5 garbage,
-            # not stale plausible rows.  Mark every lane dirty so the
-            # next _stage_group occupant zeroes its full tail.
-            bufs, dirty = stage
-            _san.poison(bufs.values())
-            for j in range(len(dirty)):
-                dirty[j] = self.batch_lanes
-        self._stage_pool.append(stage)
-
-    def _stage_group(self, batches: List[np.ndarray]):
-        """Staged H2D upload for the grouped dispatch: host-side stack of
-        the run's batches into a cached staging buffer set, then ONE
-        jax.device_put per field — replacing the previous K x fields
-        separate transfers plus a device-side jnp.stack.  Dirty-row
-        tracking zeroes only the lanes the set's previous occupant
-        touched.  Returns (device columns, staging set) — the caller owns
-        the set until its dispatch resolved."""
-        stage = self._stage_acquire()
-        bufs, dirty = stage
-        for name, buf in bufs.items():
-            for j in range(self.GROUP_K):
-                n = len(batches[j]) if j < len(batches) else 0
-                if dirty[j] > n:
-                    buf[j, n:dirty[j]] = 0
-                if n:
-                    buf[j, :n] = batches[j][name]
-        for j in range(self.GROUP_K):
-            dirty[j] = len(batches[j]) if j < len(batches) else 0
-        return (
-            {name: jax.device_put(buf) for name, buf in bufs.items()}, stage
-        )
+    def _group_rows(self, k: int) -> int:
+        """The leading dimension of a grouped run's staged stack, from the
+        run's length: the short stack where it fits, GROUP_K beyond."""
+        short = self.GROUP_ROWS_SHORT
+        return short if k <= short < self.GROUP_K else self.GROUP_K
 
     def commit_group_fast(
         self, batches: List[np.ndarray], timestamps: List[int],
@@ -3202,13 +3106,8 @@ class TpuStateMachine:
             _obs.counter("ops.group.batches").inc(k)
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d", n=k):
-            stacked, stage = self._stage_group(batches)
-            cnt = jnp.asarray(
-                counts + [0] * (self.GROUP_K - k), dtype=jnp.uint64
-            )
-            tss = jnp.asarray(
-                timestamps + [timestamps[-1]] * (self.GROUP_K - k),
-                dtype=jnp.uint64,
+            staged = staging.stage_group(
+                batches, self.batch_lanes, timestamps, self._group_rows(k)
             )
         # Host row bounds advance at SUBMIT (not readback): the next
         # group's growth decision must see this group's inserts coming,
@@ -3236,12 +3135,10 @@ class TpuStateMachine:
             # (or lane.shutdown(wait=True) in reset paths).
             with txtrace.stage("dispatch", seq=seq, n=k):
                 (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
-                 id_lo, id_hi, keys, ok) = _group_fast_dispatch(
-                    self.ledger, stacked, cnt, tss
-                )
+                 *index_feed) = _group_fast_dispatch(self.ledger, *staged)
             with txtrace.stage("index_append", seq=seq, n=k):
                 for j in range(k):
-                    self._index_append_device(keys, id_lo, id_hi, ok, row=j)
+                    self._index_append_device(*index_feed, row=j)
             if merkle_closure:
                 # Commitment updates ride the ledger chain on the lane,
                 # PER BATCH: one key-size class per workload shape, so
@@ -3256,7 +3153,7 @@ class TpuStateMachine:
         armed = armed_mirror or self._merkle_forest is not None
         result = self._lane_dispatch(dispatch, deferred, seq)
         handle = DeviceCommitHandle(
-            self, result, counts, timestamps, stacked=True, stage=stage,
+            self, result, counts, timestamps, stacked=True,
             # Batch retention feeds mirror recovery re-dispatch; the
             # forest needs no retention (a mismatch escalates to the
             # durable-state rebuild instead).
@@ -3385,10 +3282,9 @@ class TpuStateMachine:
         if _obs.enabled:
             _obs.counter("ops.route.fast").inc()
         self._grow_if_needed(transfers=count)
-        soa = self._pad_soa(batch)
-        self.ledger, codes = sm.create_transfers_fast(
-            self.ledger, soa, jnp.uint64(count), jnp.uint64(timestamp)
-        )
+        with txtrace.stage("stage_h2d"):
+            staged = self._stage(batch, timestamp)
+        self.ledger, codes = sm.create_transfers_fast(self.ledger, *staged)
         # Overflow flag rides the codes readback: one sync, not two.
         codes, overflow = self._d2h_codes(
             codes, self.ledger.transfers.probe_overflow
@@ -3398,7 +3294,7 @@ class TpuStateMachine:
             # Load-factor management keeps this unreachable; losing inserts
             # silently is the one unacceptable outcome, so fail loud.
             raise RuntimeError("transfers probe overflow during fast insert")
-        self._index_append(soa, codes, count)
+        self._index_append(staged, codes, count)
         results = self._compress(codes, count)
         self._update_commit_timestamp(codes, count, timestamp)
         return results
@@ -3452,11 +3348,10 @@ class TpuStateMachine:
         seq = txtrace.group_seq  # for the closure's spans on the lane
         with txtrace.stage("stage_h2d"):
             # Staged on the serving thread, for the route's own programs.
-            if self._ledger_is_sharded:
-                staged = self._stage_sharded(batch, timestamp)
-            else:
-                soa = self._pad_soa(batch)
-                cnt, ts = jnp.uint64(count), jnp.uint64(timestamp)
+            staged = (
+                self._stage_sharded(batch, timestamp)
+                if self._ledger_is_sharded else self._stage(batch, timestamp)
+            )
         # Snapshot the growth target pre-submit (see _grow_if_needed).
         need = self._transfers_bound + count
         self._transfers_bound += count
@@ -3489,22 +3384,18 @@ class TpuStateMachine:
             def dispatch():
                 with txtrace.stage("grow", seq=seq):
                     self._grow_if_needed(transfers_need=need)
-                # The probed kernel donates BOTH the ledger and the staged
-                # SoA (the pad columns become scratch instead of pinned
-                # inputs); index maintenance uses the passed-through id
-                # and key columns — the donated ``soa`` dict must not be
-                # touched after this call.
+                # Index maintenance uses the id and key columns the
+                # program passes through: no staged operand is sliced here.
                 with txtrace.stage("dispatch", seq=seq):
                     (self.ledger, codes, overflow,  # tblint: ignore[lane-race] FIFO+join
-                     id_lo, id_hi, keys, ok) = sm.create_transfers_fast_probed(
-                        self.ledger, soa, cnt, ts
+                     *index_feed) = sm.create_transfers_fast_probed(
+                        self.ledger, *staged
                     )
                 with txtrace.stage("index_append", seq=seq):
-                    self._index_append_device(keys, id_lo, id_hi, ok)
+                    self._index_append_device(*index_feed)
                 if merkle_closure:
                     # Commitment update rides the ledger chain; keys come
-                    # from the retained HOST batch (the staged SoA was
-                    # donated above).
+                    # from the retained HOST batch.
                     self._merkle_update_transfers_batches([batch])
                 return codes, overflow
 
@@ -3917,7 +3808,8 @@ class TpuStateMachine:
                 transfers=count, posted=pv_count, history=hist_count
             )
 
-        soa = self._pad_soa(batch)
+        with txtrace.stage("stage_h2d"):
+            staged = self._stage(batch, timestamp)
         kernel = (
             scan_path.create_accounts_seq
             if operation == "create_accounts"
@@ -3927,18 +3819,16 @@ class TpuStateMachine:
         # mutation the touched-key over-approximation cannot see; the
         # commitment forest rebuilds at the next update/check.
         self._merkle_mark_dirty()
-        self.ledger, codes = kernel(
-            self.ledger, soa, jnp.uint64(count), jnp.uint64(timestamp)
-        )
+        self.ledger, codes = kernel(self.ledger, *staged)
         codes = self._d2h_codes(codes)
         if operation == "create_accounts":
             self._accounts_bound += count
-            self._scan_append_accounts(soa, codes, count)
+            self._scan_append_accounts(staged, codes, count)
         else:
             self._transfers_bound += count
             self._posted_bound += pv_count
             self._history_bound += hist_count
-            self._index_append(soa, codes, count)
+            self._index_append(staged, codes, count)
         results = self._compress(codes, count)
         self._update_commit_timestamp(codes, count, timestamp)
         return results
@@ -3955,10 +3845,11 @@ class TpuStateMachine:
         self.scans_transfers.reset()
         return True
 
-    def _index_append_device(self, keys, id_lo, id_hi, ok, row=None) -> None:
-        """The index append of a dispatch-lane closure, right after its
-        kernel, from what that kernel returned: the index key columns, the
-        id columns and the lanes it wrote (a grouped dispatch: all of them
+    def _index_append_device(self, id_lo, id_hi, keys, ok, row=None) -> None:
+        """The index append of a keyed route (a dispatch-lane closure, or
+        the blocking general commit), right after its kernel, from what
+        that kernel returned, in its order: the id columns, the index key
+        columns and the lanes it wrote (a grouped dispatch: all of them
         stacked, and ``row`` the batch).  No mask and no slice is taken
         here: on a TPU each would be a dispatch the device waits for."""
         if self._index_lazy_reset():
@@ -3987,46 +3878,42 @@ class TpuStateMachine:
             # log(rows) levels ever).  Same grace as a table growth.
             self._sanitize_grace = True
 
-    def _index_append(
-        self, soa: dict, codes: np.ndarray, count: int, keys=None
-    ) -> None:
-        """The index append of a blocking route, from host codes.  ``keys``
-        are the index key columns the route's kernel returned; a route whose
-        kernel returns none (the sequential path, the unprobed fast kernel)
-        reads them back from the transfers table by id."""
-        if self._index_lazy_reset():
-            return
+    def _written_mask(self, codes: np.ndarray, count: int) -> jax.Array:
         ok = np.zeros(self.batch_lanes, dtype=bool)
         ok[:count] = codes[:count] == 0
-        ok_dev = jnp.asarray(ok)
-        probed = keys is None
+        return jnp.asarray(ok)
+
+    def _index_append(self, staged: tuple, codes: np.ndarray, count: int) -> None:
+        """The index append of a blocking route whose kernel returns no
+        keys (the sequential path, the unprobed fast kernel), from host
+        codes: the keys are read back from the transfers table by id.  The
+        id columns are sliced out of the staged operands here, on the host
+        (``staging.id_columns``): two programs of their own, on routes no
+        deferred commit takes."""
+        if self._index_lazy_reset():
+            return
+        ok_dev = self._written_mask(codes, count)
+        id_lo, id_hi = staging.id_columns(staged, types.TRANSFER_DTYPE)
         if _obs.enabled:
-            _obs.counter(
-                "index.runs.probed" if probed else "index.runs.keyed"
-            ).inc()
-        written = ok_dev
-        if probed:
-            keys, written = index_ops.probe_keys(
-                self.ledger, soa["id_lo"], soa["id_hi"], ok_dev
-            )
-        self.index.append_batch(keys, soa["id_lo"], soa["id_hi"], written)
+            _obs.counter("index.runs.probed").inc()
+        keys, written = index_ops.probe_keys(self.ledger, id_lo, id_hi, ok_dev)
+        self.index.append_batch(keys, id_lo, id_hi, written)
         if self.scans_transfers.indexes:
             self.scans_transfers.append_batch(
-                self.ledger, soa["id_lo"], soa["id_hi"], ok_dev
+                self.ledger, id_lo, id_hi, ok_dev
             )
 
     def _scan_append_accounts(
-        self, soa: dict, codes: np.ndarray, count: int
+        self, staged: tuple, codes: np.ndarray, count: int
     ) -> None:
         if not self.scans_accounts.indexes:
             return
         if self.config.lazy_index or self._shard_mesh is not None:
             self.scans_accounts.reset()
             return
-        ok = np.zeros(self.batch_lanes, dtype=bool)
-        ok[:count] = codes[:count] == 0
         self.scans_accounts.append_batch(
-            self.ledger, soa["id_lo"], soa["id_hi"], jnp.asarray(ok)
+            self.ledger, *staging.id_columns(staged, types.ACCOUNT_DTYPE),
+            self._written_mask(codes, count),
         )
 
     def _update_commit_timestamp(

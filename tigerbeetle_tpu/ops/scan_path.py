@@ -25,15 +25,15 @@ completeness; the dispatcher sends hot batches to the vectorized kernels.
 
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .. import u128
+from .. import types, u128
 from ..u128 import U128
 from . import hash_table as ht
+from . import staging
 from .state_machine import (
     ACCOUNT_COLS,
     AF_CREDITS_MUST_NOT_EXCEED_DEBITS,
@@ -55,6 +55,16 @@ from .state_machine import (
 )
 
 U64M = jnp.uint64(0xFFFF_FFFF_FFFF_FFFF)
+
+
+def _staged_program(dtype):
+    """jit a sequential kernel as a program over ``staging.stage_batch``'s
+    operands, the ledger donated."""
+    def jit(impl):
+        return jax.jit(
+            staging.staged(impl, dtype), donate_argnames=("ledger",)
+        )
+    return jit
 
 BALANCE_FIELDS = (
     "debits_pending_lo",
@@ -184,7 +194,7 @@ def _balance_lanes(b: Dict[str, U128]) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, donate_argnames=("ledger",))
+@_staged_program(types.TRANSFER_DTYPE)
 def create_transfers_seq(
     ledger: Ledger,
     batch: Dict[str, jax.Array],
@@ -687,7 +697,7 @@ def _exists_postvoid_scalar(t, e, p):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, donate_argnames=("ledger",))
+@_staged_program(types.ACCOUNT_DTYPE)
 def create_accounts_seq(
     ledger: Ledger,
     batch: Dict[str, jax.Array],

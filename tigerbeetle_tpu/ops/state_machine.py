@@ -39,10 +39,11 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from .. import u128
+from .. import types, u128
 from ..obs.metrics import registry as _metrics
 from ..u128 import U128
 from . import hash_table as ht
+from . import staging
 
 MAX_PROBE = 1 << 12
 
@@ -456,7 +457,8 @@ def create_accounts_impl(
 
 
 create_accounts = _obs_jit(
-    create_accounts_impl, "create_accounts", donate_argnames=("ledger",)
+    staging.staged(create_accounts_impl, types.ACCOUNT_DTYPE),
+    "create_accounts", donate_argnames=("ledger",),
 )
 
 
@@ -724,8 +726,8 @@ def create_transfers_impl(
 
 
 create_transfers_fast = _obs_jit(
-    create_transfers_impl, "create_transfers_fast",
-    donate_argnames=("ledger",),
+    staging.staged(create_transfers_impl, types.TRANSFER_DTYPE),
+    "create_transfers_fast", donate_argnames=("ledger",),
 )
 
 
@@ -746,16 +748,16 @@ def create_transfers_fast_probed_impl(
     donation check.  Riding the commit dispatch, it costs zero extra syncs
     (the codes D2H carries it along).
 
-    The BATCH is donated along with the ledger (its ~1 MB of pad-SoA
-    columns become scratch/output space instead of live inputs pinned for
-    the whole dispatch); what the caller's index maintenance needs is
-    passed through as outputs, which may alias the donated buffers: the
-    id columns, ``index_keys`` (the account columns and the timestamps
-    the kernel stored) and ``written_lanes`` (the lanes it stored a row
-    for), so the append costs the host one dispatch and no mask or slice
-    of its own.  Callers must hand this kernel a per-dispatch staged SoA
-    (machine._pad_soa with count > 0, or an explicit copy) — never the
-    cached zero-count template."""
+    What the caller's index maintenance needs is passed through as
+    outputs: the id columns, ``index_keys`` (the account columns and the
+    timestamps the kernel stored) and ``written_lanes`` (the lanes it
+    stored a row for), so the append costs the host one dispatch and no
+    mask or slice of its own (a slice of a staged operand taken on the host
+    would be a program the device waits for).  The staged operands are NOT
+    donated: the id columns are slices of a packed buffer and could not
+    alias it, the 1 MB is fresh for every request and freed with it, and
+    on XLA-CPU ``device_put`` may alias the host arrays zero-copy (the
+    sharded steps' rule, ``ops/staging.py``)."""
     id_lo, id_hi = batch["id_lo"], batch["id_hi"]
     keys = index_keys(batch, count, timestamp)
     ledger, codes = create_transfers_impl(ledger, batch, count, timestamp)
@@ -766,8 +768,8 @@ def create_transfers_fast_probed_impl(
 
 
 create_transfers_fast_probed = _obs_jit(
-    create_transfers_fast_probed_impl, "create_transfers_fast_probed",
-    donate_argnames=("ledger", "batch"),
+    staging.staged(create_transfers_fast_probed_impl, types.TRANSFER_DTYPE),
+    "create_transfers_fast_probed", donate_argnames=("ledger",),
 )
 
 

@@ -79,9 +79,10 @@ from typing import Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import u128
+from .. import types, u128
 from ..u128 import U128
 from . import hash_table as ht
+from . import staging
 from .state_machine import (
     AF_CREDITS_MUST_NOT_EXCEED_DEBITS,
     AF_DEBITS_MUST_NOT_EXCEED_CREDITS,
@@ -101,6 +102,7 @@ from .state_machine import (
     _chain_codes,
     _timestamps,
     _u128_col,
+    written_lanes,
 )
 
 # Routing flag bits returned by the kernel (uint32). Nonzero => nothing was
@@ -1283,9 +1285,12 @@ def create_transfers_full_impl(
     use_waves: bool = False,
 ) -> Tuple[jax.Array, ...]:
     """Returns (ledger', codes uint32[N], flags uint32 scalar), a fourth
-    wave-profile vector when ``use_waves`` (see below), and LAST the
-    INDEX_KEY_COLS of the rows it wrote (a post or void lane: the PENDING
-    transfer's accounts), for the secondary index (ops/index.py).
+    wave-profile vector when ``use_waves`` (see below), and LAST what the
+    secondary index (ops/index.py) takes, as the fast programs give it: the
+    batch's two id columns, the INDEX_KEY_COLS of the rows it wrote (a post
+    or void lane: the PENDING transfer's accounts) and the lanes it wrote
+    (``sm.written_lanes``), so the host slices no staged operand and
+    uploads no mask.
 
     flags == 0: the batch was applied and ``codes`` are the final results.
     flags != 0: NOTHING was applied (ledger' == ledger value-wise); the host
@@ -1402,14 +1407,20 @@ def create_transfers_full_impl(
     out = Ledger(
         accounts=accounts, transfers=transfers, posted=posted, history=history
     )
-    keys = {name: ins_rows[name] for name in INDEX_KEY_COLS}
+    # What the host's index append takes, in the fast programs' order: the
+    # id columns, the key columns of the rows written, the lanes written.
+    index_feed = (
+        batch["id_lo"], batch["id_hi"],
+        {name: ins_rows[name] for name in INDEX_KEY_COLS},
+        written_lanes(plan.codes, count),
+    )
     if use_waves:
         wave_vec = jnp.concatenate([
             plan.passes.reshape(1), plan.wave_bound.reshape(1),
             plan.wave_hist,
         ])
-        return out, plan.codes, kflags, wave_vec, keys
-    return out, plan.codes, kflags, keys
+        return (out, plan.codes, kflags, wave_vec) + index_feed
+    return (out, plan.codes, kflags) + index_feed
 
 
 def _exists_regular(t, e, t_amount: U128, n) -> jax.Array:
@@ -1472,7 +1483,8 @@ def _exists_postvoid(t, e, p, n) -> jax.Array:
 
 
 create_transfers_full = jax.jit(
-    create_transfers_full_impl, donate_argnames=("ledger",),
+    staging.staged(create_transfers_full_impl, types.TRANSFER_DTYPE),
+    donate_argnames=("ledger",),
     static_argnames=(
         "max_passes", "has_postvoid", "has_history", "use_waves",
     ),

@@ -49,8 +49,7 @@ writes ``tb/full_apply|full_posted``.
 
 from __future__ import annotations
 
-import functools
-from typing import Dict, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +59,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import types
 from ..u128 import mix64
 from ..ops import hash_table as ht
+from ..ops import staging
 from ..ops import state_machine as sm
 from ..ops.state_machine import (
     ACCOUNT_COLS,
@@ -155,51 +155,6 @@ def _specs_like(tree):
     return jax.tree_util.tree_map(lambda _: P(AXIS), tree)
 
 
-@functools.lru_cache(maxsize=None)
-def _staged_names(dtype: np.dtype) -> Tuple[tuple, tuple]:
-    """A wire dtype's fields by staged width: the ``uint64`` columns, and the
-    narrower ones (``uint32`` on the device: ``types.to_soa``'s widening)."""
-    wide = tuple(n for n in dtype.names if dtype.fields[n][0] == np.uint64)
-    return wide, tuple(n for n in dtype.names if n not in wide)
-
-
-def stage_batch(mesh: Mesh, batch: np.ndarray, lanes: int, timestamp: int):
-    """Stage one host batch for a sharded commit step: the operands every
-    step takes after the ledger, put ONCE and already replicated on ``mesh``,
-    so that the step's dispatch finds each one in place on every chip.
-
-    Returns ``(cols64, cols32, meta)``: the batch's ``uint64`` columns as the
-    rows of one ``uint64[14, lanes]`` buffer, its narrower ones as the rows
-    of one ``uint32[5, lanes]``, both in the dtype's field order and zero
-    beyond ``len(batch)`` (the pad contract the kernels rely on), and
-    ``meta = uint64[2]`` = (count, timestamp): 3 transfers a chip in ONE
-    ``device_put`` where 19 column puts to device 0 and two eager scalars
-    were re-placed on every chip at every call (PERF.md PR 38).  The host
-    arrays are fresh for each batch, so nothing can refill one under a
-    transfer that still reads it; the steps slice the columns back out by
-    name (``_unstage``) inside the program."""
-    n = len(batch)
-    assert n <= lanes, "batch exceeds configured lanes"
-    wide, narrow = _staged_names(batch.dtype)
-    cols64 = np.zeros((len(wide), lanes), np.uint64)
-    cols32 = np.zeros((len(narrow), lanes), np.uint32)
-    for i, name in enumerate(wide):
-        cols64[i, :n] = batch[name]
-    for i, name in enumerate(narrow):
-        cols32[i, :n] = batch[name]
-    meta = np.array([n, timestamp], np.uint64)
-    return jax.device_put((cols64, cols32, meta), NamedSharding(mesh, P()))
-
-
-def _unstage(dtype: np.dtype, cols64, cols32, meta):
-    """Inside a step: ``stage_batch``'s operands back as (the batch's columns
-    by name, count, timestamp), what the single-device kernels take."""
-    wide, narrow = _staged_names(dtype)
-    batch = {name: cols64[i] for i, name in enumerate(wide)}
-    batch.update({name: cols32[i] for i, name in enumerate(narrow)})
-    return batch, meta[0], meta[1]
-
-
 def _psum_one_owner(x):
     """psum of a value that at most ONE shard holds non-zero (every other
     shard contributes 0).  The TPU lowers only plain 32-bit Sum all-reduces
@@ -245,7 +200,7 @@ class _ShardGather:
 def sharded_create_transfers(mesh: Mesh, probed: bool = False):
     """Build the jitted sharded create_transfers step for ``mesh``.
 
-    Returns fn(ledger, *stage_batch(...)) -> (ledger, codes), with the
+    Returns fn(ledger, *staging.stage_batch(...)) -> (ledger, codes), with the
     ledger sharded per make_sharded_ledger and the staged batch replicated.
 
     ``probed`` (STATIC) additionally returns the per-shard transfers
@@ -258,7 +213,7 @@ def sharded_create_transfers(mesh: Mesh, probed: bool = False):
     shift = n_shards.bit_length() - 1
 
     def local_step(ledger: Ledger, cols64, cols32, meta):
-        batch, count, timestamp = _unstage(
+        batch, count, timestamp = staging.unstage(
             types.TRANSFER_DTYPE, cols64, cols32, meta
         )
         acc, tr = ledger.accounts, ledger.transfers
@@ -377,7 +332,7 @@ def sharded_create_transfers_full(
     exact docs/waves.md semantics, now on the mesh path.  On, a FOURTH
     replicated int32[11] wave-profile vector is returned.
 
-    Returns fn(ledger, *stage_batch(...)) -> (ledger, codes, kflags
+    Returns fn(ledger, *staging.stage_batch(...)) -> (ledger, codes, kflags
     [, wave_vec]).
     """
     from ..ops import transfer_full as _tf
@@ -406,7 +361,7 @@ def sharded_create_transfers_full(
         )
 
     def local_step(ledger: Ledger, cols64, cols32, meta):
-        batch, count, timestamp = _unstage(
+        batch, count, timestamp = staging.unstage(
             types.TRANSFER_DTYPE, cols64, cols32, meta
         )
         acc, tr, posted_t = ledger.accounts, ledger.transfers, ledger.posted
@@ -625,12 +580,12 @@ def sharded_lookup(mesh: Mesh, table_name: str):
 
 def sharded_create_accounts(mesh: Mesh):
     """Jitted sharded create_accounts step for ``mesh``:
-    fn(ledger, *stage_batch(...)) -> (ledger, codes)."""
+    fn(ledger, *staging.stage_batch(...)) -> (ledger, codes)."""
     n_shards = mesh.devices.size
     shift = n_shards.bit_length() - 1
 
     def local_step(ledger: Ledger, cols64, cols32, meta):
-        batch, count, timestamp = _unstage(
+        batch, count, timestamp = staging.unstage(
             types.ACCOUNT_DTYPE, cols64, cols32, meta
         )
         acc = ledger.accounts

@@ -3,20 +3,11 @@
 The static suite (tools/tblint rules donation / size-class / lane-race /
 shard-rep) proves discipline over the source; this module is its runtime
 twin for the cases static analysis cannot close — test/CI-only (the
-checks cost real work: buffer fills, D2H template reads), never armed in
-production serving.  Three checks, in the VOPR spirit of "assert the
-invariant, then search for the violation":
-
-- DONATION POISONING — when a pooled staging set goes back on the
-  machine's free-list, every byte is filled with the 0xA5 sentinel.  A
-  use-after-release (the runtime shape of use-after-donate: a dispatch
-  closure or index append still holding the pooled numpy mirror after
-  resolve released it) now reads screaming garbage instead of stale
-  plausible rows, and ``assert_not_poisoned`` turns it into a hard error
-  at the consumer.  The cached zero-count pad template gets the dual
-  check: ``template_guard`` verifies it is still all-zero at every reuse,
-  so a kernel that donated it (the machine._pad_soa contract) is caught
-  at the NEXT commit, not at the next digest mismatch.
+checks cost real work), never armed in production serving.  Two checks, in
+the VOPR spirit of "assert the invariant, then search for the violation"
+(a third, donation poisoning of the pooled staging sets and a guard on the
+cached zero-count template, went with the pool and the template in PR 46:
+a request's operands are fresh host arrays that no program donates):
 
 - RECOMPILE TRIPWIRE — ``compile_tripwire`` diffs
   ``jaxenv.compile_count()`` around a region that must not compile
@@ -37,20 +28,12 @@ with the registry off) and, when the registry is enabled, a
 from __future__ import annotations
 
 import os
-from typing import Dict, Iterable, Optional
-
-import numpy as np
+from typing import Dict, Optional
 
 __all__ = [
-    "SanitizeError", "enabled", "strict", "SENTINEL_BYTE",
-    "poison", "is_poisoned", "assert_not_poisoned", "template_guard",
+    "SanitizeError", "enabled", "strict",
     "compile_tripwire", "assert_registry_disabled", "counts",
 ]
-
-#: Every byte of a poisoned buffer (0xA5A5... in every lane width): not
-#: 0x00 (a plausible pad), not 0xFF (a plausible sentinel id), and odd in
-#: every field so poisoned ids/amounts can never look committed.
-SENTINEL_BYTE = 0xA5
 
 
 class SanitizeError(AssertionError):
@@ -91,56 +74,6 @@ def _count(name: str, n: int = 1) -> None:
 def _reset_counts() -> None:
     """Tests only."""
     _COUNTS.clear()
-
-
-# -- donation poisoning ------------------------------------------------------
-
-def poison(buffers: Iterable[np.ndarray]) -> int:
-    """Fill each numpy buffer with the sentinel byte; returns how many
-    buffers were poisoned.  Used by machine._stage_release on every
-    pooled staging set under TB_SANITIZE."""
-    n = 0
-    for buf in buffers:
-        np.asarray(buf).view(np.uint8).fill(SENTINEL_BYTE)
-        n += 1
-    if n:
-        _count("donation_poisons", n)
-    return n
-
-
-def is_poisoned(buf) -> bool:
-    """True when the buffer is entirely sentinel bytes (a released pooled
-    buffer nobody refilled).  Empty buffers are never poisoned."""
-    flat = np.asarray(buf).view(np.uint8)
-    return flat.size > 0 and bool((flat == SENTINEL_BYTE).all())
-
-
-def assert_not_poisoned(buf, where: str = "buffer") -> None:
-    """Consumer-side check: reading a fully-poisoned buffer IS the
-    use-after-donate, stopped at the read instead of the digest."""
-    if is_poisoned(buf):
-        _count("use_after_donate")
-        raise SanitizeError(
-            f"use-after-donate: {where} is sentinel-poisoned (0x"
-            f"{SENTINEL_BYTE:02X} fill) — it was released/donated and "
-            "must not be read again"
-        )
-
-
-def template_guard(template: Dict[str, object],
-                   where: str = "cached zero template") -> None:
-    """Verify a cached zero-count template is still all-zero.  A donated
-    template (machine._pad_soa's contract: batch-donating kernels must
-    get a COPY) shows up here as XLA scratch at the next reuse."""
-    _count("template_checks")
-    for name, col in template.items():
-        host = np.asarray(col)
-        if host.size and host.any():
-            _count("template_corruptions")
-            raise SanitizeError(
-                f"{where}: column {name!r} is no longer zero — the "
-                "template was donated to a kernel (copy before donating)"
-            )
 
 
 # -- recompile tripwire ------------------------------------------------------

@@ -133,8 +133,8 @@ TIERS = {
     "sanitize": dict(
         # TB_SANITIZE runtime sanitizer smoke (docs/tblint.md): steady
         # serving under the sanitizer must observe ZERO XLA compiles
-        # (strict tripwire armed) with the staging pool sentinel-
-        # poisoned, one injected violation of each check must be caught,
+        # (strict tripwire armed), one injected violation of each check
+        # must be caught,
         # a pinned VOPR seed must run green, and the sanitize.* counters
         # must land in METRICS.json.  Artifact: SANITIZE_SMOKE.json.
         cmd=["tools/sanitize_smoke.py"],

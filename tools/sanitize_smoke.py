@@ -1,20 +1,18 @@
 """CI sanitize smoke: prove the TB_SANITIZE runtime sanitizer end to end.
 
-Four proofs, each asserting the artifact (not just the exit code):
+Four proofs, each asserting the artifact (not just the exit code; the
+staging pool's donation poisoning and the cached template's guard went with
+the pool and the template in PR 46):
 
 1. STEADY SERVING IS COMPILE-FREE — a real TpuStateMachine under
    TB_SANITIZE=1: warmup + one warm group absorb every first-use jit,
    then a strict-armed serving region of grouped commits must observe
-   ZERO XLA compiles (the PR 10 recompile class, asserted at the source)
-   while the staging pool's released sets are sentinel-poisoned.
+   ZERO XLA compiles (the PR 10 recompile class, asserted at the source).
 2. INJECTED VIOLATIONS ARE CAUGHT — one deliberate violation of each
-   sanitizer check must raise SanitizeError: a corrupted cached zero
-   template (donation), a read of a poisoned staging column
-   (use-after-donate), a leaked registry enable (the leak guard), and a
-   forced recompile inside a strict tripwire region.
+   sanitizer check must raise SanitizeError: a leaked registry enable (the
+   leak guard), and a forced recompile inside a strict tripwire region.
 3. VOPR UNDER SANITIZE — a pinned seed runs green with TB_SANITIZE=1
-   (the sanitizer must never shift a schedule: it only reads, poisons
-   free-list buffers, and counts).
+   (the sanitizer must never shift a schedule: it only reads and counts).
 4. COUNTERS IN METRICS.json — the sanitize.* series land in the registry
    snapshot dumped to METRICS.json, like every other smoke tier.
 
@@ -109,40 +107,14 @@ def main() -> int:
         assert serving_compiles == 0, (
             f"{serving_compiles} compile(s) in the steady serving region"
         )
-        poisons = san.counts().get("donation_poisons", 0)
-        assert poisons > 0, "staging releases should have poisoned"
-        assert m._stage_pool and all(
-            san.is_poisoned(col)
-            for bufs, _ in m._stage_pool for col in bufs.values()
-        ), "pooled staging sets must be sentinel-poisoned"
         summary["checks"]["serving"] = {
             "timed_groups": 4, "serving_compiles": serving_compiles,
-            "donation_poisons": poisons,
-            "template_checks": san.counts().get("template_checks", 0),
         }
 
         # -- 2. injected violations all caught ---------------------------
         caught = {}
 
-        key = next(iter(m._pad_soa_zero))
-        saved = dict(m._pad_soa_zero[key])
         import jax.numpy as jnp
-
-        col = next(iter(m._pad_soa_zero[key]))
-        m._pad_soa_zero[key][col] = jnp.ones(lanes, jnp.uint64)
-        try:
-            m._pad_soa(np.zeros(0, dtype=key[0]))  # same dtype as corrupted
-        except san.SanitizeError:
-            caught["template_donation"] = True
-        m._pad_soa_zero[key] = saved
-
-        poisoned_col = next(
-            iter(m._stage_pool[0][0].values())
-        )
-        try:
-            san.assert_not_poisoned(poisoned_col, "released staging column")
-        except san.SanitizeError:
-            caught["use_after_donate"] = True
 
         try:
             san.assert_registry_disabled("smoke scope")  # registry IS on
@@ -161,7 +133,6 @@ def main() -> int:
             caught["forced_recompile"] = True
 
         assert caught == {
-            "template_donation": True, "use_after_donate": True,
             "registry_leak": True, "forced_recompile": True,
         }, f"injected violations not all caught: {caught}"
         summary["checks"]["injected_violations"] = caught
@@ -189,17 +160,14 @@ def main() -> int:
         k: v for k, v in snap["counters"].items()
         if k.startswith("sanitize.")
     }
-    for needed in ("sanitize.donation_poisons", "sanitize.template_checks",
-                   "sanitize.recompiles", "sanitize.registry_leaks",
-                   "sanitize.use_after_donate",
-                   "sanitize.template_corruptions"):
+    for needed in ("sanitize.recompiles", "sanitize.registry_leaks"):
         assert sanitize_series.get(needed, 0) > 0, (
             f"{needed} missing/zero in the registry snapshot: "
             f"{sorted(sanitize_series)}"
         )
     with open(metrics_path) as f:
         dumped = json.load(f)
-    assert "sanitize.donation_poisons" in dumped.get("counters", {}), (
+    assert "sanitize.recompiles" in dumped.get("counters", {}), (
         "sanitize counters missing from METRICS.json"
     )
     summary["checks"]["counters"] = sanitize_series

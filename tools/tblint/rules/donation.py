@@ -1,27 +1,13 @@
-"""donation: buffer-donation and staging-pool aliasing discipline.
+"""donation: use-after-donate.
 
-``donate_argnames`` hands a buffer to XLA to scribble over; three misuses
-have each needed a prose proof somewhere in this repo's dispatch funnels
-(machine.py / parallel/sharded.py, PR 7/11):
-
-1. USE-AFTER-DONATE — the donated value is read again after the call
-   without being rebound from the call's result.  XLA is free to have
-   reused the buffer: the read returns garbage (or raises a deleted-buffer
-   error, backend-dependent).
-2. DONATING A POOLED/CACHED BUFFER — a cached zero-count template or a
-   pooled staging set handed to a donating parameter gets consumed; the
-   next commit that pulls it from the pool reads scratch.  (The contract
-   note on machine._pad_soa: a template handed to a batch-donating kernel
-   must be copied first.)
-3. DONATING A STAGING ALIAS — ``jax.device_put`` of a pooled numpy staging
-   buffer may alias it zero-copy on XLA-CPU (the machine._stage_group
-   note); donating the resulting device array lets XLA scribble into the
-   pool behind the dirty-row tracking's back.
+``donate_argnames`` hands a buffer to XLA to scribble over.  The misuse
+this rule flags: the donated value is read again after the call without
+being rebound from the call's result.  XLA is free to have reused the
+buffer: the read returns garbage (or raises a deleted-buffer error,
+backend-dependent).
 
 The analysis is module-local and name-level: jitgraph.analyze_wrappers
-resolves which call-site names donate which parameters; pooled buffers are
-names bound from ``*_stage_*`` helpers or subscripts of pool/template/
-cache attributes (``self._stage_pool``, ``self._pad_soa_zero``, ...).
+resolves which call-site names donate which parameters.
 """
 
 from __future__ import annotations
@@ -30,13 +16,7 @@ import ast
 from typing import Iterable, List, Optional, Set
 
 from ..core import FileContext, Finding, Rule, register
-from ..jitgraph import _root_name, _terminal_name, module_wrappers
-
-#: Attribute-name fragments marking a pool / cached-template container.
-POOL_ATTR_FRAGMENTS = ("pool", "template", "_zero", "cache", "stage")
-
-#: Call-name fragments whose result is a pooled staging buffer (set).
-POOL_CALL_FRAGMENTS = ("stage_acquire", "stage_group")
+from ..jitgraph import module_wrappers
 
 
 def _expr_key(expr: ast.AST) -> Optional[str]:
@@ -51,22 +31,6 @@ def _expr_key(expr: ast.AST) -> Optional[str]:
     return None
 
 
-def _is_pool_attr(expr: ast.AST) -> bool:
-    """self._stage_pool[...], self._pad_soa_zero[key], obj.template_cache."""
-    if isinstance(expr, ast.Subscript):
-        return _is_pool_attr(expr.value)
-    if isinstance(expr, ast.Attribute):
-        return any(f in expr.attr for f in POOL_ATTR_FRAGMENTS)
-    return False
-
-
-def _is_pool_call(expr: ast.AST) -> bool:
-    if not isinstance(expr, ast.Call):
-        return False
-    name = _terminal_name(expr.func) or ""
-    return any(f in name for f in POOL_CALL_FRAGMENTS)
-
-
 class _FnScan:
     """One linear pass over a function body, in source order."""
 
@@ -76,47 +40,17 @@ class _FnScan:
         self.ctx = ctx
         self.fn = fn
         self.wrappers = module_wrappers(ctx)
-        self.pooled: Set[str] = set()     # names bound to pooled buffers
         self.findings: List[Finding] = []
         # (key, donate line): donated values awaiting a rebind or a use.
         self.donated_live: dict = {}
 
-    def _mentions_pooled(self, expr: ast.AST) -> bool:
-        for sub in ast.walk(expr):
-            if isinstance(sub, ast.Name) and sub.id in self.pooled:
-                return True
-            if isinstance(sub, (ast.Attribute, ast.Subscript)) and \
-                    _is_pool_attr(sub):
-                return True
-        return False
-
-    def _bind(self, target: ast.AST, pooled: bool) -> None:
-        if isinstance(target, ast.Name):
-            (self.pooled.add if pooled else self.pooled.discard)(target.id)
-        elif isinstance(target, (ast.Tuple, ast.List)):
+    def _bind(self, target: ast.AST) -> None:
+        if isinstance(target, (ast.Tuple, ast.List)):
             for elt in target.elts:
-                self._bind(elt, pooled)
+                self._bind(elt)
         key = _expr_key(target)
         if key is not None:
             self.donated_live.pop(key, None)
-
-    def _value_pooled(self, value: ast.AST) -> bool:
-        if _is_pool_call(value) or _is_pool_attr(value):
-            return True
-        if isinstance(value, ast.Call):
-            name = _terminal_name(value.func)
-            root = _root_name(value.func)
-            # device_put/asarray of a pooled numpy buffer may alias it
-            # zero-copy on XLA-CPU: the result stays "pooled".
-            if name in ("device_put", "asarray") and root in (
-                "jax", "jnp", "np", "numpy",
-            ):
-                return any(self._mentions_pooled(a) for a in value.args[:1])
-        if isinstance(value, (ast.Tuple, ast.List)):
-            return any(self._value_pooled(e) for e in value.elts)
-        if isinstance(value, ast.Name):
-            return value.id in self.pooled
-        return False
 
     def _check_call(self, call: ast.Call, stmt_targets: Set[str]) -> None:
         func_name = None
@@ -130,15 +64,6 @@ class _FnScan:
         if info is None or not info.donated:
             return
         for pname, arg in info.donated_args(call):
-            if self._mentions_pooled(arg):
-                self.findings.append(Finding(
-                    self.rule.id, self.ctx.display_path,
-                    arg.lineno, arg.col_offset,
-                    f"pooled/cached buffer donated to {func_name}"
-                    f"({pname}=): the pool's next user reads XLA scratch "
-                    "— copy before donating",
-                ))
-                continue
             key = _expr_key(arg)
             if key is None:
                 continue
@@ -234,11 +159,10 @@ class _FnScan:
         for call in donating_calls:
             self._check_call(call, targets)
         if isinstance(stmt, ast.Assign):
-            pooled = self._value_pooled(stmt.value)
             for t in stmt.targets:
-                self._bind(t, pooled)
+                self._bind(t)
         elif isinstance(stmt, ast.AnnAssign) and stmt.value is not None:
-            self._bind(stmt.target, self._value_pooled(stmt.value))
+            self._bind(stmt.target)
         elif isinstance(stmt, ast.AugAssign):
             key = _expr_key(stmt.target)
             if key is not None:
@@ -248,14 +172,12 @@ class _FnScan:
 @register
 class DonationRule(Rule):
     id = "donation"
-    summary = ("use-after-donate, donating a pooled/cached buffer, or "
-               "donating a device_put staging alias")
+    summary = "use-after-donate: a donated value read before it is rebound"
     rationale = (
-        "A donated buffer becomes XLA scratch: reading it afterward, or "
-        "donating a cached template / pooled staging buffer (which "
-        "device_put may alias zero-copy on XLA-CPU), silently corrupts "
-        "the next commit that touches the pool — the bug class PR 7/11 "
-        "carry prose proofs against."
+        "A donated buffer becomes XLA scratch: reading it afterward "
+        "returns garbage or raises a deleted-buffer error, "
+        "backend-dependent — the bug class PR 7/11 carry prose proofs "
+        "against."
     )
 
     def check(self, ctx: FileContext) -> Iterable[Finding]:

@@ -40,6 +40,7 @@ def main() -> int:
     from tigerbeetle_tpu.config import LedgerConfig
     from tigerbeetle_tpu.machine import TpuStateMachine
     from tigerbeetle_tpu.obs.metrics import registry
+    from tigerbeetle_tpu.ops import staging
     from tigerbeetle_tpu.ops import state_machine as sm
     from tigerbeetle_tpu.ops import transfer_full as tf
 
@@ -110,8 +111,7 @@ def main() -> int:
     acc["id_lo"][:16] = 1 + np.arange(16, dtype=np.uint64)
     acc["ledger"][:16] = 1
     acc["code"][:16] = 10
-    soa = {k: jnp.asarray(v) for k, v in types.to_soa(acc).items()}
-    led, _ = sm.create_accounts(led, soa, jnp.uint64(16), jnp.uint64(16))
+    led, _ = sm.create_accounts(led, *staging.stage_batch(acc[:16], 64, 16))
     b = np.zeros(64, dtype=types.TRANSFER_DTYPE)
     b["id_lo"][:8] = 100 + np.arange(8, dtype=np.uint64)
     b["debit_account_id_lo"][:8] = 1 + np.arange(8) % 8
