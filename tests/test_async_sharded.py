@@ -184,7 +184,6 @@ class TestMachineComposition:
         _need_devices(shards)
         blocking = make_machine(shards=shards)
         grouped = make_machine(shards=shards)
-        grouped.group_device_commit = True
         batches = [batch(10_000, 12), batch(20_000, 9), batch(30_000, 15)]
         b_res = [blocking.create_transfers(b) for b in batches]
         tss = [

@@ -75,7 +75,6 @@ class Pair:
     def __init__(self, n_accounts, cfg=CFG):
         self.n_accounts = n_accounts
         self.m = TpuStateMachine(cfg, batch_lanes=LANES)
-        self.m.group_device_commit = True
         self.ref = M.ReferenceStateMachine()
         for first in range(1, n_accounts + 1, LANES):
             rows = _accounts(first, min(LANES, n_accounts + 1 - first))
@@ -169,7 +168,6 @@ class Served:
                                time_ns=lambda: 0)
         self.replica.open()
         self.replica.async_checkpoint = True  # as run_server does
-        self.replica.machine.group_device_commit = True
         self._ready = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
@@ -410,7 +408,6 @@ def test_a_group_behind_a_pending_one_that_crosses_a_checkpoint_is_not_cut(
                              batch_lanes=LANES, time_ns=lambda: 0)
             self.r.open()
             self.r.pipeline_depth = 2
-            self.r.machine.group_device_commit = True
 
     first = [0xB100 + i for i in range(62)]
     second = [0xB200 + i for i in range(8)]

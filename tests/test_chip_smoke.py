@@ -2,10 +2,9 @@
 black-box run of ``python -m tigerbeetle_tpu start``.
 
 The script itself accepts no CPU; the expected platform, the tiny sizes and
-the child's environment are passed from here.  TB_GROUP_COMMIT=1 steers the
-child onto the grouped dispatch, which is the default only on a TPU.  The
-removal and rebuild of libtb.so belongs to the script's ``main`` and is not
-exercised (it would pull the library from under the other workers)."""
+the child's environment are passed from here.  The removal and rebuild of
+libtb.so belongs to the script's ``main`` and is not exercised (it would
+pull the library from under the other workers)."""
 
 import copy
 import os
@@ -27,7 +26,6 @@ TINY = chip_smoke.Sizes(
 
 def _run(tmp_path_factory, shards):
     env = jaxenv.child_env(cpu=True, n_devices=max(shards, 1))
-    env["TB_GROUP_COMMIT"] = "1"
     report = {}
     chip_smoke.run(
         TINY, seed=7, shards=shards, env=env, platform="cpu",

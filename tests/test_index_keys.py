@@ -32,7 +32,6 @@ class Pair:
 
     def __init__(self, **kwargs):
         self.m = make_machine(**kwargs)
-        self.m.group_device_commit = True
         self.ref = make_model()
         self.shadow = index.TransferIndex(base=LANES)
 

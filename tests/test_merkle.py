@@ -220,7 +220,6 @@ class TestRootOracle:
             handles.append(h)
         for h in handles:
             h.resolve()
-        m.group_device_commit = True
         batches = [plain_batch(30_000 + j * 100, 16) for j in range(3)]
         tss = [m.prepare("create_transfers", 16) for _ in range(3)]
         assert m.commit_group_fast(batches, tss) is not None

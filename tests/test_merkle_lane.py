@@ -1,26 +1,15 @@
-"""Cross-batch conflict fusion + deferred commitment lane (ISSUE 18;
-docs/commit_pipeline.md fusion section, docs/commitments.md deferred
-lane).
+"""The deferred commitment lane (TB_MERKLE_ASYNC; docs/commitments.md).
 
-Both knobs are perf-only by contract and default-off:
+The knob is perf-only by contract and default-off: the Merkle path refresh
+trails the dispatch closure in a commitment lane; every root observation
+(scrub, checkpoint, get_proof, state-sync) settles first, so observed roots
+are exactly the synchronous ones.
 
-- TB_FUSE: the dispatch lane fuses runs of non-conflicting client batches
-  (disjoint admission-time conflict signatures, vsr/overload.plan_fusion)
-  into one wider padded dispatch — replies, busy/eviction, and session
-  ordering per-request unchanged; a conflicting or unfusable (linked /
-  two-phase / balancing) batch always dispatches solo.
-- TB_MERKLE_ASYNC: the Merkle path refresh trails the dispatch closure in
-  a commitment lane; every root observation (scrub, checkpoint,
-  get_proof, state-sync) settles first, so observed roots are exactly the
-  synchronous ones.
-
-Covered here: planner/signature/coalesce units, machine-level lane
-settle-before-observe, replica-level differentials vs testing/model.py
-across conflicting / non-conflicting / zipf / two-phase mixes at
-TB_PIPELINE {1,2} x TB_SHARDS {0,2} (shard cells @slow, ci integration
-tier), the forced-conflict no-fuse collapse (conflict_rejects > 0 with
-unchanged replies), off-path digest identity, and the pinned VOPR seed
-under both knobs (@slow).
+Covered here: the touch-record coalescer, machine-level
+settle-before-observe, replica-level differentials vs testing/model.py and
+the lane-off replica across conflicting / non-conflicting / zipf /
+two-phase mixes at TB_PIPELINE {1,2} x TB_SHARDS {0,2}, and the pinned VOPR
+seed under the knob (@slow).
 """
 
 import os
@@ -35,7 +24,6 @@ from tigerbeetle_tpu.machine import TpuStateMachine
 from tigerbeetle_tpu.obs.metrics import registry
 from tigerbeetle_tpu.ops import merkle as merkle_ops
 from tigerbeetle_tpu.testing import model as M
-from tigerbeetle_tpu.vsr import overload
 
 LANES = 64
 CFG = LedgerConfig(
@@ -61,8 +49,8 @@ def accounts_batch():
 
 
 def disjoint_batch(first_id, n, client, per=4):
-    """Transfers confined to client's own account partition — disjoint
-    conflict signatures across clients, the mix that fuses."""
+    """Transfers confined to client's own account partition: no account
+    is shared across clients."""
     lo = client * per
     return types.transfers_array([
         types.transfer(
@@ -75,8 +63,8 @@ def disjoint_batch(first_id, n, client, per=4):
 
 
 def shared_batch(first_id, n):
-    """Transfers over the SHARED pool — overlapping signatures, the mix
-    that must refuse to fuse."""
+    """Transfers over the SHARED pool: every client's batch touches
+    every account."""
     return types.transfers_array([
         types.transfer(
             id=first_id + i, debit_account_id=1 + i % N_ACCOUNTS,
@@ -88,9 +76,9 @@ def shared_batch(first_id, n):
 
 
 def two_phase_batch(first_id, n):
-    """In-batch pending + post pairs: unfusable by flag classification
-    (order-sensitive beyond slot disjointness) — must dispatch solo and
-    still match the oracle."""
+    """In-batch pending + post pairs: not fast-path eligible, so a run
+    of them is refused whole and executes inline on the general kernel —
+    and must still match the oracle."""
     half = n // 2
     return types.transfers_array(
         [
@@ -111,8 +99,8 @@ def two_phase_batch(first_id, n):
 
 
 def zipf_batch(first_id, n, seed):
-    """Zipfian-hot plain transfers: heavy account overlap, fusable flags
-    — the planner must conservatively reject, results identical."""
+    """Zipfian-hot plain transfers: heavy account overlap on the fast
+    path."""
     rng = np.random.default_rng(seed)
     rows = []
     for i in range(n):
@@ -125,86 +113,7 @@ def zipf_batch(first_id, n, seed):
     return types.transfers_array(rows)
 
 
-# -- planner / signature / coalesce units ----------------------------------
-
-
-class TestConflictSignature:
-    def test_disjoint_batches_have_disjoint_signatures(self):
-        a = overload.conflict_signature(disjoint_batch(1000, 8, client=0))
-        b = overload.conflict_signature(disjoint_batch(2000, 8, client=1))
-        assert a is not None and b is not None
-        assert np.intersect1d(a, b, assume_unique=True).size == 0
-
-    def test_shared_accounts_overlap(self):
-        a = overload.conflict_signature(shared_batch(1000, 8))
-        b = overload.conflict_signature(shared_batch(2000, 8))
-        assert np.intersect1d(a, b, assume_unique=True).size > 0
-
-    def test_unfusable_flags_return_none(self):
-        assert overload.conflict_signature(two_phase_batch(3000, 8)) is None
-        linked = types.transfers_array([
-            types.transfer(id=1, debit_account_id=1, credit_account_id=2,
-                           amount=1, ledger=1, code=1,
-                           flags=types.TransferFlags.LINKED),
-            types.transfer(id=2, debit_account_id=2, credit_account_id=3,
-                           amount=1, ledger=1, code=1),
-        ])
-        assert overload.conflict_signature(linked) is None
-
-    def test_empty_batch_signature(self):
-        sig = overload.conflict_signature(types.transfers_array([]))
-        assert sig is not None and sig.size == 0
-
-
-class TestPlanFusion:
-    def _ts(self, batches, t0=100):
-        """Contiguous prepare timestamps: ts[j] = ts[j-1] + len(b[j])."""
-        out, t = [], t0
-        for b in batches:
-            t += len(b)
-            out.append(t)
-        return out
-
-    def test_disjoint_contiguous_run_fuses_whole(self):
-        bs = [disjoint_batch(1000 * (c + 1), 8, client=c) for c in range(4)]
-        segs, rejects = overload.plan_fusion(bs, self._ts(bs), LANES)
-        assert segs == [(0, 4)]
-        assert rejects == 0
-
-    def test_conflicting_run_stays_solo(self):
-        bs = [shared_batch(1000 * (c + 1), 8) for c in range(3)]
-        segs, rejects = overload.plan_fusion(bs, self._ts(bs), LANES)
-        assert segs == [(0, 1), (1, 2), (2, 3)]
-        assert rejects > 0
-
-    def test_lane_capacity_splits_segments(self):
-        bs = [disjoint_batch(1000 * (c + 1), 8, client=c) for c in range(4)]
-        segs, rejects = overload.plan_fusion(bs, self._ts(bs), 16)
-        # 8 rows each, 16-lane cap: pairs at most.
-        assert all(e - s <= 2 for s, e in segs)
-        assert sum(e - s for s, e in segs) == 4
-        assert rejects == 0  # capacity splits are not conflict rejects
-
-    def test_timestamp_gap_refuses_fusion(self):
-        bs = [disjoint_batch(1000, 8, client=0),
-              disjoint_batch(2000, 8, client=1)]
-        ts = self._ts(bs)
-        ts[1] += 5  # an op in between: per-lane timestamps would shift
-        segs, rejects = overload.plan_fusion(bs, ts, LANES)
-        assert segs == [(0, 1), (1, 2)]
-        assert rejects == 0
-
-    def test_unfusable_member_passes_through_solo(self):
-        bs = [disjoint_batch(1000, 8, client=0), two_phase_batch(5000, 8),
-              disjoint_batch(2000, 8, client=1)]
-        segs, _rejects = overload.plan_fusion(bs, self._ts(bs), LANES)
-        assert (1, 2) in segs  # the two-phase batch dispatches alone
-
-    def test_fusion_enabled_env_parsing(self):
-        assert not overload.fusion_enabled(env={})
-        assert not overload.fusion_enabled(env={"TB_FUSE": "0"})
-        assert not overload.fusion_enabled(env={"TB_FUSE": "off"})
-        assert overload.fusion_enabled(env={"TB_FUSE": "1"})
+# -- coalesce units ----------------------------------------------------------
 
 
 class TestCoalesceTouchRecords:
@@ -352,9 +261,9 @@ class TestDeferredLane:
 class ReplicaHarness:
     """A solo replica served through on_request_group_pipelined, clock
     pinned so reply bytes compare across knob settings (the
-    test_async_sharded harness, with the PR 18 knobs on the machine)."""
+    test_async_sharded harness, with the lane's knob on the machine)."""
 
-    def __init__(self, tmp, name, depth, shards=0, fuse=False,
+    def __init__(self, tmp, name, depth, shards=0,
                  merkle_async=False, merkle=False):
         from tigerbeetle_tpu.vsr import wire
         from tigerbeetle_tpu.vsr.replica import Replica
@@ -379,7 +288,6 @@ class ReplicaHarness:
                 self.r.machine.scrub_paranoid = False
         self.r.open()
         self.r.pipeline_depth = depth
-        self.r.machine.fuse_batches = fuse
         self.r.machine.merkle_async = merkle_async
         self.sessions = {}
 
@@ -486,13 +394,13 @@ def _check_against_model(groups, bodies):
 MIXES = ["disjoint", "conflicting", "two_phase", "zipf"]
 
 
-class TestFusionDifferential:
+class TestLaneDifferential:
     @pytest.mark.parametrize("mix", MIXES)
     @pytest.mark.parametrize("depth", [1, 2])
     def test_vs_model_and_off_path(self, tmp_path, depth, mix):
-        """Fused serving matches the scalar oracle AND the unfused
-        replica bit for bit (replies + digest + balances) at every
-        depth x mix point — single device."""
+        """Serving with the lane on matches the scalar oracle AND the
+        lane-off replica bit for bit (replies + digest + balances) at
+        every depth x mix point — single device."""
         self._run_cell(str(tmp_path), depth, 0, mix)
 
     @pytest.mark.parametrize("mix", ["disjoint", "two_phase"])
@@ -511,8 +419,7 @@ class TestFusionDifferential:
         balances_off = off.r.machine.balances_snapshot()
         off.close()
         on = ReplicaHarness(tmp, f"on_{depth}_{shards}_{mix}", depth,
-                            shards=shards, fuse=True, merkle_async=True,
-                            merkle=True)
+                            shards=shards, merkle_async=True, merkle=True)
         bodies_on = on.serve_groups(groups)
         assert bodies_on == bodies_off
         assert on.r.machine.digest() == digest_off
@@ -523,62 +430,29 @@ class TestFusionDifferential:
         on.close()
         _check_against_model(groups, bodies_off)
 
-    @pytest.mark.parametrize("knob", ["fuse", "async"])
-    def test_one_knob_alone_engages_and_matches_off(self, tmp_path, knob):
-        """Each knob ON ALONE serves the off path's bytes, and engages:
-        the non-conflicting mix must drive fuse.fused_runs with width > 1
-        (the deferred lane: merkle.lane.deferred_updates) — otherwise
-        the differentials above prove nothing."""
+    def test_lane_alone_engages_and_matches_off(self, tmp_path):
+        """The lane ON serves the off path's bytes, and engages: the mix
+        must drive merkle.lane.deferred_updates — otherwise the
+        differentials above prove nothing."""
         tmp, groups = str(tmp_path), _mix_groups("disjoint")
         off = ReplicaHarness(tmp, "off", 2)
         want = off.serve_groups(groups), off.r.machine.digest()
         off.close()
         with registry.enabled_scope():
-            h = ReplicaHarness(tmp, knob, 2, fuse=knob == "fuse",
-                               merkle_async=knob == "async",
-                               merkle=knob == "async")
+            h = ReplicaHarness(tmp, "async", 2, merkle_async=True,
+                               merkle=True)
             got = h.serve_groups(groups), h.r.machine.digest()
             h.close()
             snap = registry.snapshot()
         assert got == want
-        if knob == "fuse":
-            assert snap["counters"].get("fuse.fused_runs", 0) > 0
-            assert snap["histograms"]["fuse.fused_width"]["max"] > 1
-        else:
-            assert snap["counters"]["merkle.lane.deferred_updates"] > 0
-
-
-class TestForcedConflictNoFuse:
-    def test_conflict_rejects_and_replies_unchanged(self, tmp_path):
-        """A forced-conflict schedule (every batch over the shared pool)
-        must refuse to fuse — conflict_rejects > 0, fused_runs == 0 —
-        and serve byte-identical replies to the fuse-off path."""
-        tmp = str(tmp_path)
-        groups = _mix_groups("conflicting")
-        off = ReplicaHarness(tmp, "fc_off", 2)
-        bodies_off = off.serve_groups(groups)
-        digest_off = off.r.machine.digest()
-        off.close()
-        with registry.enabled_scope():
-            on = ReplicaHarness(tmp, "fc_on", 2, fuse=True)
-            bodies_on = on.serve_groups(groups)
-            digest_on = on.r.machine.digest()
-            on.close()
-            snap = registry.snapshot()
-            assert snap["counters"].get("fuse.conflict_rejects", 0) > 0
-            assert snap["counters"].get("fuse.fused_runs", 0) == 0
-        assert bodies_on == bodies_off
-        assert digest_on == digest_off
+        assert snap["counters"]["merkle.lane.deferred_updates"] > 0
 
 
 @pytest.mark.slow
-class TestVoprFused:
-    def test_pinned_seed_green_both_knobs(self, tmp_path, monkeypatch):
-        """The pinned VOPR seed replays green with TB_FUSE=1 +
-        TB_MERKLE_ASYNC=1: consensus replicas commit per-op (fusion never
-        engages there) and every scrub/checkpoint oracle observes settled
-        roots only."""
-        monkeypatch.setenv("TB_FUSE", "1")
+class TestVoprDeferredLane:
+    def test_pinned_seed_green_with_the_lane(self, tmp_path, monkeypatch):
+        """The pinned VOPR seed replays green with TB_MERKLE_ASYNC=1:
+        every scrub/checkpoint oracle observes settled roots only."""
         monkeypatch.setenv("TB_MERKLE_ASYNC", "1")
         from tigerbeetle_tpu.sim.vopr import EXIT_PASSED, run_seed
 

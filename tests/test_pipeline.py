@@ -108,9 +108,7 @@ class TestMachineDeferred:
 
     def test_group_deferred_matches_blocking(self):
         deferred = make_machine()
-        deferred.group_device_commit = True
         blocking = make_machine()
-        blocking.group_device_commit = True
         batches = [batch(1000 * (k + 1), 20 + k) for k in range(4)]
         tss_d = [
             deferred.prepare("create_transfers", len(b), 0) for b in batches
@@ -146,7 +144,6 @@ class TestMachineDeferred:
 
     def test_forced_probe_overflow_group(self):
         m = make_machine()
-        m.group_device_commit = True
         batches = [batch(6000, 4), batch(7000, 4)]
         tss = [m.prepare("create_transfers", 4, 0) for _ in batches]
         handle = m.commit_group_fast(batches, tss, deferred=True)
@@ -162,7 +159,10 @@ class TestMachineDeferred:
 class ReplicaHarness:
     """A solo replica served directly through on_request_group_pipelined
     (the TCP bus's path), clock pinned so reply bytes compare across
-    engines."""
+    engines.  With ``group`` off, serve() hands the replica one request a
+    commit group: a group of one is the ungrouped path."""
+
+    group = True  # a subclass with an __init__ of its own serves whole groups
 
     def __init__(self, tmp, name, depth, group):
         from tigerbeetle_tpu.vsr import wire
@@ -175,7 +175,7 @@ class ReplicaHarness:
                          batch_lanes=LANES, time_ns=lambda: 0)
         self.r.open()
         self.r.pipeline_depth = depth
-        self.r.machine.group_device_commit = group
+        self.group = group
         self.sessions = {}
 
     def request(self, client, request_n, op, body):
@@ -212,10 +212,16 @@ class ReplicaHarness:
         assert replies[0][0][256:] == b"", "account setup failed"
 
     def serve(self, reqs, deferred_replies=False):
-        replies, fs = self.r.on_request_group_pipelined(
-            reqs, deferred_replies=deferred_replies
-        )
-        return replies, fs
+        if self.group:
+            return self.r.on_request_group_pipelined(
+                reqs, deferred_replies=deferred_replies
+            )
+        assert not deferred_replies
+        replies, fs = [], None
+        for req in reqs:
+            out, fs = self.r.on_request_group_pipelined([req])
+            replies.extend(out)
+        return replies, fs  # the IO pool is FIFO: the last fsync covers all
 
     def close(self):
         self.r.close()
@@ -302,7 +308,7 @@ class TestReplicaDifferential:
         assert outs[1][2] == ref.balances_snapshot()
 
     def test_deferred_replies_promise_and_busy_guard(self, tmp_path):
-        h = ReplicaHarness(str(tmp_path), "promise", 2, False)
+        h = ReplicaHarness(str(tmp_path), "promise", 2, True)
         wire = h.wire
         c1, c2 = 0x400, 0x401
         h.register(c1)
@@ -345,13 +351,27 @@ class TestReplicaDifferential:
         registry.reset()
         registry.enable()
         try:
-            h = ReplicaHarness(str(tmp_path), "metrics", 2, False)
+            h = ReplicaHarness(str(tmp_path), "metrics", 2, True)
             _mixed_stream(h)
+            # One more group whose refused run (plain, then a linked
+            # chain) sits past the group's head, behind a lookup.
+            wire = h.wire
+            replies, fs = h.serve([
+                h.request(0x300, 5, wire.Operation.lookup_transfers,
+                          (10_001).to_bytes(16, "little")),
+                h.request(0x301, 5, wire.Operation.create_transfers,
+                          batch(90_000, 7).tobytes()),
+                h.request(0x302, 5, wire.Operation.create_transfers,
+                          linked_batch(95_000, 6).tobytes()),
+            ])
+            if fs is not None:
+                fs.result()
+            assert all(replies)
             h.close()
             snap = registry.snapshot()
             counters = snap["counters"]
-            assert counters.get("pipeline.groups", 0) >= 3
-            assert counters.get("pipeline.dispatches", 0) >= 4
+            assert counters.get("pipeline.groups", 0) >= 4
+            assert counters.get("pipeline.dispatches", 0) >= 3
             assert counters.get("pipeline.resolves", 0) == counters.get(
                 "pipeline.dispatches"
             )

@@ -59,8 +59,6 @@ class Served:
                                time_ns=lambda: 0)
         self.replica.open()
         self.replica.async_checkpoint = True  # as run_server does
-        # The grouped scan is the default only on a TPU.
-        self.replica.machine.group_device_commit = True
         self.server = None
         self._ready = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)

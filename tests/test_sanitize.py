@@ -178,7 +178,6 @@ def test_steady_serving_has_zero_recompiles(monkeypatch):
     """The acceptance shape: after warmup + one warm group, further
     same-shape grouped commits compile NOTHING (strict tripwire armed)."""
     m = make_sanitized_machine(monkeypatch)
-    m.group_device_commit = True
     m.warmup()
     commit_group(m, 40_000, n=8)     # warm group: first-use index/scan jits
     m._sanitize_arm_tripwire()       # re-baseline at the steady state

@@ -125,7 +125,6 @@ class TestScrubDigest:
                 r.open()
                 r.machine.retry_tick_s = 0
                 r.pipeline_depth = depth
-                r.machine.group_device_commit = group
                 sessions = {}
 
                 def req(client, n, op, body):
@@ -158,9 +157,11 @@ class TestScrubDigest:
                             batch((g * 3 + k + 1) * 10_000, 8 + k).tobytes())
                         for k, c in enumerate(clients)
                     ]
-                    replies, fs = r.on_request_group_pipelined(reqs)
-                    if fs is not None:
-                        fs.result()
+                    # Ungrouped: one request a commit group.
+                    for chunk in [reqs] if group else [[q] for q in reqs]:
+                        replies, fs = r.on_request_group_pipelined(chunk)
+                        if fs is not None:
+                            fs.result()
                 r.pipeline_flush()
                 assert r.machine.scrub_check() is True
                 assert r.machine.scrub_mismatches == 0, (depth, group)
@@ -239,9 +240,7 @@ class TestDispatchRetry:
         """A failed dispatch with TWO runs in flight: both must resolve
         with results identical to the blocking twin's (FIFO recovery)."""
         m = make_machine(scrub_interval=8)
-        m.group_device_commit = True
         twin = make_machine()
-        twin.group_device_commit = True
         batches = [batch(2000, 8), batch(3000, 8)]
         tss = [m.prepare("create_transfers", 8, 0) for _ in batches]
         m.inject_device_faults(1)

@@ -59,7 +59,6 @@ def _machine(shards):
         LedgerConfig(accounts_capacity_log2=9, transfers_capacity_log2=12,
                      posted_capacity_log2=8),
         batch_lanes=LANES, shards=shards)
-    m.group_device_commit = True
     return m
 
 

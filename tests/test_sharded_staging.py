@@ -37,7 +37,6 @@ def _machine():
         LedgerConfig(accounts_capacity_log2=9, transfers_capacity_log2=12,
                      posted_capacity_log2=8),
         batch_lanes=LANES, shards=SHARDS)
-    m.group_device_commit = True
     assert m.pipeline_depth == 2 and m.waves_enabled
     return m
 

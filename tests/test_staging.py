@@ -103,7 +103,6 @@ def _machine(**kwargs):
         LedgerConfig(accounts_capacity_log2=9, transfers_capacity_log2=12,
                      posted_capacity_log2=8, **kwargs),
         batch_lanes=LANES)
-    m.group_device_commit = True
     assert m.pipeline_depth == 2 and not m.shards
     return m
 
@@ -242,13 +241,11 @@ def test_no_run_length_compiles_after_warmup():
 @pytest.mark.parametrize("k", [7, 9])
 def test_a_grouped_run_equals_its_batches_one_by_one(k):
     grouped, serial = _machine(), _machine()
-    serial.group_device_commit = False
     for m in (grouped, serial):
         assert m.create_accounts(_accounts(), wall_clock_ns=1000) == []
     batches = _run(10_000, k)
     stamps = [grouped.prepare("create_transfers", len(b), 0) for b in batches]
     got = grouped.commit_group_fast(batches, stamps)
-    assert serial.commit_group_fast(batches, stamps) is None
     want = []
     for b in batches:
         ts = serial.prepare("create_transfers", len(b), 0)

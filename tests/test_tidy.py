@@ -80,3 +80,27 @@ def test_reference_citations_present():
         if not re.search(r"\.zig", head):
             missing.append(name)
     assert not missing, f"vsr modules without reference citations: {missing}"
+
+
+def test_no_dispatch_shape_switch_left():
+    """Cross-batch fusion and the grouping knob went with PR 47: a run of
+    transfers is grouped whenever the machine accepts it.  Their names must
+    not come back in the program, the tools, the tests or the documents
+    (`benchmarks/` is the benchmark's to clean: ROADMAP B-II.0)."""
+    gone = ["TB_" + "FUSE", "TB_GROUP_" + "COMMIT", "fuse_" + "batches",
+            "group_device_" + "commit", "plan_" + "fusion",
+            "_FusedRun" + "Handle"]
+    repo = os.path.dirname(SRC_ROOT)
+    paths = [os.path.join(repo, "README.md")]
+    for top in ("tigerbeetle_tpu", "tools", "tests", "docs"):
+        for dirpath, _dirs, files in os.walk(os.path.join(repo, top)):
+            paths.extend(
+                os.path.join(dirpath, name) for name in files
+                if name.endswith((".py", ".md", ".cpp", ".h", ".json"))
+            )
+    bad = []
+    for path in paths:
+        with open(path, errors="replace") as f:
+            for i, line in enumerate(f, 1):
+                bad.extend(f"{path}:{i}: {w}" for w in gone if w in line)
+    assert not bad, "\n".join(bad[:20])

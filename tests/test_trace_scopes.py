@@ -195,7 +195,6 @@ def test_scoped_programs_answer_as_the_model_does():
     """Fast, grouped and general commits, both lookups and the index query
     on one small ledger, beside the scalar oracle."""
     m = make_machine()
-    m.group_device_commit = True
     ref = make_model()
 
     def both(b):
@@ -250,7 +249,6 @@ def test_a_lazy_index_appends_no_run_and_rebuilds_for_a_query(mode):
         m = machine.TpuStateMachine(
             dataclasses.replace(CFG, lazy_index=True), batch_lanes=LANES)
         assert m.create_accounts(accounts_batch(), wall_clock_ns=1000) == []
-    m.group_device_commit = True
     ref = make_model()
 
     def model(b):

@@ -137,7 +137,6 @@ def _machine(posted_log2):
         LedgerConfig(accounts_capacity_log2=8, transfers_capacity_log2=12,
                      posted_capacity_log2=posted_log2),
         batch_lanes=LANES)
-    m.group_device_commit = True
     grown = []
     grow = m._table_grow
     m._table_grow = lambda table, name, capacity: (

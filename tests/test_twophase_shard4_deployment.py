@@ -77,7 +77,6 @@ def _machine(shards, posted_log2=12):
         LedgerConfig(accounts_capacity_log2=9, transfers_capacity_log2=13,
                      posted_capacity_log2=posted_log2),
         batch_lanes=LANES, shards=shards)
-    m.group_device_commit = True
     return m
 
 

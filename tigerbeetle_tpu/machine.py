@@ -412,13 +412,6 @@ class TpuStateMachine:
             }
             if _obs.enabled:
                 _obs.gauge("sharding.shards").set(shards)
-        # Grouped device commit (commit_group_fast).  None = auto: enabled
-        # on the TPU backend only.  The gate dates from the padded scan,
-        # whose empty steps paid table-sized temporaries on XLA-CPU (and
-        # 32 ms each on a v5e, PERF.md PR 27); the loop runs none now, but
-        # nobody has measured it on XLA-CPU.  Tests force True to pin the
-        # path.
-        self._group_device_commit: Optional[bool] = None
         # Host data-plane mode (host_engine.py): commits run in the native
         # engine over a numpy mirror; the device ledger is materialized
         # lazily for queries/checkpoints/digests.  The mirror is the
@@ -588,9 +581,6 @@ class TpuStateMachine:
         # the per-commit update sequence.  Empty unless the knob is on.
         self._merkle_async: Optional[bool] = None  # lazy (TB_MERKLE_ASYNC)
         self._merkle_pending: List[Tuple[str, np.ndarray]] = []
-        # Cross-batch conflict fusion (TB_FUSE; vsr/overload.py): read by
-        # the replica's dispatch lane, lazy like the knobs above.
-        self._fuse_batches: Optional[bool] = None  # lazy (TB_FUSE)
         # Plain-int event counters (read by obs/vopr_viz and tests without
         # the global metrics registry).
         self.scrub_checks = 0
@@ -1946,15 +1936,14 @@ class TpuStateMachine:
             self.ledger, codes_f = self._shard_steps["fast"](
                 self.ledger, *staged_t
             )
-            if self.pipeline_depth > 1 or self.group_device_commit:
-                # The async sharded engine dispatches the PROBED sharded
-                # step — deferred at depth >= 2 AND blocking grouped runs
-                # (commit_group_fast routes through it at any depth); a
-                # client must never pay its compile mid-request.  The batch
-                # is not donated, so the staged operands serve every step.
-                r = self._shard_steps["fast_probed"](self.ledger, *staged_t)
-                self.ledger = r[0]
-                np.asarray(r[1]), np.asarray(r[2])
+            # The async sharded engine dispatches the PROBED sharded
+            # step — deferred at depth >= 2 AND blocking grouped runs
+            # (commit_group_fast routes through it at any depth); a
+            # client must never pay its compile mid-request.  The batch
+            # is not donated, so the staged operands serve every step.
+            r = self._shard_steps["fast_probed"](self.ledger, *staged_t)
+            self.ledger = r[0]
+            np.asarray(r[1]), np.asarray(r[2])
             step = self._shard_steps[
                 "full_waves" if self.waves_enabled else "full"
             ]
@@ -2007,19 +1996,18 @@ class TpuStateMachine:
                     self.ledger, *staged_t
                 )
                 np.asarray(codes_p)
-            if self.group_device_commit:
-                # The grouped dispatch is a distinct program for each of
-                # the stack's two leading dimensions (_group_rows), ONE for
-                # every group length that fits it (a zero count runs zero
-                # steps); a client must never pay a compile mid-group.
-                for rows in sorted({self._group_rows(2),
-                                    self._group_rows(self.GROUP_K)}):
-                    self.ledger, codes_g, *_ = _group_fast_dispatch(
-                        self.ledger, *staging.stage_group(
-                            [empty], self.batch_lanes, [1], rows
-                        )
+            # The grouped dispatch is a distinct program for each of
+            # the stack's two leading dimensions (_group_rows), ONE for
+            # every group length that fits it (a zero count runs zero
+            # steps); a client must never pay a compile mid-group.
+            for rows in sorted({self._group_rows(2),
+                                self._group_rows(self.GROUP_K)}):
+                self.ledger, codes_g, *_ = _group_fast_dispatch(
+                    self.ledger, *staging.stage_group(
+                        [empty], self.batch_lanes, [1], rows
                     )
-                    np.asarray(codes_g)
+                )
+                np.asarray(codes_g)
         np.asarray(codes_a), np.asarray(codes_t), int(kflags)
 
     # -- prepare (state_machine.zig:503-512) --------------------------------
@@ -2858,12 +2846,12 @@ class TpuStateMachine:
 
     def _fast_path_ok(self, batch: np.ndarray) -> bool:
         """Plain-transfer batches run the fast kernel.  Measured on one v5e
-        at the served table sizes (PERF.md section 5; 8190-event batches):
-        an execution of the general program is 101 ms, a lone fast request
-        53.5 ms and a grouped fast step ~48 ms, so a batch that can take
-        the fast kernel does.  The preconditions are
-        ops/state_machine.py's P1-P4, checked host-side in a few vector ops
-        over the batch."""
+        at the control's table sizes (PERF_LEDGER.jsonl, PR 46; 8190-event
+        batches): an execution of the general program is 61.0 ms
+        (`general_kernel_ms`), a grouped fast step 23.8 ms
+        (`kernel_ms_per_batch`), so a batch that can take the fast kernel
+        does.  The preconditions are ops/state_machine.py's P1-P4, checked
+        host-side in a few vector ops over the batch."""
         if (
             self._tiering
             or self._history_accounts_possible
@@ -2877,23 +2865,7 @@ class TpuStateMachine:
             return False
         return True
 
-    # -- grouped device commit ----------------------------------------------
-
-    @property
-    def group_device_commit(self) -> bool:
-        if self._group_device_commit is None:
-            import os
-
-            env = os.environ.get("TB_GROUP_COMMIT")
-            self._group_device_commit = (
-                env == "1" if env in ("0", "1")
-                else jax.default_backend() == "tpu"
-            )
-        return self._group_device_commit
-
-    @group_device_commit.setter
-    def group_device_commit(self, value: bool) -> None:
-        self._group_device_commit = value
+    # -- commit-path switches -----------------------------------------------
 
     @property
     def waves_enabled(self) -> bool:
@@ -2918,27 +2890,9 @@ class TpuStateMachine:
         self._waves_enabled = bool(value)
 
     @property
-    def fuse_batches(self) -> bool:
-        """Cross-batch conflict fusion (TB_FUSE env, default OFF; the CLI's
-        --fuse-batches overrides).  Read by the replica's dispatch lane:
-        runs of non-conflicting client batches (vsr/overload.plan_fusion's
-        admission-time conflict index) fuse into one wider padded dispatch
-        on the EXISTING jit size classes.  Off is bit-identical — no
-        signature is computed, every run dispatches exactly as before."""
-        if self._fuse_batches is None:
-            from .vsr import overload
-
-            self._fuse_batches = overload.fusion_enabled()
-        return self._fuse_batches
-
-    @fuse_batches.setter
-    def fuse_batches(self, value: bool) -> None:
-        self._fuse_batches = bool(value)
-
-    @property
     def merkle_async(self) -> bool:
-        """Deferred commitment lane (TB_MERKLE_ASYNC env, default OFF; the
-        CLI's --merkle-async overrides).  On, committed batches enqueue
+        """Deferred commitment lane (TB_MERKLE_ASYNC env only, default
+        OFF).  On, committed batches enqueue
         touched-row records instead of paying the O(batch * log cap)
         leaf->root refresh inside the dispatch closure; merkle_settle()
         drains the lane at every point a maintained root is observed
@@ -3065,8 +3019,7 @@ class TpuStateMachine:
         resolves the handle (in dispatch order) when it needs the results
         — dispatch N+1 then overlaps readback N."""
         if (
-            not self.group_device_commit
-            or self._engine is not None
+            self._engine is not None
             or self.force_sequential
             or not (2 <= len(batches) <= self.GROUP_K)
         ):

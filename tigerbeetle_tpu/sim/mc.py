@@ -232,7 +232,6 @@ class DigestMachine:
         self.retry_tick_s = 0
         self.shards = 0
         self.pipeline_depth = 1
-        self.group_device_commit = False
         self.GROUP_K = 1
         self.ledger = None
         self.cold = _ColdStub()

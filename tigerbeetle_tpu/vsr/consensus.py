@@ -2959,8 +2959,8 @@ class VsrReplica(Replica):
         # the TB_MERKLE_ASYNC commitment lane before the roots are read),
         # so a deferred-lane backlog on THIS replica can never skew the
         # roots a rejoining peer descends against.  Consensus commits are
-        # per-op besides (TB_FUSE never engages here), keeping peer forests
-        # byte-identical — docs/commitments.md composition sections.
+        # per-op besides, keeping peer forests byte-identical —
+        # docs/commitments.md composition sections.
         pack = self._sync_pack_for(self.op_checkpoint)
         if pack is None:
             return []

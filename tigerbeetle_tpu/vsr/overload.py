@@ -267,140 +267,3 @@ class AdmissionQueue:
             del self._clients[client]
         return (CLASS_CLIENT, client, item)
 
-
-# -- cross-batch conflict index (TB_FUSE; docs/commit_pipeline.md) ------------
-#
-# Index-Based Scheduling for Parallel SMR (PAPERS.md 1911.11329): compute a
-# cheap per-batch conflict index AHEAD of dispatch — at the admission seam,
-# where batches are still opaque FIFO units — so the dispatch lane can fuse
-# runs of provably independent client batches into one wider padded dispatch.
-# This is the cross-batch analogue of the TB_WAVES in-batch hazard lanes
-# (ops/transfer_full.py): where waves schedule dependent lanes WITHIN one
-# batch, the signature below certifies independence BETWEEN batches, over the
-# same touched-(debit, credit)-account-slot vocabulary plus the inserted and
-# referenced transfer ids.
-#
-# Safety stance: the signature is a conservative disjointness certificate.
-# Two fused fast-path batches can only couple through (a) a duplicate
-# transfer id (the second insert's `exists` result depends on the first) or
-# (b) a shared account row (balance reads — unobservable on the fast path,
-# whose preconditions outlaw limits/balancing/overflow, but kept in the
-# signature anyway: over-rejection is always safe, under-rejection never
-# happens because equal keys hash equally).  Everything heavier — two-phase,
-# balancing, linked chains — is flag-unfusable and the machine's own
-# fast-path refusal is the final bit-identical fallback.
-
-# Mixed-hash namespace salts: a transfer id equal to an account id is NOT a
-# conflict, so the two key spaces hash into disjoint streams.
-_SIG_SALT_ID = 0x9E3779B97F4A7C15
-_SIG_SALT_ACCOUNT = 0xC2B2AE3D27D4EB4F
-# Flags that make a batch unfusable outright (the fast path refuses them
-# anyway — machine._SLOW_TRANSFER_FLAGS — but rejecting here keeps the
-# refusal off the dispatch path): two-phase fulfillment, balancing, linked.
-_UNFUSABLE_FLAGS = 0x3D  # LINKED | POST | VOID | BALANCING_DEBIT/CREDIT
-
-
-def fusion_enabled(env: Optional[dict] = None) -> bool:
-    """TB_FUSE gate ('' / '0' / 'off' all mean off; the CLI's
-    --fuse-batches sets it).  Off is bit-identical: no signature is ever
-    computed and every run dispatches exactly as before."""
-    value = (env if env is not None else os.environ).get("TB_FUSE", "")
-    return str(value).strip().lower() not in ("", "0", "off", "false")
-
-
-def _mix64(hi, lo, salt: int):
-    """Cheap 64-bit key mix (splitmix-style) over (hi, lo) uint64 columns.
-    Collisions only ever OVER-reject a fusion candidate."""
-    import numpy as np
-
-    with np.errstate(over="ignore"):
-        x = (hi.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
-             ^ lo.astype(np.uint64)) + np.uint64(salt)
-        x ^= x >> np.uint64(30)
-        x *= np.uint64(0xBF58476D1CE4E5B9)
-        x ^= x >> np.uint64(27)
-    return x
-
-
-def conflict_signature(batch):
-    """Sorted-unique uint64 conflict index of one create_transfers batch:
-    mixed hashes of the inserted transfer ids, any referenced pending ids,
-    and both touched account sides.  None when the batch carries an
-    unfusable flag (two-phase / balancing / linked — in-batch coupling the
-    cross-batch certificate cannot speak for).  Computed host-side in a few
-    vector ops — cheap enough to ride the admission loop ahead of
-    dispatch."""
-    import numpy as np
-
-    if len(batch) == 0:
-        return np.zeros(0, np.uint64)
-    flags = batch["flags"]
-    if bool((flags & _UNFUSABLE_FLAGS).any()):
-        return None
-    keys = [
-        _mix64(batch["id_hi"], batch["id_lo"], _SIG_SALT_ID),
-        _mix64(batch["debit_account_id_hi"], batch["debit_account_id_lo"],
-               _SIG_SALT_ACCOUNT),
-        _mix64(batch["credit_account_id_hi"], batch["credit_account_id_lo"],
-               _SIG_SALT_ACCOUNT),
-    ]
-    pend = (batch["pending_id_lo"] != 0) | (batch["pending_id_hi"] != 0)
-    if bool(pend.any()):
-        keys.append(_mix64(
-            batch["pending_id_hi"][pend], batch["pending_id_lo"][pend],
-            _SIG_SALT_ID,
-        ))
-    return np.unique(np.concatenate(keys))
-
-
-def plan_fusion(batches, timestamps, max_lanes: int):
-    """Greedy fusion plan over one run of consecutive create_transfers
-    batches: returns ``(segments, conflict_rejects)`` where segments is a
-    list of (start, stop) index ranges — each segment's batches fuse into
-    ONE padded dispatch — covering the run in order.
-
-    A batch joins the open segment only when ALL of:
-
-    - the fused row count stays within ``max_lanes`` (the batch-lanes pad
-      the fast kernel already compiles for — fusing must land on EXISTING
-      jit size classes, never mint new ones);
-    - its prepare timestamp is CONTIGUOUS with the segment
-      (``ts[j] - count[j] == ts[j-1]``): per-lane timestamps derive as
-      ``ts - count + lane + 1``, so contiguity makes the fused dispatch's
-      lane timestamps bit-identical to the per-batch ones;
-    - its conflict signature is disjoint from the segment's running union
-      (and neither side is flag-unfusable).
-
-    Only signature overlaps count toward ``conflict_rejects`` — capacity
-    and contiguity breaks are scheduling geometry, not conflicts."""
-    import numpy as np
-
-    n = len(batches)
-    segments: List[Tuple[int, int]] = []
-    rejects = 0
-    sigs = [conflict_signature(b) for b in batches]
-    start = 0
-    seg_rows = len(batches[0]) if n else 0
-    seg_sig = sigs[0] if n else None
-    for j in range(1, n):
-        fusable = seg_sig is not None and sigs[j] is not None
-        fits = seg_rows + len(batches[j]) <= max_lanes
-        contiguous = (
-            int(timestamps[j]) - len(batches[j]) == int(timestamps[j - 1])
-        )
-        disjoint = fusable and (
-            np.intersect1d(seg_sig, sigs[j], assume_unique=True).size == 0
-        )
-        if fusable and fits and contiguous and not disjoint:
-            rejects += 1
-        if fusable and fits and contiguous and disjoint:
-            seg_rows += len(batches[j])
-            seg_sig = np.union1d(seg_sig, sigs[j])
-            continue
-        segments.append((start, j))
-        start = j
-        seg_rows = len(batches[j])
-        seg_sig = sigs[j]
-    if n:
-        segments.append((start, n))
-    return segments, rejects

@@ -34,7 +34,6 @@ from ..obs.metrics import registry as _obs
 from ..obs.txtrace import dump_blackboxes, txtrace
 from ..utils.tracer import tracer
 from . import checkpoint as checkpoint_mod
-from . import overload as overload_mod
 from . import wire
 from .journal import Journal
 from .storage import Storage
@@ -718,19 +717,12 @@ class Replica:
                 # observe exactly the ops before it, or replies diverge
                 # from backups' and crash-replay's strict op-order
                 # execution.
-                if self.machine.fuse_batches and len(run) >= 2:
-                    # TB_FUSE, depth-1 twin: fuse + dispatch + resolve at
-                    # the run's own position (blocking); entries a
-                    # mid-run refusal leaves out of ``precomputed`` fall
-                    # through to per-op commits below.
-                    self._commit_run_fused_blocking(run, precomputed)
-                else:
-                    res = self.machine.commit_group_fast(
-                        [r[1] for r in run], [r[2] for r in run]
-                    )
-                    if res is not None:
-                        for (jj, _b, _t), results in zip(run, res):
-                            precomputed[jj] = _encode_results(results)
+                res = self.machine.commit_group_fast(
+                    [r[1] for r in run], [r[2] for r in run]
+                )
+                if res is not None:
+                    for (jj, _b, _t), results in zip(run, res):
+                        precomputed[jj] = _encode_results(results)
             reply = self._commit_prepare(
                 prepare_h, prepare_body, replay=False,
                 result_body=precomputed.get(j),
@@ -834,13 +826,11 @@ class Replica:
             j = 0
             while j in runs:
                 run = runs[j]
-                pairs, covered = self._dispatch_run_split(run, prepared)
-                for subrun, handle in pairs:
-                    self._pipeline_track(subrun, handle, result_bodies, skip)
-                j += covered
-                if covered < len(run):
-                    break  # refused (whole run or a fused tail): those
-                    # ops execute inline in phase A
+                handle = self._dispatch_run(run, prepared)
+                if handle is None:
+                    break  # refused: its ops execute inline in phase A
+                self._pipeline_track(run, handle, result_bodies, skip)
+                j += len(run)
         finally:
             with txtrace.stage("wal_write", n=len(messages)):
                 for message in messages:
@@ -872,18 +862,12 @@ class Replica:
                 continue
             run = runs.get(j)
             if run is not None and j != 0:
-                pairs, covered = self._dispatch_run_split(run, prepared)
-                for subrun, handle in pairs:
-                    self._pipeline_track(subrun, handle, result_bodies, skip)
-                if covered == len(run):
+                handle = self._dispatch_run(run, prepared)
+                if handle is not None:
+                    self._pipeline_track(run, handle, result_bodies, skip)
                     continue
                 if _obs.enabled:
                     _obs.counter("pipeline.stall.refusal").inc()
-                if covered:
-                    # The covered prefix is tracked (this op included);
-                    # the refused tail executes inline at its own
-                    # positions below.
-                    continue
                 # Refused run (mid-run fast-path refusal, tiering, ...):
                 # its ops fall through to per-op execution at their own
                 # positions below.
@@ -1095,148 +1079,6 @@ class Replica:
                                 run_len=len(run))
         return handle
 
-    def _plan_run_fusion(self, run):
-        """Conflict-fusion plan for one device run (TB_FUSE): member
-        batches with disjoint admission-time conflict signatures
-        (vsr/overload.plan_fusion) coalesce into wider dispatched batches.
-        Returns [(subrun, dispatch_batch, dispatch_timestamp), ...] in op
-        order — a width-1 segment passes its batch through untouched, a
-        wider one concatenates the members (host-side; the machine pads
-        the result onto the same jit size classes a solo batch uses) and
-        carries the LAST member's prepare timestamp, which per-lane
-        timestamp math maps back to every member's solo values because
-        plan_fusion requires timestamp contiguity."""
-        batches = [b for _jj, b, _t in run]
-        timestamps = [t for _jj, _b, t in run]
-        segments, rejects = overload_mod.plan_fusion(
-            batches, timestamps, self.machine.batch_lanes
-        )
-        if rejects and _obs.enabled:
-            _obs.counter("fuse.conflict_rejects").inc(rejects)
-        plan = []
-        for s, e in segments:
-            if e - s == 1:
-                plan.append((run[s:e], batches[s], timestamps[s]))
-            else:
-                plan.append((
-                    run[s:e],
-                    np.concatenate(batches[s:e]),
-                    timestamps[e - 1],
-                ))
-        return plan
-
-    @staticmethod
-    def _note_fused_dispatch(plan) -> None:
-        if not _obs.enabled:
-            return
-        for subrun, _b, _t in plan:
-            if len(subrun) > 1:
-                _obs.counter("fuse.fused_runs").inc()
-                _obs.histogram("fuse.fused_width", "batches").observe(
-                    len(subrun)
-                )
-
-    def _dispatch_run_split(self, run, prepared=None):
-        """Fusion-aware deferred dispatch of one device run: returns
-        ``(pairs, covered)`` where pairs is ``[(subrun, handle), ...]``
-        in op order and ``covered`` counts the leading run entries those
-        handles own.  ``covered < len(run)`` means a fast-path refusal
-        stopped the run mid-way — the uncovered tail executes inline at
-        its own positions (phase A's per-op path drains the lane first,
-        so op order is preserved exactly as with today's whole-run
-        refusal).  With TB_FUSE off (or a too-short run) this is the
-        plain single-handle dispatch."""
-        machine = self.machine
-        if not machine.fuse_batches or len(run) < 2:
-            handle = self._dispatch_run(run, prepared)
-            if handle is None:
-                return [], 0
-            return [(run, handle)], len(run)
-        plan = self._plan_run_fusion(run)
-        if machine.group_device_commit and len(plan) >= 2:
-            # Grouped lane: ONE stacked dispatch over the fused segment
-            # batches (each still <= batch_lanes rows, so the scan sees
-            # the exact shapes it already compiled for).
-            inner = machine.commit_group_fast(
-                [b for _s, b, _t in plan],
-                [t for _s, _b, t in plan],
-                deferred=True,
-            )
-            if inner is None:
-                return [], 0  # whole-run refusal: all ops execute inline
-            handle = _FusedRunHandle(
-                inner,
-                [[len(b) for _jj, b, _t in subrun] for subrun, _b, _t in plan],
-            )
-            self._note_fused_dispatch(plan)
-            self._trace_fused_dispatch(run, prepared, len(plan))
-            return [(run, handle)], len(run)
-        # Grouping off (or one segment): each segment dispatches through
-        # the per-batch deferred fast kernel — a fused segment IS one
-        # batch there, which is the whole win on hosts without the
-        # grouped dispatch (fewer padded kernel bodies, fewer readbacks).
-        pairs = []
-        covered = 0
-        for subrun, batch, timestamp in plan:
-            inner = machine.commit_fast_deferred(batch, timestamp)
-            if inner is None:
-                break
-            handle = (
-                _FusedRunHandle(inner, [[len(b) for _jj, b, _t in subrun]])
-                if len(subrun) > 1 else inner
-            )
-            self._note_fused_dispatch([(subrun, batch, timestamp)])
-            self._trace_fused_dispatch(subrun, prepared, 1)
-            pairs.append((subrun, handle))
-            covered += len(subrun)
-        return pairs, covered
-
-    def _commit_run_fused_blocking(self, run, precomputed) -> bool:
-        """Depth-1 fused commit: plan, dispatch, and RESOLVE the run's
-        fused segments at its position in op order, landing per-member
-        result bodies in ``precomputed``.  Returns True when every run
-        entry resolved; False leaves the refused tail to the caller's
-        per-op path (bit-identical inline execution)."""
-        machine = self.machine
-        plan = self._plan_run_fusion(run)
-        if machine.group_device_commit and len(plan) >= 2:
-            res = machine.commit_group_fast(
-                [b for _s, b, _t in plan], [t for _s, _b, t in plan]
-            )
-            if res is None:
-                return False
-            self._note_fused_dispatch(plan)
-            for (subrun, _b, _t), seg_res in zip(plan, res):
-                members = _demux_compressed(
-                    seg_res, [len(b) for _jj, b, _tt in subrun]
-                )
-                for (jj, _bb, _tt), member_res in zip(subrun, members):
-                    precomputed[jj] = _encode_results(member_res)
-            return True
-        for subrun, batch, timestamp in plan:
-            handle = machine.commit_fast_deferred(batch, timestamp)
-            if handle is None:
-                return False
-            seg_res = handle.resolve()[0]
-            self._note_fused_dispatch([(subrun, batch, timestamp)])
-            members = _demux_compressed(
-                seg_res, [len(b) for _jj, b, _tt in subrun]
-            )
-            for (jj, _bb, _tt), member_res in zip(subrun, members):
-                precomputed[jj] = _encode_results(member_res)
-        return True
-
-    def _trace_fused_dispatch(self, run, prepared, segments: int) -> None:
-        if prepared is None or not txtrace.active:
-            return
-        for jj, _b, _t in run:
-            trace = int(prepared[jj][1]["trace"])
-            if trace:
-                txtrace.hop(trace, "replica.dispatch_lane",
-                            replica=self.replica,
-                            op=int(prepared[jj][1]["op"]),
-                            run_len=len(run), fused_segments=segments)
-
     def _group_device_runs(
         self, admitted, single_ok: bool = False
     ) -> Dict[int, List[Tuple]]:
@@ -1254,20 +1096,11 @@ class Replica:
 
         ``single_ok`` (the pipelined engine): length-1 runs are emitted
         too — a lone create_transfers op dispatches DEFERRED through the
-        per-batch fast kernel (machine.commit_fast_deferred), so the
-        readback overlap works even where grouping is off (XLA-CPU: the
-        auto-gate turns it on only on a TPU backend).  When grouping is
-        off entirely, every create_transfers op becomes its own run."""
+        per-batch fast kernel (machine.commit_fast_deferred), so its
+        readback overlaps as a grouped run's does.  The longest run is the
+        machine's GROUP_K; a stand-in machine that cannot group
+        (sim/mc.py: GROUP_K = 1) gets runs of one at most."""
         runs: Dict[int, List[Tuple]] = {}
-        machine = self.machine
-        grouping = bool(getattr(machine, "group_device_commit", False))
-        # TB_FUSE widens run collection even where the grouped dispatch is
-        # unavailable: the fusion planner (_dispatch_run_split) needs to
-        # SEE consecutive create_transfers ops to coalesce them, and its
-        # fused segments dispatch through the per-batch kernel there.
-        fusing = bool(getattr(machine, "fuse_batches", False))
-        if not grouping and not single_ok and not fusing:
-            return runs
         if self.hash_log is not None:
             # The determinism oracle records a per-op ledger digest at
             # commit time; a grouped dispatch applies the whole run before
@@ -1277,7 +1110,7 @@ class Replica:
             # optimization.
             return runs
         min_len = 1 if single_ok else 2
-        max_len = machine.GROUP_K if (grouping or fusing) else 1
+        max_len = getattr(self.machine, "GROUP_K", 1)
         run: List[Tuple[int, np.ndarray, int]] = []
 
         def flush() -> None:
@@ -2260,59 +2093,6 @@ class Replica:
             dbg.close()
             self._debug_file = None
         self.storage.close()
-
-
-class _FusedRunHandle:
-    """Per-client demux over a fused dispatch (TB_FUSE; docs/
-    commit_pipeline.md fusion section): the inner DeviceCommitHandle
-    resolved per DISPATCHED batch — one or more of which are
-    concatenations of member client batches — and this wrapper reslices
-    each dispatched batch's compressed (lane, code) results back to
-    per-member results by row offset, preserving the engine's
-    one-result-list-per-run-entry retire contract.  Lane timestamps need
-    no translation: plan_fusion only fuses timestamp-contiguous members,
-    which makes every fused row's device timestamp equal its solo
-    dispatch value (docs/commitments.md).
-
-    ``member_counts`` is one list per dispatched batch of the member row
-    counts, in member order; resolve() returns the flattened per-member
-    result lists."""
-
-    def __init__(self, inner, member_counts: List[List[int]]):
-        self._inner = inner
-        self._member_counts = member_counts
-
-    @property
-    def join_wait_s(self) -> float:
-        return self._inner.join_wait_s
-
-    def discard(self) -> None:
-        self._inner.discard()
-
-    def resolve(self) -> List[List[Tuple[int, int]]]:
-        results = self._inner.resolve()
-        out: List[List[Tuple[int, int]]] = []
-        for res, counts in zip(results, self._member_counts):
-            out.extend(_demux_compressed(res, counts))
-        return out
-
-
-def _demux_compressed(
-    res: List[Tuple[int, int]], counts: List[int]
-) -> List[List[Tuple[int, int]]]:
-    """Slice one dispatched batch's compressed (lane, error_code) pairs
-    (ascending lanes; machine._compress) into per-member result lists by
-    row offset, rebasing each member's lanes to its own numbering."""
-    out: List[List[Tuple[int, int]]] = []
-    offset = 0
-    for c in counts:
-        out.append([
-            (lane - offset, code)
-            for lane, code in res
-            if offset <= lane < offset + c
-        ])
-        offset += c
-    return out
 
 
 _OP_NAMES = {

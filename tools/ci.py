@@ -254,10 +254,9 @@ TIERS = {
             "test_exhausted_node_does_not_truncate_siblings",
             "tests/test_scan_builder.py::TestMaintenance::"
             "test_account_scans",
-            # Cross-batch fusion + deferred commitment lane (PR 18): the
-            # pinned VOPR seed under TB_FUSE=1 x TB_MERKLE_ASYNC=1 —
-            # @slow, so it runs whole here.
-            "tests/test_fusion.py::TestVoprFused",
+            # Deferred commitment lane (PR 18): the pinned VOPR seed
+            # under TB_MERKLE_ASYNC=1 — @slow, so it runs whole here.
+            "tests/test_merkle_lane.py::TestVoprDeferredLane",
             "tests/test_merkle.py::TestMerkleProofs::test_proof_kinds_sharded",
             "tests/test_block_repair.py::"
             "test_missing_cold_run_repaired_from_peer",

@@ -64,7 +64,6 @@ def main() -> int:
                          posted_capacity_log2=10),
             batch_lanes=lanes,
         )
-        m.group_device_commit = True
         accs = types.accounts_array([
             types.account(id=i + 1, ledger=1, code=10)
             for i in range(n_accounts)

@@ -22,11 +22,12 @@ constructor whose shape argument is volatile un-stabilized, or (b) a
 volatile value on a ``static_argnames`` parameter (every distinct value
 is a recompile), or (c) an array built by JOINING a dynamic member list
 (``np.concatenate``/``hstack``/``vstack`` over a comprehension, a
-volatile slice, or a ``*splat``) — the PR 18 fused-run case: the joined
-width is the fused width, ``len()`` of the fused list, so an un-padded
-fused dispatch compiles one program per distinct fusion plan.  Fused-run
-padding must land on the EXISTING jit size classes (``batch_lanes`` /
-``GROUP_K`` attribute pads or ``bit_length()`` rounding)."""
+volatile slice, or a ``*splat``) — any joined batch: the joined width is
+the sum of the members' lengths, ``len()`` of the list that was joined, so
+an un-padded joined array compiles one program per distinct membership.
+A joined batch's padding must land on the EXISTING jit size classes
+(``batch_lanes`` / ``GROUP_K`` attribute pads or ``bit_length()``
+rounding)."""
 
 from __future__ import annotations
 
@@ -39,7 +40,7 @@ from ..jitgraph import _root_name, _terminal_name, module_wrappers
 _CONSTRUCTORS = {"zeros", "ones", "empty", "full", "arange", "asarray",
                  "array", "stack", "tile", "repeat"}
 #: member-list joiners: the result's leading dim is the SUM of member
-#: lengths — the fused-run width (PR 18 cross-batch fusion)
+#: lengths — the width of a joined batch
 _JOINERS = {"concatenate", "concat", "hstack", "vstack"}
 _ARRAY_MODULES = {"np", "jnp", "numpy"}
 _STABILIZERS = {"bit_length"}
@@ -99,9 +100,9 @@ class _Volatility:
 
     def _joiner_width_volatile(self, call: ast.Call) -> bool:
         """np.concatenate/hstack/vstack over a dynamic member list: the
-        joined leading dim is the fused width — len() of the fused list —
-        unless the operand is padded to a config constant / bit_length
-        size class (the fused-run discipline, PR 18)."""
+        joined leading dim is the members' summed width — len() of the
+        joined list — unless the operand is padded to a config constant /
+        bit_length size class."""
         name = _terminal_name(call.func)
         root = _root_name(call.func)
         if name not in _JOINERS or root not in _ARRAY_MODULES:
