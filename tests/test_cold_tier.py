@@ -270,3 +270,48 @@ class TestEvictionExactness:
         # A fresh store over the same directory continues the sequence.
         store2 = cold_mod.ColdStore(str(tmp_path / "c"))
         assert store2.next_seq == store.next_seq
+
+
+@pytest.mark.parametrize("write_lanes", [64, 1 << 19])
+def test_the_rehash_lays_the_table_out_as_one_claim_over_the_slots(
+        monkeypatch, write_lanes):
+    """drop_evicted (the kept rows compacted, one sort-free claim, written a
+    chunk at a time) against the form it replaced: ONE claim_slots over all
+    the slots' lanes and one write.  Slot for slot, column for column, with
+    the write in several trips and in one."""
+    import jax
+    import jax.numpy as jnp
+
+    from tigerbeetle_tpu.ops import hash_table as ht
+
+    monkeypatch.setattr(cold_mod, "_WRITE_LANES", write_lanes)
+    rng = np.random.default_rng(48)
+    capacity, rows = 1 << 10, 500
+    dtypes = {"timestamp": jnp.uint64, "amount_lo": jnp.uint64,
+              "code": jnp.uint32}
+    table = ht.make_table(capacity, dtypes)
+    for start in range(0, rows, 100):     # five batches: real probe chains
+        lo = jnp.asarray(rng.integers(1, 1 << 62, 100).astype(np.uint64))
+        hi = jnp.asarray(rng.integers(0, 3, 100).astype(np.uint64))
+        at = jnp.arange(start, start + 100, dtype=jnp.uint64)
+        table, _ = ht.insert(
+            table, lo, hi, jnp.ones(100, jnp.bool_),
+            {"timestamp": at + jnp.uint64(1), "amount_lo": lo ^ hi,
+             "code": at.astype(jnp.uint32)}, capacity)
+    threshold = jnp.uint64(rows // 2)
+    keep = cold_mod._live(table) & (table.cols["timestamp"] > threshold)
+    fresh = ht.make_table(capacity, dtypes)
+    claimed, _ = ht.claim_slots(
+        fresh, table.key_lo, table.key_hi, keep, capacity)
+    want = ht.write_rows(
+        fresh, table.key_lo, table.key_hi, claimed, keep, table.cols)
+    got = jax.jit(
+        cold_mod.drop_evicted.__wrapped__, static_argnames=("k",)
+    )(table, threshold, k=256)
+    assert int(got.count) == int(want.count) == rows - rows // 2
+    for name in ("key_lo", "key_hi", "tombstone"):
+        assert np.array_equal(np.asarray(getattr(got, name)),
+                              np.asarray(getattr(want, name))), name
+    for name in dtypes:
+        assert np.array_equal(np.asarray(got.cols[name]),
+                              np.asarray(want.cols[name])), name

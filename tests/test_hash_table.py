@@ -209,6 +209,41 @@ def test_claim_parity_with_sorted_protocol():
         t = ht.write_rows(t, lo, hi, got, mask, {"val": lo})
 
 
+@pytest.mark.parametrize("case", ["every_lane", "masked_lanes",
+                                  "half_full", "one_home"])
+def test_a_claim_into_an_empty_table_is_claim_slots(case):
+    """claim_slots_empty (a rehash's claim: lanes by the million, no sort)
+    picks the slots claim_slots picks on an empty table."""
+    rng = np.random.default_rng(0xE3F7)
+    capacity, n = 1 << 10, 400
+    ids = rng.choice(np.arange(1, 1 << 20), size=n, replace=False)
+    mask_np = np.ones(n, bool)
+    if case == "masked_lanes":
+        mask_np = rng.random(n) < 0.6
+    elif case == "half_full":
+        n = capacity // 2
+        ids = rng.choice(np.arange(1, 1 << 20), size=n, replace=False)
+        mask_np = np.ones(n, bool)
+    elif case == "one_home":
+        from tigerbeetle_tpu.u128 import mix64
+
+        cands = np.arange(1, 40_000, dtype=np.uint64)
+        homes = np.asarray(mix64(
+            jnp.asarray(cands), jnp.zeros(len(cands), jnp.uint64))
+        ) & np.uint64(capacity - 1)
+        ids = np.concatenate([cands[homes == 7][:20], ids[:200]])
+        mask_np = np.ones(len(ids), bool)
+    lo, hi = keys_of([int(v) for v in ids])
+    mask = jnp.asarray(mask_np)
+    want, overflow = ht.claim_slots(
+        ht.make_table(capacity, {"val": jnp.uint64}), lo, hi, mask, capacity)
+    got = jax.jit(ht.claim_slots_empty, static_argnums=0)(
+        capacity, lo, hi, mask)
+    assert not bool(overflow)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (np.asarray(got)[~mask_np] == capacity).all()
+
+
 def test_claim_parity_forced_home_collisions():
     """Many lanes sharing one home slot place in strict batch-lane order
     past the cluster (the lowest-lane-wins rule)."""

@@ -192,6 +192,49 @@ def test_merge_carries_a_run_and_three_levels(empty_level):
     assert_levels_equal(got, oracle(cat))
 
 
+# -- above level 9: merged by passes, not sorted (index._merge2, index._carry) --
+
+
+def _cut(whole, lo, hi, rows=None):
+    rows = np.arange(len(whole["ts"])) if rows is None else rows
+    return oracle({name: col[rows[lo:hi]] for name, col in whole.items()})
+
+
+@pytest.mark.parametrize("case", [c for c in CASES if c != "ties"])
+def test_merge_by_passes_is_the_sort(case):
+    """Two ordered levels whose 2n keys differ, as the ledger's do (a
+    timestamp is unique; `ties` repeats whole keys with rows that differ,
+    which only a stable sort orders): one level of 2n rows dealt out row by
+    row."""
+    rng = np.random.default_rng(50)
+    whole = CASES[case](rng, 2 * N)
+    deal = rng.permutation(2 * N)
+    a, b = _cut(whole, 0, N, deal), _cut(whole, N, 2 * N, deal)
+    got = index._merge2_jit(_device(a), _device(b))
+    assert_levels_equal(got, oracle(whole))
+
+
+@pytest.mark.parametrize("sorted_levels", [1, 2, 3])
+def test_a_carry_above_the_sorted_levels_is_the_sort(monkeypatch,
+                                                     sorted_levels):
+    """A run and levels 0..2, of which the sort takes the run and the
+    first `sorted_levels` and the passes merge the rest in: 3 is every
+    level, the accepted cells' program alone."""
+    monkeypatch.setattr(index, "_SORT_LEVELS", sorted_levels)
+    whole = _acct_hi_set(np.random.default_rng(51), 8 * N)
+    parts = [_cut(whole, lo, hi) for lo, hi in
+             ((0, N), (N, 2 * N), (2 * N, 4 * N), (4 * N, 8 * N))]
+    got = index._carry(_device(parts[0]), [_device(p) for p in parts[1:]])
+    assert_levels_equal(got, oracle(whole))
+
+
+def test_the_merge_by_passes_lowers_to_no_sort_and_no_gather():
+    level = {name: jax.ShapeDtypeStruct((N,), jnp.uint64)
+             for name in index.COLS}
+    text = index._merge2_jit.lower(level, level).as_text()
+    assert "stablehlo.sort" not in text and "gather" not in text
+
+
 # -- the pyramids, beside one kept in numpy by the oracle ---------------------
 
 
@@ -281,6 +324,31 @@ def test_transfer_index_levels_are_the_oracles(seeded):
     for side, levels in (("debit_account_id", m.index.dr_levels),
                          ("credit_account_id", m.index.cr_levels)):
         assert m.index.occupied == pyramids[side].occupied
+        for k, (got, want) in enumerate(zip(levels, pyramids[side].levels)):
+            assert_levels_equal(got, want, f"{side}[{k}].")
+
+
+def test_transfer_index_above_the_sorted_levels_holds_the_oracles_levels(
+        monkeypatch):
+    """Eight appends carry into level 3; with the sort stopped at level 1
+    the two levels above are merged in by passes."""
+    monkeypatch.setattr(index, "_SORT_LEVELS", 1)
+    rng = np.random.default_rng(52)
+    held = index.TransferIndex(N)
+    pyramids = {side: NumpyPyramid(N)
+                for side in ("debit_account_id", "credit_account_id")}
+    for j in range(8):
+        keys, id_lo, id_hi, ok = _batch_keys(rng, N, _acct_hi_set)
+        keys["timestamp"] = keys["timestamp"] + np.uint64(j * N)
+        held.append_batch(
+            {name: jnp.asarray(col) for name, col in keys.items()},
+            jnp.asarray(id_lo), jnp.asarray(id_hi), jnp.asarray(ok))
+        for side, pyramid in pyramids.items():
+            pyramid.append(_side_level(
+                keys, keys["timestamp"], id_lo, id_hi, ok, side))
+    assert held.occupied == [False, False, False, True]
+    for side, levels in (("debit_account_id", held.dr_levels),
+                         ("credit_account_id", held.cr_levels)):
         for k, (got, want) in enumerate(zip(levels, pyramids[side].levels)):
             assert_levels_equal(got, want, f"{side}[{k}].")
 

@@ -117,9 +117,29 @@ def main(argv=None) -> int:
     p_start.add_argument("--tick-ms", type=int, default=None,
                          help="cluster consensus tick cadence")
     p_start.add_argument("--hot-transfers-log2-max", type=int, default=None,
+                         metavar="N",
                          help="cap the device-resident transfers window at "
                               "2^N slots; older transfers spill to a cold "
-                              "host store (BASELINE config 4 tiering)")
+                              "host store (BASELINE config 4 tiering).  At "
+                              "the cap an eviction moves the older half of "
+                              "the window to an id-sorted run file beside "
+                              "the data file, inline on the serving thread, "
+                              "before the batch that would pass load 0.5 "
+                              "(docs/deploy.md).  Start the table there: "
+                              "--cache-transfers-log2 N")
+    p_start.add_argument("--cold-bloom-log2", type=int, default=None,
+                         metavar="N",
+                         help="bits of the cold tier's Bloom filter, 2^N, "
+                              "allocated at start with "
+                              "--hot-transfers-log2-max and carried into "
+                              "every commit (default: the hot window's log2 "
+                              "+ 6, i.e. 12 bits an id for a cold store of "
+                              "eight hot windows).  It never changes shape "
+                              "under that design load; past it it grows, "
+                              "and the commit program recompiles.  A false "
+                              "positive costs one more dispatch of the "
+                              "whole batch: size for a batch, not an id "
+                              "(docs/deploy.md)")
     p_start.add_argument("--pipeline-depth", type=int, default=None,
                          metavar="N",
                          help="commit-pipeline depth for the serving path: "
@@ -793,13 +813,18 @@ def _arm_blackbox(replica) -> None:
 # What a table can take: 2^32 slots of the narrowest table (posted, 21 B a
 # slot) are more than the memory of any device this serves from.
 _TABLE_LOG2_MAX = 32
+# The cold tier's filter: what `ops/cold.make_bloom` takes (128 B to 2 GiB),
+# and the least a `start` without `--cold-bloom-log2` allocates (128 KiB).
+_BLOOM_LOG2_MIN, _BLOOM_LOG2_MAX, _BLOOM_LOG2_DEFAULT_MIN = 10, 34, 20
 
 
 def _ledger_config(args):
     """`start`'s three table options onto the default LedgerConfig, each on
-    its own.  Raises ValueError for a size the tables cannot take: nothing
-    is clamped, because a server with other tables than its operator asked
-    for is another deployment."""
+    its own, and under `--hot-transfers-log2-max` the cold tier's filter
+    (`--cold-bloom-log2`; default: the hot window's log2 + 6, 12 bits an id
+    for a cold store of eight windows).  Raises ValueError for a size the
+    tables cannot take: nothing is clamped, because a server with other
+    tables than its operator asked for is another deployment."""
     import dataclasses
 
     from .config import LedgerConfig
@@ -823,10 +848,26 @@ def _ledger_config(args):
                 f"2^{log2_min} to 2^{_TABLE_LOG2_MAX} slots"
                 + (f" under --shards {shards}" if shards >= 2 else "")
             )
-    return dataclasses.replace(LedgerConfig(), **{
+    fields = {
         f"{table}_capacity_log2": log2
         for table, log2 in sizes.items() if log2 is not None
-    })
+    }
+    hot_log2 = getattr(args, "hot_transfers_log2_max", None)
+    bloom_log2 = getattr(args, "cold_bloom_log2", None)
+    if bloom_log2 is not None and hot_log2 is None:
+        raise ValueError(
+            "--cold-bloom-log2 sizes the cold tier's filter: it needs "
+            "--hot-transfers-log2-max")
+    if hot_log2 is not None:
+        if bloom_log2 is None:
+            bloom_log2 = min(_BLOOM_LOG2_MAX,
+                             max(_BLOOM_LOG2_DEFAULT_MIN, hot_log2 + 6))
+        if not _BLOOM_LOG2_MIN <= bloom_log2 <= _BLOOM_LOG2_MAX:
+            raise ValueError(
+                f"--cold-bloom-log2 {bloom_log2}: the filter takes "
+                f"2^{_BLOOM_LOG2_MIN} to 2^{_BLOOM_LOG2_MAX} bits")
+        fields["bloom_bits_log2"] = bloom_log2
+    return dataclasses.replace(LedgerConfig(), **fields)
 
 
 def _cmd_start(args) -> int:

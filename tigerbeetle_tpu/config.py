@@ -69,9 +69,11 @@ class LedgerConfig:
     # Upper bound on linear-probe distance before the kernel reports the table
     # as over-full (host must grow/rebuild; analogous to cache eviction limits).
     max_probe: int = 64
-    # Cold-tier Bloom filter size (machine.py tiering): 2^N bits; sized so
-    # the false-positive rate stays low as spilled-id counts grow (the
-    # filter doubles on saturation either way — this is the floor).
+    # Cold-tier Bloom filter size (machine.py tiering): 2^N bits, allocated
+    # when tiering starts and an ARGUMENT of the general commit program, so
+    # `start --cold-bloom-log2` sizes it for the deployment's cold store (12
+    # bits a cold id is its design load; past that it grows, and the program
+    # recompiles).  This is the floor a machine built without `start` gets.
     bloom_bits_log2: int = 20
     # Fraction of live hot transfers spilled per eviction (machine.evict_cold).
     eviction_fraction: float = 0.5

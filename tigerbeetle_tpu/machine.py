@@ -511,6 +511,7 @@ class TpuStateMachine:
         self._bloom_log2 = cfg.bloom_bits_log2
         self._bloom_np = None
         self._bloom_dev = None
+        self._bloom_grows = 0
         self._evictions = 0
         # Commit pipeline (docs/commit_pipeline.md): bounded deferred-
         # readback depth (TB_PIPELINE; resolved lazily so tests can set the
@@ -594,6 +595,41 @@ class TpuStateMachine:
         if self._tiering:
             self._bloom_np = np.zeros(((1 << self._bloom_log2) // 32,), np.uint32)
             self._bloom_dev = make_bloom(self._bloom_log2)
+
+    def _warmup_cold_tier(self) -> None:
+        """The tier's own programs, so that none compiles inside a request:
+        the rehydration (a batch's lanes) and, once the hot table stands at
+        its ceiling (a deployment starts it there: `--cache-transfers-log2`
+        = `--hot-transfers-log2-max`), the three programs of an eviction at
+        that capacity, the extract at the size class of an eviction under
+        the design load.  On the empty table each evicts nothing."""
+        from .ops import cold as cold_mod
+
+        empty = np.zeros(0, dtype=types.TRANSFER_DTYPE)
+        table, n = cold_mod.rehydrate(
+            self.ledger.transfers,
+            *staging.stage_batch(empty, self.batch_lanes, 0),
+            max_probe=self.config.max_probe,
+        )
+        self.ledger = self.ledger.replace(transfers=table)
+        hot_max = self.hot_transfers_capacity_max
+        if hot_max is not None and table.capacity == hot_max:
+            num = self._eviction_permille()
+            threshold, _, _ = cold_mod.eviction_threshold(table, num, 1000)
+            # An eviction at the ceiling finds the table at load 0.5: the
+            # size classes of the rows that leave and of those that stay.
+            leaving = (hot_max // 2) * num // 1000
+            lanes = self.batch_lanes
+            packed = cold_mod.extract_evicted(
+                table, threshold, cold_mod.size_class(leaving, lanes))
+            jax.block_until_ready(  # tblint: ignore[host-sync] warm-up
+                (n, packed, cold_mod.drop_evicted(
+                    table, threshold,
+                    cold_mod.size_class(hot_max // 2 - leaving, lanes),
+                ).count)
+            )
+        if _obs.enabled:
+            self._report_cold_gauges()
 
     def _d2h_codes(self, codes, overflow=None, stage=None, seq=0):
         """The blocking device->host read of a commit's result codes: the
@@ -1980,6 +2016,8 @@ class TpuStateMachine:
                 use_waves=self.waves_enabled,
             )
             self.ledger, codes_t, kflags = r[0], r[1], r[2]
+        if self._tiering:
+            self._warmup_cold_tier()
         if self._fast_path_ok(empty):
             # Only pay the extra compile when the fast path is reachable
             # (tiering / restored limit flags / blown balance bound disable
@@ -2237,14 +2275,21 @@ class TpuStateMachine:
                     use_waves=use_waves,
                 )
             self.ledger, codes, kflags = r[0], r[1], r[2]
-            wave_vec = r[3] if use_waves else None
+            # The rest in the program's order (tf.create_transfers_full):
+            # the waves' vector, the tier's flagged lanes, the index's feed.
+            rest = iter(r[3:])
+            wave_vec = next(rest) if use_waves else None
+            cold_lanes = next(rest) if self._bloom_dev is not None else None
+            index_feed = tuple(rest)
             # The kflags scalar read IS this path's blocking device sync
-            # (the codes transfer below rides an already-complete dispatch).
-            kflags, wave_host = self._full_kflags_sync(kflags, wave_vec)
+            # (the codes transfer below rides an already-complete dispatch);
+            # under the cold tier the lanes FLAG_COLD is about ride it too.
+            kflags, wave_host, cold_lanes = self._full_kflags_sync(
+                kflags, wave_vec, cold_lanes)
             if kflags == 0:
                 results = self._full_commit_success(
                     codes, count, pv_count, hist_count, timestamp,
-                    wave_host, index_feed=r[-4:],
+                    wave_host, index_feed=index_feed,
                 )
                 # Deferred tier rebalance: eviction is only safe BETWEEN
                 # batches (mid-loop it would invalidate the certification
@@ -2255,10 +2300,13 @@ class TpuStateMachine:
                 _obs.counter("ops.general.retries").inc()
             ev0 = self._evictions
             if kflags & tf.FLAG_COLD:
-                # Possible cold-tier ids: resolve exactly on the host,
-                # rehydrate any real cold rows into the hot table, and
-                # certify the batch so Bloom false positives terminate.
-                self._resolve_cold(batch)
+                # Possible cold-tier ids: resolve the flagged lanes exactly
+                # on the host, rehydrate any real cold rows into the hot
+                # table, and certify the batch so Bloom false positives
+                # terminate (an unflagged lane's ids are hot or miss the
+                # filter, which has no false negatives).
+                with txtrace.stage("cold_resolve", n=count):
+                    self._resolve_cold(batch, cold_lanes)
                 # Any eviction voids the certification: freshly-cold rows
                 # must be re-detected by the Bloom on the next attempt.
                 cold_checked = (
@@ -2280,19 +2328,24 @@ class TpuStateMachine:
                 cold_checked = jnp.zeros((self.batch_lanes,), jnp.bool_)
         raise RuntimeError("transfer kernel could not place batch after growth")
 
-    def _full_kflags_sync(self, kflags, wave_vec):
+    def _full_kflags_sync(self, kflags, wave_vec, cold_lanes=None):
         """The general kernel's blocking commit barrier, shared by the
         single-device and sharded dispatch loops: the kflags scalar read
-        (plus the 11-scalar wave profile riding the SAME sync when armed),
-        timed so the e2e decomposition sees the device wait."""
+        (plus the 11-scalar wave profile riding the SAME sync when armed,
+        and under the cold tier the lanes FLAG_COLD is about), timed so the
+        e2e decomposition sees the device wait.  Returns ``(kflags, wave
+        profile or None, cold lanes or None)`` on the host."""
         self._injected_fault_check()
         t0 = _time.perf_counter()
         with txtrace.stage("full_sync"):
-            if wave_vec is not None and _obs.enabled:
+            if not _obs.enabled:
+                wave_vec = None
+            if wave_vec is not None or cold_lanes is not None:
                 got = jax.device_get(  # tblint: ignore[host-sync] commit barrier
-                    (kflags, wave_vec)
+                    (kflags, wave_vec, cold_lanes)
                 )
-                kflags, wave_host = int(got[0]), got[1]
+                kflags, wave_host, cold_lanes = got
+                kflags = int(kflags)
             else:
                 kflags = int(kflags)
                 wave_host = None
@@ -2300,7 +2353,7 @@ class TpuStateMachine:
         if _obs.enabled:
             _obs.counter("ops.dispatch").inc()
             _obs.histogram("ops.dispatch_wait_us", "us").observe(wait * 1e6)
-        return kflags, wave_host
+        return kflags, wave_host, cold_lanes
 
     def _full_commit_success(self, codes, count, pv_count, hist_count,
                              timestamp, wave_host, index_feed=None):
@@ -2430,7 +2483,7 @@ class TpuStateMachine:
                 r = step(self.ledger, *staged)
             self.ledger, codes, kflags = r[0], r[1], r[2]
             wave_vec = r[3] if use_waves else None
-            kflags, wave_host = self._full_kflags_sync(kflags, wave_vec)
+            kflags, wave_host, _ = self._full_kflags_sync(kflags, wave_vec)
             if kflags == 0:
                 if _obs.enabled:
                     _obs.counter("sharding.batches").inc()
@@ -3375,60 +3428,68 @@ class TpuStateMachine:
 
     # -- cold tier (ops/cold.py) --------------------------------------------
 
-    def _resolve_cold(self, batch: np.ndarray) -> None:
-        """Host-exact resolution of a FLAG_COLD batch: rehydrate every cold
-        row referenced by id or pending_id into the hot table."""
-        ids = {
-            (int(r["id_lo"]), int(r["id_hi"])) for r in batch
-        } | {
-            (int(r["pending_id_lo"]), int(r["pending_id_hi"])) for r in batch
-        }
-        ids.discard((0, 0))
-        found = self.cold.lookup_many(sorted(ids))
-        if not found:
-            return
-        # Skip ids already hot (an earlier rehydration): double-inserting a
-        # key would corrupt the hot table's uniqueness invariant.
-        keys = sorted(found)
-        hot_found, _ = sm.lookup_transfers(
-            self.ledger,
-            jnp.asarray([k[0] for k in keys], jnp.uint64),
-            jnp.asarray([k[1] for k in keys], jnp.uint64),
-        )
-        hot_found = np.asarray(hot_found)
-        rows = [found[k] for k, h in zip(keys, hot_found) if not h]
-        if rows:
-            self._rehydrate(np.stack(rows).view(types.TRANSFER_DTYPE))
+    def _resolve_cold(self, batch: np.ndarray, cold_lanes=None) -> int:
+        """Host-exact resolution of a FLAG_COLD batch: every cold row that a
+        FLAGGED lane references by id (``cold_lanes`` bit 0) or pending_id
+        (bit 1) is rehydrated into the hot table.  ``cold_lanes`` None (the
+        sequential route, which has no filter): every lane, both ids.
+        Returns the rows found cold.  Vectorised over the flagged ids
+        (``ColdStore.lookup_arrays``); one upload and one program for the
+        rows (``_rehydrate``)."""
+        if cold_lanes is None:
+            by_id = by_pend = np.ones(len(batch), dtype=bool)
+        else:
+            cold_lanes = np.asarray(cold_lanes)[: len(batch)]
+            by_id, by_pend = (cold_lanes & 1) != 0, (cold_lanes & 2) != 0
+        keys = np.unique(np.stack([
+            np.concatenate([batch["id_lo"][by_id],
+                            batch["pending_id_lo"][by_pend]]),
+            np.concatenate([batch["id_hi"][by_id],
+                            batch["pending_id_hi"][by_pend]]),
+        ], axis=1), axis=0)
+        keys = keys[(keys != 0).any(axis=1)]
+        found, rows = self.cold.lookup_arrays(keys[:, 0], keys[:, 1])
+        n_found = int(found.sum())
+        if _obs.enabled and cold_lanes is not None:
+            # A re-dispatch for FLAG_COLD, and whether any of it was true.
+            _obs.counter("cold.redispatches").inc()
+            _obs.counter("cold.flagged_lanes").inc(len(keys))
+            _obs.counter("cold.false_positive_lanes").inc(
+                len(keys) - n_found)
+            if not n_found:
+                _obs.counter("cold.false_redispatches").inc()
+        if n_found:
+            with txtrace.stage("cold_rehydrate", n=n_found):
+                self._rehydrate(rows[found])
+        return n_found
 
     def _rehydrate(self, rows: np.ndarray) -> None:
         """Insert cold rows back into the hot table (immutable duplicates of
-        their cold copies; a later eviction may spill them again)."""
-        from .ops import hash_table as ht_mod
+        their cold copies; a later eviction may spill them again), but for
+        those already hot (an earlier rehydration).  A batch's lanes at a
+        time through ONE program of that shape (``ops/cold.rehydrate``,
+        warmed at start), so no count of rows compiles anything."""
+        from .ops import cold as cold_mod
 
         # No eviction here (evictions mid-commit invalidate the batch's
         # certification); a slightly-elevated load factor until the next
         # between-batches rebalance is fine.
         self._grow_if_needed(transfers=len(rows), evict_ok=False)
-        n = len(rows)
-        lanes = max(self.batch_lanes, 1 << (n - 1).bit_length() if n else 1)
-        padded = np.zeros(lanes, dtype=types.TRANSFER_DTYPE)
-        padded[:n] = rows
-        soa = {k: jnp.asarray(v) for k, v in types.to_soa(padded).items()}
-        mask = jnp.arange(lanes) < n
-        id_lo, id_hi = soa.pop("id_lo"), soa.pop("id_hi")
-        row_cols = {
-            name: soa[name].astype(dt)
-            for name, dt in sm.TRANSFER_COLS.items()
-        }
-        transfers, _ = ht_mod.insert(
-            self.ledger.transfers, id_lo, id_hi, mask, row_cols,
-            self.config.max_probe,
-        )
+        transfers, inserted = self.ledger.transfers, 0
+        for at in range(0, len(rows), self.batch_lanes):
+            transfers, n = cold_mod.rehydrate(
+                transfers, *staging.stage_batch(
+                    rows[at:at + self.batch_lanes], self.batch_lanes, 0),
+                max_probe=self.config.max_probe,
+            )
+            inserted += int(n)
         if bool(np.asarray(transfers.probe_overflow)):
             raise RuntimeError("cold rehydration overflowed the hot table")
         self.ledger = self.ledger.replace(transfers=transfers)
-        self._transfers_bound += n
+        self._transfers_bound += inserted
         self._merkle_mark_dirty()  # rows appeared outside a commit batch
+        if _obs.enabled:
+            _obs.counter("cold.rehydrated_rows").inc(inserted)
 
     def evict_cold(self, frac: Optional[float] = None) -> int:
         """Spill the oldest ~frac of live hot transfers to the cold store.
@@ -3468,27 +3529,44 @@ class TpuStateMachine:
         if not self._tiering:
             self._tiering = True
             self._bloom_np = np.zeros(((1 << self._bloom_log2) // 32,), np.uint32)
-        if frac is None:
-            frac = self.config.eviction_fraction
-        num = max(1, min(999, int(frac * 1000)))
-        threshold = cold_mod.eviction_threshold(self.ledger.transfers, num, 1000)
-        k = self.ledger.transfers.capacity
-        n, key_lo, key_hi, cols = cold_mod.extract_evicted(
-            self.ledger.transfers, threshold, k
-        )
-        rows = cold_mod.rows_to_numpy(n, key_lo, key_hi, cols)
-        if len(rows) == 0:
-            return 0
-        self.cold.append_run(rows)
-        self.ledger = self.ledger.replace(
-            transfers=cold_mod.drop_evicted(self.ledger.transfers, threshold)
-        )
-        cold_mod.bloom_add_host(
-            self._bloom_np, rows["id_lo"].astype(np.uint64),
-            rows["id_hi"].astype(np.uint64),
-        )
-        self._maybe_grow_bloom()
-        self._bloom_dev = jnp.asarray(self._bloom_np)
+        num = self._eviction_permille(frac)
+        table = self.ledger.transfers
+        # Each device step ends in a blocking read, so a span's time is its
+        # program's (docs/tracing.md: `cold_evict` and its six children).
+        with txtrace.stage("cold_evict"):
+            with txtrace.stage("cold_threshold"):
+                threshold, n, live = jax.device_get(  # tblint: ignore[host-sync] eviction
+                    cold_mod.eviction_threshold(table, num, 1000))
+                n, live = int(n), int(live)
+            if n == 0:
+                return 0
+            with txtrace.stage("cold_extract", n=n):
+                # The evicted count's size class, not the capacity: the
+                # gather moves the rows that leave and no others.
+                packed = cold_mod.extract_evicted(
+                    table, threshold,
+                    cold_mod.size_class(n, self.batch_lanes),
+                )
+                jax.block_until_ready(packed)  # tblint: ignore[host-sync] eviction
+            with txtrace.stage("cold_fetch", n=n):
+                rows = cold_mod.rows_to_numpy(*packed)
+                del packed
+            with txtrace.stage("cold_spill", n=n):
+                # Durable before the eviction returns: sorted by id,
+                # written, fsynced, renamed.
+                self.cold.append_run(rows)
+            with txtrace.stage("cold_rehash", n=live - n):
+                table = cold_mod.drop_evicted(
+                    table, threshold,
+                    cold_mod.size_class(live - n, self.batch_lanes),
+                )
+                jax.block_until_ready(table.count)  # tblint: ignore[host-sync] eviction
+                self.ledger = self.ledger.replace(transfers=table)
+            with txtrace.stage("cold_filter", n=n):
+                cold_mod.bloom_add_host(
+                    self._bloom_np, rows["id_lo"], rows["id_hi"])
+                self._maybe_grow_bloom()
+                self._bloom_dev = jnp.asarray(self._bloom_np)
         self._transfers_bound = max(0, self._transfers_bound - len(rows))
         self._evictions += 1
         self._merkle_mark_dirty()  # rows left the hot table wholesale
@@ -3497,16 +3575,43 @@ class TpuStateMachine:
             # (replica pipeline naming: prefetch/commit/compact/checkpoint).
             _obs.counter("ops.compactions").inc()
             _obs.counter("ops.rows_evicted").inc(len(rows))
+            self._report_cold_gauges()
         # The query index stores ids (not slots), so it stays valid; row
         # resolution for cold ids happens in get_account_transfers.
         return len(rows)
 
+    def _eviction_permille(self, frac: Optional[float] = None) -> int:
+        """The share of the live rows an eviction moves, in thousandths."""
+        if frac is None:
+            frac = self.config.eviction_fraction
+        return max(1, min(999, int(frac * 1000)))
+
+    def _report_cold_gauges(self) -> None:
+        _obs.gauge("cold.rows").set(self.cold.count)
+        _obs.gauge("cold.runs").set(len(self.cold.runs))
+        _obs.gauge("cold.bloom_bits_log2").set(self._bloom_log2)
+
     def _maybe_grow_bloom(self) -> None:
-        """Keep >= ~12 bits per cold id (false-positive rate ~1e-3 at 4
-        hashes); rebuild from the runs at the next power of two if not."""
+        """The filter's design load is 12 bits a cold id (false-positive
+        rate ~1e-3 at 4 hashes); ``start --cold-bloom-log2`` sizes it so
+        that a deployment stays under it.  Past it, rebuild from the runs
+        at four times the bits: a NEW SHAPE of the general commit program's
+        argument, so the next commit compiles (tens of seconds on a v5e) inside a
+        request.  Counted, and said once."""
         while self.cold.count * 12 > (1 << self._bloom_log2):
+            if not self._bloom_grows:
+                warnings.warn(
+                    f"cold tier: {self.cold.count} cold ids pass the design "
+                    f"load of a 2^{self._bloom_log2}-bit filter; it grows, "
+                    "and the commit program recompiles at each growth "
+                    "(size it at start: --cold-bloom-log2)",
+                    RuntimeWarning, stacklevel=2,
+                )
+            self._bloom_grows += 1
             self._bloom_log2 += 2
             self._bloom_np = self.cold.rebuild_bloom(self._bloom_log2)
+            if _obs.enabled:
+                _obs.counter("cold.bloom.grows").inc()
 
     def _transfer_growth_counts(self, batch: np.ndarray) -> Tuple[int, int]:
         """(posted rows, history rows) this batch could append at most —
@@ -3901,28 +4006,23 @@ class TpuStateMachine:
         if self._engine is not None:
             found, rows = self._engine.lookup_transfers(ids)
             return rows[found]  # no cold tier in host mode
-        lo = jnp.asarray([i & U64_MAX for i in ids], jnp.uint64)
-        hi = jnp.asarray([i >> 64 for i in ids], jnp.uint64)
-        found, cols = sm.lookup_transfers(self._query_ledger(), lo, hi)
-        found = np.asarray(found)
+        lo_np = np.array([i & U64_MAX for i in ids], np.uint64)
+        hi_np = np.array([i >> 64 for i in ids], np.uint64)
+        found, cols = sm.lookup_transfers(
+            self._query_ledger(), jnp.asarray(lo_np), jnp.asarray(hi_np)
+        )
+        found = np.array(found)
         self._sanitize_absorb_compiles()  # read-path first-use jit
         host = {k: np.asarray(v) for k, v in cols.items()}
         rows = types.from_soa(host, types.TRANSFER_DTYPE)
         if self.cold.count and not found.all():
             # Misses may be cold (evicted): merge rows from the spill,
             # preserving request order.
-            out = []
-            for i, ident in enumerate(ids):
-                if found[i]:
-                    out.append(rows[i])
-                else:
-                    row = self.cold.lookup(ident & U64_MAX, ident >> 64)
-                    if row is not None:
-                        out.append(row)
-            return (
-                np.stack(out).view(types.TRANSFER_DTYPE)
-                if out else np.zeros(0, dtype=types.TRANSFER_DTYPE)
-            )
+            miss = np.flatnonzero(~found)
+            was_cold, cold_rows = self.cold.lookup_arrays(
+                lo_np[miss], hi_np[miss])
+            rows[miss[was_cold]] = cold_rows[was_cold]
+            found[miss[was_cold]] = True
         return rows[found]
 
     # -- queries (state_machine.zig:693-892, 1128-1195) ----------------------
@@ -4013,21 +4113,12 @@ class TpuStateMachine:
         if self.cold.count and bool((idx_valid & ~found).any()):
             # Index hits whose rows were evicted: resolve from the spill,
             # preserving timestamp order.
-            merged = []
-            for i in range(len(idx_valid)):
-                if not idx_valid[i]:
-                    continue
-                if found[i]:
-                    merged.append(out[i])
-                else:
-                    row = self.cold.lookup(int(tl_np[i]), int(th_np[i]))
-                    if row is not None:
-                        merged.append(row)
-            rows_np = (
-                np.stack(merged).view(types.TRANSFER_DTYPE)
-                if merged else np.zeros(0, dtype=types.TRANSFER_DTYPE)
-            )
-            return rows_np[: min(limit, QUERY_ROWS_MAX)]
+            miss = np.flatnonzero(idx_valid & ~found)
+            was_cold, cold_rows = self.cold.lookup_arrays(
+                tl_np[miss], th_np[miss])
+            found = np.array(found)
+            out[miss[was_cold]] = cold_rows[was_cold]
+            found[miss[was_cold]] = True
         return out[idx_valid & found][: min(limit, QUERY_ROWS_MAX)]
 
     # -- general composed scans (ops/scan_builder.py) ------------------------
@@ -4217,7 +4308,10 @@ class TpuStateMachine:
             # cold, so a tiered checkpoint restores sharded just fine.
             self._tiering = True
             self.cold.load_manifest(manifest)
-            self._bloom_log2 = int(state.get("bloom_log2", self._bloom_log2))
+            # The filter is derived state, rebuilt from the runs: the size
+            # this start asked for stands unless the checkpoint's is larger.
+            self._bloom_log2 = max(
+                self._bloom_log2, int(state.get("bloom_log2", 0)))
             self._bloom_np = self.cold.rebuild_bloom(self._bloom_log2)
             self._bloom_dev = jnp.asarray(self._bloom_np)
         elif self.cold.runs:

@@ -75,6 +75,8 @@ STAGES = (
     "grow",             # ... its table growth check
     "dispatch",         # ... its jitted commit call(s)
     "full_sync",        # ... the general route's blocking device wait
+    "cold_resolve",     # ... a FLAG_COLD batch's flagged ids, resolved exactly
+    "cold_rehydrate",   # ...... its true cold rows back into the hot table
     "index_append",     # ... its secondary-index maintenance
     "merkle_refresh",   # ... touched-path leaf->root update kernels
     "unshard",          # machine: --shards' canonical copy, rebuilt for a read
@@ -89,6 +91,13 @@ STAGES = (
     "checkpoint_d2h",   # ... every table column copied to the host
     "checkpoint_digest",  # ... the ledger's digest (a device program + wait)
     "checkpoint_write",  # replica: forest files, fsync, superblock (bg thread)
+    "cold_evict",       # machine: a tier eviction, whole (serving thread)
+    "cold_threshold",   # ... the timestamp that halves the hot window
+    "cold_extract",     # ... the leaving rows compacted and packed (device)
+    "cold_fetch",       # ... those rows to the host, one fetch
+    "cold_spill",       # ... sorted by id, written, fsynced: the run file
+    "cold_rehash",      # ... the hot table rebuilt without them (device)
+    "cold_filter",      # ... their ids into the filter, and its upload
     "loop_wait",        # bus: the event loop asleep in its selector
 )
 

@@ -13,18 +13,36 @@ stores ids, not rows).  So:
   without them.
 - A device-resident BLOOM FILTER over all cold ids rides along with every
   commit dispatch: a lane whose id (or pending_id) misses the hot table but
-  hits the filter sets FLAG_COLD and the kernel applies NOTHING.  The host
-  then resolves the batch's ids against the cold store exactly — cold
-  PENDINGS are rehydrated into the hot table — and re-dispatches with a
-  per-lane ``cold_checked`` mask so Bloom false positives cannot loop.
+  hits the filter sets FLAG_COLD and the kernel applies NOTHING; the
+  per-lane mask of those hits comes back with the flags.  The host then
+  resolves the FLAGGED lanes' ids against the cold store exactly
+  (``ColdStore.lookup_arrays``: one vectorised binary search a run) — every
+  true cold row is rehydrated into the hot table (``rehydrate``, one
+  program of the batch's shape) — and re-dispatches the whole batch with a
+  per-lane ``cold_checked`` mask so Bloom false positives cannot loop.  A
+  false positive so costs one more dispatch of the whole batch, which is
+  why the filter is sized per BATCH and not per id (docs/deploy.md).
   No false negatives: every cold id is in the filter, so exists-precedence
   stays exact.
+- The filter's SHAPE is fixed at start (``start --cold-bloom-log2``): it is
+  an argument of the general commit program, so a growth recompiles that
+  program inside a request.  Past its design load (12 bits a cold id) it
+  still grows, counted (``cold.bloom.grows``) and logged once.
 - Queries and lookups resolve missing rows from the cold store by id on the
-  host (binary search per run).
+  host (the same vectorised search).
 
-Eviction happens at CHECKPOINT boundaries so crash-replay determinism holds
-(replay from a checkpoint starts from the post-eviction state; the runs
-written at eviction become durable with the same checkpoint).
+WHEN an eviction runs (``machine.evict_cold``), always on the serving thread
+and always BETWEEN two batches, never inside one: (a) in a commit's growth
+check, before the batch is staged, when the batch would take the hot table
+past load 0.5 at its ceiling (``_grow_if_needed``: the usual site under
+load); (b) right after a committed batch and at a checkpoint's capture
+(``_maybe_evict_between_batches``), when rehydrated rows have taken the
+table there.  Where it falls is a pure function of the committed op stream,
+so crash replay and every replica evict at the same op.  The run file is
+written and fsynced before the eviction returns; a checkpoint's
+``cold_manifest`` then makes it part of the durable state.  What it holds
+the serving thread for, span by span, is in docs/tracing.md
+(``cold_evict``).
 """
 
 from __future__ import annotations
@@ -42,6 +60,7 @@ from .. import types
 from ..utils.fs import atomic_write
 from ..vsr.checksum import checksum as _checksum
 from . import hash_table as ht
+from . import staging
 from . import state_machine as sm
 
 BLOOM_HASHES = 4
@@ -100,6 +119,9 @@ def bloom_add_host(bloom_np: np.ndarray, id_lo: np.ndarray, id_hi: np.ndarray) -
     h2 = mix64(id_hi ^ np.uint64(0x9E3779B97F4A7C15), id_lo) | np.uint64(1)
     for i in range(BLOOM_HASHES):
         pos = (h1 + np.uint64(i) * h2) & np.uint64(n_bits - 1)
+        # numpy >= 1.25 runs `ufunc.at` through an indexed inner loop: 82 ns
+        # a position at 4 x 4.2 M of them into a 64 MB filter, all of it
+        # cache misses (a pass a bit over a byte view took five times that).
         np.bitwise_or.at(
             bloom_np, (pos >> np.uint64(5)).astype(np.int64),
             (np.uint32(1) << (pos & np.uint64(31)).astype(np.uint32)),
@@ -278,13 +300,53 @@ class ColdStore:
                 return np.asarray(run[left])
         return None
 
+    def lookup_arrays(
+        self, id_lo: np.ndarray, id_hi: np.ndarray
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """``(found bool[n], rows TRANSFER_DTYPE[n])`` for n ids at once,
+        newest run first as ``lookup``: one binary search a run over its two
+        key columns, every id advancing together (``log2(len(run))`` steps of
+        a few vector operations; no Python loop over the ids).  A row that
+        was not found is zero."""
+        id_lo = np.ascontiguousarray(id_lo, dtype=np.uint64)
+        id_hi = np.ascontiguousarray(id_hi, dtype=np.uint64)
+        n = len(id_lo)
+        found = np.zeros(n, dtype=bool)
+        rows = np.zeros(n, dtype=types.TRANSFER_DTYPE)
+        for run in reversed(self.runs):
+            todo = np.flatnonzero(~found)
+            if not len(todo):
+                break
+            lo_col, hi_col = run["id_lo"], run["id_hi"]
+            q_lo, q_hi = id_lo[todo], id_hi[todo]
+            left = np.zeros(len(todo), dtype=np.int64)
+            right = np.full(len(todo), len(run), dtype=np.int64)
+            while True:
+                open_ = left < right
+                if not open_.any():
+                    break
+                mid = (left + right) >> 1
+                at = np.where(open_, mid, 0)
+                m_lo, m_hi = lo_col[at], hi_col[at]
+                less = (m_hi < q_hi) | ((m_hi == q_hi) & (m_lo < q_lo))
+                left = np.where(open_ & less, mid + 1, left)
+                right = np.where(open_ & ~less, mid, right)
+            at = np.minimum(left, len(run) - 1)
+            hit = (left < len(run)) & (lo_col[at] == q_lo) & (
+                hi_col[at] == q_hi)
+            if hit.any():
+                rows[todo[hit]] = run[at[hit]]
+                found[todo[hit]] = True
+        return found, rows
+
     def lookup_many(self, ids: List[Tuple[int, int]]) -> Dict[Tuple[int, int], np.void]:
-        out = {}
-        for lo, hi in ids:
-            row = self.lookup(lo, hi)
-            if row is not None:
-                out[(lo, hi)] = row
-        return out
+        if not ids:
+            return {}
+        found, rows = self.lookup_arrays(
+            np.array([lo for lo, _hi in ids], dtype=np.uint64),
+            np.array([hi for _lo, hi in ids], dtype=np.uint64),
+        )
+        return {ids[i]: rows[i] for i in np.flatnonzero(found)}
 
     def rebuild_bloom(self, bits_log2: int) -> np.ndarray:
         bloom = np.zeros(((1 << bits_log2) // 32,), np.uint32)
@@ -387,64 +449,172 @@ class ColdStore:
 # ---------------------------------------------------------------------------
 
 
+def size_class(count: int, floor: int = 1) -> int:
+    """The static ``k`` of ``extract_evicted`` and ``drop_evicted`` for
+    ``count`` rows: the power of two at or above it (at least ``floor``), so
+    that a deployment's evictions share their programs whatever each one's
+    exact counts."""
+    return max(floor, 1 << max(0, count - 1).bit_length())
+
+
+def _live(table: ht.Table) -> jax.Array:
+    return ((table.key_lo != 0) | (table.key_hi != 0)) & ~table.tombstone
+
+
 @functools.partial(jax.jit, static_argnames=("frac_num", "frac_den"))
-def eviction_threshold(table: ht.Table, frac_num: int, frac_den: int) -> jax.Array:
-    """Timestamp T such that ~frac of the live rows have ts <= T."""
-    live = ((table.key_lo != 0) | (table.key_hi != 0)) & ~table.tombstone
+def eviction_threshold(
+    table: ht.Table, frac_num: int, frac_den: int
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(T, leaving, live)``: the timestamp T such that ~frac of the live
+    rows have ts <= T, how many live rows have (what the extract will
+    compact), and how many rows are live (the rest is what the rehash
+    keeps): both programs' size classes are chosen from the two counts on
+    the host.
+
+    T is the ``k``-th smallest live timestamp, ``k = count * frac`` (what
+    sorting them and reading ``order[k]`` gave), found by bisection on the
+    VALUE: the least T with more than ``k`` timestamps at or under it, 64
+    counting passes over the column.  No sort: the v5e compiler takes over a
+    minute for one of 2^24 rows, and the sort itself longer than 64 passes."""
+    live = _live(table)
     ts = jnp.where(live, table.cols["timestamp"], jnp.uint64(0xFFFFFFFFFFFFFFFF))
-    order = jnp.sort(ts)
     k = (table.count * jnp.uint64(frac_num)) // jnp.uint64(frac_den)
     k = jnp.minimum(k, jnp.uint64(table.capacity - 1))
-    return order[k.astype(jnp.int64)]
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = lo + ((hi - lo) >> jnp.uint64(1))
+        enough = jnp.sum((ts <= mid).astype(jnp.uint64)) > k
+        return jnp.where(enough, lo, mid + jnp.uint64(1)), jnp.where(
+            enough, mid, hi)
+
+    threshold, _ = jax.lax.fori_loop(
+        0, 64, halve, (jnp.uint64(0), jnp.uint64(0xFFFFFFFFFFFFFFFF)))
+    leaving = jnp.sum((live & (ts <= threshold)).astype(jnp.uint64))
+    return threshold, leaving, jnp.sum(live.astype(jnp.uint64))
+
+
+def _compacted(table: ht.Table, mask: jax.Array, k: int):
+    """The rows of the slots ``mask`` sets, in slot order, in the first lanes
+    of ``k``: ``(lane holds a row bool[k], {column: [k]})`` with ``id_lo`` /
+    ``id_hi`` for the key; zero beyond the count.  A running count places
+    each row (``ht.compact_lanes``); no argsort."""
+    idx = ht.compact_lanes(mask, k)
+    held = idx < table.capacity
+    at = jnp.where(held, idx, 0)
+    cols = dict(table.cols, id_lo=table.key_lo, id_hi=table.key_hi)
+    return held, {
+        name: jnp.where(held, col[at], jnp.zeros((), col.dtype))
+        for name, col in cols.items()
+    }
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def extract_evicted(table: ht.Table, threshold_ts: jax.Array, k: int):
-    """Compact the rows with ts <= threshold into the first ``k`` lanes.
+    """Compact the rows with ts <= threshold into the first ``k`` lanes, in
+    slot order, packed as ``ops/staging.py`` packs a batch of transfers.
 
-    Returns (count, key_lo[k], key_hi[k], cols{...}[k]); the caller pulls
-    these to the host (rare, amortized) and then rebuilds the table."""
-    live = ((table.key_lo != 0) | (table.key_hi != 0)) & ~table.tombstone
-    evict = live & (table.cols["timestamp"] <= threshold_ts)
-    order = jnp.argsort(~evict)  # evicted rows first, stable
-    idx = order[:k]
-    n = jnp.sum(evict.astype(jnp.uint64))
-    sel = jnp.arange(k, dtype=jnp.uint64) < n
-    out_cols = {
-        name: jnp.where(sel, col[idx], jnp.zeros((), col.dtype))
-        for name, col in table.cols.items()
-    }
+    Returns ``(count, cols64 uint64[14, k], cols32 uint32[5, k])``: the
+    TRANSFER_DTYPE fields by staged width in the dtype's order, zero beyond
+    ``count``.  The caller pulls the two buffers in ONE ``device_get``
+    (``rows_to_numpy``) and then rebuilds the table."""
+    evict = _live(table) & (table.cols["timestamp"] <= threshold_ts)
+    held, rows = _compacted(table, evict, k)
+    wide, narrow = staging.staged_names(types.TRANSFER_DTYPE)
+
+    def packed(names, dtype):
+        return jnp.stack([rows[name].astype(dtype) for name in names])
+
     return (
-        n,
-        jnp.where(sel, table.key_lo[idx], 0),
-        jnp.where(sel, table.key_hi[idx], 0),
-        out_cols,
+        jnp.sum(held.astype(jnp.uint64)),
+        packed(wide, jnp.uint64), packed(narrow, jnp.uint32),
     )
 
 
-@jax.jit
-def drop_evicted(table: ht.Table, threshold_ts: jax.Array) -> ht.Table:
+# Lanes a trip of drop_evicted's write: a scatter's executable grows with its
+# indices on a v5e (35 scatters of 2^22 indices: 17 MB in the compile cache;
+# of 2^19 in a loop: 1.7), and so does its run time, from a floor of 0.76 ms
+# into a column of 2^24 slots.
+_WRITE_LANES = 1 << 19
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def drop_evicted(table: ht.Table, threshold_ts: jax.Array, k: int) -> ht.Table:
     """Rebuild the hot table without the evicted rows (fresh rehash — no
-    tombstone debt)."""
-    live = ((table.key_lo != 0) | (table.key_hi != 0)) & ~table.tombstone
-    keep = live & (table.cols["timestamp"] > threshold_ts)
-    fresh = ht.make_table(
-        table.capacity, {k: v.dtype for k, v in table.cols.items()}
-    )
-    claimed, _ = ht.claim_slots(
-        fresh, table.key_lo, table.key_hi, keep, table.capacity
-    )
-    return ht.write_rows(
-        fresh, table.key_lo, table.key_hi, claimed, keep, table.cols
+    tombstone debt): the kept rows (at most ``k``) compacted in slot order,
+    claimed into an empty table of the same capacity in ONE claim and
+    written ``_WRITE_LANES`` at a time.  The claim is
+    ``ht.claim_slots_empty``: claim_slots' protocol with the lanes in the
+    order the slots had, so each row lands where one claim over all the
+    slots' lanes put it, without that claim's sort of 2^24 lanes (207 s to
+    compile for a v5e; PERF.md section 5 has what the eviction's programs
+    cost to compile and to run)."""
+    keep = _live(table) & (table.cols["timestamp"] > threshold_ts)
+    held, rows = _compacted(table, keep, k)
+    rows["claimed"] = ht.claim_slots_empty(
+        table.capacity, rows["id_lo"], rows["id_hi"], held)
+    rows["held"] = held
+    chunk = min(k, _WRITE_LANES)
+
+    def write_chunk(i, fresh):
+        part = {
+            name: jax.lax.dynamic_slice_in_dim(col, i * chunk, chunk)
+            for name, col in rows.items()
+        }
+        return ht.write_rows(
+            fresh, part["id_lo"], part["id_hi"], part["claimed"],
+            part["held"], {name: part[name] for name in table.cols},
+        )
+
+    kept = jnp.sum(held.astype(jnp.int32))
+    return jax.lax.fori_loop(
+        0, (kept + chunk - 1) // chunk, write_chunk,
+        ht.make_table(
+            table.capacity, {n: v.dtype for n, v in table.cols.items()}),
     )
 
 
-def rows_to_numpy(n, key_lo, key_hi, cols) -> np.ndarray:
-    """Assemble extracted device rows into a host TRANSFER_DTYPE array.
-    Slices ON DEVICE before the pull: an eviction transfers O(evicted)
-    bytes, not O(hot-window capacity)."""
-    count = int(n)
-    host = {name: np.asarray(col[:count]) for name, col in cols.items()}
-    host["id_lo"] = np.asarray(key_lo[:count])
-    host["id_hi"] = np.asarray(key_hi[:count])
-    return types.from_soa(host, types.TRANSFER_DTYPE)
+def rows_to_numpy(n, cols64, cols32) -> np.ndarray:
+    """Assemble ``extract_evicted``'s packed rows into a host TRANSFER_DTYPE
+    array: one fetch of both buffers (19 columns went one by one), cut to
+    the count on the host."""
+    count, host64, host32 = jax.device_get(  # tblint: ignore[host-sync] eviction
+        (n, cols64, cols32)
+    )
+    count = int(count)
+    wide, narrow = staging.staged_names(types.TRANSFER_DTYPE)
+    rows = np.zeros(count, dtype=types.TRANSFER_DTYPE)
+    for i, name in enumerate(wide):
+        rows[name] = host64[i, :count]
+    for i, name in enumerate(narrow):
+        rows[name] = host32[i, :count]
+    return rows
+
+
+def rehydrate_impl(
+    table: ht.Table, batch: Dict[str, jax.Array], count: jax.Array,
+    _timestamp: jax.Array, max_probe: int,
+) -> Tuple[ht.Table, jax.Array]:
+    """Insert the first ``count`` cold rows of ``batch`` (whole rows, their
+    own timestamps) into the hot table, but for those already hot (an
+    earlier rehydration: a key inserted twice would break the table's
+    uniqueness).  Returns ``(table', rows inserted)``; an insert that ran
+    out of probes sets ``table'.probe_overflow``."""
+    lanes = batch["id_lo"].shape[0]
+    valid = jnp.arange(lanes, dtype=jnp.int32) < count.astype(jnp.int32)
+    look = ht.lookup(table, batch["id_lo"], batch["id_hi"], max_probe)
+    fresh = valid & ~look.found
+    cols = {name: batch[name].astype(dt) for name, dt in sm.TRANSFER_COLS.items()}
+    out, _ = ht.insert(
+        table, batch["id_lo"], batch["id_hi"], fresh, cols, max_probe
+    )
+    return out, jnp.sum(fresh.astype(jnp.int32))
+
+
+# ``(table, *staging.stage_batch(rows, lanes, 0))``: one upload, one program
+# of the batch's shape whatever the number of rows.
+rehydrate = jax.jit(
+    staging.staged(rehydrate_impl, types.TRANSFER_DTYPE),
+    donate_argnames=("ledger",),  # the table: `staged` names its first operand
+    static_argnames=("max_probe",),
+)

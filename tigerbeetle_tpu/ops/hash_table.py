@@ -120,7 +120,7 @@ def _window(n: int) -> int:
     return min(n, max(64, n // 8))
 
 
-def _compact_lanes(open_lanes: jax.Array, window: int) -> jax.Array:
+def compact_lanes(open_lanes: jax.Array, window: int) -> jax.Array:
     """int32[window]: the indices of the first ``window`` set lanes of
     ``open_lanes``, in lane order; fill lanes carry ``n`` (out of range:
     dropped by scatters).  ``jnp.nonzero(size=window, fill_value=n)`` by
@@ -206,7 +206,7 @@ def lookup(
     # max_probe, in which case the narrow loop runs no trip, the window is
     # full of open lanes and overflow reads true, as it must).
     with jax.named_scope("tb/lookup_compact"):
-        idx = _compact_lanes(~done, window)
+        idx = compact_lanes(~done, window)
         active = idx < n
         idx_safe = jnp.where(active, idx, 0)
         home_w, lo_w, hi_w = home[idx_safe], key_lo[idx_safe], key_hi[idx_safe]
@@ -360,7 +360,7 @@ def claim_slots(
     # the wide phase exited on overflow, in which case the narrow cond is
     # already false and the truncation is inert).  Fill lanes carry index
     # n: inactive in the narrow body, dropped by its scatters.
-    idx = _compact_lanes(unplaced, window)
+    idx = compact_lanes(unplaced, window)
     active = idx < n
     idx_safe = jnp.where(active, idx, 0)
     home_w = home[idx_safe]
@@ -399,6 +399,72 @@ def claim_slots(
          claimed, overflow, next_rank),
     )
     return claimed.astype(jnp.uint64), overflow
+
+
+def claim_slots_empty(
+    capacity: int,
+    key_lo: jax.Array,
+    key_hi: jax.Array,
+    insert_mask: jax.Array,
+    hash_shift: int = 0,
+) -> jax.Array:
+    """claim_slots into an EMPTY table of ``capacity`` slots, for lanes by
+    the million (a rehash: ops/cold.drop_evicted): the same protocol, so the
+    same slots, without the sort.
+
+    claim_slots ranks each lane within its home group by one sort of all the
+    lanes, which a batch pays gladly and a rehash cannot: the v5e compiler
+    takes 207 s over the sort of 2^24 lanes and 100 s over one of 2^16.
+    Here the winner of a contested slot, the lowest lane, is found by a
+    scatter-min of the bidders' lane numbers into a per-slot ``owner``
+    column, which is the occupancy as well (the table starts empty), and
+    since every unplaced lane advances at every trip the probe offset is
+    the trip's number.  Wide phase, one compaction, narrow phase, as in
+    claim_slots.  Returns the claimed slots (``capacity`` for a masked
+    lane); the table's load is at most 0.5, so every lane is placed."""
+    n = key_lo.shape[0]
+    assert n < 0xFFFFFFFF
+    free = jnp.uint32(0xFFFFFFFF)
+    slot_mask = jnp.uint32(capacity - 1)
+    home = (
+        (mix64(key_lo, key_hi) >> jnp.uint64(hash_shift))
+        & jnp.uint64(capacity - 1)
+    ).astype(jnp.uint32)
+
+    def probe(home, lane, state, stop):
+        def open_lanes(state):
+            _, trip, unplaced, _ = state
+            return (jnp.sum(unplaced) > stop) & (trip < capacity)
+
+        def trip_body(state):
+            owner, trip, unplaced, claimed = state
+            cur = (home + trip) & slot_mask
+            bid = unplaced & (owner[cur] == free)
+            owner = owner.at[jnp.where(bid, cur, capacity)].min(
+                lane, mode="drop")
+            win = bid & (owner[cur] == lane)
+            return (owner, trip + jnp.uint32(1), unplaced & ~win,
+                    jnp.where(win, cur, claimed))
+
+        return jax.lax.while_loop(open_lanes, trip_body, state)
+
+    window = _window(n)
+    owner, trip, unplaced, claimed = probe(
+        home, jnp.arange(n, dtype=jnp.uint32),
+        (jnp.full((capacity,), free), jnp.uint32(0), insert_mask,
+         jnp.full((n,), capacity, jnp.uint32)),
+        window,
+    )
+    idx = compact_lanes(unplaced, window)
+    active = idx < n
+    at = jnp.where(active, idx, 0)
+    _, _, _, claimed_w = probe(
+        home[at], idx.astype(jnp.uint32),
+        (owner, trip, active, jnp.full((window,), capacity, jnp.uint32)),
+        0,
+    )
+    return claimed.at[jnp.where(active, idx, n)].set(
+        claimed_w, mode="drop").astype(jnp.uint64)
 
 
 def write_rows(

@@ -10,7 +10,9 @@ Structure: per side (debit / credit) a pyramid of sorted runs; level k holds
 B·2^k entries sorted by (account_hi, account_lo, timestamp), B = one batch of
 lanes.  Each committed batch appends one sorted run at level 0; when a level
 is occupied the runs carry upward binary-counter style, each merge one
-concat+sort of static shape (compiled once per level).  Amortized append cost
+concat+sort of static shape (compiled once per level; above level 9 the
+sort stops there and the levels above are merged in by compare-exchange
+passes, _merge2: a sort's executable grows with its rows).  Amortized append cost
 is O(log N) sorts of geometric sizes; a query binary-searches every level
 (static unroll) and gathers a bounded candidate window, so query cost is
 O(levels · K) — FLAT in table capacity.
@@ -160,8 +162,70 @@ def _merge(levels: List[Dict[str, jax.Array]]) -> Dict[str, jax.Array]:
         return _sort_level(cat)
 
 
+# A carry into a level above this one sorts no further than it: the levels
+# above are merged in, two sorted levels at a time (_merge2).
+_SORT_LEVELS = 9
+
+
+def _merge2(a: Dict[str, jax.Array], b: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """Two levels of n rows each, both in _sort_level's order, as one of 2n:
+    a bitonic merge.  ``a`` then ``b`` reversed is a bitonic sequence, and
+    log2(2n) compare-exchange passes at strides n, n/2, ..., 1 order it; a
+    pass is elementwise over the five columns rolled by its stride, and the
+    passes are the trips of ONE loop, so the program is a pass long.
+
+    Why the top levels do not sort: a sort's executable and its compile time
+    grow with its rows on a v5e (the level-10 merge as a sort: 27.8 MB in
+    the compile cache and about a minute inside the request that first
+    fills the level, after 25.7 MB and as long for level 9), and a level
+    above the ninth is filled once in 1,024 requests.  This program, for
+    level 10: 0.30 MB, a second to compile, 94 ms for its 2^23 rows (the
+    passes unrolled at static strides: 10.8 MB, 8 s, 100 ms; my chip run,
+    PR 48, call `p48`).  Equal keys are equal rows (a timestamp is unique a
+    side; sentinel rows are all ones), so the passes, which are not stable,
+    give what the stable sort gives."""
+    def less(p, q):
+        return (p["acct_hi"] < q["acct_hi"]) | ((p["acct_hi"] == q["acct_hi"]) & (
+            (p["acct_lo"] < q["acct_lo"]) | (
+                (p["acct_lo"] == q["acct_lo"]) & (p["ts"] < q["ts"]))))
+
+    with jax.named_scope("tb/index_merge"):
+        rows = {name: jnp.concatenate([a[name], b[name][::-1]]) for name in COLS}
+        n = rows["ts"].shape[0]
+        lane = jnp.arange(n, dtype=jnp.uint32)
+
+        def exchange(i, rows):
+            # Lane j is exchanged with lane j ^ stride: the pair's first
+            # keeps the lesser row, its second the greater.
+            stride = (n // 2) >> i
+            second = (lane & stride.astype(jnp.uint32)) != 0
+            other = {
+                name: jnp.where(
+                    second, jnp.roll(col, stride), jnp.roll(col, -stride))
+                for name, col in rows.items()
+            }
+            take = less(other, rows) ^ second
+            return {
+                name: jnp.where(take, other[name], col)
+                for name, col in rows.items()
+            }
+
+        return jax.lax.fori_loop(0, n.bit_length() - 1, exchange, rows)
+
+
 _merge_jit = jax.jit(_merge)
+_merge2_jit = jax.jit(_merge2)
 _sort_level_jit = jax.jit(_sort_level)
+
+
+def _carry(run: Dict[str, jax.Array], levels: List[Dict[str, jax.Array]]):
+    """``run`` and the occupied ``levels`` below level k = len(levels), as
+    level k's rows."""
+    low = min(len(levels), _SORT_LEVELS)
+    out = _merge_jit([run] + levels[:low])
+    for lvl in levels[low:]:
+        out = _merge2_jit(out, lvl)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("acct_field", "capacity"))
@@ -337,8 +401,8 @@ class TransferIndex:
             self.dr_levels[0] = dr_run
             self.cr_levels[0] = cr_run
         else:
-            self.dr_levels[k] = _merge_jit([dr_run] + self.dr_levels[:k])
-            self.cr_levels[k] = _merge_jit([cr_run] + self.cr_levels[:k])
+            self.dr_levels[k] = _carry(dr_run, self.dr_levels[:k])
+            self.cr_levels[k] = _carry(cr_run, self.cr_levels[:k])
             for j in range(k):
                 cap = self.base << j
                 self.dr_levels[j] = _sentinel_level(cap)

@@ -24,7 +24,7 @@ from ..obs.metrics import registry as _obs
 
 
 @functools.lru_cache(maxsize=None)
-def _staged_names(dtype: np.dtype) -> Tuple[tuple, tuple]:
+def staged_names(dtype: np.dtype) -> Tuple[tuple, tuple]:
     """A wire dtype's fields by staged width: the ``uint64`` columns, and the
     narrower ones (``uint32`` on the device: ``types.to_soa``'s widening)."""
     wide = tuple(n for n in dtype.names if dtype.fields[n][0] == np.uint64)
@@ -33,7 +33,7 @@ def _staged_names(dtype: np.dtype) -> Tuple[tuple, tuple]:
 
 def _fill(cols64: np.ndarray, cols32: np.ndarray, batch: np.ndarray) -> None:
     n = len(batch)
-    wide, narrow = _staged_names(batch.dtype)
+    wide, narrow = staged_names(batch.dtype)
     for i, name in enumerate(wide):
         cols64[i, :n] = batch[name]
     for i, name in enumerate(narrow):
@@ -62,7 +62,7 @@ def stage_batch(batch: np.ndarray, lanes: int, timestamp: int, sharding=None):
     reads it (on XLA-CPU ``device_put`` may alias a numpy buffer zero-copy)."""
     n = len(batch)
     assert n <= lanes, "batch exceeds configured lanes"
-    wide, narrow = _staged_names(batch.dtype)
+    wide, narrow = staged_names(batch.dtype)
     cols64 = np.zeros((len(wide), lanes), np.uint64)
     cols32 = np.zeros((len(narrow), lanes), np.uint32)
     _fill(cols64, cols32, batch)
@@ -83,7 +83,7 @@ def stage_group(
     short stack."""
     k = len(batches)
     assert 0 < k <= rows
-    wide, narrow = _staged_names(batches[0].dtype)
+    wide, narrow = staged_names(batches[0].dtype)
     cols64 = np.zeros((rows, len(wide), lanes), np.uint64)
     cols32 = np.zeros((rows, len(narrow), lanes), np.uint32)
     meta = np.zeros((2, rows), np.uint64)
@@ -99,7 +99,7 @@ def stage_group(
 def unstage(dtype: np.dtype, cols64, cols32, meta):
     """Inside a program: ``stage_batch``'s operands back as (the batch's
     columns by name, count, timestamp), what the kernels' bodies take."""
-    wide, narrow = _staged_names(dtype)
+    wide, narrow = staged_names(dtype)
     batch = {name: cols64[i] for i, name in enumerate(wide)}
     batch.update({name: cols32[i] for i, name in enumerate(narrow)})
     return batch, meta[0], meta[1]
@@ -108,7 +108,7 @@ def unstage(dtype: np.dtype, cols64, cols32, meta):
 def column_row(dtype: np.dtype, name: str) -> int:
     """The row of ``cols64`` (or of ``cols32``, for a narrow field) that holds
     the column ``name``: the packed order is this module's to know."""
-    wide, narrow = _staged_names(dtype)
+    wide, narrow = staged_names(dtype)
     return wide.index(name) if name in wide else narrow.index(name)
 
 

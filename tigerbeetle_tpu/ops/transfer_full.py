@@ -159,6 +159,9 @@ class GatherCtx(NamedTuple):
     postedT_val: jax.Array
     probe_grow: jax.Array  # uint32 scalar: FLAG_GROW_*/FLAG_COLD bits
     accounts_capacity: jax.Array  # uint64 scalar: GLOBAL slot-space bound
+    # uint8[N] under the cold tier's filter (else None): the lanes FLAG_COLD
+    # is about, bit 0 the lane's id, bit 1 its pending_id.
+    cold_lanes: jax.Array = None
 
 
 class ApplyPlan(NamedTuple):
@@ -638,6 +641,7 @@ def build_gather_ctx(
     # the HOT table but hitting the cold Bloom filter needs host resolution
     # (exact exists-precedence demands the cold row). cold_checked lanes were
     # already certified not-cold by the host, so false positives terminate.
+    cold_lanes = None
     if bloom is not None:
         from .cold import bloom_check_impl
 
@@ -645,17 +649,28 @@ def build_gather_ctx(
             cold_checked if cold_checked is not None
             else jnp.zeros((n,), jnp.bool_)
         )
-        cold_ids = (
-            valid & ~ex_look.found & ~checked
-            & bloom_check_impl(bloom, tid.lo, tid.hi)
-        )
-        cold_pend = (
-            postvoid & ~p_found_for_cold & ~checked
-            & bloom_check_impl(bloom, pend_id.lo, pend_id.hi)
-        )
-        probe_grow = probe_grow | jnp.where(
-            jnp.any(cold_ids | cold_pend), jnp.uint32(FLAG_COLD), jnp.uint32(0)
-        )
+        with jax.named_scope("tb/full_bloom"):
+            cold_ids = (
+                valid & ~ex_look.found & ~checked
+                & bloom_check_impl(bloom, tid.lo, tid.hi)
+            )
+            # Which of a lane's two ids hit: bit 0 its id, bit 1 its
+            # pending_id.  The host resolves exactly these (FLAG_COLD).
+            cold_lanes = cold_ids.astype(jnp.uint8)
+            if has_postvoid:
+                # (No post or void lane, no pending id to look for: the
+                # second walk of the filter compiles away.)
+                cold_pend = (
+                    postvoid & ~p_found_for_cold & ~checked
+                    & bloom_check_impl(bloom, pend_id.lo, pend_id.hi)
+                )
+                cold_lanes = cold_lanes | (
+                    cold_pend.astype(jnp.uint8) << jnp.uint8(1)
+                )
+            probe_grow = probe_grow | jnp.where(
+                jnp.any(cold_lanes != 0), jnp.uint32(FLAG_COLD),
+                jnp.uint32(0),
+            )
 
     return GatherCtx(
         ex_found=ex_found, e_tab=e_tab,
@@ -664,6 +679,7 @@ def build_gather_ctx(
         postedT_found=postedT_found, postedT_val=postedT_val,
         probe_grow=probe_grow,
         accounts_capacity=jnp.uint64(ledger.accounts.capacity),
+        cold_lanes=cold_lanes,
     )
 
 
@@ -1290,7 +1306,9 @@ def create_transfers_full_impl(
     batch's two id columns, the INDEX_KEY_COLS of the rows it wrote (a post
     or void lane: the PENDING transfer's accounts) and the lanes it wrote
     (``sm.written_lanes``), so the host slices no staged operand and
-    uploads no mask.
+    uploads no mask.  Under the cold tier (``bloom`` given) the lanes
+    FLAG_COLD is about come just before those four (uint8[N]: bit 0 the
+    lane's id hit the filter, bit 1 its pending_id), read with the flags.
 
     flags == 0: the batch was applied and ``codes`` are the final results.
     flags != 0: NOTHING was applied (ledger' == ledger value-wise); the host
@@ -1414,13 +1432,15 @@ def create_transfers_full_impl(
         {name: ins_rows[name] for name in INDEX_KEY_COLS},
         written_lanes(plan.codes, count),
     )
+    head = (out, plan.codes, kflags)
     if use_waves:
-        wave_vec = jnp.concatenate([
+        head += (jnp.concatenate([
             plan.passes.reshape(1), plan.wave_bound.reshape(1),
             plan.wave_hist,
-        ])
-        return (out, plan.codes, kflags, wave_vec) + index_feed
-    return (out, plan.codes, kflags) + index_feed
+        ]),)
+    if bloom is not None:
+        head += (ctx.cold_lanes,)
+    return head + index_feed
 
 
 def _exists_regular(t, e, t_amount: U128, n) -> jax.Array:

@@ -105,7 +105,7 @@ def packed(bs, _pool):
 
 
 def _fill(cols64, cols32, b):
-    wide, narrow = staging._staged_names(b.dtype)
+    wide, narrow = staging.staged_names(b.dtype)
     n = len(b)
     for i, name in enumerate(wide):
         cols64[i, :n] = b[name]
